@@ -268,7 +268,6 @@ def backward(heads, head_grads=None, retain_graph: bool = False,
         outs_ct = cotan.pop(id(node), None)
         if outs_ct is None:
             continue
-        host_vjp = getattr(node.fn, "_host_vjp", None)
         sparse_vjp = getattr(node.fn, "_sparse_vjp", None)
         if create_graph:
             in_grads = _vjp_recorded(node, outs_ct)
@@ -277,10 +276,6 @@ def backward(heads, head_grads=None, retain_graph: bool = False,
             # gradient comes back as a parts-backed RowSparseNDArray whose
             # size scales with the batch's live rows, not the table
             in_grads = sparse_vjp(node.in_values, outs_ct)
-        elif host_vjp is not None:
-            # host-computed op (CustomOp on a backend without host-callback
-            # support): gradient runs on concrete values outside any trace
-            in_grads = host_vjp(node.in_values, outs_ct)
         else:
             primals, vjp_fn = jax.vjp(node.fn, *node.in_values)
             # fill missing cotangents with zeros of the primal out shape

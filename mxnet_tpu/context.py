@@ -76,8 +76,6 @@ class Context:
             devs = _cpu_devices()
         else:
             devs = _accel_devices()
-        if not devs:
-            raise RuntimeError("no %s devices available" % self.device_type)
         if self.device_id >= len(devs):
             # Mirror the reference's lenient behaviour: out-of-range ids only
             # fail at first use; here we fail fast with a clear message.
@@ -106,20 +104,48 @@ def _cpu_devices():
     # local (addressable) devices only: in a multi-process job
     # jax.devices() is the GLOBAL list and placing onto another process's
     # device is an error
-    try:
-        return jax.local_devices(backend="cpu")
-    except RuntimeError:
-        # Some deployments expose only the accelerator backend (no host-CPU
-        # platform registered).  cpu() then resolves to the default devices so
-        # default-context array creation still works; arrays simply live in
-        # HBM, which is semantically fine (XLA owns placement).
-        return jax.local_devices()
+    return jax.local_devices(backend="cpu")
+
+
+def _pick_accel(devs, platforms):
+    """The accelerator devices among ``devs``, or raise.
+
+    The one exception is a process that was TOLD to use the CPU
+    (``platforms``, i.e. ``jax.config.jax_platforms``, is ``"cpu"`` — what
+    ``JAX_PLATFORMS=cpu`` sets for the test suite): there the CPU is what
+    was asked for and ``mx.tpu()`` names it.  Anywhere else a missing
+    accelerator is an error, never a silent run on the host."""
+    accel = [d for d in devs if d.platform != "cpu"]
+    if accel:
+        return accel
+    if platforms == "cpu":
+        return list(devs)
+    raise RuntimeError(
+        "no accelerator device: jax sees only %r and the process was not "
+        "pinned to the CPU (JAX_PLATFORMS=cpu)" % (list(devs),))
 
 
 def _accel_devices():
-    devs = jax.local_devices()
-    non_cpu = [d for d in devs if d.platform != "cpu"]
-    return non_cpu if non_cpu else devs
+    return _pick_accel(jax.local_devices(), jax.config.jax_platforms)
+
+
+def on_tpu(*arrays) -> bool:
+    """True when the computation runs on a TPU — THE platform probe for
+    kernel dispatch (Pallas vs composed XLA) and the autotune table's
+    interpret-record refusal.
+
+    An eager op runs where its operands are committed, and a TPU machine
+    has a host CPU backend too: a model that has not been moved yet
+    (``current_context()`` defaults to ``cpu(0)``) computes there, where a
+    compiled Pallas kernel cannot go.  So concrete committed ``arrays``
+    answer for themselves; tracers — and no arrays — answer with the
+    default backend.  A backend that fails to initialise raises out of
+    here: it must not turn into a quiet dense fallback."""
+    for a in arrays:
+        if isinstance(a, jax.Array) and not isinstance(a, jax.core.Tracer) \
+                and a.committed:
+            return all(d.platform == "tpu" for d in a.devices())
+    return jax.devices()[0].platform == "tpu"
 
 
 def cpu(device_id: int = 0) -> Context:

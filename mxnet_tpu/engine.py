@@ -72,67 +72,27 @@ def _apply_env_engine_type():
 _apply_env_engine_type()
 
 
-# Persistent-cache entries that are UNSAFE to reload on jaxlib <= 0.4.36:
-# the donated-buffer train-step executables (DataParallelStep's step_fn /
-# scan_fn, and the Trainer's fused update since it gained the ZeRO
-# sharded path — sharded inputs make donation settle through a second
-# lowering, which creates the poisoned pair; the plain replicated fused
-# program never relowered and was safe).  A training loop writes TWO
-# entries for the same step (the first call lowers against fresh host
-# arrays, the donation-settled relowering against committed outputs); a
-# later process that deserializes BOTH and chains them through donation
-# computes NaN and then segfaults/aborts inside jaxlib (reproduced
-# deterministically on the CPU backend with the bert_small train step
-# and again with the dp-sharded fused update; single-entry reloads are
-# fine, the poisoned state needs the pair).  Until the runtime bug is
-# gone, these entries are purged at enable time — the step recompiles
-# once per process, everything else stays warm.
-_UNSAFE_CACHE_PREFIXES = ("jit_step_fn-", "jit_scan_fn-", "jit_fused-")
-
-
-def _purge_unsafe_entries(path):
-    """Remove known-unsafe executables from the cache dir; returns how
-    many entry files were dropped (journaled via telemetry)."""
-    n = 0
-    try:
-        for fname in os.listdir(path):
-            if fname.startswith(_UNSAFE_CACHE_PREFIXES):
-                try:
-                    os.unlink(os.path.join(path, fname))
-                    n += 1
-                except OSError:
-                    pass
-    except OSError:
-        return 0
-    if n:
-        from . import telemetry
-        telemetry.event("compilation_cache", "purged_unsafe_entries",
-                        count=n, prefixes=list(_UNSAFE_CACHE_PREFIXES))
-    return n
-
-
-def enable_compilation_cache(path=None):
+def enable_compilation_cache():
     """Persistent XLA executable cache (the TPU analogue of the
     reference's cuDNN autotune cache + graph-plan reuse): compiled
     programs are keyed by HLO and reused across PROCESSES, so repeat
     runs of benches/tests/training scripts skip their multi-second
-    compiles.  Safe to call multiple times; failures (read-only fs,
-    unsupported backend) degrade to normal compilation.
+    compiles.  Safe to call multiple times; returns the directory in
+    use.
 
-    Donated train-step executables are purged from the cache on enable
-    (see ``_UNSAFE_CACHE_PREFIXES``): reloading a donation-settled pair
-    of them is numerically wrong and then fatal on jaxlib <= 0.4.36."""
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already holds that
+    directory and no other is set here; otherwise the cache lives at the
+    fixed ``<checkout>/.jax_cache`` (the path is part of the cache key,
+    so a directory that moves never hits).  A directory that cannot be
+    created raises: a run that silently recompiles everything is not
+    the run that was asked for."""
     import jax
-    path = path or os.environ.get("MXNET_TPU_COMPILATION_CACHE")
-    if path is None:
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
         path = os.path.join(os.path.dirname(os.path.dirname(
             os.path.abspath(__file__))), ".jax_cache")
-    try:
         os.makedirs(path, exist_ok=True)
-        _purge_unsafe_entries(path)
         jax.config.update("jax_compilation_cache_dir", path)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-        return path
-    except Exception:
-        return None
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+    return path

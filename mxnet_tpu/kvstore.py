@@ -619,7 +619,6 @@ def _cross_process_packed_reducer(npacked, n, shape, dtype_str, threshold):
     from jax import lax
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
     from .gradient_compression import unpack_2bit
-    from .parallel.mesh import shard_map_compat as _shard_map
 
     nproc = jax.process_count()
     per_proc = len(jax.local_devices())
@@ -633,8 +632,8 @@ def _cross_process_packed_reducer(npacked, n, shape, dtype_str, threshold):
         dense = jax.vmap(lambda p: unpack_2bit(p, n, threshold))(allp)
         return jnp.sum(dense, axis=0).astype(dtype_str).reshape(shape)
 
-    fn = _shard_map(per_shard, mesh=mesh, in_specs=P("worker"),
-                    out_specs=P())
+    fn = jax.shard_map(per_shard, mesh=mesh, in_specs=P("worker"),
+                       out_specs=P(), check_vma=False)
     return jax.jit(fn), sharding, per_proc
 
 

@@ -5,14 +5,17 @@ The reference implements its data pipeline in C++ (recordio readers +
 this package is the TPU build's native equivalent.  pybind11 is not in the
 image, so the library exposes a C ABI and we bind it with ctypes.
 
-The shared library is compiled on first use (g++ is in the image) and
-cached next to this file; everything degrades gracefully to the pure-Python
-paths when compilation is unavailable.
+The shared library is compiled from ``mxtpu_io.cc`` on first use and cached
+next to this file (git-ignored: a checkout always builds its own).  A host
+with no ``g++`` uses the pure-Python readers; a host that HAS the compiler
+and fails to build or load the library raises — a broken native layer must
+not pass for a slow one.
 """
 from __future__ import annotations
 
 import ctypes
 import os
+import shutil
 import subprocess
 import threading
 
@@ -30,26 +33,34 @@ __all__ = ["lib", "available", "NativeRecordFile", "NativeImagePipeline"]
 
 
 def _build():
+    # per-process temp name: test workers that all find the library missing
+    # build side by side, and each must replace it with a whole file
+    tmp = os.path.join(_DIR, "libmxtpu_io.%d.tmp.so" % os.getpid())
     cmd = ["g++", "-O3", "-std=c++17", "-shared", "-fPIC", _SRC,
-           "-o", _SO + ".tmp", "-ljpeg", "-lpthread"]
+           "-o", tmp, "-ljpeg", "-lpthread"]
     subprocess.run(cmd, check=True, capture_output=True)
-    os.replace(_SO + ".tmp", _SO)
+    os.replace(tmp, _SO)
 
 
 def lib():
-    """Load (building if needed) the native library; None if unavailable."""
+    """Load (building if needed) the native library; None on a host with
+    no C++ compiler.  A failed build or load raises."""
     global _lib, _tried
     with _lock:
         if _lib is not None or _tried:
             return _lib
         _tried = True
-        try:
-            if (not os.path.exists(_SO)
-                    or os.path.getmtime(_SO) < os.path.getmtime(_SRC)):
+        if (not os.path.exists(_SO)
+                or os.path.getmtime(_SO) < os.path.getmtime(_SRC)):
+            if shutil.which("g++") is None:
+                return None
+            try:
                 _build()
-            L = ctypes.CDLL(_SO)
-        except Exception:
-            return None
+            except subprocess.CalledProcessError as e:
+                raise RuntimeError(
+                    "building %s failed:\n%s"
+                    % (_SRC, e.stderr.decode(errors="replace"))) from e
+        L = ctypes.CDLL(_SO)
         u8p = ctypes.POINTER(ctypes.c_uint8)
         L.mxtpu_rec_open.restype = ctypes.c_void_p
         L.mxtpu_rec_open.argtypes = [ctypes.c_char_p]

@@ -81,19 +81,13 @@ def _make_op_func(op):
                 return op.fn(key, *full_args, **kw)
             return op.fn(*full_args, **kw)
 
-        factory = getattr(op.fn, "_host_vjp_factory", None)
         sfactory = getattr(op.fn, "_sparse_vjp_factory", None)
-        if factory is not None or sfactory is not None:
+        if sfactory is not None:
             static_kwargs = {k: v for k, v in kwargs.items()
                              if k not in kw_keys}
-            if factory is not None:
-                hook = factory(static_kwargs)
-                if hook is not None:   # only on callback-less backends
-                    fn._host_vjp = hook
-            if sfactory is not None:
-                shook = sfactory(static_kwargs)
-                if shook is not None:  # only when sparse_grad requested
-                    fn._sparse_vjp = shook
+            shook = sfactory(static_kwargs)
+            if shook is not None:  # only when sparse_grad requested
+                fn._sparse_vjp = shook
         return invoke_fn(fn, arrays, name=op.name, out=out,
                          n_outputs=op.num_outputs, ctx=ctx,
                          record=op.differentiable)

@@ -10,7 +10,7 @@ these on the accelerator: multibox_target.cu, multi_proposal.cu).
 
 TPU-native mapping: all four ops are pure jnp/lax compositions with
 static shapes, so SSD/RPN train steps jit into one XLA program with NO
-host callbacks (this platform does not support them anyway):
+host callbacks:
 
 * the greedy sequential parts (bipartite matching, NMS sweeps) become
   ``lax.scan``/``fori_loop`` over score-sorted candidates with masked

@@ -21,11 +21,7 @@ from jax import lax
 
 from .registry import register, alias
 
-try:
-    from jax.ad_checkpoint import checkpoint_name as _remat_name
-except ImportError:  # older jax: names unused, identity keeps semantics
-    def _remat_name(x, name):
-        return x
+from jax.ad_checkpoint import checkpoint_name as _remat_name
 
 
 # ---------------------------------------------------------------------------
@@ -294,16 +290,9 @@ def batch_norm_add_relu(data, residual, gamma, beta, moving_mean, moving_var,
 
 
 def _bound_axis_names():
-    """Mapped-context axis names currently in scope (None if the
-    introspection API is unavailable in this jax version)."""
-    try:
-        from jax._src.core import get_axis_env
-    except ImportError:
-        return None
-    try:
-        return tuple(get_axis_env().axis_sizes)
-    except Exception:
-        return None
+    """Mapped-context axis names currently in scope."""
+    from jax._src.core import get_axis_env
+    return tuple(get_axis_env().axis_sizes)
 
 
 @register("_contrib_SyncBatchNorm", num_outputs=3, needs_training=True,
@@ -331,14 +320,7 @@ def sync_batch_norm(data, gamma, beta, moving_mean, moving_var,
         mean = jnp.mean(data, axis=ax, dtype=jnp.float32)
         sq = jnp.mean(jnp.square(data), axis=ax, dtype=jnp.float32)
         bound = _bound_axis_names()
-        if bound is None:
-            # no introspection: best effort — sync when the axis resolves
-            try:
-                mean = lax.pmean(mean, key)
-                sq = lax.pmean(sq, key)
-            except NameError:
-                pass
-        elif key in bound:
+        if key in bound:
             mean = lax.pmean(mean, key)
             sq = lax.pmean(sq, key)
         elif bound:

@@ -30,6 +30,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from .. import context as _context
 from .registry import register
 
 __all__ = ["flash_attention", "flash_attention_bshd",
@@ -47,47 +48,97 @@ _LANES = 128
 #  * _DENSE_MIN_SEQ: below this, one XLA dot covers the whole score
 #    matrix and the pallas grid/DMA setup costs more than it saves —
 #    dense must win, so the dispatcher never sends these to a kernel;
-#  * _VMEM_CLAMP: budget for a kernel invocation's VMEM working set
-#    (blocks + fp32 score tile + scratch), leaving headroom out of the
-#    ~16 MiB/core for Mosaic's double buffering.
+#  * _VMEM_CLAMP: budget for a kernel invocation's VMEM working set as
+#    _fwd_vmem_bytes / _bwd_vmem_bytes estimate it, out of the 16 MiB
+#    scoped limit.  The headroom is not only Mosaic's: XLA places small
+#    operands of the surrounding program in VMEM too, so the same kernel
+#    is refused earlier inside a train step than alone.
 _SHORT_SEQ_MAX_TK = 1024
 _DENSE_MIN_SEQ = 128
 _VMEM_CLAMP = 12 * 1024 * 1024
+_BWD_MIN_BLOCK_Q = 128
 
 
 def _fwd_vmem_bytes(block_q, block_k, Dp, itemsize):
     """Forward working set of one grid step: q/o blocks, k/v blocks, the
-    fp32 score tile (exp/normalize reuse its buffer — ONE live copy),
-    and the m/l/acc scratch rows."""
+    m/l/acc scratch rows, and TWO fp32 (block_q, block_k) tiles — the
+    score tile (exp/normalize reuse its buffer) plus the iota/compare/
+    select temporaries of the masked variants, which every public entry
+    can reach (causal, kv_lens, segment ids) and which the plan cannot
+    tell apart.  The v5e compiler's own scoped-VMEM figures for the
+    masked kernels stay under two tiles; the mask-free kernel needs
+    one."""
     qo = 2 * block_q * Dp * itemsize
     kv = 2 * block_k * Dp * itemsize
-    score = block_q * block_k * 4
+    score = 2 * block_q * block_k * 4
     scratch = block_q * (2 * _LANES + Dp) * 4
     return qo + kv + score + scratch
+
+
+def _bwd_vmem_bytes(block_q, block_k, Dp, itemsize):
+    """Backward working set of one grid step, sized at the dk/dv kernel
+    (the larger of the split pair; the fused single-K-block kernel holds
+    the same): the pipelined q/do and k/v/dk/dv blocks, double-buffered,
+    the dk/dv fp32 accumulators, and the (block_k, block_q) score
+    temporaries.  pT, dpT and dsT are never all live in fp32 — the v5e
+    compiler's own scoped-VMEM figures come to 1.6-1.85 fp32 tiles
+    across D in {64, 128} x {bf16, f32}, masks included — so two tiles
+    bound them."""
+    blocks = 2 * (2 * block_q + 4 * block_k) * Dp * itemsize
+    acc = 2 * block_k * Dp * 4
+    score = 2 * block_q * block_k * 4
+    return blocks + acc + score
+
+
+def _bwd_block_q(block_q, block_k, Dp, itemsize):
+    """The backward's q block for a plan's (block_q, block_k): capped at
+    the largest power of two whose backward working set honours
+    ``_VMEM_CLAMP``.  q blocks are independent in every backward
+    kernel, so only block_k — which decides the kernel variant — is
+    shared with the forward.  At the bf16 D=64 defaults (512, 2048) the
+    backward keeps 512; D=128 or fp32 operands halve it to 256."""
+    cap = 2048
+    while cap > _BWD_MIN_BLOCK_Q and \
+            _bwd_vmem_bytes(cap, block_k, Dp, itemsize) > _VMEM_CLAMP:
+        cap //= 2
+    return min(block_q, cap)
+
+
+def _blocks_fit(block_q, block_k, Dp, itemsize):
+    """THE validity predicate for an attention (block_q, block_k): the
+    forward fits its budget at these blocks and the backward fits its
+    own at some q block (``_bwd_block_q`` finds it).  The heuristic, the
+    autotuner's candidate pruning and the cost-table re-validation all
+    go through here, so no plan reaches a kernel the chip's compiler
+    refuses for VMEM."""
+    return _fwd_vmem_bytes(block_q, block_k, Dp, itemsize) <= _VMEM_CLAMP \
+        and _bwd_vmem_bytes(min(block_q, _BWD_MIN_BLOCK_Q), block_k, Dp,
+                            itemsize) <= _VMEM_CLAMP
 
 
 def tune_attention_blocks(seq_q, seq_k, head_dim, dtype="bfloat16"):
     """Default (block_q, block_k) for a (S, D, dtype) attention shape.
 
     Short K axes (<= _SHORT_SEQ_MAX_TK) take the whole axis as one
-    lane-aligned block so the single-pass kernel applies; long axes keep
-    the v5e-tuned streaming defaults (1024, 2048), halved until the
-    working set honours the VMEM clamp (large D / fp32 shapes)."""
+    lane-aligned block so the single-pass kernel applies; long axes take
+    (512, 2048) — the largest blocks today's v5e compiler accepts with
+    every mask variant, forward and backward — halved until the working
+    sets honour their VMEM budgets (large D / fp32 shapes)."""
     itemsize = jnp.dtype(dtype).itemsize
     Dp = head_dim + (-head_dim) % 64
     if seq_k <= _SHORT_SEQ_MAX_TK:
         block_k = max(_LANES, seq_k + (-seq_k) % _LANES)
         block_q = min(max(8, seq_q + (-seq_q) % 8), 512)
         while block_q > 128 and \
-                _fwd_vmem_bytes(block_q, block_k, Dp, itemsize) > _VMEM_CLAMP:
+                not _blocks_fit(block_q, block_k, Dp, itemsize):
             block_q //= 2
         return block_q, block_k
-    block_q, block_k = 1024, 2048
+    block_q, block_k = 512, 2048
     while block_k > 512 and \
-            _fwd_vmem_bytes(block_q, block_k, Dp, itemsize) > _VMEM_CLAMP:
+            not _blocks_fit(block_q, block_k, Dp, itemsize):
         block_k //= 2
     while block_q > 256 and \
-            _fwd_vmem_bytes(block_q, block_k, Dp, itemsize) > _VMEM_CLAMP:
+            not _blocks_fit(block_q, block_k, Dp, itemsize):
         block_q //= 2
     return block_q, block_k
 
@@ -111,8 +162,8 @@ def attention_dispatch(seq_q, seq_k, head_dim, dtype="bfloat16",
     on-miss measured search needs the ``MXNET_AUTOTUNE=1`` opt-in;
     default mode measures nothing), else from the
     ``tune_attention_blocks`` heuristic.  Either way the chosen blocks
-    satisfy the VMEM clamp — table entries are re-validated against
-    the same ``_fwd_vmem_bytes`` predicate the heuristic honours.
+    fit VMEM forward and backward — table entries are re-validated
+    against the same ``_blocks_fit`` predicate the heuristic honours.
 
     ``census=False`` is the secondary-lookup spelling (the custom-vjp
     backward re-reading the forward's decision): same answer, but no
@@ -121,7 +172,7 @@ def attention_dispatch(seq_q, seq_k, head_dim, dtype="bfloat16",
     from .. import telemetry
     from .. import tune as _tune
     if on_tpu is None:
-        on_tpu = _use_pallas()
+        on_tpu = _context.on_tpu()
     if not on_tpu or min(seq_q, seq_k) < _DENSE_MIN_SEQ:
         if census:
             telemetry.inc("attention.kernel.dense_fallback")
@@ -150,14 +201,6 @@ def attention_dispatch(seq_q, seq_k, head_dim, dtype="bfloat16",
                         block_k=block_k, tuner_source=source)
     return {"kernel": kernel, "block_q": block_q, "block_k": block_k,
             "tuner_source": source}
-
-
-def _compiler_params(pltpu, **kw):
-    """``pltpu.CompilerParams`` with a fallback to the pre-rename
-    ``TPUCompilerParams`` (jax < 0.4.34) — same fields, same semantics."""
-    cls = getattr(pltpu, "CompilerParams", None) \
-        or getattr(pltpu, "TPUCompilerParams")
-    return cls(**kw)
 
 
 # ---------------------------------------------------------------------------
@@ -499,7 +542,7 @@ def pallas_flash_attention(q, k, v, causal=False, scale=None,
                 jax.ShapeDtypeStruct((B * H, Tqp, Dp), q.dtype),
                 jax.ShapeDtypeStruct((B * H, Tqp, 1), jnp.float32),
             ],
-            compiler_params=_compiler_params(pltpu,
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel")),
             interpret=interpret,
         )(qp, kp, vp, *extra)
@@ -530,7 +573,7 @@ def pallas_flash_attention(q, k, v, causal=False, scale=None,
             pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, Dp), jnp.float32),
         ],
-        compiler_params=_compiler_params(pltpu,
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qp, kp, vp, *extra)
@@ -626,7 +669,7 @@ def pallas_flash_attention_bshd(q, k, v, causal=False, scale=None,
                 jax.ShapeDtypeStruct((B, Tqp, H * Dp), q.dtype),
                 jax.ShapeDtypeStruct((B, H, Tqp, 1), jnp.float32),
             ],
-            compiler_params=_compiler_params(pltpu,
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "parallel")),
             interpret=interpret,
         )(qp, kp, vp, *extra)
@@ -664,7 +707,7 @@ def pallas_flash_attention_bshd(q, k, v, causal=False, scale=None,
             pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, Dp), jnp.float32),
         ],
-        compiler_params=_compiler_params(pltpu,
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
@@ -930,19 +973,9 @@ def pallas_flash_attention_bwd(q, k, v, out, lse, do, causal=False,
         block_k = tk if block_k is None else block_k
     block_q = min(block_q, max(8, Tq))
     block_k = min(block_k, max(8, Tk))
-    if Tk <= block_k:
-        # fused dqkv path (see below): THREE (block_k, block_q) fp32
-        # score temporaries can be live at once — pT feeds dv before
-        # dpT/dsT are consumed — and they dominate VMEM, so clamp
-        # block_q (to a power of two, keeping the padding tidy) to hold
-        # them inside a 10 MiB slice of the ~16 MiB budget (the rest is
-        # the dk/dv fp32 accumulators and the q/k/v/do blocks).
-        # Arithmetic at defaults: block_k=2048 -> max_bq =
-        # 10 MiB / (3 * 4 B * 2048) = 426 -> block_q 256, i.e.
-        # 3 * 256 * 2048 * 4 B = 6 MiB of score temporaries.
-        max_bq = max(8, (10 * 1024 * 1024) // (3 * 4 * block_k))
-        pow2 = 1 << (max_bq.bit_length() - 1)
-        block_q = min(block_q, pow2)
+    # the backward picks its own q block under its own VMEM budget
+    block_q = _bwd_block_q(block_q, block_k, D + (-D) % 64,
+                           q.dtype.itemsize)
 
     # delta = rowsum(dO ∘ O) — one cheap fused elementwise+reduce pass
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
@@ -1013,7 +1046,7 @@ def pallas_flash_attention_bwd(q, k, v, out, lse, do, causal=False,
                     jax.ShapeDtypeStruct((B * H, Tkp, Dp), k.dtype),
                     jax.ShapeDtypeStruct((B * H, Tkp, Dp), v.dtype),
                 ],
-                compiler_params=_compiler_params(pltpu,
+                compiler_params=pltpu.CompilerParams(
                     dimension_semantics=("parallel",)),
                 interpret=interpret,
             )(qp, kp, vp, dop, lsep, dltp, *fused_extra)
@@ -1050,7 +1083,7 @@ def pallas_flash_attention_bwd(q, k, v, out, lse, do, causal=False,
             ],
             scratch_shapes=[pltpu.VMEM((block_k, Dp), jnp.float32),
                             pltpu.VMEM((block_k, Dp), jnp.float32)],
-            compiler_params=_compiler_params(pltpu,
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary")),
             interpret=interpret,
         )(qp, kp, vp, dop, lsep, dltp, *fused_extra)
@@ -1094,7 +1127,7 @@ def pallas_flash_attention_bwd(q, k, v, out, lse, do, causal=False,
                                lambda b, qi, ki: (b, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((B * H, Tqp, Dp), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, Dp), jnp.float32)],
-        compiler_params=_compiler_params(pltpu,
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qp, kp, vp, dop, lsep, dltp, *dq_extra)
@@ -1122,7 +1155,7 @@ def pallas_flash_attention_bwd(q, k, v, out, lse, do, causal=False,
         ],
         scratch_shapes=[pltpu.VMEM((block_k, Dp), jnp.float32),
                         pltpu.VMEM((block_k, Dp), jnp.float32)],
-        compiler_params=_compiler_params(pltpu,
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qp, kp, vp, dop, lsep, dltp, *kv_extra)
@@ -1152,6 +1185,8 @@ def pallas_flash_attention_bwd_bshd(q, k, v, out, lse, do, causal=False,
         block_k = tk if block_k is None else block_k
     block_q = min(block_q, max(8, Tq))
     block_k = min(block_k, max(8, Tk))
+    block_q = _bwd_block_q(block_q, block_k, D + (-D) % 128,
+                           q.dtype.itemsize)
 
     # delta = rowsum(dO ∘ O), emitted directly in (B, H, Tq) order — the
     # einsum output order makes XLA fuse the transpose into the reduce
@@ -1204,7 +1239,7 @@ def pallas_flash_attention_bwd_bshd(q, k, v, out, lse, do, causal=False,
                                lambda b, h, qi, ki: (b, qi, h)),
         out_shape=jax.ShapeDtypeStruct((B, Tqp, H * Dp), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, Dp), jnp.float32)],
-        compiler_params=_compiler_params(pltpu,
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
@@ -1237,7 +1272,7 @@ def pallas_flash_attention_bwd_bshd(q, k, v, out, lse, do, causal=False,
         ],
         scratch_shapes=[pltpu.VMEM((block_k, Dp), jnp.float32),
                         pltpu.VMEM((block_k, Dp), jnp.float32)],
-        compiler_params=_compiler_params(pltpu,
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
@@ -1253,12 +1288,12 @@ def pallas_flash_attention_bwd_bshd(q, k, v, out, lse, do, causal=False,
 # public op with custom VJP
 # ---------------------------------------------------------------------------
 
-def _use_pallas(*arrays):
-    try:
-        platform = jax.devices()[0].platform
-    except Exception:
-        return False
-    return platform == "tpu"
+def per_batch_shard(fn, operands, replicated=(), summed=None):
+    """A kernel call inside a program whose batch GSPMD shards goes through
+    ``parallel.mesh.per_batch_shard`` (imported late: ``parallel`` imports
+    the op registry)."""
+    from ..parallel.mesh import per_batch_shard as shard
+    return shard(fn, operands, replicated=replicated, summed=summed)
 
 
 def _int_zero_cotangent(x):
@@ -1313,12 +1348,15 @@ def _reference_attention(q, k, v, causal, scale, kv_lens=None,
 
 
 def _flash_fwd(q, k, v, causal, scale, kv_lens, q_segments, kv_segments):
-    plan = attention_dispatch(q.shape[2], k.shape[2], q.shape[3], q.dtype)
+    plan = attention_dispatch(q.shape[2], k.shape[2], q.shape[3], q.dtype,
+                              on_tpu=_context.on_tpu(q))
     if plan["kernel"] != "dense_fallback":
-        out, lse = pallas_flash_attention(
-            q, k, v, causal=causal, scale=scale, return_lse=True,
-            block_q=plan["block_q"], block_k=plan["block_k"],
-            kv_lens=kv_lens, q_segments=q_segments, kv_segments=kv_segments)
+        out, lse = per_batch_shard(
+            lambda q, k, v, kl, qs, ks: pallas_flash_attention(
+                q, k, v, causal=causal, scale=scale, return_lse=True,
+                block_q=plan["block_q"], block_k=plan["block_k"],
+                kv_lens=kl, q_segments=qs, kv_segments=ks),
+            (q, k, v, kv_lens, q_segments, kv_segments))
         return out, (q, k, v, out, lse, kv_lens, q_segments, kv_segments)
     out = _reference_attention(q, k, v, causal, scale, kv_lens, q_segments,
                                kv_segments)
@@ -1337,10 +1375,13 @@ def _flash_bwd(causal, scale, res, g):
         # lookup (no double census, never a second search)
         plan = attention_dispatch(q.shape[2], k.shape[2], q.shape[3],
                                   q.dtype, census=False)
-        dq, dk, dv = pallas_flash_attention_bwd(
-            q, k, v, out, lse, g, causal=causal, scale=scale,
-            block_q=plan["block_q"], block_k=plan["block_k"],
-            kv_lens=kv_lens, q_segments=q_segments, kv_segments=kv_segments)
+        dq, dk, dv = per_batch_shard(
+            lambda q, k, v, out, lse, g, kl, qs, ks:
+            pallas_flash_attention_bwd(
+                q, k, v, out, lse, g, causal=causal, scale=scale,
+                block_q=plan["block_q"], block_k=plan["block_k"],
+                kv_lens=kl, q_segments=qs, kv_segments=ks),
+            (q, k, v, out, lse, g, kv_lens, q_segments, kv_segments))
     else:
         # recompute-based VJP through the memory-linear jnp path
         _, vjp = jax.vjp(
@@ -1380,12 +1421,15 @@ def flash_attention_bshd(q, k, v, causal=False, scale=None, kv_lens=None):
 
 
 def _flash_bshd_fwd(q, k, v, causal, scale, kv_lens):
-    plan = attention_dispatch(q.shape[1], k.shape[1], q.shape[3], q.dtype)
+    plan = attention_dispatch(q.shape[1], k.shape[1], q.shape[3], q.dtype,
+                              on_tpu=_context.on_tpu(q))
     if plan["kernel"] != "dense_fallback":
-        out, lse = pallas_flash_attention_bshd(
-            q, k, v, causal=causal, scale=scale, return_lse=True,
-            block_q=plan["block_q"], block_k=plan["block_k"],
-            kv_lens=kv_lens)
+        out, lse = per_batch_shard(
+            lambda q, k, v, kl: pallas_flash_attention_bshd(
+                q, k, v, causal=causal, scale=scale, return_lse=True,
+                block_q=plan["block_q"], block_k=plan["block_k"],
+                kv_lens=kl),
+            (q, k, v, kv_lens))
         return out, (q, k, v, out, lse, kv_lens)
     bhtd = lambda x: jnp.swapaxes(x, 1, 2)
     out = _reference_attention(bhtd(q), bhtd(k), bhtd(v), causal, scale,
@@ -1400,10 +1444,13 @@ def _flash_bshd_bwd(causal, scale, res, g):
         # axis 1, D axis 3); census=False — quiet secondary lookup
         plan = attention_dispatch(q.shape[1], k.shape[1], q.shape[3],
                                   q.dtype, census=False)
-        dq, dk, dv = pallas_flash_attention_bwd_bshd(
-            q, k, v, out, lse, g, causal=causal, scale=scale,
-            block_q=plan["block_q"], block_k=plan["block_k"],
-            kv_lens=kv_lens)
+        dq, dk, dv = per_batch_shard(
+            lambda q, k, v, out, lse, g, kl:
+            pallas_flash_attention_bwd_bshd(
+                q, k, v, out, lse, g, causal=causal, scale=scale,
+                block_q=plan["block_q"], block_k=plan["block_k"],
+                kv_lens=kl),
+            (q, k, v, out, lse, g, kv_lens))
     else:
         bhtd = lambda x: jnp.swapaxes(x, 1, 2)
         _, vjp = jax.vjp(

@@ -30,7 +30,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .pallas_attention import _compiler_params, _use_pallas
+from .. import context as _context
+from .pallas_attention import per_batch_shard
 
 __all__ = ["fused_scale_shift_add_relu", "fused_bn_add_relu_epilogue",
            "pallas_epilogue_fwd", "pallas_epilogue_bwd"]
@@ -57,8 +58,9 @@ def _epi_bwd_kernel(x_ref, s_ref, y_ref, ct_ref, dx_ref, dr_ref,
     x = x_ref[...].astype(jnp.float32)
     ct = ct_ref[...].astype(jnp.float32)
     # the ReLU mask recomputes from y (y > 0 iff the pre-ReLU value was
-    # positive), so the boolean mask is never materialized in HBM
-    g = jnp.where(y_ref[...] > 0, ct, 0.0)
+    # positive), so the boolean mask is never materialized in HBM.  The
+    # compare runs in fp32: v5e has no bf16 vector compare
+    g = jnp.where(y_ref[...].astype(jnp.float32) > 0, ct, 0.0)
     dx_ref[...] = (g * s_ref[...]).astype(dx_ref.dtype)
     dr_ref[...] = g.astype(dr_ref.dtype)
 
@@ -194,7 +196,7 @@ def pallas_epilogue_bwd(x2d, s_row, y2d, ct2d, interpret=False,
         ],
         scratch_shapes=[pltpu.VMEM((1, block_c), jnp.float32),
                         pltpu.VMEM((1, block_c), jnp.float32)],
-        compiler_params=_compiler_params(pltpu,
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(xp, sp, yp, ctp)
@@ -222,11 +224,12 @@ def _fssar_fwd(x2d, scale, shift, r2d):
     t_row = shift.astype(jnp.float32).reshape(1, -1)
     # graftlint: disable-next=retrace-shape-branch -- kernel-vs-dense
     # choice is per-shape trace-time specialization by design
-    if not _use_pallas() or _pick_blocks(x2d.shape[0], x2d.shape[1], 5) \
-            is None:
+    if not _context.on_tpu(x2d) or \
+            _pick_blocks(x2d.shape[0], x2d.shape[1], 5) is None:
         y = _jnp_epilogue(x2d, s_row, t_row, r2d)
         return y, (x2d, scale, shift, r2d, None)
-    y = pallas_epilogue_fwd(x2d, s_row, t_row, r2d)
+    y = per_batch_shard(pallas_epilogue_fwd, (x2d, s_row, t_row, r2d),
+                        replicated=(1, 2))
     return y, (x2d, scale, shift, r2d, y)
 
 
@@ -240,7 +243,10 @@ def _fssar_bwd(res, ct):
             x2d, scale, shift, r2d)
         return vjp(ct)
     s_row = scale.astype(jnp.float32).reshape(1, -1)
-    dx, dr, ds, dt = pallas_epilogue_bwd(x2d, s_row, y, ct)
+    # the per-column dscale/dshift reductions are partial sums per shard
+    dx, dr, ds, dt = per_batch_shard(
+        pallas_epilogue_bwd, (x2d, s_row, y, ct), replicated=(1,),
+        summed=(False, False, True, True))
     return (dx, ds.reshape(scale.shape).astype(scale.dtype),
             dt.reshape(shift.shape).astype(shift.dtype),
             dr.astype(r2d.dtype))

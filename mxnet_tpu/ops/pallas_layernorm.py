@@ -23,7 +23,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .pallas_attention import _compiler_params
+from .. import context as _context
+from .pallas_attention import per_batch_shard
 
 __all__ = ["fused_layer_norm", "pallas_layer_norm_fwd",
            "pallas_layer_norm_bwd"]
@@ -160,18 +161,11 @@ def pallas_layer_norm_bwd(x2d, gamma, mu, rstd, ct2d,
         ],
         scratch_shapes=[pltpu.VMEM((1, C), jnp.float32),
                         pltpu.VMEM((1, C), jnp.float32)],
-        compiler_params=_compiler_params(pltpu,
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(xp, gamma.reshape(1, C), mup, rsp, ctp)
     return dx[:N], dg.reshape(C), db.reshape(C)
-
-
-def _use_pallas():
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
 
 
 # bwd holds x, ct and dx blocks as f32 in VMEM (3 * block * C * 4B) plus
@@ -234,13 +228,15 @@ def _jnp_ln(data, gamma, beta, eps):
 def _fln_fwd(data, gamma, beta, eps):
     C = data.shape[-1]
     block = _pick_block_rows(C, rows=data.size // C)
-    if not _use_pallas() or block is None:
+    if not _context.on_tpu(data) or block is None:
         out = _jnp_ln(data, gamma, beta, eps)
         return out, (data, gamma, beta, None, None)
     shape = data.shape
     x2d = data.reshape(-1, C)
-    y, mu, rstd = pallas_layer_norm_fwd(x2d, gamma, beta, eps,
-                                        block_rows=block)
+    y, mu, rstd = per_batch_shard(
+        lambda x, g, b: pallas_layer_norm_fwd(x, g, b, eps,
+                                              block_rows=block),
+        (x2d, gamma, beta), replicated=(1, 2))
     return y.reshape(shape), (data, gamma, beta, mu, rstd)
 
 
@@ -252,9 +248,13 @@ def _fln_bwd(eps, res, ct):
         _, vjp = jax.vjp(lambda d, g, b: _jnp_ln(d, g, b, eps),
                          data, gamma, beta)
         return vjp(ct)
-    dx2, dg, db = pallas_layer_norm_bwd(
-        data.reshape(-1, C), gamma, mu, rstd, ct.reshape(-1, C),
-        block_rows=_pick_block_rows(C, rows=data.size // C, quiet=True))
+    block = _pick_block_rows(C, rows=data.size // C, quiet=True)
+    # dgamma/dbeta are partial sums per shard
+    dx2, dg, db = per_batch_shard(
+        lambda x, g, mu, rs, ct: pallas_layer_norm_bwd(
+            x, g, mu, rs, ct, block_rows=block),
+        (data.reshape(-1, C), gamma, mu, rstd, ct.reshape(-1, C)),
+        replicated=(1,), summed=(False, True, True))
     return (dx2.reshape(shape), dg.astype(gamma.dtype),
             db.astype(beta.dtype))
 

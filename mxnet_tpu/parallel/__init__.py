@@ -115,25 +115,13 @@ def initialize(coordinator_address=None, num_processes=None, process_id=None,
         # PS_HEARTBEAT_TIMEOUT, docs/faq/env_var.md DMLC heartbeat family)
         kw["heartbeat_timeout_seconds"] = int(
             os.environ["MXNET_TPU_HEARTBEAT_TIMEOUT"])
-    # drop knobs this jax doesn't know (heartbeat_timeout_seconds and
-    # friends moved between releases) — they tune latency, not semantics
-    import inspect
-    params = inspect.signature(jax.distributed.initialize).parameters
-    if not any(p.kind is inspect.Parameter.VAR_KEYWORD
-               for p in params.values()):
-        kw = {k: v for k, v in kw.items() if k in params}
     if os.environ.get("MXNET_TPU_RECOVERABLE", "") in ("1", "true"):
         # survive peer failure instead of fail-fast: the kvstore's
         # num_dead_node() liveness view stays queryable after a worker
         # dies (reference get_num_dead_node semantics — survivors keep
         # running; fail-fast remains the default, matching round-3's
-        # hard-failure contract).  The config option only exists on
-        # newer jax; older clients already keep the coordination
-        # service's live-nodes view queryable without it.
-        try:
-            jax.config.update("jax_enable_recoverability", True)
-        except AttributeError:
-            pass
+        # hard-failure contract).
+        jax.config.update("jax_enable_recoverability", True)
     jax.distributed.initialize(**kw)
 
 

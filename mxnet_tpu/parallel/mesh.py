@@ -7,30 +7,17 @@ topology work is XLA's job; we only name axes and pick shapes.
 """
 from __future__ import annotations
 
+import contextlib
 import threading
 from typing import Optional, Sequence
 
 import jax
 import numpy as onp
-from jax.sharding import Mesh
+from jax import lax
+from jax.sharding import Mesh, PartitionSpec as P
 
 __all__ = ["set_mesh", "get_mesh", "current_mesh", "default_mesh",
-           "device_mesh", "shard_map_compat"]
-
-
-def shard_map_compat(fn, **kwargs):
-    """shard_map across jax spellings (top-level vs experimental; the
-    replication-check kwarg renamed check_rep→check_vma) — the one shim
-    every mesh-sharded component (pipeline, MoE, ring attention, packed
-    kvstore push) uses."""
-    import inspect
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
-    params = inspect.signature(shard_map).parameters
-    check_kw = "check_vma" if "check_vma" in params else "check_rep"
-    return shard_map(fn, **{check_kw: False}, **kwargs)
+           "device_mesh", "batch_sharded_over", "per_batch_shard"]
 
 
 class _MeshState(threading.local):
@@ -84,6 +71,69 @@ def device_mesh(shape: Optional[Sequence[int]] = None,
         shape = (len(devices),)
     arr = onp.array(devices).reshape(tuple(shape))
     return Mesh(arr, tuple(axis_names))
+
+
+class _BatchAxis(threading.local):
+    def __init__(self):
+        self.mesh: Optional[Mesh] = None
+        self.axis: Optional[str] = None
+
+
+_BATCH_AXIS = _BatchAxis()
+
+
+@contextlib.contextmanager
+def batch_sharded_over(mesh: Optional[Mesh], axis: str = "dp"):
+    """Declare, while a program is being traced, that its batch is sharded
+    over ``mesh[axis]`` by GSPMD (jit over arrays with a NamedSharding).
+
+    The compiler partitions composed XLA ops on its own, but not a Mosaic
+    (Pallas TPU) kernel: "Mosaic kernels cannot be automatically
+    partitioned".  Inside such a program a kernel has to be wrapped in a
+    ``shard_map`` by hand, and only the code that laid the batch out knows
+    over which axis.  ``DataParallelStep`` opens this scope around its
+    forward+backward trace; the kernel entry points in ``ops/`` go through
+    :func:`per_batch_shard`.  ``mesh=None`` (one device) is a no-op."""
+    prev = _BATCH_AXIS.mesh, _BATCH_AXIS.axis
+    _BATCH_AXIS.mesh, _BATCH_AXIS.axis = mesh, axis
+    try:
+        yield
+    finally:
+        _BATCH_AXIS.mesh, _BATCH_AXIS.axis = prev
+
+
+def per_batch_shard(fn, operands, replicated=(), summed=None):
+    """``fn(*operands)``, run once per batch shard of the declared scope.
+
+    Every operand and every output is split on its leading (batch or row)
+    dim, except the operand positions in ``replicated`` (whole on every
+    shard) and the outputs flagged in ``summed`` — one bool per element of
+    the tuple ``fn`` returns: a flagged output is a per-shard partial sum
+    (a backward's per-channel reduction), summed over the axis and
+    returned whole.  ``None`` operands pass through.  Outside
+    :func:`batch_sharded_over`, or over an axis of one device, this is a
+    plain call."""
+    mesh, axis = _BATCH_AXIS.mesh, _BATCH_AXIS.axis
+    if mesh is None or mesh.shape[axis] == 1:
+        return fn(*operands)
+    present = [i for i, o in enumerate(operands) if o is not None]
+
+    def body(*args):
+        full = [None] * len(operands)
+        for i, a in zip(present, args):
+            full[i] = a
+        out = fn(*full)
+        if summed is None:
+            return out
+        return tuple(lax.psum(o, axis) if s else o
+                     for o, s in zip(out, summed))
+
+    in_specs = tuple(P() if i in replicated else P(axis) for i in present)
+    out_specs = P(axis) if summed is None else \
+        tuple(P() if s else P(axis) for s in summed)
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)(
+                             *[operands[i] for i in present])
 
 
 def default_mesh() -> Mesh:

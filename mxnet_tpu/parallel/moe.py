@@ -112,9 +112,8 @@ def moe_ffn_apply(params, x, mesh: Mesh, axis: str = "ep",
         return out.astype(x.dtype)
 
     in_specs = ({"router": P(), "w1": P(axis), "w2": P(axis)}, P(axis))
-    from .mesh import shard_map_compat
-    fn = shard_map_compat(per_shard, mesh=mesh, in_specs=in_specs,
-                          out_specs=P(axis))
+    fn = jax.shard_map(per_shard, mesh=mesh, in_specs=in_specs,
+                       out_specs=P(axis), check_vma=False)
     return fn(params, x)
 
 

@@ -132,33 +132,38 @@ def pad_batch(rows: Sequence[onp.ndarray], bucket: int,
     return out
 
 
-def _aot_compile(fn, spec):
+def _aot_compile(fn, *specs):
     """The whole AOT pipeline for one bucket: jit -> lower at the
-    bucket aval -> compile.  One callable, one compile, and the
+    bucket avals -> compile.  One callable, one compile, and the
     returned executable never traces again — which is why constructing
     the jit wrapper here (once per bucket, outside any loop) is not a
     retrace hazard: the wrapper's own cache is never exercised."""
     import jax
 
-    return jax.jit(fn).lower(spec).compile()
+    return jax.jit(fn).lower(*specs).compile()
 
 
 class AotModel:
     """Per-bucket AOT-compiled executables of one model function.
 
     ``fn(x: [B, *feature_shape] array) -> array`` must be
-    jax-traceable; parameters ride as closure constants.  After
-    :meth:`compile_all`, :meth:`run` dispatches a padded bucket batch
-    with no tracing on the path — a shape outside the compiled menu
-    raises immediately.
+    jax-traceable.  With ``params`` (a list of arrays) the signature is
+    ``fn(params, x)`` and the parameters are ARGUMENTS of every bucket
+    executable: one copy on the device, shared by the whole menu.
+    Closed over instead they are baked into each executable as
+    constants — for ResNet-50 a 173 MB compile-cache entry and a copy of
+    the weights in HBM per bucket.  After :meth:`compile_all`,
+    :meth:`run` dispatches a padded bucket batch with no tracing on the
+    path — a shape outside the compiled menu raises immediately.
     """
 
     def __init__(self, fn=None, feature_shape=(), dtype="float32",
-                 name="model", fn_for_bucket=None):
+                 name="model", fn_for_bucket=None, params=None):
         if fn is None and fn_for_bucket is None:
             raise MXNetError("AotModel needs fn or fn_for_bucket")
         self._fn = fn
         self._fn_for_bucket = fn_for_bucket
+        self._params = params
         self.feature_shape = tuple(int(d) for d in feature_shape)
         self.dtype = onp.dtype(dtype)
         self.name = _unique_name(str(name))
@@ -170,15 +175,15 @@ class AotModel:
                    name="model"):
         """Serve a gluon HybridBlock in-process: the eval-mode forward
         is functionalized exactly as ``contrib.stablehlo.export_block``
-        traces it (training=False, parameters captured as values)."""
+        traces it (training=False; the parameter values of this moment
+        are what every later request sees)."""
         from ..contrib.stablehlo import _functional_eval_forward
         fn, params = _functional_eval_forward(net)
         if not params:
             raise MXNetError("AotModel.from_block: net has no "
                              "initialized parameters")
-        pvals = [p._data._data for p in params]
-        return cls(fn=lambda x: fn(pvals, x), feature_shape=feature_shape,
-                   dtype=dtype, name=name)
+        return cls(fn=fn, feature_shape=feature_shape, dtype=dtype,
+                   name=name, params=[p._data._data for p in params])
 
     @classmethod
     def from_exported(cls, prefix, epoch=0, name=None):
@@ -223,7 +228,13 @@ class AotModel:
             t0 = time.perf_counter()
             fn = self._fn if self._fn is not None \
                 else self._fn_for_bucket(b)
-            self._compiled[b] = _aot_compile(fn, spec)
+            if self._params is None:
+                self._compiled[b] = _aot_compile(fn, spec)
+            else:
+                self._compiled[b] = _aot_compile(
+                    fn, [jax.ShapeDtypeStruct(v.shape, v.dtype,
+                                              sharding=v.sharding)
+                         for v in self._params], spec)
             dur_ms = round((time.perf_counter() - t0) * 1e3, 3)
             telemetry.record_compile(
                 "serve.%s.b%d" % (self.name, b),
@@ -245,4 +256,6 @@ class AotModel:
         if compiled is None:
             raise MXNetError("AotModel %r: bucket %d was never compiled"
                              % (self.name, bucket))
-        return compiled(x)
+        if self._params is None:
+            return compiled(x)
+        return compiled(self._params, x)

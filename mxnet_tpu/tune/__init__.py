@@ -34,6 +34,7 @@ from __future__ import annotations
 import os
 from typing import Optional, Sequence, Tuple
 
+from .. import context as _context
 from . import cost_table, search
 from .cost_table import (CostTable, FAMILY_FIELDS, KERNEL_FAMILIES,
                          SCHEMA_VERSION, canon_dtype, canon_shape,
@@ -75,17 +76,11 @@ def autotune_enabled() -> bool:
     return val not in ("0", "false", "off", "no", "")
 
 
-def _platform_is_tpu() -> bool:
-    # one platform probe for the whole package (the interpret-record
-    # refusal uses the same predicate)
-    return cost_table._on_real_chip()
-
-
 def _search_allowed() -> bool:
     # on-miss search compiles and times real kernels; off-TPU that means
     # interpret mode, which only the offline CLI opts into explicitly
     return autotune_enabled() and (
-        _platform_is_tpu()
+        _context.on_tpu()
         or os.environ.get("MXNET_AUTOTUNE_INTERPRET", "0") == "1")
 
 
@@ -179,7 +174,7 @@ def _dispatch_search(family, shape, dt):
     from .. import telemetry
     from . import model as _model
     interp = os.environ.get("MXNET_AUTOTUNE_INTERPRET", "0") == "1" \
-        and not _platform_is_tpu()
+        and not _context.on_tpu()
     cm = None
     if _model.model_enabled():
         try:

@@ -29,6 +29,8 @@ import os
 import threading
 from typing import Dict, Optional, Tuple
 
+from .. import context as _context
+
 SCHEMA_VERSION = 1
 
 # family -> ordered config fields (the tuple order table_blocks returns)
@@ -91,28 +93,18 @@ def canon_shape(shape) -> Tuple[int, ...]:
 
 
 def platform_id() -> str:
-    """Chip identity the table is keyed on: the device kind when jax can
-    say ('TPU v5 lite' -> 'tpu-v5-lite'), else the platform name.  A
-    config measured on one chip generation must never be served on
-    another."""
+    """Chip identity the table is keyed on: the device kind as jax
+    reports it ('TPU v5 lite' -> 'tpu-v5-lite', 'cpu' on the host
+    backend).  A config measured on one chip generation must never be
+    served on another — so a device jax cannot read raises; it does not
+    become a key of its own."""
     with _platform_lock:
         if _PLATFORM["id"] is None:
-            try:
-                import jax
-                dev = jax.devices()[0]
-                kind = getattr(dev, "device_kind", "") or dev.platform
-                _PLATFORM["id"] = str(kind).strip().lower().replace(" ", "-")
-            except Exception:
-                _PLATFORM["id"] = "unknown"
+            import jax
+            dev = jax.devices()[0]
+            kind = dev.device_kind or dev.platform
+            _PLATFORM["id"] = str(kind).strip().lower().replace(" ", "-")
         return _PLATFORM["id"]
-
-
-def _on_real_chip() -> bool:
-    try:
-        import jax
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
 
 
 def default_table_path() -> str:
@@ -280,7 +272,7 @@ class CostTable:
             rec = self._entries.get(self._key(family, shape, dtype,
                                               platform))
             if rec is not None and rec.get("interpret") and \
-                    _on_real_chip():
+                    _context.on_tpu():
                 return None
             return dict(rec) if rec else None
 

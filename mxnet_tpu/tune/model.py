@@ -38,6 +38,8 @@ import math
 import os
 from typing import Dict, List, Optional, Sequence, Tuple
 
+from .. import context as _context
+
 MODEL_SCHEMA = 1
 # below this many samples a model is untrained by definition; the
 # normal-equation fit is exact, so the floor only guards generalization
@@ -248,7 +250,7 @@ def training_samples(table, family: str,
     from . import cost_table as ct
 
     if include_interpret is None:
-        include_interpret = not ct._on_real_chip()
+        include_interpret = not _context.on_tpu()
     fields = ct.FAMILY_FIELDS.get(family)
     if fields is None:
         return []

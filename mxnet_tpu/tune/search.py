@@ -8,8 +8,9 @@ and the offline CLI (``python -m mxnet_tpu.tune``) and the on-miss
 dispatch search both call :func:`search_config`.
 
 Candidate pruning REUSES the kernels' own sizing arithmetic —
-``_fwd_vmem_bytes``/``_VMEM_CLAMP`` from ``ops/pallas_attention`` and
-the ``_VMEM_BUDGET`` constants from the norm modules — the exact
+``_blocks_fit`` (forward and backward VMEM budgets) from
+``ops/pallas_attention`` and the ``_VMEM_BUDGET`` constants from the
+norm modules — the exact
 expressions graftlint's static pallas estimator folds, so no invalid
 candidate is ever timed and the static rule rejects anything the
 search could not have emitted.
@@ -154,8 +155,7 @@ def valid_config(family: str, shape: Sequence[int], dtype,
     try:
         if family == "attention":
             import jax.numpy as jnp
-            from ..ops.pallas_attention import (_fwd_vmem_bytes,
-                                                _VMEM_CLAMP, _LANES)
+            from ..ops.pallas_attention import _blocks_fit, _LANES
             seq_q, seq_k, head_dim = shape
             bq, bk = int(config["block_q"]), int(config["block_k"])
             # sublane (8) / lane (128) alignment: Mosaic rejects
@@ -165,7 +165,7 @@ def valid_config(family: str, shape: Sequence[int], dtype,
                 return False
             Dp = head_dim + (-head_dim) % 64
             itemsize = jnp.dtype(dtype).itemsize
-            return _fwd_vmem_bytes(bq, bk, Dp, itemsize) <= _VMEM_CLAMP
+            return _blocks_fit(bq, bk, Dp, itemsize)
         if family == "fused_norm":
             from ..ops.pallas_fused_norm import _VMEM_BUDGET
             br, bc = int(config["block_r"]), int(config["block_c"])

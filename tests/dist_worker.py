@@ -17,7 +17,6 @@ import numpy as onp  # noqa: E402
 
 def main():
     import jax
-    jax.config.update("jax_platforms", "cpu")
     import mxnet_tpu as mx
     from mxnet_tpu import kvstore, parallel
 

@@ -248,7 +248,6 @@ def main_ckpt_phase2():
 
 def main():
     import jax
-    jax.config.update("jax_platforms", "cpu")
     from mxnet_tpu import parallel
 
     parallel.initialize()

@@ -13,7 +13,7 @@ import os
 
 import pytest
 
-from mxnet_tpu import telemetry, tune
+from mxnet_tpu import context, telemetry, tune
 from mxnet_tpu.ops import pallas_attention as PA
 from mxnet_tpu.ops import pallas_fused_norm as FN
 from mxnet_tpu.ops import pallas_layernorm as LN
@@ -96,7 +96,7 @@ def test_flash_bwd_threads_tuned_blocks(monkeypatch):
         captured.update(kw)
         return q, k, v
     monkeypatch.setattr(PA, "pallas_flash_attention_bwd", fake_bwd)
-    monkeypatch.setattr(PA, "_use_pallas", lambda *a: True)
+    monkeypatch.setattr(context, "on_tpu", lambda *a: True)
     x = jnp.asarray(onp.zeros((1, 1, 384, 64), "float32"), jnp.bfloat16)
     lse = jnp.zeros((1, 1, 384), jnp.float32)
     res = (x, x, x, x, lse, None, None, None)
@@ -158,7 +158,7 @@ def test_stale_entry_retuned_under_autotune(monkeypatch):
                             {"block_q": 4096, "block_k": 4096})
     monkeypatch.setattr(search, "_measure_candidate",
                         lambda f, s, d, cfg, **kw: float(cfg["block_q"]))
-    monkeypatch.setattr(tune, "_platform_is_tpu", lambda: True)
+    monkeypatch.setattr(context, "on_tpu", lambda *a: True)
     monkeypatch.setenv("MXNET_AUTOTUNE", "1")
     plan = PA.attention_dispatch(512, 512, 64, "bfloat16", on_tpu=True)
     assert plan["tuner_source"] == "searched"
@@ -176,7 +176,7 @@ def test_invalid_entry_plus_failed_search_counts_one_fallback(monkeypatch):
     def broken(*a, **kw):
         raise RuntimeError("no chip")
     monkeypatch.setattr(search, "_measure_candidate", broken)
-    monkeypatch.setattr(tune, "_platform_is_tpu", lambda: True)
+    monkeypatch.setattr(context, "on_tpu", lambda *a: True)
     monkeypatch.setenv("MXNET_AUTOTUNE", "1")
     before = _counter("autotune.fallback")
     plan = PA.attention_dispatch(512, 512, 64, "bfloat16", on_tpu=True)
@@ -188,14 +188,13 @@ def test_interpret_records_refused_on_real_chip(monkeypatch):
     """Interpret-mode (smoke) timings are stamped into the record and
     never served on a real chip — there they read as a miss, so
     MXNET_AUTOTUNE can re-tune with real measurements."""
-    from mxnet_tpu.tune import cost_table as ct
     tune.get_table().record("attention", (512, 512, 64), "bfloat16",
                             {"block_q": 256, "block_k": 512},
                             interpret=True)
     rec = tune.get_table().lookup("attention", (512, 512, 64),
                                   "bfloat16")
     assert rec is not None and rec["interpret"] is True  # CPU: servable
-    monkeypatch.setattr(ct, "_on_real_chip", lambda: True)
+    monkeypatch.setattr(context, "on_tpu", lambda *a: True)
     assert tune.get_table().lookup("attention", (512, 512, 64),
                                    "bfloat16") is None
 
@@ -310,7 +309,7 @@ def test_dispatch_search_honors_trial_budget(monkeypatch):
         calls.append(dict(cfg))
         return float(cfg["block_q"])          # smallest block_q wins
     monkeypatch.setattr(search, "_measure_candidate", fake_measure)
-    monkeypatch.setattr(tune, "_platform_is_tpu", lambda: True)
+    monkeypatch.setattr(context, "on_tpu", lambda *a: True)
     monkeypatch.setenv("MXNET_AUTOTUNE", "1")
     monkeypatch.setenv("MXNET_AUTOTUNE_TRIALS", "3")
 
@@ -376,7 +375,7 @@ def test_failed_dispatch_search_is_memoized(monkeypatch):
         calls.append(1)
         raise RuntimeError("no chip")
     monkeypatch.setattr(search, "_measure_candidate", broken)
-    monkeypatch.setattr(tune, "_platform_is_tpu", lambda: True)
+    monkeypatch.setattr(context, "on_tpu", lambda *a: True)
     monkeypatch.setenv("MXNET_AUTOTUNE", "1")
     monkeypatch.setenv("MXNET_AUTOTUNE_TRIALS", "2")
     p1 = PA.attention_dispatch(512, 512, 64, "bfloat16", on_tpu=True)

@@ -21,7 +21,7 @@ import pytest
 import jax
 
 import mxnet_tpu as mx
-from mxnet_tpu import telemetry, tune
+from mxnet_tpu import context, telemetry, tune
 from mxnet_tpu.gluon import nn
 from mxnet_tpu.gluon import loss as gloss
 from mxnet_tpu import parallel
@@ -152,11 +152,11 @@ def test_interpret_samples_excluded_on_real_chip(monkeypatch):
              interpret=True,
              results=[{"config": {"block_q": 128, "block_k": 512},
                        "ms": 1.0}])
-    monkeypatch.setattr(ct, "_on_real_chip", lambda: True)
+    monkeypatch.setattr(context, "on_tpu", lambda *a: True)
     assert M.training_samples(t, "attention") == []
     assert len(M.training_samples(t, "attention",
                                   include_interpret=True)) == 1
-    monkeypatch.setattr(ct, "_on_real_chip", lambda: False)
+    monkeypatch.setattr(context, "on_tpu", lambda *a: False)
     assert len(M.training_samples(t, "attention")) == 1
 
 

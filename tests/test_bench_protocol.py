@@ -297,9 +297,6 @@ def test_hard_failures_gate_grad_compression_wire(bench):
                    dict(bad["legs"][2])]
     hard = bench._hard_failures([bad])
     assert any("int8" in h and "wire_ratio" in h for h in hard)
-    crash = {"bench": "grad_compression",
-             "error": "RuntimeError('boom')", "compressed_ok": False}
-    assert any("crashed" in h for h in bench._hard_failures([crash]))
 
 
 def test_hard_failures_gate_grad_compression_parity(bench):
@@ -322,3 +319,28 @@ def test_hard_failures_gate_grad_compression_reshard(bench):
     bad["reshard"] = dict(bad["reshard"], residual_bitwise_ok=False)
     hard = bench._hard_failures([bad])
     assert any("bitwise" in h for h in hard)
+
+
+def test_peaks_table_is_keyed_by_device_kind_and_refuses_others(bench):
+    """One table with its source, keyed by jax's device_kind; a utilisation
+    against another chip's peak is not a number, so an unknown kind (the
+    CPU this suite runs on) is an error, not a default."""
+    v5e = bench.DEVICE_PEAKS["TPU v5 lite"]
+    assert v5e == {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9}
+    assert bench._device()["platform"] == "cpu"
+    with pytest.raises(RuntimeError, match="no published peaks"):
+        bench._peaks()
+
+
+def test_every_json_line_carries_the_device(bench, capsys):
+    import json
+    import jax
+    bench._emit({"metric": "m", "value": 1.0})
+    bench._emit({"bench": "x" * 5000}, log=True)
+    cap = capsys.readouterr()
+    stamp = {"platform": "cpu", "kind": jax.devices()[0].device_kind,
+             "count": len(jax.devices())}
+    assert json.loads(cap.out)["device"] == stamp
+    # a progress line is cut to size with the stamp first, so it survives
+    assert cap.err.startswith('# {"device": ' + json.dumps(stamp))
+    assert len(cap.err) < 2100

@@ -121,13 +121,13 @@ def test_custom_trains_under_module():
     assert losses[-1] < losses[0] * 0.7, (losses[0], losses[-1])
 
 
-def test_custom_op_traced_without_callbacks_raises_clearly():
-    """On a backend with no host-callback support, tracing a CustomOp
-    must fail at trace time with an actionable MXNetError — not with the
-    backend's compile-time rejection."""
+def test_custom_op_traced_under_jit_and_jit_of_grad():
+    """A CustomOp is a host callback inside the compiled program: it
+    runs under jit, and under jit(grad) (transform tracers layered on the
+    staging tracer) the backward callback supplies the gradient."""
     import jax
+    import jax.numpy as jnp
     import mxnet_tpu as mx
-    from mxnet_tpu import operator as op_mod
 
     class Plus1(mx.operator.CustomOp):
         def forward(self, is_train, req, in_data, out_data, aux):
@@ -135,9 +135,9 @@ def test_custom_op_traced_without_callbacks_raises_clearly():
                         in_data[0].asnumpy() + 1.0)
 
         def backward(self, req, out_grad, in_data, out_data, in_grad, aux):
-            self.assign(in_grad[0], req[0], out_grad[0].asnumpy())
+            self.assign(in_grad[0], req[0], 3.0 * out_grad[0].asnumpy())
 
-    @mx.operator.register("plus1_nocb")
+    @mx.operator.register("plus1_jit")
     class Plus1Prop(mx.operator.CustomOpProp):
         def list_arguments(self):
             return ["data"]
@@ -148,44 +148,25 @@ def test_custom_op_traced_without_callbacks_raises_clearly():
         def create_operator(self, ctx, shapes, dtypes):
             return Plus1()
 
-    saved = op_mod._CALLBACK_SUPPORT
-    op_mod._CALLBACK_SUPPORT = False
-    try:
-        # eager fallback still works
-        out = mx.nd.Custom(mx.nd.ones((2, 2)), op_type="plus1_nocb")
-        assert float(out.asnumpy().sum()) == 8.0
-        # traced use raises the actionable error
-        import jax.numpy as jnp
-        with pytest.raises(mx.MXNetError, match="host callbacks"):
-            jax.jit(lambda x: mx.nd.Custom(
-                mx.nd.from_jax(x), op_type="plus1_nocb")._data)(
-                    jnp.ones((2, 2)))
-        # nested transform tracers (jit of grad) must be detected too —
-        # a JVPTracer wrapping the staging tracer used to slip past
-        with pytest.raises(mx.MXNetError, match="host callbacks"):
-            jax.jit(jax.grad(lambda x: mx.nd.Custom(
-                mx.nd.from_jax(x), op_type="plus1_nocb")._data.sum()))(
-                    jnp.ones((2, 2)))
-    finally:
-        op_mod._CALLBACK_SUPPORT = saved
+    out = mx.nd.Custom(mx.nd.ones((2, 2)), op_type="plus1_jit")
+    assert float(out.asnumpy().sum()) == 8.0
+    out = jax.jit(lambda x: mx.nd.Custom(
+        mx.nd.from_jax(x), op_type="plus1_jit")._data)(jnp.ones((2, 2)))
+    onp.testing.assert_array_equal(onp.asarray(out), onp.full((2, 2), 2.0))
+    g = jax.jit(jax.grad(lambda x: mx.nd.Custom(
+        mx.nd.from_jax(x), op_type="plus1_jit")._data.sum()))(
+            jnp.ones((2, 2)))
+    onp.testing.assert_array_equal(onp.asarray(g), onp.full((2, 2), 3.0))
 
 
-def test_callback_probe_inside_active_trace():
-    """The support probe must escape the ambient trace: when the first
-    CustomOp use in a process is under jit, the probe fires mid-trace and
-    used to stage its own jit into the outer jaxpr, mis-caching False."""
+def test_custom_op_first_use_under_jit():
+    """The first CustomOp use in a process may be under jit (a
+    hybridized block whose first op is the custom op)."""
     import jax
     import jax.numpy as jnp
-    import mxnet_tpu.operator as op_mod
 
-    saved = op_mod._CALLBACK_SUPPORT
-    op_mod._CALLBACK_SUPPORT = None    # simulate fresh process
-    try:
-        out = jax.jit(lambda x: mx.nd.Custom(
-            mx.nd.from_jax(x), op_type="numpy_softmax")._data)(
-                jnp.ones((2, 3)))
-        onp.testing.assert_allclose(onp.asarray(out),
-                                    onp.full((2, 3), 1.0 / 3), rtol=1e-6)
-        assert op_mod._CALLBACK_SUPPORT is True
-    finally:
-        op_mod._CALLBACK_SUPPORT = saved
+    out = jax.jit(lambda x: mx.nd.Custom(
+        mx.nd.from_jax(x), op_type="numpy_softmax")._data)(
+            jnp.ones((2, 3)))
+    onp.testing.assert_allclose(onp.asarray(out),
+                                onp.full((2, 3), 1.0 / 3), rtol=1e-6)

@@ -21,20 +21,9 @@ import launch  # noqa: E402  (tools/launch.py)
 
 _WORKER = os.path.join(_REPO, "tests", "dist_worker.py")
 
-# the XLA CPU backend only executes computations whose devices span
-# processes (the cross-worker jitted reductions these tests assert) from
-# jax 0.5 on ("Multiprocess computations aren't implemented on the CPU
-# backend" before that); the liveness test below needs no cross-process
-# computation and runs everywhere
 import jax  # noqa: E402
 
-_cpu_multiprocess = pytest.mark.skipif(
-    jax.__version_info__ < (0, 5, 0),
-    reason="XLA CPU backend lacks cross-process computations on "
-           "jax<0.5 — the same path runs on DCN for real pods")
 
-
-@_cpu_multiprocess
 @pytest.mark.parametrize("n", [2, 8])
 def test_dist_sync_kvstore_multiprocess(n):
     env = dict(os.environ)
@@ -45,7 +34,6 @@ def test_dist_sync_kvstore_multiprocess(n):
     assert codes == [0] * n, codes
 
 
-@_cpu_multiprocess
 def test_dist_hybrid_topology_2x4():
     """2 processes x 4 virtual devices each: DCN x ICI hybrid mesh.
     The worker asserts bitwise-exact hybrid-sharded gradient aggregation,

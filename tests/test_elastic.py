@@ -144,8 +144,8 @@ def test_reshard_auto_knob_unsharded_and_back(mesh8):
     assert st_b._shard_n == 4 and all(st_b._shard_slots)
     _run(st_a, 2), _run(st_b, 2)
     for (ka, pa), (kb, pb) in zip(
-            sorted(net_a.collect_params().items()),
-            sorted(net_b.collect_params().items())):
+            net_a.collect_params().items(),
+            net_b.collect_params().items()):
         onp.testing.assert_allclose(pa.data().asnumpy(),
                                     pb.data().asnumpy(),
                                     rtol=2e-5, atol=2e-6, err_msg=ka)
@@ -196,8 +196,8 @@ def test_trainer_reshard_parity(mesh8):
     assert fused is not None and fused._shard_n == 4
     parallel.set_mesh(mesh8)
     for (ka, pa), (kb, pb) in zip(
-            sorted(net_a.collect_params().items()),
-            sorted(net_b.collect_params().items())):
+            net_a.collect_params().items(),
+            net_b.collect_params().items()):
         onp.testing.assert_allclose(pa.data().asnumpy(),
                                     pb.data().asnumpy(),
                                     rtol=2e-5, atol=2e-6, err_msg=ka)

@@ -81,35 +81,6 @@ def test_engine_knobs():
     mx.engine.set_bulk_size(prev)
 
 
-def test_compilation_cache_purges_unsafe_entries(tmp_path):
-    """enable_compilation_cache drops donated train-step executables
-    (jit_step_fn/jit_scan_fn, and jit_fused since the ZeRO sharded
-    update made the fused program relower after donation settles) from
-    the cache dir: reloading a donation-settled pair of them is
-    numerically wrong then fatal on jaxlib <= 0.4.36 (see
-    engine._UNSAFE_CACHE_PREFIXES)."""
-    import jax
-    from mxnet_tpu import engine, telemetry
-    d = tmp_path / "cache"
-    d.mkdir()
-    for name in ("jit_step_fn-abc123-cache", "jit_step_fn-abc123-atime",
-                 "jit_scan_fn-def456-cache", "jit_fused-777-cache",
-                 "jit_norm-888-cache"):
-        (d / name).write_bytes(b"x")
-    prev = jax.config.jax_compilation_cache_dir
-    try:
-        out = engine.enable_compilation_cache(str(d))
-    finally:
-        jax.config.update("jax_compilation_cache_dir", prev)
-    assert out == str(d)
-    left = sorted(p.name for p in d.iterdir())
-    assert left == ["jit_norm-888-cache"]
-    snap = telemetry.snapshot()
-    ev = [e for e in snap["events"]
-          if e["kind"] == "compilation_cache"]
-    assert ev and ev[-1]["count"] == 4
-
-
 def test_namespace_submodules_forward():
     """mx.nd.random / mx.nd.linalg / mx.sym.random / mx.sym.linalg mirror
     the upstream module layout (reference python/mxnet/ndarray/{random,

@@ -83,8 +83,8 @@ def test_data_parallel_matches_single_device(mesh8):
         l.backward()
         trainer.step(1)  # DataParallelStep takes the mean loss itself
 
-    for (ka, pa), (kb, pb) in zip(sorted(net_a.collect_params().items()),
-                                  sorted(net_b.collect_params().items())):
+    for (ka, pa), (kb, pb) in zip(net_a.collect_params().items(),
+                                  net_b.collect_params().items()):
         onp.testing.assert_allclose(
             pa.data().asnumpy(), pb.data().asnumpy(), rtol=2e-4, atol=2e-5)
 
@@ -118,8 +118,8 @@ def test_scan_steps_matches_sequential_calls(mesh8):
 
     onp.testing.assert_allclose(losses_scan.asnumpy(), losses_seq,
                                 rtol=1e-5, atol=1e-6)
-    for (ka, pa), (kb, pb) in zip(sorted(net_a.collect_params().items()),
-                                  sorted(net_b.collect_params().items())):
+    for (ka, pa), (kb, pb) in zip(net_a.collect_params().items(),
+                                  net_b.collect_params().items()):
         onp.testing.assert_allclose(
             pa.data().asnumpy(), pb.data().asnumpy(), rtol=2e-5, atol=2e-6)
 
@@ -157,8 +157,8 @@ def test_scan_steps_first_call_adam_is_finite(mesh8):
              for xi, yi in zip(x, y)]
     onp.testing.assert_allclose(losses.asnumpy(), l_seq, rtol=1e-5,
                                 atol=1e-6)
-    for (ka, pa), (kb, pb) in zip(sorted(net.collect_params().items()),
-                                  sorted(net_b.collect_params().items())):
+    for (ka, pa), (kb, pb) in zip(net.collect_params().items(),
+                                  net_b.collect_params().items()):
         onp.testing.assert_allclose(pa.data().asnumpy(),
                                     pb.data().asnumpy(),
                                     rtol=2e-5, atol=2e-6)
@@ -184,12 +184,12 @@ def test_scan_steps_then_call_interleave(mesh8):
 
 def test_psum_in_shard_map(mesh8):
     from jax.sharding import PartitionSpec as P
-    from mxnet_tpu.parallel.mesh import shard_map_compat
 
     def f(x):
         return parallel.psum(x, "dp")
 
-    fn = shard_map_compat(f, mesh=mesh8, in_specs=P("dp"), out_specs=P())
+    fn = jax.shard_map(f, mesh=mesh8, in_specs=P("dp"), out_specs=P(),
+                       check_vma=False)
 
     x = jnp.arange(8.0)
     out = fn(x)
@@ -350,3 +350,94 @@ def test_multi_precision_master_resyncs_on_external_set_data():
     w = net.weight.data().asnumpy().astype("float32")
     # one small-lr step away from the loaded value, NOT the stale master
     assert onp.abs(w - loaded).max() < 0.05, w
+
+
+# ---------------------------------------------------------------------------
+# Pallas kernels inside a program whose batch GSPMD shards
+# ---------------------------------------------------------------------------
+
+def _sharded(mesh, *arrays):
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    return [jax.device_put(a, NamedSharding(mesh, P("dp"))) for a in arrays]
+
+
+def test_per_batch_shard_is_a_plain_call_outside_a_scope(mesh8):
+    from mxnet_tpu.parallel.mesh import batch_sharded_over, per_batch_shard
+    x = jnp.arange(16.0).reshape(8, 2)
+    f = lambda a, b: (a * 2, None if b is None else b.sum())
+    y, s = per_batch_shard(f, (x, None))
+    assert s is None and float(y.sum()) == 2 * float(x.sum())
+    # a one-device axis is a plain call too
+    one = parallel.device_mesh((1,), ("dp",), devices=jax.devices()[:1])
+    with batch_sharded_over(one):
+        y, s = per_batch_shard(f, (x, x))
+    assert float(s) == float(x.sum())
+
+
+@pytest.mark.parametrize("lens", [False, True], ids=["nomask", "kv_lens"])
+def test_per_batch_shard_attention_matches_unsharded(mesh8, lens):
+    """The flash kernels (interpret mode here) wrapped per dp shard inside a
+    jitted program over batch-sharded operands give what the unsharded call
+    gives, forward and backward — the wrapping the custom-vjp ops apply on
+    a chip, where Mosaic kernels cannot be partitioned automatically."""
+    from mxnet_tpu.ops import pallas_attention as PA
+    from mxnet_tpu.parallel.mesh import batch_sharded_over, per_batch_shard
+    rs = onp.random.RandomState(0)
+    q, k, v, g = (jnp.asarray(rs.randn(8, 2, 128, 16).astype("float32"))
+                  for _ in range(4))
+    kl = jnp.asarray(rs.randint(40, 129, (8,)).astype("int32")) \
+        if lens else None
+    kw = dict(interpret=True, block_q=64, block_k=64)
+
+    def fwd(q, k, v, kl):
+        return PA.pallas_flash_attention(q, k, v, return_lse=True,
+                                         kv_lens=kl, **kw)
+
+    def bwd(q, k, v, out, lse, g, kl):
+        return PA.pallas_flash_attention_bwd(q, k, v, out, lse, g,
+                                             kv_lens=kl, **kw)
+
+    out, lse = fwd(q, k, v, kl)
+    want = (out, lse) + tuple(bwd(q, k, v, out, lse, g, kl))
+
+    @jax.jit
+    def program(q, k, v, g, kl):
+        with batch_sharded_over(mesh8):
+            out, lse = per_batch_shard(fwd, (q, k, v, kl))
+            grads = per_batch_shard(bwd, (q, k, v, out, lse, g, kl))
+        return (out, lse) + tuple(grads)
+
+    ops = _sharded(mesh8, q, k, v, g) + \
+        (_sharded(mesh8, kl) if lens else [None])
+    got = program(*ops)
+    assert len(got[0].sharding.device_set) == 8
+    for a, b in zip(got, want):
+        onp.testing.assert_allclose(onp.asarray(a), onp.asarray(b),
+                                    rtol=1e-5, atol=1e-5)
+
+
+def test_per_batch_shard_sums_the_norm_backward_reductions(mesh8):
+    """The BN-epilogue backward's per-column dscale/dshift are partial sums
+    per shard: flagged ``summed`` they come back whole and equal to the
+    unsharded reduction; dx/dresidual stay split by rows."""
+    from mxnet_tpu.ops import pallas_fused_norm as FN
+    from mxnet_tpu.parallel.mesh import batch_sharded_over, per_batch_shard
+    rs = onp.random.RandomState(1)
+    x, y, ct = (jnp.asarray(rs.randn(64, 256).astype("float32"))
+                for _ in range(3))
+    s = jnp.asarray(rs.rand(1, 256).astype("float32"))
+    bwd = functools.partial(FN.pallas_epilogue_bwd, interpret=True,
+                            block_r=8, block_c=128)
+    want = bwd(x, s, y, ct)
+
+    @jax.jit
+    def program(x, s, y, ct):
+        with batch_sharded_over(mesh8):
+            return per_batch_shard(bwd, (x, s, y, ct), replicated=(1,),
+                                   summed=(False, False, True, True))
+
+    xs, ys, cts = _sharded(mesh8, x, y, ct)
+    got = program(xs, s, ys, cts)
+    for a, b in zip(got, want):
+        onp.testing.assert_allclose(onp.asarray(a), onp.asarray(b),
+                                    rtol=1e-5, atol=1e-4)

@@ -58,8 +58,11 @@ def _build_step(mesh, shard, optimizer=None, bf16=False):
 
 
 def _params_close(net_a, net_b, rtol=2e-5, atol=2e-6):
-    for (ka, pa), (kb, pb) in zip(sorted(net_a.collect_params().items()),
-                                  sorted(net_b.collect_params().items())):
+    # creation order, not name order: the global layer counter makes
+    # "dense10_" sort before "dense9_", which mispaired the two nets
+    # whenever a worker happened to cross a power of ten between them
+    for (ka, pa), (kb, pb) in zip(net_a.collect_params().items(),
+                                  net_b.collect_params().items()):
         onp.testing.assert_allclose(
             pa.data().asnumpy().astype("float32"),
             pb.data().asnumpy().astype("float32"), rtol=rtol, atol=atol,
@@ -225,7 +228,6 @@ def test_reduce_scatter_padded_all_gather_unpad(mesh8):
     """Uneven leaf through the explicit shard_map spelling: N replicas
     each contribute, every replica ends with the summed full leaf."""
     from jax.sharding import PartitionSpec as P
-    from mxnet_tpu.parallel.mesh import shard_map_compat
 
     shape = (3, 7)   # 21 elements: pads to 24 over 8 replicas
     base = onp.arange(21, dtype="float32").reshape(shape)
@@ -235,7 +237,8 @@ def test_reduce_scatter_padded_all_gather_unpad(mesh8):
         assert shard.shape == (coll.padded_size(21, 8) // 8,)
         return coll.all_gather_unpad(shard, shape, "dp")
 
-    fn = shard_map_compat(f, mesh=mesh8, in_specs=P("dp"), out_specs=P())
+    fn = jax.shard_map(f, mesh=mesh8, in_specs=P("dp"), out_specs=P(),
+                       check_vma=False)
     stacked = jnp.asarray(
         onp.stack([base * (r + 1) for r in range(8)]))  # (8, 3, 7)
     out = fn(stacked.reshape(8, -1))
@@ -346,3 +349,23 @@ def test_trainer_unplaced_weights_keep_replicated_update(mesh8):
     _trainer_epoch(net, tr, mesh8, False, k=2)
     fused = tr._kv_fused or tr._local_fused
     assert fused is not None and not fused._sharded
+
+
+def test_eager_grad_buffers_follow_the_data_onto_the_mesh(mesh8):
+    """A Parameter's eager grad buffer lives where its data lives.  The
+    sharded step re-places the data on its mesh (first call) and again on a
+    re-formed mesh (``reshard``); left behind, a weight-sized buffer per
+    parameter stays on the first device — what a four-chip run showed as
+    uneven HBM."""
+    net, step = _build_step(mesh8, True)
+    params = list(net.collect_params().values())
+    assert all(len(p.grad()._data.devices()) == 1 for p in params)
+    step(mx.nd.array(_X), mx.nd.array(_Y))
+    for p in params:
+        assert p.grad()._data.sharding == p.data()._data.sharding
+        assert len(p.grad()._data.devices()) == 8
+    mesh4 = parallel.device_mesh((4,), ("dp",), devices=jax.devices()[:4])
+    step.reshard(mesh4)
+    for p in params:
+        assert p.grad()._data.devices() == p.data()._data.devices() \
+            == set(jax.devices()[:4])

@@ -13,7 +13,14 @@ best-effort fallback (too late if jax backends already initialized).
 Launchers:
   local — N processes on this host (the reference's ``--launcher local``
           test fixture, SURVEY.md §4 "distributed tests without a real
-          cluster").
+          cluster").  CPU-ONLY for N > 1: every worker gets the same
+          environment, so on a host with chips each would try to take
+          every chip, and a chip belongs to one process at a time.
+          ``launch_local`` refuses N > 1 unless the workers' environment
+          pins them to the CPU (``JAX_PLATFORMS=cpu``).  One process
+          drives all the chips of a host through a device mesh
+          (``python chip_smoke.py --chips 4``); N = 1 runs unrestricted,
+          and this launcher itself never touches jax.
   ssh   — one process per host from --hostfile.
 
 Env contract (set for each spawned process):
@@ -53,13 +60,21 @@ def _worker_env(base, coordinator, n, rank):
 
 
 def launch_local(n, command, env=None):
-    """Spawn n local workers; returns the list of exit codes."""
+    """Spawn n local workers; returns the list of exit codes.  More than
+    one worker on a host is a CPU fixture (see the module docstring)."""
+    env = os.environ if env is None else env
+    if n > 1 and env.get("JAX_PLATFORMS") != "cpu":
+        raise RuntimeError(
+            "launch_local(%d, ...) is CPU-only: %d workers with one "
+            "environment would each try to take every chip of this host. "
+            "Set JAX_PLATFORMS=cpu for the workers, or drive the chips "
+            "from one process with a device mesh." % (n, n))
     coordinator = "127.0.0.1:%d" % _free_port()
     procs = []
     for rank in range(n):
         procs.append(subprocess.Popen(
             command, shell=isinstance(command, str),
-            env=_worker_env(env or os.environ, coordinator, n, rank)))
+            env=_worker_env(env, coordinator, n, rank)))
     codes = [p.wait() for p in procs]
     return codes
 

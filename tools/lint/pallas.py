@@ -14,10 +14,10 @@ static VMEM-footprint estimate.
   via constant folding of the enclosing function (including ``min``-
   clamp chains), exceeds the module's explicit ``_VMEM_CLAMP`` budget.
 
-The folder follows the codebase's own sizing arithmetic: e.g. the fused
-dqkv backward's ``max_bq = max(8, (10 MiB)//(3*4*block_k))`` /
-``pow2 = 1 << (max_bq.bit_length()-1)`` clamp folds to block_q=256 at
-the default block_k=2048, and the footprint is checked *after* it.
+The folder follows the codebase's own sizing arithmetic: ``min``-clamp
+chains over literals fold, and the footprint is checked *after* them.  A
+block that comes out of a function call (the attention backward's
+``_bwd_block_q``) keeps its prior binding, the conservative upper bound.
 """
 from __future__ import annotations
 

@@ -85,6 +85,12 @@ class NumericsSanitizer:
         leaves record dtype only."""
         import jax.numpy as jnp
         arr = _unwrap(value)
+        if getattr(arr, "is_deleted", lambda: False)():
+            # a donated buffer (Trainer(donate_grads=True)): the update
+            # consumed it and there is nothing left to read.  Executing on
+            # it raises — and on the multi-device CPU backend of jaxlib
+            # 0.9.0 the failed launch then hangs the next sharded op.
+            return
         dt = str(arr.dtype)
         bad = 0
         if _is_inexact(arr.dtype):

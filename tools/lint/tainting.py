@@ -22,7 +22,10 @@ STATIC_ATTRS = {"shape", "ndim", "dtype", "size", "itemsize",
 # builtins whose result is host/static data regardless of args
 STATIC_FUNCS = {"len", "isinstance", "issubclass", "type", "hasattr",
                 "getattr", "callable", "id", "repr", "str", "format",
-                "range", "print", "sorted_keys"}
+                "range", "print", "sorted_keys",
+                # context.on_tpu(*arrays): a Python bool from where concrete
+                # operands are committed; a tracer only says "default backend"
+                "on_tpu"}
 
 SYNC_BUILTINS = {"float", "int", "bool", "complex"}
 SYNC_METHODS = {"item", "tolist", "block_until_ready",
