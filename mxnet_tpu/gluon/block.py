@@ -21,6 +21,7 @@ import re
 import threading
 from collections import OrderedDict
 
+import jax
 import numpy as onp
 
 from .. import autograd
@@ -278,7 +279,11 @@ class Block:
     def __call__(self, *args, **kwargs):
         for hook in self._forward_pre_hooks.values():
             hook(self, args)
-        out = self.forward(*args, **kwargs)
+        # the block's name on every operation traced under it: a device
+        # trace can then be reduced by block (metadata only; eagerly a
+        # push and a pop of the name stack)
+        with jax.named_scope(self._name or type(self).__name__):
+            out = self.forward(*args, **kwargs)
         for hook in self._forward_hooks.values():
             hook(self, args, out)
         return out

@@ -545,6 +545,7 @@ def pallas_flash_attention(q, k, v, causal=False, scale=None,
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel")),
             interpret=interpret,
+            name="flash_short_fwd",
         )(qp, kp, vp, *extra)
         out = out.reshape(B, H, Tqp, Dp)[:, :, :Tq, :D]
         if return_lse:
@@ -576,6 +577,7 @@ def pallas_flash_attention(q, k, v, causal=False, scale=None,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_stream_fwd",
     )(qp, kp, vp, *extra)
     out = out.reshape(B, H, Tqp, Dp)[:, :, :Tq, :D]
     if return_lse:
@@ -672,6 +674,7 @@ def pallas_flash_attention_bshd(q, k, v, causal=False, scale=None,
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "parallel")),
             interpret=interpret,
+            name="flash_bshd_short_fwd",
         )(qp, kp, vp, *extra)
         out = out.reshape(B, Tqp, H, Dp)[:, :Tq, :, :D]
         if return_lse:
@@ -711,6 +714,7 @@ def pallas_flash_attention_bshd(q, k, v, causal=False, scale=None,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
+        name="flash_bshd_stream_fwd",
     )(qp, kp, vp, *extra)
     out = out.reshape(B, Tqp, H, Dp)[:, :Tq, :, :D]
     if return_lse:
@@ -1049,6 +1053,7 @@ def pallas_flash_attention_bwd(q, k, v, out, lse, do, causal=False,
                 compiler_params=pltpu.CompilerParams(
                     dimension_semantics=("parallel",)),
                 interpret=interpret,
+                name="flash_dqkv_single",
             )(qp, kp, vp, dop, lsep, dltp, *fused_extra)
             dq = dq.reshape(B, H, Tqp, Dp)[:, :, :Tq, :D]
             dk = dk.reshape(B, H, Tkp, Dp)[:, :, :Tk, :D]
@@ -1086,6 +1091,7 @@ def pallas_flash_attention_bwd(q, k, v, out, lse, do, causal=False,
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary")),
             interpret=interpret,
+            name="flash_dqkv_fused",
         )(qp, kp, vp, dop, lsep, dltp, *fused_extra)
         dq = dq.reshape(B, H, Tqp, Dp)[:, :, :Tq, :D]
         dk = dk.reshape(B, H, Tkp, Dp)[:, :, :Tk, :D]
@@ -1130,6 +1136,7 @@ def pallas_flash_attention_bwd(q, k, v, out, lse, do, causal=False,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_dq",
     )(qp, kp, vp, dop, lsep, dltp, *dq_extra)
 
     kv_extra, kv_especs = extra_for(lambda i, j: i, lambda i, j: j)
@@ -1158,6 +1165,7 @@ def pallas_flash_attention_bwd(q, k, v, out, lse, do, causal=False,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_dkv",
     )(qp, kp, vp, dop, lsep, dltp, *kv_extra)
 
     dq = dq.reshape(B, H, Tqp, Dp)[:, :, :Tq, :D]
@@ -1243,6 +1251,7 @@ def pallas_flash_attention_bwd_bshd(q, k, v, out, lse, do, causal=False,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
+        name="flash_bshd_dq",
     )(qp, kp, vp, dop, lsep, dltp, *lops)
 
     lops, lspecs = lens_specs()
@@ -1276,6 +1285,7 @@ def pallas_flash_attention_bwd_bshd(q, k, v, out, lse, do, causal=False,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
+        name="flash_bshd_dkv",
     )(qp, kp, vp, dop, lsep, dltp, *lops)
 
     dq = dq.reshape(B, Tqp, H, Dp)[:, :Tq, :, :D]

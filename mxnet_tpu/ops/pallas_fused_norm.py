@@ -152,6 +152,7 @@ def pallas_epilogue_fwd(x2d, s_row, t_row, r2d, interpret=False,
         out_specs=pl.BlockSpec((block_r, block_c), lambda ci, ri: (ri, ci)),
         out_shape=jax.ShapeDtypeStruct((Rp, Cp), x2d.dtype),
         interpret=interpret,
+        name="bn_add_relu_fwd",
     )(xp, sp, tp, rp)
     return y[:R, :C]
 
@@ -199,6 +200,7 @@ def pallas_epilogue_bwd(x2d, s_row, y2d, ct2d, interpret=False,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="bn_add_relu_bwd",
     )(xp, sp, yp, ctp)
     return dx[:R, :C], dr[:R, :C], ds[:, :C], dt[:, :C]
 

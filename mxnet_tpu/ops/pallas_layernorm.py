@@ -120,6 +120,7 @@ def pallas_layer_norm_fwd(x2d, gamma, beta, eps, block_rows=_BLOCK_ROWS,
             jax.ShapeDtypeStruct((Np, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="layernorm_fwd",
     )(xp, g2, b2)
     return y[:N], mu[:N], rstd[:N]
 
@@ -164,6 +165,7 @@ def pallas_layer_norm_bwd(x2d, gamma, mu, rstd, ct2d,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
+        name="layernorm_bwd",
     )(xp, gamma.reshape(1, C), mup, rsp, ctp)
     return dx[:N], dg.reshape(C), db.reshape(C)
 

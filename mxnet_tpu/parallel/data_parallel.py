@@ -826,11 +826,75 @@ class DataParallelStep:
         # and step event are causally linked either way
         with telemetry.trace():
             with telemetry.span("parallel.step", hist=True,
-                                memory=(idx % 32 == 0)) as _sp:
+                                memory=(idx % 32 == 0),
+                                step_num=idx) as _sp:
                 out = self._dispatch_inner(data, label, scan)
             telemetry.emit_step("parallel", idx, step_ms=_sp.duration_ms,
                                 owner=self)
         return out
+
+    def _batch_sharding(self, ndim, scan):
+        """Where a batch array of ``ndim`` dimensions lives on the mesh:
+        its batch dimension over ``dp``."""
+        import jax.sharding as jsh
+        # under scan the leading dim is the step axis; the batch (dim 1)
+        # is the one sharded over dp
+        lead = (None, "dp") if scan else ("dp",)
+        spec = jsh.PartitionSpec(*lead, *([None] * (ndim - len(lead))))
+        return jsh.NamedSharding(self._mesh, spec)
+
+    @staticmethod
+    def _cache_key(dval, lval, scan):
+        """The key of the step program for a batch: mode, shapes and
+        dtypes (of arrays or of ``ShapeDtypeStruct``s)."""
+        sig = lambda v: (None if v is None
+                         else (tuple(v.shape), str(v.dtype)))
+        return ("scan" if scan else "call",
+                tuple(sig(d) for d in dval) if isinstance(dval, tuple)
+                else sig(dval), sig(lval))
+
+    def lower(self, data, label, scan=False):
+        """The step program a call with this batch runs, as
+        ``jax.stages.Lowered``: ``.as_text(debug_info=True)`` shows the
+        scopes (``jvp(forward)``, ``transpose(jvp(forward))``,
+        ``optimizer``, the blocks' names), ``.compile()`` gives
+        ``memory_analysis()`` and the optimised text.  Nothing runs and
+        no state moves.
+
+        Lowered at the specs of the NEXT call — parameters, optimizer
+        state and the device-side carries as the last step left them — so
+        the step must have run once at these shapes; compiling it then
+        hits the cache of the steady-state executable."""
+        def spec(v):
+            return jax.ShapeDtypeStruct(
+                v.shape, v.dtype,
+                sharding=v.sharding if getattr(v, "committed", False)
+                else None)
+
+        def batch_spec(x):
+            if x is None:
+                return None
+            val = x._data if isinstance(x, NDArray) else jnp.asarray(x)
+            if self._mesh is None:
+                return spec(val)
+            return jax.ShapeDtypeStruct(
+                val.shape, val.dtype,
+                sharding=self._batch_sharding(val.ndim, scan))
+
+        dspec = (tuple(batch_spec(d) for d in data)
+                 if isinstance(data, (tuple, list)) else batch_spec(data))
+        lspec = batch_spec(label)
+        jfn = self._cache.get(self._cache_key(dspec, lspec, scan))
+        if jfn is None:
+            raise RuntimeError(
+                "lower(): no step program for this batch yet — run the "
+                "step once at these shapes first")
+        state = [[p._data._data for p in self._params], self._opt_states,
+                 self._t_dev, self._lrs_dev, self._rng_dev]
+        if self._compress:
+            state.append(self._corrupt_ok_dev)
+        state = jax.tree_util.tree_map(spec, state)
+        return jfn.lower(*state[:5], dspec, lspec, *state[5:])
 
     def _dispatch_inner(self, data, label, scan):
         def prep(x):
@@ -850,16 +914,7 @@ class DataParallelStep:
                     # donate a private copy instead of the original
                     val = jnp.array(val, copy=True)
             if self._mesh is not None:
-                import jax.sharding as jsh
-                if scan:
-                    # leading dim is the step axis; the batch (dim 1) is
-                    # the one sharded over dp
-                    spec = jsh.PartitionSpec(None, "dp",
-                                             *([None] * (val.ndim - 2)))
-                else:
-                    spec = jsh.PartitionSpec("dp",
-                                             *([None] * (val.ndim - 1)))
-                target = jsh.NamedSharding(self._mesh, spec)
+                target = self._batch_sharding(val.ndim, scan)
                 # batches pre-placed by the input pipeline
                 # (``DevicePrefetchIter(mesh=...)`` lays per-replica
                 # shards directly on their target devices) skip even the
@@ -871,20 +926,17 @@ class DataParallelStep:
 
         # data may be a tuple of forward inputs (None entries allowed),
         # e.g. (tokens, token_types, mask, valid_length) for BERT
-        dval = (tuple(prep(d) for d in data) if isinstance(data, (tuple, list))
-                else prep(data))
-        lval = prep(label)
+        with telemetry.span("parallel.step.place"):
+            dval = (tuple(prep(d) for d in data)
+                    if isinstance(data, (tuple, list)) else prep(data))
+            lval = prep(label)
         if scan:
             first = (next(d for d in dval if d is not None)
                      if isinstance(dval, tuple) else dval)
             lead = first.shape[0]
         else:
             lead = 1
-        sig = lambda v: (None if v is None
-                         else (tuple(v.shape), str(v.dtype)))
-        key = ("scan" if scan else "call",
-               tuple(sig(d) for d in dval) if isinstance(dval, tuple)
-               else sig(dval), sig(lval))
+        key = self._cache_key(dval, lval, scan)
         jfn = self._cache.get(key)
         if jfn is None:
             # cache miss = an XLA retrace; report the structured key so
@@ -974,7 +1026,9 @@ class DataParallelStep:
             argv.append(self._corrupt_fire_dev if chaos.should_fire(
                 "grad_compress_corrupt", step=self._t)
                 else self._corrupt_ok_dev)
-        new_pvals, new_states, self._t_dev, self._rng_dev, loss = jfn(*argv)
+        with telemetry.span("parallel.step.call"):
+            new_pvals, new_states, self._t_dev, self._rng_dev, loss = \
+                jfn(*argv)
         if self._donate_batch:
             # remember this call's donated buffers so re-feeding one
             # raises in prep — accumulated (not replaced) so a buffer
@@ -1082,7 +1136,10 @@ class DataParallelStep:
                 full = list(pvals)
                 for i, v in zip(trainable, tvals):
                     full[i] = v
-                return fwd(full, use_key, dval, lval)
+                # one scope names both passes: jvp(forward) on forward
+                # operations, transpose(jvp(forward)) on backward ones
+                with jax.named_scope("forward"):
+                    return fwd(full, use_key, dval, lval)
 
             # a Pallas kernel in the forward or backward has to know
             # that GSPMD shards this program's batch over the dp axis
@@ -1092,36 +1149,38 @@ class DataParallelStep:
 
             new_pvals = list(pvals)
             new_states = []
-            for slot, (i, g) in enumerate(zip(trainable, grads)):
-                st_leaves = opt_states[slot]
-                if shard_slots[slot]:
-                    new_pvals[i], new_st = sharded_update(
-                        slot, i, pvals[i], g, t, lrs, st_leaves,
-                        corrupt)
+            with jax.named_scope("optimizer"):
+                for slot, (i, g) in enumerate(zip(trainable, grads)):
+                    st_leaves = opt_states[slot]
+                    if shard_slots[slot]:
+                        new_pvals[i], new_st = sharded_update(
+                            slot, i, pvals[i], g, t, lrs, st_leaves,
+                            corrupt)
+                        new_states.append(new_st)
+                        continue
+                    if mp_slots[slot]:
+                        # fp32 master path (reference mp_* kernels): update
+                        # the master, re-quantize the working weight from it
+                        master, rest = st_leaves[0], st_leaves[1:]
+                        res = steps[slot](master, g.astype(jnp.float32), t,
+                                          lrs[slot], *rest)
+                        new_master, new_rest = _opt.pin_update_dtypes(
+                            res, master, rest)
+                        new_pvals[i] = new_master.astype(pvals[i].dtype)
+                        new_states.append([new_master] + new_rest)
+                        continue
+                    # graftlint: disable-next=retrace-closure-array -- step
+                    # fns are per-slot constants; step_fn is jitted once per
+                    # (mode, shapes) cache key by design
+                    res = steps[slot](
+                        pvals[i], g, t, lrs[slot].astype(pvals[i].dtype),
+                        *st_leaves)
+                    # see optimizer.pin_update_dtypes: traced-t bias
+                    # corrections are strong f32 and once silently rewrote
+                    # bf16 params as f32 from step 2 on
+                    new_pvals[i], new_st = _opt.pin_update_dtypes(
+                        res, pvals[i], st_leaves)
                     new_states.append(new_st)
-                    continue
-                if mp_slots[slot]:
-                    # fp32 master path (reference mp_* kernels): update
-                    # the master, re-quantize the working weight from it
-                    master, rest = st_leaves[0], st_leaves[1:]
-                    res = steps[slot](master, g.astype(jnp.float32), t,
-                                      lrs[slot], *rest)
-                    new_master, new_rest = _opt.pin_update_dtypes(
-                        res, master, rest)
-                    new_pvals[i] = new_master.astype(pvals[i].dtype)
-                    new_states.append([new_master] + new_rest)
-                    continue
-                # graftlint: disable-next=retrace-closure-array -- step
-                # fns are per-slot constants; step_fn is jitted once per
-                # (mode, shapes) cache key by design
-                res = steps[slot](pvals[i], g, t,
-                                  lrs[slot].astype(pvals[i].dtype), *st_leaves)
-                # see optimizer.pin_update_dtypes: traced-t bias
-                # corrections are strong f32 and once silently rewrote
-                # bf16 params as f32 from step 2 on
-                new_pvals[i], new_st = _opt.pin_update_dtypes(
-                    res, pvals[i], st_leaves)
-                new_states.append(new_st)
             for i, v in mutated.items():
                 new_pvals[i] = v
             return new_pvals, new_states, t + 1, next_key, loss_val

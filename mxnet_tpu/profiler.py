@@ -157,35 +157,33 @@ class Domain:
 
 
 class _Span:
-    """start/stop scope emitting a TraceAnnotation (the engine's
-    opr_profile hook analogue, threaded_engine.h:85) AND a telemetry
-    span — the object model is live even when no XLA capture runs:
-    durations land in ``telemetry.snapshot()`` and the journal."""
+    """start/stop scope (the engine's opr_profile hook analogue,
+    threaded_engine.h:85): a telemetry span ``profiler.<label>``, which
+    is itself the ``TraceAnnotation`` in a running capture — the object
+    model is live even when no XLA capture runs: durations land in
+    ``telemetry.snapshot()`` and the journal.  Only with telemetry
+    disabled, when that span is a no-op, the scope annotates the trace
+    itself, so a region is never annotated twice and never lost."""
 
     def __init__(self, domain, name):
         self.domain = domain
         self.name = name
-        self._ann = None
-        self._tspan = None
+        self._scope = None
 
     def _label(self):
         return "%s::%s" % (self.domain.name, self.name) if self.domain \
             else self.name
 
     def start(self):
-        label = self._label()
-        self._ann = jax.profiler.TraceAnnotation(label)
-        self._ann.__enter__()
-        self._tspan = telemetry.span("profiler.%s" % label)
-        self._tspan.__enter__()
+        label = "profiler.%s" % self._label()
+        self._scope = telemetry.span(label) if telemetry.enabled() \
+            else jax.profiler.TraceAnnotation(label)
+        self._scope.__enter__()
 
     def stop(self):
-        if self._tspan is not None:
-            self._tspan.__exit__(None, None, None)
-            self._tspan = None
-        if self._ann is not None:
-            self._ann.__exit__(None, None, None)
-            self._ann = None
+        if self._scope is not None:
+            self._scope.__exit__(None, None, None)
+            self._scope = None
 
     def __enter__(self):
         self.start()

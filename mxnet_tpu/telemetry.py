@@ -17,7 +17,12 @@ Primitives
 ----------
 * ``span(name)`` — ``with telemetry.span("step"): ...`` scoped wall-time
   timer; aggregates (count/total/min/max/last) live in the snapshot and
-  each completed span appends a journal event.
+  each completed span appends a journal event.  The span is also a
+  ``jax.profiler.TraceAnnotation`` for its lifetime, so in ANY profiler
+  capture it is an event on the ``/host:CPU`` plane of the
+  ``.xplane.pb``, on the clock of the device planes.
+* ``recent_spans(name, n)`` — the last ``n`` completed spans of a name
+  with their children's durations, read back from the journal.
 * ``inc(name, delta)`` / ``counter(name)`` — monotonic counters.
 * ``gauge(name, value)`` — last-value gauges (ring occupancy, RSS, ...).
 * ``event(kind, name, **data)`` — structured entry in the bounded
@@ -46,9 +51,9 @@ Exporters
 * ``snapshot()`` — in-process dict (counters, gauges, span aggregates,
   compile counts, recent events); ``bench.py`` embeds it in BENCH
   artifacts.
-* ``export_chrome_trace(path)`` — chrome://tracing JSON of the journal's
-  spans/counters; written next to a ``jax.profiler`` capture it gives
-  the host-side timeline alongside the XLA device trace.
+* the profiler's own trace — scoped spans are written into whatever
+  ``jax.profiler`` capture is running (see ``span``); there is no second
+  timeline file.
 * ``export_jsonl(path)`` / ``set_jsonl_sink(path)`` — one-shot dump or
   streaming append of journal events as JSON lines
   (``tools/parse_log.py`` parses them back into tables).
@@ -63,8 +68,11 @@ import threading
 import time
 from collections import deque
 
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
+
 __all__ = [
-    "span", "observe", "span_event", "inc", "counter", "gauge", "event",
+    "span", "observe", "span_event", "recent_spans", "inc", "counter",
+    "gauge", "event",
     "snapshot", "reset", "enabled", "enable", "disable", "disabled",
     "trace", "current_trace", "current_span", "new_trace_id",
     "set_rank", "get_rank", "sync_clock",
@@ -72,7 +80,7 @@ __all__ = [
     "record_compile", "compile_counts", "compile_deltas",
     "sample_memory",
     "add_step_hook", "remove_step_hook", "emit_step",
-    "export_chrome_trace", "export_jsonl", "set_jsonl_sink",
+    "export_jsonl", "set_jsonl_sink",
     "JOURNAL_MAXLEN",
 ]
 
@@ -325,12 +333,14 @@ def _record_span(name, start, dur_s, journal=True, trace=None, sid=None,
 class _Span:
     """Scoped wall-time timer.  ``duration_ms`` is readable after exit.
     Inside an active trace context the journal record carries the trace
-    id plus a ``sid``/``parent`` chain (nested spans link causally)."""
+    id plus a ``sid``/``parent`` chain (nested spans link causally).
+    For the same interval the span is a profiler annotation (a step
+    annotation when it was given a ``step_num``)."""
 
     __slots__ = ("name", "memory", "hist", "_t0", "duration_ms",
-                 "_trace", "_sid", "_parent")
+                 "_trace", "_sid", "_parent", "_ann")
 
-    def __init__(self, name, memory=False, hist=False):
+    def __init__(self, name, memory=False, hist=False, step_num=None):
         self.name = name
         self.memory = memory
         self.hist = hist
@@ -339,6 +349,8 @@ class _Span:
         self._trace = None
         self._sid = None
         self._parent = None
+        self._ann = TraceAnnotation(name) if step_num is None \
+            else StepTraceAnnotation(name, step_num=step_num)
 
     def __enter__(self):
         self._trace = getattr(_tls, "trace", None)
@@ -346,11 +358,13 @@ class _Span:
             self._parent = getattr(_tls, "span", None)
             self._sid = _next_id()
             _tls.span = self._sid
+        self._ann.__enter__()
         self._t0 = _now()
         return self
 
     def __exit__(self, *a):
         dur = _now() - self._t0
+        self._ann.__exit__(None, None, None)
         self.duration_ms = dur * 1e3
         if self._trace is not None:
             _tls.span = self._parent
@@ -376,17 +390,68 @@ class _NoopSpan:
         return False
 
 
-def span(name, memory=False, hist=False):
+def span(name, memory=False, hist=False, step_num=None):
     """``with telemetry.span("step"): ...`` — time a scope.  With
-    ``hist=True`` the duration also feeds the ``name`` histogram."""
+    ``hist=True`` the duration also feeds the ``name`` histogram.
+
+    The scope is a ``jax.profiler.TraceAnnotation`` too: under any
+    profiler capture (``mx.profiler``, ``jax.profiler.start_trace``) the
+    span is an event of this thread's line on the trace's ``/host:CPU``
+    plane, on the device planes' clock.  With ``step_num`` it is a
+    ``StepTraceAnnotation`` carrying that stat (one training step).
+    Disabled telemetry gives a no-op span with no annotation."""
     if not _enabled:
         return _NoopSpan()
-    return _Span(name, memory=memory, hist=hist)
+    return _Span(name, memory=memory, hist=hist, step_num=step_num)
+
+
+def recent_spans(name, n):
+    """The last ``n`` completed ``name`` spans, oldest first, read from
+    the journal: ``(spans, short)``.  Each span is ``{"ts", "dur_ms",
+    "children"}`` with ``children`` mapping a child span's name to the
+    summed ``dur_ms`` of the spans that name this one as ``parent`` —
+    so self time is ``dur_ms - sum(children.values())``.  ``short`` is
+    how many of the ``n`` the journal no longer (or never) held: 0 means
+    the answer is whole.  A span whose children may already have fallen
+    off the bounded journal is not counted as held.
+
+    Children link through ``sid``/``parent``, which spans carry inside
+    a ``trace()`` context only."""
+    with _lock:
+        records = list(_journal)
+    if len(records) == _journal.maxlen:
+        # full journal: whatever was evicted was appended before the
+        # oldest record that is left, so only spans that STARTED after
+        # that moment are sure to have all their children here
+        oldest = records[0]
+        whole_after = oldest["ts"] + oldest.get("dur_ms", 0.0) * 1e-3 \
+            if oldest["kind"] == "span" else oldest["ts"]
+    else:
+        whole_after = None
+    children, found = {}, []
+    for rec in records:
+        if rec["kind"] != "span":
+            continue
+        parent = rec.get("parent")
+        if parent is not None:
+            kids = children.setdefault(parent, {})
+            kids[rec["name"]] = kids.get(rec["name"], 0.0) + rec["dur_ms"]
+        if rec["name"] == name and (whole_after is None
+                                    or rec["ts"] >= whole_after):
+            found.append(rec)
+    found = found[-n:] if n > 0 else []
+    # a span outside any trace() has no sid, and no record names None
+    spans = [{"ts": rec["ts"], "dur_ms": rec["dur_ms"],
+              "children": children.get(rec.get("sid"), {})}
+             for rec in found]
+    return spans, n - len(spans)
 
 
 def observe(name, dur_s, hist=False):
     """Record an externally-measured duration into the span aggregates
-    (for stages timed by hand, e.g. inside the prefetch feeder loop)."""
+    (for stages timed by hand, e.g. inside the prefetch feeder loop).
+    Aggregates only: a duration handed over after the fact is no scope,
+    so it is neither journaled nor a profiler annotation."""
     if not _enabled:
         return
     _record_span(name, _now() - dur_s, dur_s, journal=False)
@@ -402,7 +467,11 @@ def span_event(name, dur_s, trace=None, parent=None, hist=False, **data):
     detect -> reshard -> resume) — no thread-local context covers them,
     so the caller passes the trace id it carried on the request or the
     recovery event.  Updates the span aggregates like ``observe`` and,
-    with ``hist=True``, the ``name`` histogram."""
+    with ``hist=True``, the ``name`` histogram.
+
+    Journal-only: a profiler annotation opens and closes on ONE thread,
+    so a span timed across threads cannot be one and is not in the
+    profiler's trace."""
     if not _enabled:
         return
     start = _now() - dur_s
@@ -870,48 +939,4 @@ def export_jsonl(path):
             for r in events:
                 f.write(json.dumps(r, default=str) + "\n")
             f.write(json.dumps(rec, default=str) + "\n")
-    return path
-
-
-def export_chrome_trace(path=None):
-    """Write the journal as chrome://tracing JSON.
-
-    Spans become complete (``ph:"X"``) events on their recording
-    thread; counters at export time become one ``ph:"C"`` sample;
-    compile/recompile/step events become instants.  Default path:
-    ``telemetry.trace.json`` inside the profiler's trace dir, so the
-    file lands next to a ``jax.profiler`` capture and the two open in
-    the same viewer (host timeline + device timeline)."""
-    if path is None:
-        from . import profiler as _prof
-        path = os.path.join(_prof._trace_dir(), "telemetry.trace.json")
-    pid = os.getpid()
-    out = []
-    with _lock:
-        events = list(_journal)
-        counters = dict(_counters)
-    for rec in events:
-        ts_us = (rec["ts"] - _WALL0) * 1e6
-        if rec["kind"] == "span":
-            out.append({"name": rec["name"], "ph": "X", "pid": pid,
-                        "tid": rec.get("tid", 0), "ts": ts_us,
-                        "dur": rec.get("dur_ms", 0) * 1e3,
-                        "cat": "telemetry"})
-        else:
-            args = {k: v for k, v in rec.items()
-                    if k not in ("ts", "kind", "name")}
-            out.append({"name": "%s:%s" % (rec["kind"], rec["name"]),
-                        "ph": "i", "s": "p", "pid": pid,
-                        "tid": rec.get("tid", 0), "ts": ts_us,
-                        "cat": "telemetry", "args": args})
-    ts_us = _now() * 1e6
-    for name, val in counters.items():
-        out.append({"name": name, "ph": "C", "pid": pid, "ts": ts_us,
-                    "args": {"value": val}})
-    os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
-    from .fsutil import atomic_write_path
-    with atomic_write_path(path) as tmp:
-        with open(tmp, "w") as f:
-            json.dump({"traceEvents": out,
-                       "displayTimeUnit": "ms"}, f, default=str)
     return path

@@ -13,6 +13,7 @@ a test of this file has started — never at import, in a ``skipif`` or a
 from another worker.
 """
 import os
+import re
 
 import pytest
 
@@ -60,6 +61,33 @@ def _compile(fn, *specs):
     return text
 
 
+@pytest.fixture(scope="module")
+def compiled(one_chip):
+    """``compiled(case_id)`` -> the texts of the case's forward and
+    backward programs, each compiled once for the module: the test that a
+    case compiles and the test of its kernels' names read the same texts."""
+    texts = {}
+
+    def get(case_id):
+        if case_id not in texts:
+            texts[case_id] = [_compile(fn, *specs)
+                              for fn, specs in _PROGRAMS[case_id](one_chip)]
+        return texts[case_id]
+    return get
+
+
+def _kernel_names(text):
+    """The names of the ``tpu_custom_call`` instructions of a compiled
+    program, without ``%`` and the numbering XLA appends — what
+    ``benchmark/reduce_trace.op_name`` makes of a trace event."""
+    names = []
+    for line in text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' in line:
+            head = line.split(" = ", 1)[0].split()[-1].lstrip("%")
+            names.append(re.sub(r"(\.\d+)+$", "", head))
+    return names
+
+
 # (id, layout, B, H, S, D, dtype, causal, kv_lens, segment ids)
 _ATTENTION = [
     ("bert_s512_kvlens", "bhsd", 24, 12, 512, 64, "bfloat16", 0, 1, 0),
@@ -75,68 +103,127 @@ _ATTENTION = [
 ]
 
 
-@pytest.mark.parametrize("case", _ATTENTION, ids=[c[0] for c in _ATTENTION])
-def test_flash_attention_fwd_bwd_compiles_at_dispatcher_blocks(one_chip,
-                                                               case):
+def _attention_programs(case):
     """Forward and backward at the blocks ``attention_dispatch`` itself
     picks for the shape — the pair the custom-vjp ops hand the kernels."""
     _, layout, B, H, S, D, dtype, causal, lens, seg = case
     dtype = jnp.dtype(dtype)
-    plan = PA.attention_dispatch(S, S, D, dtype, on_tpu=True, census=False)
-    assert plan["kernel"] == ("short_seq" if S <= 2048 else "streaming")
-    blocks = dict(block_q=plan["block_q"], block_k=plan["block_k"])
 
-    def sds(shape, dt=dtype):
-        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    def programs(one_chip):
+        plan = PA.attention_dispatch(S, S, D, dtype, on_tpu=True,
+                                     census=False)
+        assert plan["kernel"] == ("short_seq" if S <= 2048 else "streaming")
+        blocks = dict(block_q=plan["block_q"], block_k=plan["block_k"])
 
-    qkv = sds((B, H, S, D) if layout == "bhsd" else (B, S, H, D))
-    lse = sds((B, H, S), jnp.float32)
-    masks = {}
-    if lens:
-        masks["kv_lens"] = sds((B,), jnp.int32)
-    if seg:
-        masks["q_segments"] = masks["kv_segments"] = sds((B, S), jnp.int32)
-    fwd_fn, bwd_fn = {
-        "bhsd": (PA.pallas_flash_attention, PA.pallas_flash_attention_bwd),
-        "bshd": (PA.pallas_flash_attention_bshd,
-                 PA.pallas_flash_attention_bwd_bshd)}[layout]
+        def sds(shape, dt=dtype):
+            return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
 
-    def fwd(q, k, v, masks):
-        return fwd_fn(q, k, v, causal=bool(causal), return_lse=True,
-                      **blocks, **masks)
+        qkv = sds((B, H, S, D) if layout == "bhsd" else (B, S, H, D))
+        lse = sds((B, H, S), jnp.float32)
+        masks = {}
+        if lens:
+            masks["kv_lens"] = sds((B,), jnp.int32)
+        if seg:
+            masks["q_segments"] = masks["kv_segments"] = sds((B, S),
+                                                             jnp.int32)
+        fwd_fn, bwd_fn = {
+            "bhsd": (PA.pallas_flash_attention,
+                     PA.pallas_flash_attention_bwd),
+            "bshd": (PA.pallas_flash_attention_bshd,
+                     PA.pallas_flash_attention_bwd_bshd)}[layout]
 
-    def bwd(q, k, v, out, lse, do, masks):
-        return bwd_fn(q, k, v, out, lse, do, causal=bool(causal),
-                      **blocks, **masks)
+        def fwd(q, k, v, masks):
+            return fwd_fn(q, k, v, causal=bool(causal), return_lse=True,
+                          **blocks, **masks)
 
-    _compile(fwd, qkv, qkv, qkv, masks)
-    _compile(bwd, qkv, qkv, qkv, qkv, lse, qkv, masks)
+        def bwd(q, k, v, out, lse, do, masks):
+            return bwd_fn(q, k, v, out, lse, do, causal=bool(causal),
+                          **blocks, **masks)
+
+        return [(fwd, (qkv, qkv, qkv, masks)),
+                (bwd, (qkv, qkv, qkv, qkv, lse, qkv, masks))]
+    return programs
 
 
-def test_layernorm_fwd_bwd_compiles_at_bert_shape(one_chip):
+def _layernorm_programs(one_chip):
     """Both LayerNorm kernels at BERT-base's (24*512, 768) activation."""
     N, C = 24 * 512, 768
     x = jax.ShapeDtypeStruct((N, C), jnp.bfloat16, sharding=one_chip)
     g = jax.ShapeDtypeStruct((C,), jnp.bfloat16, sharding=one_chip)
     stat = jax.ShapeDtypeStruct((N, 1), jnp.float32, sharding=one_chip)
     block = LN._pick_block_rows(C, rows=N, quiet=True)
-    _compile(lambda x, g, b: LN.pallas_layer_norm_fwd(
-        x, g, b, 1e-5, block_rows=block), x, g, g)
-    _compile(lambda x, g, mu, rs, ct: LN.pallas_layer_norm_bwd(
-        x, g, mu, rs, ct, block_rows=block), x, g, stat, stat, x)
+    return [(lambda x, g, b: LN.pallas_layer_norm_fwd(
+                x, g, b, 1e-5, block_rows=block), (x, g, g)),
+            (lambda x, g, mu, rs, ct: LN.pallas_layer_norm_bwd(
+                x, g, mu, rs, ct, block_rows=block), (x, g, stat, stat, x))]
 
 
-@pytest.mark.parametrize("rows,cols", [(128, 256 * 56 * 56),
-                                       (128, 2048 * 7 * 7)],
-                         ids=["stage1_256x56x56", "stage4_2048x7x7"])
-def test_bn_epilogue_fwd_bwd_compiles_in_bf16(one_chip, rows, cols):
+def _epilogue_programs(rows, cols):
     """The fused BN+add+ReLU epilogue at the 2D shapes the NCHW ResNet-50
-    bs=128 step collapses to (rows = N, cols = C*H*W), in bf16 — the
-    backward's ReLU-mask compare must not run in bf16 on a v5e."""
-    x = jax.ShapeDtypeStruct((rows, cols), jnp.bfloat16, sharding=one_chip)
-    s = jax.ShapeDtypeStruct((1, cols), jnp.float32, sharding=one_chip)
-    _compile(FN.pallas_epilogue_fwd, x, s, s, x)
-    _compile(FN.pallas_epilogue_bwd, x, s, x, x)
+    bs=128 step collapses to (rows = N, cols = C*H*W), in bf16."""
+    def programs(one_chip):
+        x = jax.ShapeDtypeStruct((rows, cols), jnp.bfloat16,
+                                 sharding=one_chip)
+        s = jax.ShapeDtypeStruct((1, cols), jnp.float32, sharding=one_chip)
+        return [(FN.pallas_epilogue_fwd, (x, s, s, x)),
+                (FN.pallas_epilogue_bwd, (x, s, x, x))]
+    return programs
+
+
+_EPILOGUE = {"stage1_256x56x56": (128, 256 * 56 * 56),
+             "stage4_2048x7x7": (128, 2048 * 7 * 7)}
+
+# case id -> one_chip -> [(function, specs)]: a forward and its backward
+_PROGRAMS = {c[0]: _attention_programs(c) for c in _ATTENTION}
+_PROGRAMS["layernorm_bert"] = _layernorm_programs
+_PROGRAMS.update((k, _epilogue_programs(*v)) for k, v in _EPILOGUE.items())
+
+# case id -> the kernels of its forward, of its backward: the dispatcher's
+# variants and the two layouts each under its own stable name
+_STREAM = (["flash_stream_fwd"], ["flash_dkv", "flash_dq"])
+_KERNELS = {
+    "bert_s512_kvlens": (["flash_short_fwd"], ["flash_dqkv_single"]),
+    "s2048": (["flash_short_fwd"], ["flash_dqkv_fused"]),
+    "s4096": _STREAM,
+    "s8192": _STREAM,
+    "s4096_causal_kvlens_segments": _STREAM,
+    "s8192_d128_causal": _STREAM,
+    "s4096_f32_segments": _STREAM,
+    "bshd_s512_kvlens": (["flash_bshd_short_fwd"],
+                         ["flash_bshd_dkv", "flash_bshd_dq"]),
+    "bshd_s4096_causal": (["flash_bshd_stream_fwd"],
+                          ["flash_bshd_dkv", "flash_bshd_dq"]),
+    "layernorm_bert": (["layernorm_fwd"], ["layernorm_bwd"]),
+    "stage1_256x56x56": (["bn_add_relu_fwd"], ["bn_add_relu_bwd"]),
+    "stage4_2048x7x7": (["bn_add_relu_fwd"], ["bn_add_relu_bwd"]),
+}
+
+
+@pytest.mark.parametrize("case_id", [c[0] for c in _ATTENTION])
+def test_flash_attention_fwd_bwd_compiles_at_dispatcher_blocks(compiled,
+                                                               case_id):
+    compiled(case_id)
+
+
+def test_layernorm_fwd_bwd_compiles_at_bert_shape(compiled):
+    compiled("layernorm_bert")
+
+
+@pytest.mark.parametrize("case_id", list(_EPILOGUE))
+def test_bn_epilogue_fwd_bwd_compiles_in_bf16(compiled, case_id):
+    """The backward's ReLU-mask compare must not run in bf16 on a v5e."""
+    compiled(case_id)
+
+
+@pytest.mark.parametrize("case_id", list(_PROGRAMS))
+def test_kernels_carry_their_stable_names(compiled, case_id):
+    """Every ``tpu_custom_call`` of the compiled forward and backward is
+    named by its ``pallas_call(name=...)`` — the name a device trace shows
+    and ``breakdown.device_ops`` reports — never by the jaxpr name stack
+    (``jvp__``, a block's scope) an unnamed kernel inherits."""
+    fwd_text, bwd_text = compiled(case_id)
+    assert (sorted(_kernel_names(fwd_text)),
+            sorted(_kernel_names(bwd_text))) == _KERNELS[case_id]
 
 
 def test_flash_attention_compiles_inside_a_dp4_sharded_program(topo,
@@ -168,4 +255,5 @@ def test_flash_attention_compiles_inside_a_dp4_sharded_program(topo,
             return jax.grad(loss, argnums=(0, 1, 2))(q, k, v, kv_lens)
 
     text = _compile(program, qkv, qkv, qkv, lens)
-    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert sorted(_kernel_names(text)) == ["flash_dqkv_single",
+                                           "flash_short_fwd"]

@@ -342,22 +342,6 @@ def test_export_jsonl_and_streaming_sink(tmp_path):
     assert snap_rec[0]["spans"]["exp.step"]["count"] == 1
 
 
-def test_export_chrome_trace(tmp_path):
-    with telemetry.span("ct.step"):
-        pass
-    telemetry.inc("ct.count")
-    telemetry.event("marker", "ct.mark")
-    path = tmp_path / "telemetry.trace.json"
-    telemetry.export_chrome_trace(str(path))
-    trace = json.loads(path.read_text())
-    evs = trace["traceEvents"]
-    xs = [e for e in evs if e["ph"] == "X"]
-    assert any(e["name"] == "ct.step" for e in xs)
-    assert all("ts" in e and "dur" in e for e in xs)
-    assert any(e["ph"] == "C" and e["name"] == "ct.count" for e in evs)
-    assert any(e["ph"] == "i" for e in evs)
-
-
 # ---------------------------------------------------------------------------
 # trace contexts (ISSUE 18): trace ids, sid/parent chains, rank stamps
 # ---------------------------------------------------------------------------
