@@ -1493,7 +1493,7 @@ def bench_autotune_census(searched_shape=(64, 256)):
     from mxnet_tpu import tune as _tune
     from mxnet_tpu.tune import model as _model
     from mxnet_tpu.tune import search as _search
-    from mxnet_tpu.tune.cost_table import baked_table_path
+    from mxnet_tpu.tune.cost_table import KERNEL_FAMILIES, baked_table_path
 
     table = _tune.get_table()
     entries = []
@@ -1506,7 +1506,7 @@ def bench_autotune_census(searched_shape=(64, 256)):
             "baked": bool(rec.get("baked")),
             "best_ms": rec.get("best_ms")})
     models = {}
-    for family in ("attention", "fused_norm", "layernorm"):
+    for family in KERNEL_FAMILIES:
         m = _model.get_model(family, table=table)
         if m is None:
             models[family] = {"usable": False, "reason":

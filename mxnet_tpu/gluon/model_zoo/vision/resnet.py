@@ -6,13 +6,12 @@ pre-activation).  Constructor surface matches the reference
 
 TPU notes: every residual stage is a chain of convolutions XLA lowers onto
 the MXU; the whole network hybridizes into one XLA program.  The v1
-residual-unit tail — last BN, skip add, ReLU — runs as the fused Pallas
-epilogue (``nn.BatchNormAddReLU`` → ``ops/pallas_fused_norm.py``): XLA
-left it as separate loop fusions re-reading the activation from HBM,
-profiled at ~13% of the (HBM-bound) train step.  The fused layer keeps
-the plain BatchNorm's auto-naming alias and grid position, so parameter
-names and checkpoints are unchanged.  Train in bf16 via ``amp`` or
-``net.cast('bfloat16')`` for the headline numbers.
+residual-unit tail — last BN, skip add, ReLU — is one layer
+(``nn.BatchNormAddReLU``, MXNet's ``_contrib_BatchNormAddRelu``) whose
+arithmetic is plain ``jax.numpy``: XLA runs it as one fusion each way in
+the layout the convolutions already use.  The layer keeps the plain BatchNorm's
+auto-naming alias and grid position, so parameter names and checkpoints
+are unchanged.  Train in bf16 via ``amp`` or ``net.cast('bfloat16')``.
 """
 from __future__ import annotations
 
@@ -43,7 +42,7 @@ class BasicBlockV1(HybridBlock):
         self.body.add(nn.BatchNorm())
         self.body.add(nn.Activation("relu"))
         self.body.add(_conv3x3(channels, 1, channels))
-        # last BN of the body fuses the residual add + ReLU tail; it
+        # last BN of the body takes the residual add + ReLU tail; it
         # shares BatchNorm's auto-naming alias and sits at the same
         # position, so parameter/checkpoint names are unchanged
         self.body.add(nn.BatchNormAddReLU())
@@ -82,7 +81,7 @@ class BottleneckV1(HybridBlock):
         self.body.add(nn.Activation("relu"))
         self.body.add(nn.Conv2D(channels, kernel_size=1, strides=1,
                                 use_bias=False))
-        # fused BN + residual-add + ReLU tail (see BasicBlockV1)
+        # BN + residual-add + ReLU tail (see BasicBlockV1)
         self.body.add(nn.BatchNormAddReLU())
         if downsample:
             self.downsample = nn.HybridSequential(prefix="")

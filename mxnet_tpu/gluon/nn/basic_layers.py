@@ -232,8 +232,8 @@ class BatchNormAddReLU(BatchNorm):
     same moving-stats handling, and the same auto-naming alias as
     :class:`BatchNorm`, so substituting it for the last BatchNorm of a
     residual body keeps parameter names and checkpoints identical.  The
-    elementwise tail runs in the fused Pallas epilogue kernel on TPU
-    (``ops/pallas_fused_norm.py``)."""
+    elementwise tail is plain ``jax.numpy`` in fp32 (``ops/nn.py``), one
+    XLA fusion in the convolutions' own layout."""
 
     def _alias(self):
         return "batchnorm"

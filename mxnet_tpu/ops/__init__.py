@@ -17,6 +17,5 @@ from . import contrib_ops   # noqa: F401
 from . import detection     # noqa: F401
 from . import quantization  # noqa: F401
 from . import pallas_attention  # noqa: F401
-from . import pallas_fused_norm  # noqa: F401
 
 __all__ = ["OpDef", "register", "get_op", "list_ops", "alias"]

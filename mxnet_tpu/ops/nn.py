@@ -192,6 +192,36 @@ def upsampling(*data, scale: int = 1, sample_type: str = "nearest",
 # normalization
 # ---------------------------------------------------------------------------
 
+def _bn_stats(data, moving_mean, moving_var, axis, batch):
+    """Per-channel (mean, var): the moving statistics, or with ``batch``
+    the one-pass batch statistics :func:`batch_norm` documents."""
+    if not batch:
+        return moving_mean, moving_var
+    ax = tuple(i for i in range(data.ndim) if i != (axis % data.ndim))
+    mean = jnp.mean(data, axis=ax, dtype=jnp.float32)
+    sq = jnp.mean(jnp.square(data), axis=ax, dtype=jnp.float32)
+    # clamp: fp32 cancellation on a large-mean/low-variance channel can
+    # drive E[x²]−E[x]² slightly negative → rsqrt NaN
+    var = jnp.maximum(sq - jnp.square(mean), 0.0)
+    # under backward-mirror remat the (tiny) per-channel stats are saved
+    # so the bwd recompute never re-reduces the big activation tensor
+    mean = _remat_name(mean.astype(data.dtype), "bn_stats")
+    var = _remat_name(var.astype(data.dtype), "bn_stats")
+    return mean, var
+
+
+def _bn_fold(data, mean, var, gamma, beta, eps, fix_gamma, axis):
+    """(mean, var, gamma, beta) folded in fp32 into per-channel
+    (scale, shift) vectors, and the shape that broadcasts them on
+    ``axis`` of ``data``."""
+    g = jnp.ones_like(gamma) if fix_gamma else gamma
+    shape = [1] * data.ndim
+    shape[axis % data.ndim] = data.shape[axis % data.ndim]
+    scale = lax.rsqrt(var.astype(jnp.float32) + eps) * g.astype(jnp.float32)
+    shift = beta.astype(jnp.float32) - mean.astype(jnp.float32) * scale
+    return scale, shift, tuple(shape)
+
+
 def _bn_apply(data, mean, var, gamma, beta, eps, fix_gamma, axis):
     """Normalize + affine, the part shared by BatchNorm / SyncBatchNorm.
 
@@ -201,15 +231,10 @@ def _bn_apply(data, mean, var, gamma, beta, eps, fix_gamma, axis):
     wide intermediates alive, while scale/shift is a single fused
     multiply-add over the (HBM-bandwidth-bound) activation tensor.
     """
-    g = jnp.ones_like(gamma) if fix_gamma else gamma
-    shape = [1] * data.ndim
-    shape[axis % data.ndim] = data.shape[axis % data.ndim]
-    shp = tuple(shape)
-    mean32 = mean.astype(jnp.float32)
-    inv = lax.rsqrt(var.astype(jnp.float32) + eps) * g.astype(jnp.float32)
-    scale = inv.astype(data.dtype)
-    shift = (beta.astype(jnp.float32) - mean32 * inv).astype(data.dtype)
-    out = data * scale.reshape(shp) + shift.reshape(shp)
+    scale, shift, shp = _bn_fold(data, mean, var, gamma, beta, eps,
+                                 fix_gamma, axis)
+    out = data * scale.astype(data.dtype).reshape(shp) \
+        + shift.astype(data.dtype).reshape(shp)
     return out, lax.stop_gradient(mean), lax.stop_gradient(var)
 
 
@@ -233,19 +258,8 @@ def batch_norm(data, gamma, beta, moving_mean, moving_var,
     costs ~10% of a ResNet-50 train step on a bandwidth-bound v5e chip.
     One-pass lets XLA fuse BOTH reductions into the producing conv.
     """
-    ax = tuple(i for i in range(data.ndim) if i != (axis % data.ndim))
-    if use_global_stats or not training:
-        mean, var = moving_mean, moving_var
-    else:
-        mean = jnp.mean(data, axis=ax, dtype=jnp.float32)
-        sq = jnp.mean(jnp.square(data), axis=ax, dtype=jnp.float32)
-        # clamp: fp32 cancellation on a large-mean/low-variance channel can
-        # drive E[x²]−E[x]² slightly negative → rsqrt NaN
-        var = jnp.maximum(sq - jnp.square(mean), 0.0)
-        # under backward-mirror remat the (tiny) per-channel stats are saved
-        # so the bwd recompute never re-reduces the big activation tensor
-        mean = _remat_name(mean.astype(data.dtype), "bn_stats")
-        var = _remat_name(var.astype(data.dtype), "bn_stats")
+    mean, var = _bn_stats(data, moving_mean, moving_var, axis,
+                          training and not use_global_stats)
     return _bn_apply(data, mean, var, gamma, beta, eps, fix_gamma, axis)
 
 
@@ -257,35 +271,30 @@ def batch_norm_add_relu(data, residual, gamma, beta, moving_mean, moving_var,
                         use_global_stats: bool = False,
                         output_mean_var: bool = False, axis: int = 1,
                         cudnn_off: bool = False, training: bool = True):
-    """BatchNorm → residual add → ReLU as ONE epilogue (reference: the
-    cuDNN ``BatchNormAddRelu`` fused op MXNet enables on GPU for exactly
-    the ResNet residual-unit tail).
+    """BatchNorm → residual add → ReLU, the tail of a ResNet residual unit
+    (reference: the cuDNN ``BatchNormAddRelu`` op MXNet enables on GPU).
 
     Statistics are computed exactly as :func:`batch_norm` (one-pass
-    E[x²]−E[x]² in fp32, clamped, remat-named); the normalize/affine is
-    folded into per-channel fp32 scale/shift and the elementwise tail
-    ``relu(x*scale + shift + residual)`` runs in the fused Pallas
-    epilogue kernel on TPU (``ops/pallas_fused_norm.py``) — one read +
-    one write instead of the 2-3 loop fusions XLA emits for the
-    composed form (profiled at ~13% of the ResNet-50 step).  Returns
-    (out, batch_mean, batch_var) like BatchNorm; the moving-average
-    update stays with the caller."""
-    from .pallas_fused_norm import fused_bn_add_relu_epilogue
-
-    ax = tuple(i for i in range(data.ndim) if i != (axis % data.ndim))
-    if use_global_stats or not training:
-        mean, var = moving_mean, moving_var
-    else:
-        mean = jnp.mean(data, axis=ax, dtype=jnp.float32)
-        sq = jnp.mean(jnp.square(data), axis=ax, dtype=jnp.float32)
-        var = jnp.maximum(sq - jnp.square(mean), 0.0)
-        mean = _remat_name(mean.astype(data.dtype), "bn_stats")
-        var = _remat_name(var.astype(data.dtype), "bn_stats")
-    g = jnp.ones_like(gamma) if fix_gamma else gamma
-    inv = lax.rsqrt(var.astype(jnp.float32) + eps) * g.astype(jnp.float32)
-    shift = beta.astype(jnp.float32) - mean.astype(jnp.float32) * inv
-    out = fused_bn_add_relu_epilogue(data, inv, shift, residual,
-                                     axis % data.ndim)
+    E[x²]−E[x]² in fp32, clamped, remat-named).  The normalize/affine is
+    folded into per-channel fp32 scale/shift, and
+    ``relu(x*scale + shift + residual)`` is plain ``jax.numpy`` on the ND
+    tensor, accumulated in fp32 with ONE cast to ``data.dtype`` at the
+    end: XLA runs it as one fusion in the layout the convolutions already
+    use, and JAX differentiates it.  Returns (out, batch_mean,
+    batch_var) like BatchNorm; the moving-average update stays with the
+    caller."""
+    # graftlint: disable-next=retrace-shape-branch -- shape validation:
+    # raises on mismatch, no per-shape code paths
+    if residual.shape != data.shape:
+        raise ValueError("residual shape %r must match data shape %r"
+                         % (residual.shape, data.shape))
+    mean, var = _bn_stats(data, moving_mean, moving_var, axis,
+                          training and not use_global_stats)
+    scale, shift, shp = _bn_fold(data, mean, var, gamma, beta, eps,
+                                 fix_gamma, axis)
+    out = (data.astype(jnp.float32) * scale.reshape(shp)
+           + shift.reshape(shp) + residual.astype(jnp.float32))
+    out = jnp.maximum(out, 0.0).astype(data.dtype)
     return out, lax.stop_gradient(mean), lax.stop_gradient(var)
 
 

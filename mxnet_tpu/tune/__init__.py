@@ -1,14 +1,14 @@
 """Search-based Pallas autotuner with persistent cost tables.
 
-The three Pallas kernel families (flash attention, fused BN epilogue,
-fused LayerNorm) pick their block shapes with hand-derived min()-clamp
+The two Pallas kernel families (flash attention, fused LayerNorm)
+pick their block shapes with hand-derived min()-clamp
 heuristics tuned once for v5e defaults.  This package replaces "tuned
 once" with the TVM recipe (arxiv 1802.04799): enumerate a small config
 space, prune it through the kernels' own static VMEM predicate, time
 the survivors, and persist the winner in an on-disk cost table keyed
 like the jit cache — (family, shape, dtype, chip, schema).
 
-Dispatch contract (``attention_dispatch`` and the norm block pickers
+Dispatch contract (``attention_dispatch`` and the layernorm block picker
 consult :func:`table_config` first):
 
 * **default mode measures nothing** — no table on disk and

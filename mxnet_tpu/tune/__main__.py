@@ -11,13 +11,12 @@ Searches each instance with the same driver the on-miss dispatch path
 uses (wider default budget — offline time is cheap) and persists the
 winners to the cost table, one JSON result line per instance.  Shapes
 are colon-separated per family: attention ``seq_q:seq_k:head_dim``,
-fused_norm ``rows:cols``, layernorm ``rows:channels`` (the norm
-families key dtype-blind — their VMEM working sets are fp32 whatever
-the operand dtype — so ``--dtype`` only picks the measurement
-operands).  ``--interpret`` runs the kernels in Pallas interpret mode
-so a table can be exercised end-to-end off-TPU (functional, not
-representative — never ship interpret-mode timings as a real chip's
-table).
+layernorm ``rows:channels`` (keyed dtype-blind — its VMEM working set
+is fp32 whatever the operand dtype — so ``--dtype`` only picks the
+measurement operands).  ``--interpret`` runs the kernels in Pallas
+interpret mode so a table can be exercised end-to-end off-TPU
+(functional, not representative — never ship interpret-mode timings as
+a real chip's table).
 
 Kernel searches are model-ranked when the learned cost model
 (``tune.model``) is trained and within its CV gate — ``--no-model``
@@ -41,7 +40,7 @@ import sys
 from . import get_table, platform_id, search
 from .cost_table import FAMILY_FIELDS
 
-_SHAPE_ARITY = {"attention": 3, "fused_norm": 2, "layernorm": 2,
+_SHAPE_ARITY = {"attention": 3, "layernorm": 2,
                 "prog_prefetch": 1, "prog_scan": 2, "prog_zero": 2,
                 "prog_buckets": 1}
 

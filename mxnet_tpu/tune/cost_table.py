@@ -36,7 +36,6 @@ SCHEMA_VERSION = 1
 # family -> ordered config fields (the tuple order table_blocks returns)
 FAMILY_FIELDS = {
     "attention": ("block_q", "block_k"),
-    "fused_norm": ("block_r", "block_c"),
     "layernorm": ("block_rows",),
     # program-level schedule knobs (tune.program) share the store and
     # its discipline: same schema, same atomicity, same provenance
@@ -53,14 +52,14 @@ FAMILY_FIELDS = {
 # kernel families a table MISS may trigger a measured kernel search for
 # (tune.search.candidates only knows these; prog_* misses must resolve
 # through tune.program's own search, never a kernel grid)
-KERNEL_FAMILIES = ("attention", "fused_norm", "layernorm")
+KERNEL_FAMILIES = ("attention", "layernorm")
 
-# the norm kernels hold their working values as fp32 in VMEM regardless
-# of the operand dtype, so their block choice is dtype-blind: the table
-# key pins dtype="float32" for them (an entry baked from bf16 operands
-# serves the f32 run and vice versa — and the offline CLI's default
-# --dtype cannot strand an entry under an unreachable key)
-_KEY_DTYPE = {"fused_norm": "float32", "layernorm": "float32",
+# the layernorm kernels hold their working values as fp32 in VMEM
+# regardless of the operand dtype, so their block choice is dtype-blind:
+# the table key pins dtype="float32" for them (an entry baked from bf16
+# operands serves the f32 run and vice versa — and the offline CLI's
+# default --dtype cannot strand an entry under an unreachable key)
+_KEY_DTYPE = {"layernorm": "float32",
               # program knobs are dtype-blind by construction: their
               # shapes are workload descriptors (batch, params, dp...),
               # not array operands — EXCEPT prog_compress, whose knob

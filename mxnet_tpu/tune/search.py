@@ -9,8 +9,8 @@ dispatch search both call :func:`search_config`.
 
 Candidate pruning REUSES the kernels' own sizing arithmetic —
 ``_blocks_fit`` (forward and backward VMEM budgets) from
-``ops/pallas_attention`` and the ``_VMEM_BUDGET`` constants from the
-norm modules — the exact
+``ops/pallas_attention`` and the ``_VMEM_BUDGET`` constant from
+``ops/pallas_layernorm`` — the exact
 expressions graftlint's static pallas estimator folds, so no invalid
 candidate is ever timed and the static rule rejects anything the
 search could not have emitted.
@@ -43,11 +43,6 @@ _ATTN_INNER = 4          # chained fwd+bwd iterations inside one jit
 
 _BQ_CANDIDATES = (128, 256, 512, 1024, 2048)
 _BK_CANDIDATES = (128, 256, 512, 1024, 2048)
-# the fused-norm bwd holds 5 f32 blocks (the fwd 3): one table entry per
-# (rows, cols) serves both passes, sized at the conservative bwd set
-_NORM_N_BUFS = 5
-_BR_CANDIDATES = (8, 16, 32, 64, 128, 256, 512)
-_BC_CANDIDATES = (128, 256, 512, 1024)
 _LN_ROW_CANDIDATES = (8, 16, 32, 64, 128, 256, 512, 1024)
 
 
@@ -122,15 +117,6 @@ def heuristic_config(family: str, shape: Sequence[int],
         seq_q, seq_k, head_dim = shape
         bq, bk = tune_attention_blocks(seq_q, seq_k, head_dim, dtype)
         return {"block_q": bq, "block_k": bk}
-    if family == "fused_norm":
-        from ..ops.pallas_fused_norm import _pick_blocks_heuristic
-        rows, cols = shape
-        # fwd holds 3 f32 blocks, bwd 5; ONE (rows, cols) table entry
-        # serves both, so size at the conservative bwd working set
-        picked = _pick_blocks_heuristic(rows, cols, _NORM_N_BUFS)
-        if picked is None:
-            return None
-        return {"block_r": picked[0], "block_c": picked[1]}
     if family == "layernorm":
         from ..ops.pallas_layernorm import _pick_block_rows_heuristic
         rows, C = shape
@@ -166,12 +152,6 @@ def valid_config(family: str, shape: Sequence[int], dtype,
             Dp = head_dim + (-head_dim) % 64
             itemsize = jnp.dtype(dtype).itemsize
             return _blocks_fit(bq, bk, Dp, itemsize)
-        if family == "fused_norm":
-            from ..ops.pallas_fused_norm import _VMEM_BUDGET
-            br, bc = int(config["block_r"]), int(config["block_c"])
-            return br >= 8 and br % 8 == 0 and bc >= 128 \
-                and bc % 128 == 0 \
-                and br * bc * 4 * _NORM_N_BUFS <= _VMEM_BUDGET
         if family == "layernorm":
             from ..ops.pallas_layernorm import _VMEM_BUDGET
             rows, C = shape
@@ -198,9 +178,6 @@ def config_vmem_bytes(family: str, shape: Sequence[int], dtype,
             return int(_fwd_vmem_bytes(int(config["block_q"]),
                                        int(config["block_k"]), Dp,
                                        jnp.dtype(dtype).itemsize))
-        if family == "fused_norm":
-            return int(config["block_r"]) * int(config["block_c"]) \
-                * 4 * _NORM_N_BUFS
         if family == "layernorm":
             _, C = shape
             return 3 * 4 * int(config["block_rows"]) * int(C)
@@ -253,14 +230,6 @@ def candidates(family: str, shape: Sequence[int],
                      | {_rup(seq_k, _LANES)})
         grid = [{"block_q": bq, "block_k": bk}
                 for bq in bqs for bk in bks]
-    elif family == "fused_norm":
-        rows, cols = shape
-        brs = sorted({min(b, max(8, _rup(rows, 8)))
-                      for b in _BR_CANDIDATES})
-        bcs = sorted({min(b, max(128, _rup(cols, 128)))
-                      for b in _BC_CANDIDATES})
-        grid = [{"block_r": br, "block_c": bc}
-                for br in brs for bc in bcs]
     elif family == "layernorm":
         rows, _ = shape
         grid = [{"block_rows": b}
@@ -342,29 +311,6 @@ def measure_attention_config(batch, heads, seq_q, seq_k, head_dim, dtype,
                     timer=timer) / max(1, inner)
 
 
-def _measure_fused_norm(shape, dtype, config, calls, warmup, timer,
-                        interpret):
-    import jax
-    from ..ops import pallas_fused_norm as fn
-
-    rows, cols = shape
-    br, bc = int(config["block_r"]), int(config["block_c"])
-    x, r, ct = _rand_operands(((rows, cols),) * 3, dtype)
-    s, t = _rand_operands(((1, cols),) * 2, "float32", seed=1)
-
-    @jax.jit
-    def step(x, s, t, r, ct):
-        y = fn.pallas_epilogue_fwd(x, s, t, r, block_r=br, block_c=bc,
-                                   interpret=interpret)
-        dx, dr, ds, dt = fn.pallas_epilogue_bwd(x, s, y, ct, block_r=br,
-                                                block_c=bc,
-                                                interpret=interpret)
-        return y, dx, dr, ds, dt
-
-    return min_time(lambda: step(x, s, t, r, ct), calls=calls,
-                    warmup=warmup, timer=timer)
-
-
 def _measure_layernorm(shape, dtype, config, calls, warmup, timer,
                        interpret):
     import jax
@@ -393,17 +339,14 @@ def _measure_candidate(family, shape, dtype, config, calls=DEFAULT_CALLS,
                        warmup=DEFAULT_WARMUP, timer=None,
                        interpret=False):
     """Milliseconds for one candidate (module-level so tests can inject
-    a fake).  Attention reports per-inner-iteration time; the norm
-    families a full fwd+bwd pass."""
+    a fake).  Attention reports per-inner-iteration time; layernorm
+    a full fwd+bwd pass."""
     if family == "attention":
         seq_q, seq_k, head_dim = shape
         s = measure_attention_config(_ATTN_BATCH, _ATTN_HEADS, seq_q,
                                      seq_k, head_dim, dtype, config,
                                      calls=calls, warmup=warmup,
                                      timer=timer, interpret=interpret)
-    elif family == "fused_norm":
-        s = _measure_fused_norm(shape, dtype, config, calls, warmup,
-                                timer, interpret)
     elif family == "layernorm":
         s = _measure_layernorm(shape, dtype, config, calls, warmup,
                                timer, interpret)

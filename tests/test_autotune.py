@@ -5,8 +5,8 @@ reload → dispatch hit), corrupt/stale-schema tolerance (heuristic
 fallback, never a crash), deterministic offline search under a fake
 measurer, the strict dispatch-time trial budget, and — the regression
 guard — that DEFAULT dispatch (no table, no ``MXNET_AUTOTUNE``) is
-bit-identical to the pre-autotuner heuristics for attention and both
-norm block pickers.
+bit-identical to the pre-autotuner heuristics for attention and the
+layernorm block picker.
 """
 import json
 import os
@@ -15,7 +15,6 @@ import pytest
 
 from mxnet_tpu import context, telemetry, tune
 from mxnet_tpu.ops import pallas_attention as PA
-from mxnet_tpu.ops import pallas_fused_norm as FN
 from mxnet_tpu.ops import pallas_layernorm as LN
 from mxnet_tpu.tune import search
 from mxnet_tpu.tune.cost_table import CostTable, SCHEMA_VERSION
@@ -64,19 +63,11 @@ def test_cost_table_roundtrip_dispatch_hit():
 
 def test_norm_pickers_consult_table():
     t = tune.get_table()
-    # norm families key dtype-blind (fp32 VMEM working set): an entry
+    # layernorm keys dtype-blind (fp32 VMEM working set): an entry
     # recorded from bf16 operands serves the picker's float32 lookup
-    t.record("fused_norm", (4096, 512), "bfloat16",
-             {"block_r": 64, "block_c": 256})
-    t.record("layernorm", (4096, 1024), "float32", {"block_rows": 128})
-    # ONE (rows, cols) entry serves BOTH the fwd (3-buf) and bwd
-    # (5-buf) pickers — fwd and bwd must run the same measured blocks
-    assert FN._pick_blocks(4096, 512, 3) == (64, 256)
-    assert FN._pick_blocks(4096, 512, 5) == (64, 256)
+    t.record("layernorm", (4096, 1024), "bfloat16", {"block_rows": 128})
     assert LN._pick_block_rows(1024, rows=4096) == 128
     # other shapes keep the heuristic
-    assert FN._pick_blocks(4096, 768, 3) == \
-        FN._pick_blocks_heuristic(4096, 768, 3)
     assert LN._pick_block_rows(768, rows=4096) == \
         LN._pick_block_rows_heuristic(768)
 
@@ -223,10 +214,6 @@ def test_default_dispatch_bit_identical_to_heuristic():
                 assert plan["kernel"] == \
                     ("short_seq" if s <= bk else "streaming")
                 assert plan["tuner_source"] == "heuristic"
-    for rows, cols, n_bufs in ((512, 512, 3), (4096, 2048, 5),
-                               (64, 128, 3), (10 ** 5, 4096, 5)):
-        assert FN._pick_blocks(rows, cols, n_bufs) == \
-            FN._pick_blocks_heuristic(rows, cols, n_bufs)
     for C in (128, 768, 1024, 10 ** 6):
         assert LN._pick_block_rows(C, rows=4096) == \
             LN._pick_block_rows_heuristic(C)
@@ -240,7 +227,6 @@ def test_default_mode_never_searches(monkeypatch):
     monkeypatch.setattr(search, "_measure_candidate", boom)
     plan = PA.attention_dispatch(640, 640, 64, "bfloat16", on_tpu=True)
     assert plan["tuner_source"] == "heuristic"
-    FN._pick_blocks(512, 512, 3)
     LN._pick_block_rows(768, rows=512)
 
 
@@ -260,8 +246,6 @@ def test_candidates_prune_through_vmem_predicate():
             assert PA._fwd_vmem_bytes(c["block_q"], c["block_k"], Dp,
                                       jnp.dtype(dt).itemsize) \
                 <= PA._VMEM_CLAMP, c
-    for c in search.candidates("fused_norm", (4096, 1024), "float32"):
-        assert c["block_r"] * c["block_c"] * 4 * 5 <= FN._VMEM_BUDGET
     for c in search.candidates("layernorm", (4096, 1024), "float32"):
         assert 3 * 4 * c["block_rows"] * 1024 <= LN._VMEM_BUDGET
 
@@ -353,16 +337,12 @@ def test_table_blocks_default_and_field_order():
 
 
 def test_norm_picker_census_is_once_per_decision():
-    """One fused-epilogue routing decision censuses ONCE even though the
-    fwd/bwd kernel entries re-read the blocks; same for layernorm
-    fwd+bwd (quiet secondary lookups)."""
+    """One layernorm routing decision censuses ONCE even though the
+    backward re-reads the blocks (quiet secondary lookup)."""
     before = _counter("autotune.miss")
-    FN._pick_blocks(512, 512, 5)                       # the routing site
-    FN._pick_blocks(512, 512, 3, quiet=True)           # fwd kernel entry
-    FN._pick_blocks(512, 512, 5, quiet=True)           # bwd kernel entry
     LN._pick_block_rows(768, rows=512)                 # fwd
     LN._pick_block_rows(768, rows=512, quiet=True)     # bwd
-    assert _counter("autotune.miss") == before + 2
+    assert _counter("autotune.miss") == before + 1
 
 
 def test_failed_dispatch_search_is_memoized(monkeypatch):
@@ -433,22 +413,6 @@ def test_autotune_env_falsy_spellings(monkeypatch):
     for v in ("1", "true", "on"):
         monkeypatch.setenv("MXNET_AUTOTUNE", v)
         assert tune.autotune_enabled(), repr(v)
-
-
-def test_oversize_epilogue_blocks_clamped_to_extents():
-    """A stale/hand-edited table block larger than the instance must
-    cost its own tile only — the epilogue pads to the CLAMPED block,
-    mirroring the attention/LN kernels."""
-    import jax.numpy as jnp
-    import numpy as onp
-    x = jnp.asarray(onp.random.RandomState(0).randn(16, 128), jnp.float32)
-    s = jnp.ones((1, 128), jnp.float32)
-    t = jnp.zeros((1, 128), jnp.float32)
-    y = FN.pallas_epilogue_fwd(x, s, t, x, interpret=True,
-                               block_r=512, block_c=1024)
-    ref = FN._jnp_epilogue(x, s, t, x)
-    assert y.shape == (16, 128)
-    assert float(jnp.max(jnp.abs(y - ref))) < 1e-6
 
 
 # --- offline CLI (interpret mode, tiny shape) ------------------------------

@@ -417,27 +417,62 @@ def test_per_batch_shard_attention_matches_unsharded(mesh8, lens):
 
 
 def test_per_batch_shard_sums_the_norm_backward_reductions(mesh8):
-    """The BN-epilogue backward's per-column dscale/dshift are partial sums
+    """The LayerNorm backward's per-channel dgamma/dbeta are partial sums
     per shard: flagged ``summed`` they come back whole and equal to the
-    unsharded reduction; dx/dresidual stay split by rows."""
-    from mxnet_tpu.ops import pallas_fused_norm as FN
+    unsharded reduction; dx stays split by rows."""
+    from mxnet_tpu.ops import pallas_layernorm as LN
     from mxnet_tpu.parallel.mesh import batch_sharded_over, per_batch_shard
     rs = onp.random.RandomState(1)
-    x, y, ct = (jnp.asarray(rs.randn(64, 256).astype("float32"))
-                for _ in range(3))
-    s = jnp.asarray(rs.rand(1, 256).astype("float32"))
-    bwd = functools.partial(FN.pallas_epilogue_bwd, interpret=True,
-                            block_r=8, block_c=128)
-    want = bwd(x, s, y, ct)
+    x, ct = (jnp.asarray(rs.randn(64, 256).astype("float32"))
+             for _ in range(2))
+    mu, rstd = (jnp.asarray(rs.rand(64, 1).astype("float32"))
+                for _ in range(2))
+    g = jnp.asarray(rs.rand(256).astype("float32"))
+    bwd = functools.partial(LN.pallas_layer_norm_bwd, interpret=True,
+                            block_rows=8)
+    want = bwd(x, g, mu, rstd, ct)
 
     @jax.jit
-    def program(x, s, y, ct):
+    def program(x, g, mu, rstd, ct):
         with batch_sharded_over(mesh8):
-            return per_batch_shard(bwd, (x, s, y, ct), replicated=(1,),
-                                   summed=(False, False, True, True))
+            return per_batch_shard(bwd, (x, g, mu, rstd, ct),
+                                   replicated=(1,),
+                                   summed=(False, True, True))
 
-    xs, ys, cts = _sharded(mesh8, x, y, ct)
-    got = program(xs, s, ys, cts)
+    xs, mus, rstds, cts = _sharded(mesh8, x, mu, rstd, ct)
+    got = program(xs, g, mus, rstds, cts)
     for a, b in zip(got, want):
         onp.testing.assert_allclose(onp.asarray(a), onp.asarray(b),
                                     rtol=1e-5, atol=1e-4)
+
+
+def test_resnet18_dp8_step_gives_the_one_device_losses():
+    """The residual tail is plain ``jax.numpy``: under a ``dp`` mesh XLA
+    partitions it and all-reduces the batch statistics and their
+    gradients itself, with no per-shard wrapper — the eight-device step
+    follows the one-device step's losses."""
+    from mxnet_tpu.gluon.model_zoo import vision
+    rs = onp.random.RandomState(0)
+    x = rs.uniform(size=(16, 3, 32, 32)).astype("float32")
+    y = rs.randint(0, 10, 16).astype("float32")
+    L = gloss.SoftmaxCrossEntropyLoss()
+
+    def losses(n_devices):
+        onp.random.seed(7)
+        mx.random.seed(7)
+        net = vision.resnet18_v1(classes=10)
+        net.initialize(mx.init.Xavier())
+        net(mx.nd.array(x[:2]))
+        mesh = parallel.device_mesh((n_devices,), ("dp",),
+                                    devices=jax.devices()[:n_devices])
+        step = parallel.DataParallelStep(
+            net, lambda o, l: L(o, l),
+            # a gentle rate: at larger ones this toy problem is fitted in
+            # two steps and fp32 reassociation is amplified a thousandfold
+            mx.optimizer.SGD(learning_rate=1e-5), mesh=mesh)
+        return [float(step(mx.nd.array(x), mx.nd.array(y)).asscalar())
+                for _ in range(4)]
+
+    one, eight = losses(1), losses(8)
+    assert one[-1] < one[0]
+    onp.testing.assert_allclose(eight, one, rtol=2e-4)
