@@ -108,7 +108,7 @@ class AGInfo:
     slot of a recorded op node.
     """
 
-    __slots__ = ("node", "index", "grad", "grad_req", "array_ref")
+    __slots__ = ("node", "index", "_grad", "grad_req", "array_ref")
 
     def __init__(self, node: Optional["Node"], index: int = 0,
                  grad=None, grad_req: str = "write", array_ref=None):
@@ -117,6 +117,17 @@ class AGInfo:
         self.grad = grad          # NDArray grad buffer (variables only)
         self.grad_req = grad_req  # write | add | null
         self.array_ref = array_ref
+
+    @property
+    def grad(self):
+        """The grad buffer; given as a function, it is made at first use."""
+        if callable(self._grad):
+            self._grad = self._grad()
+        return self._grad
+
+    @grad.setter
+    def grad(self, value):
+        self._grad = value
 
 
 class Node:

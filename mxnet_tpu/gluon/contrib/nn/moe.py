@@ -1,0 +1,178 @@
+"""Sparse experts as Gluon blocks: a router over ALL the experts of a layer
+and a ``SparseExperts`` block that is told which of them it holds.
+
+One chip of an expert-parallel deployment holds a slice of each layer's
+experts.  ``SparseExperts(..., num_experts=16, experts_held=(0, 8))`` routes
+every token over all 16, computes experts 0..7 for the tokens routed to
+them — all of them, whatever the imbalance: nothing has a capacity — and
+returns ONLY that part of the layer's output; tokens routed to experts held
+elsewhere get zero.  That partial result is what the caller adds to the
+residual and hands to the next layer; the shares of all the chips add up to
+the whole layer (``tests/test_zaya.py``).  ``experts_held=None`` holds them
+all.  Across chips, ``parallel.moe`` puts an ``ep`` all-to-all round the
+same core (``ops/moe.py``).
+
+Routing is observable without a callback in the step: the block keeps the
+last step's counts as non-trainable state (as BatchNorm keeps its running
+statistics), and ``publish_routing_counts`` reads them into ``telemetry``.
+"""
+from __future__ import annotations
+
+import weakref
+
+import jax
+import numpy as onp
+
+from .... import autograd, telemetry
+from ...block import HybridBlock
+from ...nn import Dense, RMSNorm
+
+__all__ = ["DepthRouter", "SparseExperts", "publish_routing_counts"]
+
+_LIVE = weakref.WeakSet()      # the SparseExperts blocks of this process
+
+
+class DepthRouter(HybridBlock):
+    """The router of a ZAYA1 layer: ``r = x Wd (+ gamma * r_prev)`` in a
+    ``hidden``-wide space (``carry``: the previous layer's ``r`` comes in,
+    scaled by a learned scalar — depth averaging), then
+    ``softmax(W3 gelu(W2 gelu(W1 RMSNorm(r))))`` over ALL ``num_experts``.
+    Returns ``(probs, r)``; ``r`` goes to the next layer's router."""
+
+    def __init__(self, units, hidden, num_experts, carry=True, epsilon=1e-5,
+                 **kwargs):
+        super().__init__(**kwargs)
+        self._carry = carry
+        with self.name_scope():
+            def dense(width, in_units, prefix, activation=None):
+                return Dense(width, flatten=False, use_bias=False,
+                             in_units=in_units, activation=activation,
+                             prefix=prefix)
+            self.down = dense(hidden, units, "down_")
+            self.norm = RMSNorm(epsilon=epsilon, in_channels=hidden,
+                                prefix="norm_")
+            self.fc1 = dense(hidden, hidden, "fc1_", "gelu")
+            self.fc2 = dense(hidden, hidden, "fc2_", "gelu")
+            self.fc3 = dense(num_experts, hidden, "fc3_")
+            if carry:
+                self.depth_gamma = self.params.get(
+                    "depth_gamma", shape=(1,), init="ones")
+
+    def hybrid_forward(self, F, x, r_prev=None, depth_gamma=None):
+        r = self.down(x)
+        if self._carry and r_prev is not None:
+            r = r + F.broadcast_mul(r_prev, depth_gamma.reshape((1, 1, 1)))
+        logits = self.fc3(self.fc2(self.fc1(self.norm(r))))
+        return F.softmax(logits.astype("float32"), axis=-1), r
+
+
+class SparseExperts(HybridBlock):
+    """Dropless top-1 gated-SiLU experts, a slice of them held here.
+
+    ``forward(x, probs)``: ``x`` (B, S, units), ``probs`` (B, S,
+    num_experts) the router's softmax.  Token t goes to ``e = argmax(probs
+    + balance_bias)`` and, if ``e`` is held, gets ``probs[e] *
+    Wdown_e(silu(Wgate_e x) * Wup_e x)``; otherwise zero (see the module's
+    docstring).
+
+    ``balance_bias`` takes no gradient (``grad_req="null"``) and starts at
+    zero.  It is kept by the auxiliary-loss-free balancing rule (Wang et
+    al., arXiv:2408.15664; DeepSeek-V3, arXiv:2412.19437): once a TRAINING
+    step, after the step's tokens are routed, ``b_e += bias_update_rate *
+    sign(mean load - load_e)`` — an expert that got fewer tokens than an
+    even share is raised, one that got more is lowered, and the next step
+    routes by the new bias.  ``bias_update_rate=0`` (the default) leaves
+    the bias where the caller set it.  The rate is in the units of
+    ``probs``: the published 1e-3 goes with scores that spread over tenths.
+
+    State, not trained, rewritten by every training step: ``expert_load``
+    (num_experts,) the tokens routed to each expert of the layer, and
+    ``rows_computed`` (held,) the rows each held expert's products
+    covered.  ``last_expert`` is each token's expert (B, S) at the last
+    EAGER call, for whoever wants to look at the routing itself (a compiled
+    step keeps none: its shape follows the batch)."""
+
+    def __init__(self, units, hidden_size, num_experts, experts_held=None,
+                 bias_update_rate=0.0, **kwargs):
+        super().__init__(**kwargs)
+        self._bias_update_rate = float(bias_update_rate)
+        first, end = experts_held or (0, num_experts)
+        if not 0 <= first < end <= num_experts:
+            raise ValueError("experts_held=%r is no slice of %d experts"
+                             % (experts_held, num_experts))
+        self.num_experts, self.experts_held = num_experts, (first, end)
+        self.last_expert = None
+        held = end - first
+        with self.name_scope():
+            self.gate_weight = self.params.get(
+                "gate_weight", shape=(held, units, hidden_size))
+            self.up_weight = self.params.get(
+                "up_weight", shape=(held, units, hidden_size))
+            self.down_weight = self.params.get(
+                "down_weight", shape=(held, hidden_size, units))
+            self.balance_bias = self.params.get(
+                "balance_bias", shape=(num_experts,), init="zeros",
+                grad_req="null", differentiable=False)
+            self.expert_load = self.params.get(
+                "expert_load", shape=(num_experts,), init="zeros",
+                grad_req="null", differentiable=False)
+            self.rows_computed = self.params.get(
+                "rows_computed", shape=(held,), init="zeros",
+                grad_req="null", differentiable=False)
+        _LIVE.add(self)
+
+    def cast(self, dtype):
+        """The counts stay float32: bfloat16 counts no further than 256."""
+        super().cast(dtype)
+        for p in (self.expert_load, self.rows_computed, self.balance_bias):
+            p.cast("float32")
+
+    def hybrid_forward(self, F, x, probs, gate_weight, up_weight,
+                       down_weight, balance_bias, expert_load,
+                       rows_computed):
+        y, load, rows, expert = F.sparse_experts(
+            x, probs, gate_weight, up_weight, down_weight, balance_bias,
+            first=self.experts_held[0])
+        if not isinstance(expert._data, jax.core.Tracer):
+            self.last_expert = expert
+        if autograd.is_training():
+            with autograd.pause():
+                self.expert_load.set_data(load)
+                self.rows_computed.set_data(rows)
+                self.balance_bias.set_data(
+                    balance_bias + self._bias_update_rate
+                    * F.sign(F.mean(load) - load))
+        return y
+
+
+def publish_routing_counts():
+    """Read the routing counts every live ``SparseExperts`` block kept at
+    its last training step into ``telemetry`` and return them.
+
+    Gauges (summed over the blocks): ``moe.tokens_routed`` (routes to any
+    expert), ``moe.tokens_local`` (routes to held experts),
+    ``moe.dropped`` (routes to held experts that no product covered:
+    0 — the layer has no capacity to overflow).  ``moe.expert_load`` is one
+    event a block with its vector.  Returns ``{block name: {"load":
+    [...], "held": (first, end), "rows": [...]}}``, empty before the first
+    training step.  One device read a block, after the window: nothing is
+    called back from inside the step."""
+    out = {}
+    for block in sorted(_LIVE, key=lambda b: b.name):
+        if block.expert_load._data is None:
+            continue
+        load = onp.asarray(block.expert_load.data().asnumpy(), "float64")
+        rows = onp.asarray(block.rows_computed.data().asnumpy(), "float64")
+        if load.sum() == 0:
+            continue
+        out[block.name] = {"load": load.tolist(), "rows": rows.tolist(),
+                           "held": block.experts_held}
+        telemetry.event("moe.expert_load", block.name, load=load.tolist(),
+                        held=list(block.experts_held))
+    held = [sum(v["load"][v["held"][0]:v["held"][1]]) for v in out.values()]
+    telemetry.gauge("moe.tokens_routed",
+                    sum(sum(v["load"]) for v in out.values()))
+    telemetry.gauge("moe.tokens_local", sum(held))
+    telemetry.gauge("moe.dropped",
+                    sum(held) - sum(sum(v["rows"]) for v in out.values()))
+    return out

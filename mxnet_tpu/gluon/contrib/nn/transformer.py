@@ -17,11 +17,13 @@ softmax; only an arbitrary additive ``mask`` forces the dense path.
 """
 from __future__ import annotations
 
+from .... import initializer
 from ...block import HybridBlock
 from ...nn import Dense, Dropout, LayerNorm
 
 __all__ = ["MultiHeadAttention", "PositionwiseFFN",
-           "TransformerEncoderCell", "TransformerEncoder"]
+           "TransformerEncoderCell", "TransformerEncoder",
+           "CompressedConvAttention"]
 
 
 class MultiHeadAttention(HybridBlock):
@@ -160,3 +162,63 @@ class TransformerEncoder(HybridBlock):
         for cell in self.cells:
             x = cell(x, mask, valid_length)
         return x
+
+
+class CompressedConvAttention(HybridBlock):
+    """Compressed convolutional attention (CCA, arXiv:2510.04476): causal
+    grouped-query attention wholly in a compressed latent.
+
+    ``x`` (B, S, units) is projected down to ``num_heads`` query heads and
+    ``num_kv_heads`` key-value heads of ``head_dim`` (no bias anywhere);
+    q and k are mixed along the sequence by two causal convolutions
+    (depthwise of kernel ``conv_kernels[0]``, then grouped with one group
+    a head of kernel ``conv_kernels[1]``), get the q-k mean added, are
+    L2-normalised (k with a learned temperature a key-value head) and
+    rotated on ``rotary_dim`` of their dimensions; the values are half from
+    this step and half from the step before.  The equations are
+    ``ops.nn.cca_qkv``'s.  Attention runs in the flash kernels, the query
+    heads sharing their key-value head through the kernels' index maps,
+    and the output goes up from the latent to ``units``."""
+
+    def __init__(self, units, num_heads, num_kv_heads, head_dim,
+                 conv_kernels=(2, 2), rotary_dim=0, rope_theta=10000.0,
+                 **kwargs):
+        super().__init__(**kwargs)
+        if num_heads % num_kv_heads or num_kv_heads % 2:
+            raise ValueError(
+                "%d query heads must divide over an even number of "
+                "key-value heads, got %d" % (num_heads, num_kv_heads))
+        self._shape = dict(num_heads=num_heads, num_kv_heads=num_kv_heads,
+                           rotary_dim=rotary_dim, theta=float(rope_theta))
+        q_width, kv_width = num_heads * head_dim, num_kv_heads * head_dim
+        channels = q_width + kv_width
+        with self.name_scope():
+            def down(width, prefix):
+                return Dense(width, flatten=False, use_bias=False,
+                             in_units=units, prefix=prefix)
+            self.q_proj = down(q_width, "q_")
+            self.k_proj = down(kv_width, "k_")
+            self.v_now = down(kv_width // 2, "v_now_")
+            self.v_prev = down(kv_width // 2, "v_prev_")
+            self.out_proj = Dense(units, flatten=False, use_bias=False,
+                                  in_units=q_width, prefix="out_")
+            # taps drawn so that a convolution's output is as large as its
+            # input whatever the model's own initializer gives matrices
+            self.conv0_weight = self.params.get(
+                "conv0_weight", shape=(channels, 1, conv_kernels[0]),
+                init=initializer.Normal(0.5))
+            self.conv1_weight = self.params.get(
+                "conv1_weight", shape=(channels, head_dim, conv_kernels[1]),
+                init=initializer.Normal(
+                    (head_dim * conv_kernels[1]) ** -0.5))
+            self.k_scale = self.params.get(
+                "k_scale", shape=(num_kv_heads,), init="ones")
+
+    def hybrid_forward(self, F, x, conv0_weight, conv1_weight, k_scale):
+        q, k, v = F.cca_qkv(self.q_proj(x), self.k_proj(x), self.v_now(x),
+                            self.v_prev(x), conv0_weight, conv1_weight,
+                            k_scale, **self._shape)
+        out = F.flash_attention(q, k, v, causal=True)      # (B, H, S, d)
+        b, s = x.shape[0], x.shape[1]
+        return self.out_proj(out.transpose(axes=(0, 2, 1, 3)).reshape(
+            b, s, -1))

@@ -18,6 +18,7 @@ from .block import HybridBlock
 
 __all__ = ["Loss", "L2Loss", "L1Loss", "SigmoidBinaryCrossEntropyLoss",
            "SigmoidBCELoss", "SoftmaxCrossEntropyLoss", "SoftmaxCELoss",
+           "TiedSoftmaxCrossEntropyLoss",
            "KLDivLoss", "CTCLoss", "HuberLoss", "HingeLoss",
            "SquaredHingeLoss", "LogisticLoss", "TripletLoss",
            "PoissonNLLLoss", "CosineEmbeddingLoss"]
@@ -162,6 +163,39 @@ class SoftmaxCrossEntropyLoss(Loss):
 
 
 SoftmaxCELoss = SoftmaxCrossEntropyLoss
+
+
+class TiedSoftmaxCrossEntropyLoss(Loss):
+    """Softmax cross-entropy of a head TIED to the embedding, without the
+    logits: ``pred`` is the pair ``(hidden (B, S, D), weight (V, D))`` a
+    language model returns in training, ``label`` (B, S) the target ids.
+    The rows of ``weight`` are taken in blocks of about ``block_rows``
+    (``ops.nn.tied_softmax_cross_entropy``), so a 131k-row head over 16k
+    tokens never holds more than one block's logits.  A label outside
+    0..V-1 (``ignore_label``, -1) marks a position that predicts nothing;
+    the loss of a row is the mean over its other positions."""
+
+    def __init__(self, block_rows=8192, ignore_label=-1, weight=None,
+                 batch_axis=0, **kwargs):
+        super().__init__(weight, batch_axis, **kwargs)
+        self._block_rows = block_rows
+        self._ignore = ignore_label
+
+    def hybrid_forward(self, F, pred, label, sample_weight=None):
+        from ..ops.nn import tied_softmax_cross_entropy
+
+        hidden, table = pred
+
+        def fn(h, w, l, sw):
+            lab = l.astype(jnp.int32)
+            counted = (lab != self._ignore).astype(jnp.float32)
+            loss = tied_softmax_cross_entropy(
+                h, w, lab, block_rows=self._block_rows) * counted
+            loss = _w(loss, self._weight, sw)
+            return jnp.sum(loss, axis=-1) / jnp.maximum(
+                jnp.sum(counted, axis=-1), 1.0)
+        return self._dispatch(fn, [hidden, table, label, sample_weight],
+                              "tied_softmax_ce")
 
 
 class KLDivLoss(Loss):

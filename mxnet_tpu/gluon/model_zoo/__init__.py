@@ -8,5 +8,8 @@ for API parity and raise with a clear message when it is requested.
 from . import vision  # noqa: F401
 from . import bert  # noqa: F401
 from .bert import BERTModel, bert_base, bert_small  # noqa: F401
+from . import zaya  # noqa: F401
+from .zaya import ZAYA1Model, zaya1  # noqa: F401
 
-__all__ = ["vision", "bert", "BERTModel", "bert_base", "bert_small"]
+__all__ = ["vision", "bert", "BERTModel", "bert_base", "bert_small",
+           "zaya", "ZAYA1Model", "zaya1"]

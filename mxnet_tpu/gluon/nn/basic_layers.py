@@ -15,7 +15,7 @@ from ..block import Block, HybridBlock
 
 __all__ = ["Sequential", "HybridSequential", "Dense", "Dropout", "Embedding",
            "BatchNorm", "BatchNormAddReLU", "InstanceNorm", "LayerNorm",
-           "GroupNorm", "Flatten", "Lambda", "HybridLambda", "Activation"]
+           "RMSNorm", "GroupNorm", "Flatten", "Lambda", "HybridLambda", "Activation"]
 
 
 class Sequential(Block):
@@ -302,6 +302,26 @@ class LayerNorm(HybridBlock):
 
     def hybrid_forward(self, F, x, gamma, beta):
         return F.LayerNorm(x, gamma, beta, axis=self._axis, eps=self._epsilon)
+
+
+class RMSNorm(HybridBlock):
+    """``x / sqrt(mean(x^2) + epsilon) * gamma`` over ``axis``: the
+    normalisation of pre-norm decoder blocks (no mean, no shift)."""
+
+    def __init__(self, axis=-1, epsilon=1e-5, gamma_initializer="ones",
+                 in_channels=0, prefix=None, params=None):
+        super().__init__(prefix=prefix, params=params)
+        self._axis = axis
+        self._epsilon = epsilon
+        self.gamma = self.params.get(
+            "gamma", shape=(in_channels,), init=gamma_initializer,
+            allow_deferred_init=True)
+
+    def infer_shape(self, x, *args):
+        self.gamma._finish_deferred_init((x.shape[self._axis],))
+
+    def hybrid_forward(self, F, x, gamma):
+        return F.RMSNorm(x, gamma, axis=self._axis, eps=self._epsilon)
 
 
 class GroupNorm(HybridBlock):

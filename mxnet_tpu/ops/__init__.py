@@ -17,5 +17,6 @@ from . import contrib_ops   # noqa: F401
 from . import detection     # noqa: F401
 from . import quantization  # noqa: F401
 from . import pallas_attention  # noqa: F401
+from . import moe           # noqa: F401
 
 __all__ = ["OpDef", "register", "get_op", "list_ops", "alias"]

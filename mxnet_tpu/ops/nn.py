@@ -706,10 +706,69 @@ def dropout(key, data, p: float = 0.5, mode: str = "training", axes=(),
 # embedding / sequence ops
 # ---------------------------------------------------------------------------
 
+@jax.custom_vjp
+def _take_rows_grad_summed_in_f32(weight, idx):
+    """``weight[idx]`` whose gradient sums the rows of one id in float32
+    BEFORE the one cast to the weight's dtype.  Autodiff's scatter-add adds
+    in the cotangent's dtype: with bfloat16 and text-like ids — the most
+    frequent token 1,300 times in 16,384 — a row's running sum soon
+    outgrows what 8 bits of mantissa can add a single term to (17.7% off in
+    the Frobenius norm on the chip, PERF.md PR 26).  The ids are sorted,
+    equal ids summed as one segment, and each segment's sum lands in its
+    row once."""
+    return jnp.take(weight, idx, axis=0)
+
+
+def _take_rows_fwd(weight, idx):
+    return jnp.take(weight, idx, axis=0), (weight, idx)
+
+
+def _take_rows_bwd(res, g):
+    import numpy as onp
+    from ..parallel.mesh import batch_shards, per_batch_shard
+    weight, idx = res
+    table, dtype = weight.shape, weight.dtype
+
+    def summed(idx, g):
+        ids = idx.reshape(-1)
+        n = ids.shape[0]
+        order = jnp.argsort(ids)
+        ids = jnp.take(ids, order)
+        rows = jnp.take(g.reshape(n, -1).astype(jnp.float32), order, axis=0)
+        segment = jnp.cumsum(jnp.concatenate(
+            [jnp.zeros((1,), jnp.int32),
+             (ids[1:] != ids[:-1]).astype(jnp.int32)]))
+        sums = jax.ops.segment_sum(rows, segment, num_segments=n,
+                                   indices_are_sorted=True)
+        # segment s is the id of its first row; segments past the last one
+        # point outside the table and are dropped
+        first = jnp.full((n,), table[0], jnp.int32).at[segment].min(ids)
+        return (jnp.zeros(table, dtype).at[first].set(
+            sums.astype(dtype), mode="drop"),)
+
+    # under a batch GSPMD shards, each shard sorts and sums its own ids
+    # and the tables are added across shards, as autodiff's were (a sort
+    # over the whole batch would gather every shard's rows on every chip);
+    # ids that do not split (one row of positions) are summed whole
+    # graftlint: disable-next=retrace-shape-branch -- the layout of the ids
+    # is static per program: one trace a shape is what is meant
+    if idx.ndim and idx.shape[0] % batch_shards() == 0:
+        (grad,) = per_batch_shard(summed, (idx, g), summed=(True,))
+    else:
+        (grad,) = summed(idx, g)
+    return grad, onp.zeros(idx.shape, jax.dtypes.float0)
+
+
+_take_rows_grad_summed_in_f32.defvjp(_take_rows_fwd, _take_rows_bwd)
+
+
 @register("Embedding")
 def embedding(data, weight, input_dim: int = 0, output_dim: int = 0,
               dtype="float32", sparse_grad: bool = False):
     idx = jnp.clip(data.astype(jnp.int32), 0, weight.shape[0] - 1)
+    if jnp.dtype(weight.dtype).itemsize < 4:
+        # a half-width table: the rows of one id are summed in float32
+        return _take_rows_grad_summed_in_f32(weight, idx)
     return jnp.take(weight, idx, axis=0)
 
 
@@ -811,3 +870,217 @@ def mae_regression_output(data, label, grad_scale: float = 1.0):
 @register("LogisticRegressionOutput", aliases=("logistic_regression_output",))
 def logistic_regression_output(data, label, grad_scale: float = 1.0):
     return jax.nn.sigmoid(data)
+
+
+# ---------------------------------------------------------------------------
+# decoder-block ops: RMS norm, rotary embedding on part of a head, causal
+# convolutions over the sequence, and the cross-entropy against a tied
+# embedding taken in blocks of its rows
+# ---------------------------------------------------------------------------
+
+@register("RMSNorm", aliases=("rms_norm",))
+def rms_norm(data, gamma, axis: int = -1, eps: float = 1e-5):
+    """``x / sqrt(mean(x^2) + eps) * gamma`` over ``axis``; statistics in
+    fp32 whatever the input, one cast out."""
+    x32 = data.astype(jnp.float32)
+    inv = lax.rsqrt(jnp.mean(x32 * x32, axis=axis, keepdims=True) + eps)
+    shape = [1] * data.ndim
+    shape[axis] = data.shape[axis]
+    return (x32 * inv * gamma.astype(jnp.float32).reshape(shape)).astype(
+        data.dtype)
+
+
+@register("l2_normalize")
+def l2_normalize(data, scale=None, axis: int = -1, eps: float = 1e-12):
+    """``sqrt(d) x / |x|`` over ``axis`` of width d (a unit RMS vector),
+    times the learned ``scale`` where one is given: one scalar per entry
+    of axis -3 (a head of a (B, H, S, D) tensor)."""
+    x32 = data.astype(jnp.float32)
+    d = data.shape[axis]
+    out = x32 * lax.rsqrt(jnp.sum(x32 * x32, axis=axis, keepdims=True)
+                          + eps) * (d ** 0.5)
+    if scale is not None:
+        out = out * scale.astype(jnp.float32).reshape(-1, 1, 1)
+    return out.astype(data.dtype)
+
+
+@register("rotary_embedding")
+def rotary_embedding(data, rotary_dim: int = 0, theta: float = 10000.0):
+    """Rotary position embedding on the first ``rotary_dim`` of the D
+    dimensions of a (B, H, S, D) tensor (0: all of them), the rest passed
+    through.  Rotate-half pairing: dimension i turns with i + rotary_dim/2
+    by the angle ``t * theta ** (-2 i / rotary_dim)``, t from 0."""
+    r = rotary_dim or data.shape[-1]
+    half = r // 2
+    pos = jnp.arange(data.shape[-2], dtype=jnp.float32)
+    freq = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / r)
+    ang = pos[:, None] * freq[None, :]                       # (S, r/2)
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    x32 = data.astype(jnp.float32)
+    x1, x2, rest = x32[..., :half], x32[..., half:r], x32[..., r:]
+    return jnp.concatenate(
+        [x1 * cos - x2 * sin, x2 * cos + x1 * sin, rest],
+        axis=-1).astype(data.dtype)
+
+
+@register("causal_conv1d")
+def causal_conv1d(data, weight, groups: int = 1):
+    """Causal convolution over the sequence of a (B, S, C) tensor,
+    left-padded with zeros so that the output at t reads inputs t-K+1..t.
+    ``weight`` is (C_out, C_in / groups, K) as ``Conv1D``'s; tap K-1
+    meets the input at t.  ``groups == C`` is the depthwise case (a
+    multiply-add per tap); otherwise one (C/g x C/g) product per group and
+    tap, accumulated in fp32."""
+    b, s, c = data.shape
+    c_out, c_in_g, k = weight.shape
+    out = None
+    for j in range(k):
+        shift = k - 1 - j
+        xs = data if shift == 0 else \
+            jnp.pad(data, ((0, 0), (shift, 0), (0, 0)))[:, :s]
+        if c_in_g == 1 and c_out == c:
+            term = xs.astype(jnp.float32) * weight[:, 0, j].astype(
+                jnp.float32)
+        else:
+            wj = weight[:, :, j].reshape(groups, c_out // groups, c_in_g)
+            term = jnp.einsum(
+                "bsgi,goi->bsgo", xs.reshape(b, s, groups, c_in_g), wj,
+                preferred_element_type=jnp.float32).reshape(b, s, c_out)
+        out = term if out is None else out + term
+    return out.astype(data.dtype)
+
+
+def vocab_block_rows(vocab, target):
+    """The largest divisor of ``vocab`` that is at most ``target``: the
+    rows of the embedding one block of logits covers."""
+    target = max(1, min(int(target), vocab))
+    return next(r for r in range(target, 0, -1) if vocab % r == 0)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _tied_ce(hidden, weight, label, block_rows):
+    return _tied_ce_fwd(hidden, weight, label, block_rows)[0]
+
+
+def _tied_ce_fwd(hidden, weight, label, block_rows):
+    v, d = weight.shape
+    n = hidden.shape[0]
+    blocks = weight.reshape(v // block_rows, block_rows, d)
+
+    def body(carry, xs):
+        m, s, picked = carry
+        i, w = xs
+        logits = lax.dot_general(hidden, w, (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+        local = label - i * block_rows
+        here = (local >= 0) & (local < block_rows)
+        got = jnp.take_along_axis(
+            logits, jnp.clip(local, 0, block_rows - 1)[:, None], axis=1)[:, 0]
+        m_new = jnp.maximum(m, jnp.max(logits, axis=1))
+        s = s * jnp.exp(m - m_new) + jnp.sum(
+            jnp.exp(logits - m_new[:, None]), axis=1)
+        return (m_new, s, picked + jnp.where(here, got, 0.0)), None
+
+    init = (jnp.full((n,), -jnp.inf, jnp.float32),
+            jnp.zeros((n,), jnp.float32), jnp.zeros((n,), jnp.float32))
+    (m, s, picked), _ = lax.scan(
+        body, init, (jnp.arange(v // block_rows), blocks))
+    lse = m + jnp.log(s)
+    return lse - picked, (hidden, weight, label, lse)
+
+
+def _tied_ce_bwd(block_rows, res, g):
+    hidden, weight, label, lse = res
+    v, d = weight.shape
+    blocks = weight.reshape(v // block_rows, block_rows, d)
+    g = g.astype(jnp.float32)
+
+    def body(dh, xs):
+        i, w = xs
+        logits = lax.dot_general(hidden, w, (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+        col = i * block_rows + lax.broadcasted_iota(
+            jnp.int32, logits.shape, 1)
+        dl = (jnp.exp(logits - lse[:, None])
+              - (col == label[:, None]).astype(jnp.float32)) * g[:, None]
+        dl = dl.astype(hidden.dtype)
+        dw = lax.dot_general(dl, hidden, (((0,), (0,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+        dh = dh + lax.dot_general(dl, w, (((1,), (0,)), ((), ())),
+                                  preferred_element_type=jnp.float32)
+        return dh, dw.astype(weight.dtype)
+
+    dh, dw = lax.scan(body, jnp.zeros(hidden.shape, jnp.float32),
+                      (jnp.arange(v // block_rows), blocks))
+    import numpy as onp
+    return (dh.astype(hidden.dtype), dw.reshape(v, d),
+            onp.zeros(label.shape, jax.dtypes.float0))
+
+
+_tied_ce.defvjp(_tied_ce_fwd, _tied_ce_bwd)
+
+
+@register("tied_softmax_cross_entropy")
+def tied_softmax_cross_entropy(hidden, weight, label,
+                               block_rows: int = 8192):
+    """Per-row softmax cross-entropy of ``hidden @ weight.T`` against
+    ``label`` — the head TIED to the (V, D) embedding ``weight`` —
+    without the (N, V) logits: the rows of ``weight`` are taken in blocks
+    of the largest divisor of V that is at most ``block_rows``, the
+    log-sum-exp carried from block to block, and the backward recomputes
+    each block's logits, so each block's part of the weight's gradient is
+    whole when the block is done (no (V, D) fp32 accumulator) and only the
+    hidden states' gradient is summed over blocks, in fp32.  ``hidden`` is
+    (..., D), ``label`` (...) integer ids (float ids are cast); a label
+    outside 0..V-1 (say -1) picks no logit: mask such rows' loss."""
+    lead = hidden.shape[:-1]
+    loss = _tied_ce(hidden.reshape(-1, hidden.shape[-1]), weight,
+                    label.reshape(-1).astype(jnp.int32),
+                    vocab_block_rows(weight.shape[0], block_rows))
+    return loss.reshape(lead)
+
+
+@register("_contrib_cca_qkv", num_outputs=3, aliases=("cca_qkv",))
+def cca_qkv(q_lat, k_lat, v_now, v_prev, conv0_weight, conv1_weight,
+            k_scale, num_heads: int = 1, num_kv_heads: int = 1,
+            rotary_dim: int = 0, theta: float = 10000.0):
+    """Compressed convolutional attention's q, k, v from the latent
+    projections of a (B, S, ·) sequence, as (B, heads, S, d) operands for
+    ``flash_attention``:
+
+    * ``z = [q_lat ; k_lat]`` through two causal convolutions over the
+      sequence: depthwise (``conv0_weight`` (C, 1, K0)), then grouped with
+      one group a head (``conv1_weight`` (C, d, K1));
+    * the q-k mean: ``mq`` = (q_lat + its key-value head's k_lat) / 2 per
+      query head, ``mk`` = the mean of ``mq`` over the query heads of a
+      key-value head; ``q = conv_q + mq``, ``k = conv_k + mk``;
+    * q and k L2-normalised to ``sqrt(d)`` per head, k times the learned
+      ``k_scale`` (one a key-value head), then rotary on the first
+      ``rotary_dim`` dimensions;
+    * v head 0 is ``v_now`` (from h_t), head 1 ``v_prev`` shifted one
+      step (from h_(t-1), zero at t = 0), and so on in pairs."""
+    b, s, cq = q_lat.shape
+    d = cq // num_heads
+    group = num_heads // num_kv_heads
+    z = jnp.concatenate([q_lat, k_lat], axis=-1)
+    z = causal_conv1d(z, conv0_weight, groups=z.shape[-1])
+    z = causal_conv1d(z, conv1_weight, groups=num_heads + num_kv_heads)
+    q32 = q_lat.astype(jnp.float32).reshape(b, s, num_kv_heads, group, d)
+    k32 = k_lat.astype(jnp.float32).reshape(b, s, num_kv_heads, 1, d)
+    mq = (q32 + k32) * 0.5
+    mk = jnp.mean(mq, axis=3)
+    q = z[..., :cq].astype(jnp.float32) + mq.reshape(b, s, cq)
+    k = z[..., cq:].astype(jnp.float32) + mk.reshape(b, s, -1)
+
+    def heads(x, n):
+        return x.reshape(b, s, n, d).transpose(0, 2, 1, 3)
+
+    q = rotary_embedding(l2_normalize(heads(q, num_heads)),
+                         rotary_dim=rotary_dim, theta=theta)
+    k = rotary_embedding(l2_normalize(heads(k, num_kv_heads), k_scale),
+                         rotary_dim=rotary_dim, theta=theta)
+    v_prev = jnp.pad(v_prev, ((0, 0), (1, 0), (0, 0)))[:, :s]
+    half = num_kv_heads // 2
+    v = jnp.concatenate([heads(v_now, half), heads(v_prev, half)], axis=1)
+    dtype = q_lat.dtype
+    return q.astype(dtype), k.astype(dtype), v.astype(dtype)

@@ -425,8 +425,29 @@ def _pad_qkv(q, k, v, block_q, block_k):
     kp = jnp.pad(k, ((0, 0), (0, 0), (0, pad_k), (0, pad_d)))
     vp = jnp.pad(v, ((0, 0), (0, 0), (0, pad_k), (0, pad_d)))
     Tqp, Tkp, Dp = Tq + pad_q, Tk + pad_k, D + pad_d
-    return (qp.reshape(B * H, Tqp, Dp), kp.reshape(B * H, Tkp, Dp),
-            vp.reshape(B * H, Tkp, Dp), Tqp, Tkp, Dp)
+    Hkv = k.shape[1]          # < H under grouped-query attention
+    return (qp.reshape(B * H, Tqp, Dp), kp.reshape(B * Hkv, Tkp, Dp),
+            vp.reshape(B * Hkv, Tkp, Dp), Tqp, Tkp, Dp)
+
+
+def _kv_group(q, k):
+    """Query heads per key-value head (1: plain multi-head attention).
+    Head h of q reads key-value head h // group, so in the kernels' merged
+    (B*H, ...) layouts row b of q meets row b // group of k and v: the
+    BlockSpec index maps do the sharing and no repeated K/V exists in
+    HBM."""
+    H, Hkv = q.shape[1], k.shape[1]
+    if H % Hkv:
+        raise ValueError("%d query heads do not divide over %d key-value "
+                         "heads" % (H, Hkv))
+    return H // Hkv
+
+
+def _kv_row(group):
+    """Grid batch coordinate of q -> row of k/v."""
+    # graftlint: disable-next=trace-tracer-branch -- group is a Python int
+    # from the operands' static shapes
+    return (lambda b: b) if group == 1 else (lambda b: b // group)
 
 
 def _expand_mask_operands(kv_lens, q_segments, kv_segments, B, H, Tqp, Tkp,
@@ -497,6 +518,7 @@ def pallas_flash_attention(q, k, v, causal=False, scale=None,
     block_q = min(block_q, max(8, Tq))
     block_k = min(block_k, max(8, Tk))
     qp, kp, vp, Tqp, Tkp, Dp = _pad_qkv(q, k, v, block_q, block_k)
+    kvb = _kv_row(_kv_group(q, k))
     n_q = Tqp // block_q
     n_k = Tkp // block_k
     lens, qs, ks = _expand_mask_operands(kv_lens, q_segments, kv_segments,
@@ -531,8 +553,8 @@ def pallas_flash_attention(q, k, v, causal=False, scale=None,
             grid=(B * H, n_q),
             in_specs=[
                 pl.BlockSpec((1, block_q, Dp), lambda b, qi: (b, qi, 0)),
-                pl.BlockSpec((1, block_k, Dp), lambda b, qi: (b, 0, 0)),
-                pl.BlockSpec((1, block_k, Dp), lambda b, qi: (b, 0, 0)),
+                pl.BlockSpec((1, block_k, Dp), lambda b, qi: (kvb(b), 0, 0)),
+                pl.BlockSpec((1, block_k, Dp), lambda b, qi: (kvb(b), 0, 0)),
             ] + extra_specs,
             out_specs=[
                 pl.BlockSpec((1, block_q, Dp), lambda b, qi: (b, qi, 0)),
@@ -558,8 +580,10 @@ def pallas_flash_attention(q, k, v, causal=False, scale=None,
         grid=(B * H, n_q, n_k),
         in_specs=[
             pl.BlockSpec((1, block_q, Dp), lambda b, qi, ki: (b, qi, 0)),
-            pl.BlockSpec((1, block_k, Dp), lambda b, qi, ki: (b, ki, 0)),
-            pl.BlockSpec((1, block_k, Dp), lambda b, qi, ki: (b, ki, 0)),
+            pl.BlockSpec((1, block_k, Dp),
+                         lambda b, qi, ki: (kvb(b), ki, 0)),
+            pl.BlockSpec((1, block_k, Dp),
+                         lambda b, qi, ki: (kvb(b), ki, 0)),
         ] + extra_specs,
         out_specs=[
             pl.BlockSpec((1, block_q, Dp), lambda b, qi, ki: (b, qi, 0)),
@@ -912,18 +936,21 @@ def _dqkv_single_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref, *rest,
                 scale, causal, block_q, block_k, seq_k, seq_k_padded, n_q,
-                has_lens, has_seg, pid_off=0):
+                has_lens, has_seg, pid_off=0, group=1):
     import jax.experimental.pallas as pl
 
     lens_ref, qseg_ref, kseg_ref, rest = _bwd_unpack(rest, has_lens, has_seg)
     dk_ref, dv_ref, dk_acc, dv_acc = rest
 
     ki = pl.program_id(1 + pid_off)
-    qi = pl.program_id(2 + pid_off)
+    # the sequential axis: n_q q blocks, for each of the ``group`` query
+    # heads that share this key-value head in turn
+    step = pl.program_id(2 + pid_off)
+    qi = step if group == 1 else step % n_q
     kvlen = lens_ref[pl.program_id(0), 0] if has_lens else None
     needs_tail = seq_k != seq_k_padded
 
-    @pl.when(qi == 0)
+    @pl.when(step == 0)
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
@@ -950,7 +977,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref, *rest,
                           causal, has_lens, has_seg, needs_tail,
                           kvlen=kvlen, seq_k=seq_k)
 
-    @pl.when(qi == n_q - 1)
+    @pl.when(step == group * n_q - 1)
     def _finalize():
         dk_ref[...] = dk_acc[...].astype(dk_ref.dtype).reshape(
             dk_ref.shape)
@@ -986,6 +1013,9 @@ def pallas_flash_attention_bwd(q, k, v, out, lse, do, causal=False,
                     axis=-1)                                   # (B,H,Tq)
 
     qp, kp, vp, Tqp, Tkp, Dp = _pad_qkv(q, k, v, block_q, block_k)
+    group = _kv_group(q, k)
+    kvb = _kv_row(group)
+    Hkv = H // group
     pad_q = Tqp - Tq
     dop = jnp.pad(do, ((0, 0), (0, 0), (0, pad_q), (0, Dp - D))).reshape(
         B * H, Tqp, Dp)
@@ -1007,6 +1037,15 @@ def pallas_flash_attention_bwd(q, k, v, out, lse, do, causal=False,
     common = dict(scale=scale, causal=causal, block_q=block_q,
                   block_k=block_k, seq_k=Tk, seq_k_padded=Tkp,
                   has_lens=lens is not None, has_seg=qs_row is not None)
+
+    def per_kv_head(d):
+        """(B*H, Tkp, Dp) dk or dv of the one-K-block kernels, which
+        write one per QUERY head, summed over each key-value head's
+        group."""
+        d = d.reshape(B, Hkv, group, Tkp, Dp)
+        d = d[:, :, 0] if group == 1 else \
+            jnp.sum(d.astype(jnp.float32), axis=2).astype(d.dtype)
+        return d[:, :, :Tk, :D]
 
     if n_k == 1:
         # single-K-block fast path: ONE fused kernel recomputes the
@@ -1034,8 +1073,8 @@ def pallas_flash_attention_bwd(q, k, v, out, lse, do, causal=False,
                 grid=(B * H,),
                 in_specs=[
                     pl.BlockSpec((1, block_q, Dp), lambda b: (b, 0, 0)),
-                    pl.BlockSpec((1, block_k, Dp), lambda b: (b, 0, 0)),
-                    pl.BlockSpec((1, block_k, Dp), lambda b: (b, 0, 0)),
+                    pl.BlockSpec((1, block_k, Dp), lambda b: (kvb(b), 0, 0)),
+                    pl.BlockSpec((1, block_k, Dp), lambda b: (kvb(b), 0, 0)),
                     pl.BlockSpec((1, block_q, Dp), lambda b: (b, 0, 0)),
                     pl.BlockSpec((1, 1, block_q), lambda b: (b, 0, 0)),
                     pl.BlockSpec((1, 1, block_q), lambda b: (b, 0, 0)),
@@ -1056,9 +1095,7 @@ def pallas_flash_attention_bwd(q, k, v, out, lse, do, causal=False,
                 name="flash_dqkv_single",
             )(qp, kp, vp, dop, lsep, dltp, *fused_extra)
             dq = dq.reshape(B, H, Tqp, Dp)[:, :, :Tq, :D]
-            dk = dk.reshape(B, H, Tkp, Dp)[:, :, :Tk, :D]
-            dv = dv.reshape(B, H, Tkp, Dp)[:, :, :Tk, :D]
-            return dq, dk, dv
+            return dq, per_kv_head(dk), per_kv_head(dv)
         if qs_row is not None:
             fused_extra += [qs_row, ks_col]
             fused_especs += [
@@ -1070,8 +1107,8 @@ def pallas_flash_attention_bwd(q, k, v, out, lse, do, causal=False,
             grid=(B * H, n_q),
             in_specs=[
                 pl.BlockSpec((1, block_q, Dp), lambda b, qi: (b, qi, 0)),
-                pl.BlockSpec((1, block_k, Dp), lambda b, qi: (b, 0, 0)),
-                pl.BlockSpec((1, block_k, Dp), lambda b, qi: (b, 0, 0)),
+                pl.BlockSpec((1, block_k, Dp), lambda b, qi: (kvb(b), 0, 0)),
+                pl.BlockSpec((1, block_k, Dp), lambda b, qi: (kvb(b), 0, 0)),
                 pl.BlockSpec((1, block_q, Dp), lambda b, qi: (b, qi, 0)),
                 pl.BlockSpec((1, 1, block_q), lambda b, qi: (b, 0, qi)),
                 pl.BlockSpec((1, 1, block_q), lambda b, qi: (b, 0, qi)),
@@ -1094,12 +1131,12 @@ def pallas_flash_attention_bwd(q, k, v, out, lse, do, causal=False,
             name="flash_dqkv_fused",
         )(qp, kp, vp, dop, lsep, dltp, *fused_extra)
         dq = dq.reshape(B, H, Tqp, Dp)[:, :, :Tq, :D]
-        dk = dk.reshape(B, H, Tkp, Dp)[:, :, :Tk, :D]
-        dv = dv.reshape(B, H, Tkp, Dp)[:, :, :Tk, :D]
-        return dq, dk, dv
+        return dq, per_kv_head(dk), per_kv_head(dv)
 
-    def extra_for(kv_idx, q_idx):
-        # kv_idx/q_idx map grid coords -> (k-block index, q-block index)
+    def extra_for(kv_idx, q_idx, q_row=lambda b, i, j: b, lens=lens,
+                  ks_col=ks_col):
+        # kv_idx/q_idx map grid coords -> (k-block index, q-block index),
+        # q_row the grid's batch coordinate -> the row of q's operands
         ops, specs = [], []
         if lens is not None:
             ops.append(lens)
@@ -1110,7 +1147,8 @@ def pallas_flash_attention_bwd(q, k, v, out, lse, do, causal=False,
             ops += [qs_row, ks_col]
             specs += [
                 pl.BlockSpec((1, 1, block_q),
-                             lambda b, i, j: (b, 0, q_idx(i, j))),
+                             lambda b, i, j: (q_row(b, i, j), 0,
+                                              q_idx(i, j))),
                 pl.BlockSpec((1, block_k, 1),
                              lambda b, i, j: (b, kv_idx(i, j), 0)),
             ]
@@ -1119,8 +1157,8 @@ def pallas_flash_attention_bwd(q, k, v, out, lse, do, causal=False,
     dq_extra, dq_especs = extra_for(lambda i, j: j, lambda i, j: i)
     qkv_specs = [
         pl.BlockSpec((1, block_q, Dp), lambda b, qi, ki: (b, qi, 0)),
-        pl.BlockSpec((1, block_k, Dp), lambda b, qi, ki: (b, ki, 0)),
-        pl.BlockSpec((1, block_k, Dp), lambda b, qi, ki: (b, ki, 0)),
+        pl.BlockSpec((1, block_k, Dp), lambda b, qi, ki: (kvb(b), ki, 0)),
+        pl.BlockSpec((1, block_k, Dp), lambda b, qi, ki: (kvb(b), ki, 0)),
         pl.BlockSpec((1, block_q, Dp), lambda b, qi, ki: (b, qi, 0)),
         pl.BlockSpec((1, 1, block_q), lambda b, qi, ki: (b, 0, qi)),
         pl.BlockSpec((1, 1, block_q), lambda b, qi, ki: (b, 0, qi)),
@@ -1139,26 +1177,47 @@ def pallas_flash_attention_bwd(q, k, v, out, lse, do, causal=False,
         name="flash_dq",
     )(qp, kp, vp, dop, lsep, dltp, *dq_extra)
 
-    kv_extra, kv_especs = extra_for(lambda i, j: i, lambda i, j: j)
+    # dk/dv: one grid row per KEY-VALUE head; its sequential axis walks
+    # the q blocks of every query head of the group in turn (j // n_q is
+    # the head within the group, j % n_q its q block), so the group's sum
+    # is the kernel's own accumulation
+    # graftlint: disable-next=trace-tracer-branch -- group is a Python int
+    # from the operands' static shapes
+    if group == 1:
+        q_row, q_blk = (lambda b, ki, j: b), (lambda j: j)
+        kv_extra, kv_especs = extra_for(lambda i, j: i, lambda i, j: j)
+    else:
+        q_row = lambda b, ki, j: b * group + j // n_q
+        q_blk = lambda j: j % n_q
+        lens_kv, _, ks_kv = _expand_mask_operands(
+            kv_lens, q_segments, kv_segments, B, Hkv, Tqp, Tkp, true_tk=Tk,
+            transposed=True)
+        kv_extra, kv_especs = extra_for(
+            lambda i, j: i, lambda i, j: q_blk(j), q_row=q_row,
+            lens=lens_kv, ks_col=ks_kv)
     kv_specs = [
-        pl.BlockSpec((1, block_q, Dp), lambda b, ki, qi: (b, qi, 0)),
-        pl.BlockSpec((1, block_k, Dp), lambda b, ki, qi: (b, ki, 0)),
-        pl.BlockSpec((1, block_k, Dp), lambda b, ki, qi: (b, ki, 0)),
-        pl.BlockSpec((1, block_q, Dp), lambda b, ki, qi: (b, qi, 0)),
-        pl.BlockSpec((1, 1, block_q), lambda b, ki, qi: (b, 0, qi)),
-        pl.BlockSpec((1, 1, block_q), lambda b, ki, qi: (b, 0, qi)),
+        pl.BlockSpec((1, block_q, Dp),
+                     lambda b, ki, j: (q_row(b, ki, j), q_blk(j), 0)),
+        pl.BlockSpec((1, block_k, Dp), lambda b, ki, j: (b, ki, 0)),
+        pl.BlockSpec((1, block_k, Dp), lambda b, ki, j: (b, ki, 0)),
+        pl.BlockSpec((1, block_q, Dp),
+                     lambda b, ki, j: (q_row(b, ki, j), q_blk(j), 0)),
+        pl.BlockSpec((1, 1, block_q),
+                     lambda b, ki, j: (q_row(b, ki, j), 0, q_blk(j))),
+        pl.BlockSpec((1, 1, block_q),
+                     lambda b, ki, j: (q_row(b, ki, j), 0, q_blk(j))),
     ] + kv_especs
     dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, n_q=n_q, **common),
-        grid=(B * H, n_k, n_q),
+        functools.partial(_dkv_kernel, n_q=n_q, group=group, **common),
+        grid=(B * Hkv, n_k, group * n_q),
         in_specs=kv_specs,
         out_specs=[
             pl.BlockSpec((1, block_k, Dp), lambda b, ki, qi: (b, ki, 0)),
             pl.BlockSpec((1, block_k, Dp), lambda b, ki, qi: (b, ki, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B * H, Tkp, Dp), k.dtype),
-            jax.ShapeDtypeStruct((B * H, Tkp, Dp), v.dtype),
+            jax.ShapeDtypeStruct((B * Hkv, Tkp, Dp), k.dtype),
+            jax.ShapeDtypeStruct((B * Hkv, Tkp, Dp), v.dtype),
         ],
         scratch_shapes=[pltpu.VMEM((block_k, Dp), jnp.float32),
                         pltpu.VMEM((block_k, Dp), jnp.float32)],
@@ -1169,8 +1228,8 @@ def pallas_flash_attention_bwd(q, k, v, out, lse, do, causal=False,
     )(qp, kp, vp, dop, lsep, dltp, *kv_extra)
 
     dq = dq.reshape(B, H, Tqp, Dp)[:, :, :Tq, :D]
-    dk = dk.reshape(B, H, Tkp, Dp)[:, :, :Tk, :D]
-    dv = dv.reshape(B, H, Tkp, Dp)[:, :, :Tk, :D]
+    dk = dk.reshape(B, Hkv, Tkp, Dp)[:, :, :Tk, :D]
+    dv = dv.reshape(B, Hkv, Tkp, Dp)[:, :, :Tk, :D]
     return dq, dk, dv
 
 
@@ -1320,7 +1379,10 @@ def flash_attention(q, k, v, causal=False, scale=None, kv_lens=None,
                     q_segments=None, kv_segments=None):
     """Fused attention: Pallas kernels on TPU, jnp blockwise elsewhere.
 
-    softmax(q·kᵀ·scale [+ masks])·v over (B, H, T, D) inputs.  Masking:
+    softmax(q·kᵀ·scale [+ masks])·v over (B, H, T, D) inputs; ``k`` and
+    ``v`` may have fewer heads, (B, H / g, T, D): grouped-query attention,
+    query head h on key-value head h // g, shared through the kernels'
+    index maps.  Masking:
     ``causal`` (static), ``kv_lens`` (B,) per-row valid key length
     (padding mask — blocks past the length are skipped, not just masked),
     and ``q_segments``/``kv_segments`` (B, T) packed-sequence ids.
@@ -1331,6 +1393,13 @@ def flash_attention(q, k, v, causal=False, scale=None, kv_lens=None,
 
 def _reference_attention(q, k, v, causal, scale, kv_lens=None,
                          q_segments=None, kv_segments=None):
+    group = _kv_group(q, k)
+    # graftlint: disable-next=trace-tracer-branch -- group is a Python int
+    # from the operands' static shapes
+    if group > 1:
+        # off the chip the shared heads are repeated (the kernels' index
+        # maps share them instead); autodiff sums the group's gradients
+        k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
     if kv_lens is None and q_segments is None:
         from ..parallel.ring_attention import blockwise_attention
         return blockwise_attention(q, k, v, causal=causal, scale=scale)

@@ -93,7 +93,8 @@ def _grad_follows(param, sharding):
     reads it, must not break that: left behind, a weight-sized array of
     zeros per parameter stays on the first chip while everything else
     spreads over the mesh (seen as uneven HBM on four chips), or on the
-    devices of a mesh that elastic recovery has just abandoned."""
+    devices of a mesh that elastic recovery has just abandoned.  A buffer
+    nobody has read yet holds nothing to move (``gluon.parameter``)."""
     grad = param._grad
     if grad is not None:
         grad._data = jax.device_put(grad._data, sharding)

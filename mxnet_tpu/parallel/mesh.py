@@ -17,7 +17,8 @@ from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 __all__ = ["set_mesh", "get_mesh", "current_mesh", "default_mesh",
-           "device_mesh", "batch_sharded_over", "per_batch_shard"]
+           "device_mesh", "batch_sharded_over", "batch_shards",
+           "per_batch_shard"]
 
 
 class _MeshState(threading.local):
@@ -100,6 +101,13 @@ def batch_sharded_over(mesh: Optional[Mesh], axis: str = "dp"):
         yield
     finally:
         _BATCH_AXIS.mesh, _BATCH_AXIS.axis = prev
+
+
+def batch_shards() -> int:
+    """Into how many shards the declared scope splits a batch (1 outside
+    :func:`batch_sharded_over`)."""
+    mesh, axis = _BATCH_AXIS.mesh, _BATCH_AXIS.axis
+    return 1 if mesh is None else mesh.shape[axis]
 
 
 def per_batch_shard(fn, operands, replicated=(), summed=None):

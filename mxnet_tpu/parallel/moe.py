@@ -1,24 +1,31 @@
-"""Expert parallelism: a Switch-style Mixture-of-Experts FFN sharded over
-an ``ep`` mesh axis with real ``lax.all_to_all`` token exchange.
+"""Expert parallelism: a top-1 Mixture-of-Experts FFN sharded over an
+``ep`` mesh axis with real ``lax.all_to_all`` token exchange, DROPLESS.
 
 Reference capability: absent upstream as a named subsystem (MXNet-era
 MoE lived in user code); TPU-natively this is the canonical ``ep`` axis
-of the dp/tp/pp/sp/ep sharding family.  Design (the GShard/Switch
-recipe):
+of the dp/tp/pp/sp/ep sharding family.  The routing and the experts'
+products are the core in ``ops/moe.py`` that the one-chip block
+``gluon.contrib.nn.SparseExperts`` runs too; this module adds the
+exchange round it:
 
-* tokens are sharded over ``ep`` (each device owns S = N/ndev tokens);
-* a replicated router picks top-1 expert per token; each (source shard,
-  expert) pair gets a fixed capacity C — static shapes, overflow tokens
-  pass through the residual untouched (standard Switch behaviour);
-* dispatch is a one-hot (S, E, C) tensor; the send buffer
-  (ndev, E_loc, C, H) crosses the mesh with ``lax.all_to_all``, experts
-  run their FFN on (E_loc, ndev*C, H), and a second all_to_all returns
-  expert outputs to the token owners, combined with the router gate;
-* everything differentiates: all_to_all is linear, the router gate
-  carries the straight-through softmax weight.
+* tokens are sharded over ``ep`` (each device owns S = N/ndev tokens), the
+  experts' weights over ``ep`` too (E/ndev a device), the router is
+  replicated and picks one expert a token;
+* each device groups its tokens by DESTINATION device and sends every
+  device a slot of S rows — its tokens for that device packed at the
+  front, the rest padding marked "no expert" — so shapes are static and
+  no token is ever dropped, however uneven the routing (a slot can hold
+  all S tokens of its sender);
+* ``lax.all_to_all`` crosses the mesh; each device runs the core on the
+  ndev*S rows it received (grouped by its local experts, one grouped
+  product a weight, padding rows skipped), a second all_to_all returns the
+  results to the token owners, which put them back in token order and
+  apply the router's gate;
+* everything differentiates: all_to_all and the gathers are linear, the
+  gate carries the softmax weight.
 
-``moe_ffn_ref`` is the single-device oracle with identical routing
-semantics (same per-shard capacity drops) used by the tests.
+``moe_ffn_ref`` is the single-device oracle: a plain loop over the
+experts.
 """
 from __future__ import annotations
 
@@ -28,6 +35,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
+
+from ..ops import moe as _core
 
 __all__ = ["moe_ffn_init", "moe_ffn_apply", "moe_ffn_ref"]
 
@@ -45,32 +54,23 @@ def moe_ffn_init(rng, hidden, ffn, n_experts, dtype=jnp.float32):
     }
 
 
-def _route(x, router_w, n_experts, capacity):
-    """Shared routing math: (S, H) tokens → dispatch (S, E, C) one-hot,
-    combine (S, E, C) gate-weighted, both zero beyond capacity."""
-    logits = x.astype(jnp.float32) @ router_w.astype(jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)            # (S, E)
-    expert = jnp.argmax(probs, axis=-1)                # (S,)
-    gate = jnp.take_along_axis(probs, expert[:, None], axis=1)[:, 0]
-    onehot = jax.nn.one_hot(expert, n_experts, dtype=jnp.float32)
-    pos = jnp.cumsum(onehot, axis=0) * onehot - 1.0    # position in expert
-    keep = (pos >= 0) & (pos < capacity)
-    pos_oh = jax.nn.one_hot(jnp.where(keep, pos, 0).astype(jnp.int32),
-                            capacity, dtype=jnp.float32)
-    dispatch = (onehot[:, :, None] * pos_oh
-                * keep.astype(jnp.float32)[:, :, None])
-    combine = dispatch * gate[:, None, None]
-    return dispatch, combine
+def _route(x, router_w):
+    """Replicated router: (expert (S,), gate (S,)) of top-1 softmax."""
+    probs = jax.nn.softmax(
+        x.astype(jnp.float32) @ router_w.astype(jnp.float32), axis=-1)
+    return _core.top1_route(probs)
 
 
-def _expert_ffn(w1, w2, x):
-    """(E?, C?, H) per-expert GELU MLP via batched einsum."""
-    h = jax.nn.gelu(jnp.einsum("ech,ehf->ecf", x, w1))
-    return jnp.einsum("ecf,efh->ech", h, w2)
+def _gelu_experts(w1, w2):
+    """``ffn(rows, sizes)`` of the GELU MLP experts w1 (E, H, F), w2
+    (E, F, H) on rows sorted by expert."""
+    def ffn(rows, sizes):
+        hidden = jax.nn.gelu(_core.grouped_matmul(rows, w1, sizes))
+        return _core.grouped_matmul(hidden, w2, sizes)
+    return ffn
 
 
-def moe_ffn_apply(params, x, mesh: Mesh, axis: str = "ep",
-                  capacity_factor: float = 1.25):
+def moe_ffn_apply(params, x, mesh: Mesh, axis: str = "ep"):
     """MoE FFN over token-sharded input x (N, H) → (N, H).
 
     ``params['w1']/['w2']`` leading (expert) dim shards over ``axis``;
@@ -87,28 +87,36 @@ def moe_ffn_apply(params, x, mesh: Mesh, axis: str = "ep",
                          % (N, axis, ndev))
     S = N // ndev
     E_loc = E // ndev
-    capacity = max(1, int(capacity_factor * S / E))
 
-    def per_shard(params, xs):
-        xl = xs                                     # (S, H) local tokens
-        dispatch, combine = _route(xl, params["router"], E, capacity)
-        # send buffer: tokens grouped by destination device
-        send = jnp.einsum("sec,sh->ech", dispatch,
-                          xl.astype(jnp.float32))   # (E, C, H)
-        send = send.reshape(ndev, E_loc, capacity, H)
-        recv = lax.all_to_all(send, axis, split_axis=0, concat_axis=0,
-                              tiled=False)          # (ndev, E_loc, C, H)
-        # my experts' inputs from every source shard; params["w1"]/["w2"]
-        # arrive as the LOCAL (E_loc, ...) expert slice (in_specs P(axis))
-        ein = jnp.moveaxis(recv, 0, 1).reshape(E_loc, ndev * capacity, H)
-        eout = _expert_ffn(params["w1"].astype(jnp.float32),
-                           params["w2"].astype(jnp.float32),
-                           ein)                     # (E_loc, ndev*C, H)
-        back = jnp.moveaxis(eout.reshape(E_loc, ndev, capacity, H), 1, 0)
-        got = lax.all_to_all(back, axis, split_axis=0, concat_axis=0,
-                             tiled=False)           # (ndev, E_loc, C, H)
-        got = got.reshape(E, capacity, H)
-        out = jnp.einsum("sec,ech->sh", combine, got)
+    def per_shard(params, xl):
+        xl32 = xl.astype(jnp.float32)               # (S, H) local tokens
+        expert, gate = _route(xl, params["router"])
+        # my tokens grouped by destination device, one S-row slot each
+        order, place, to_dev = _core.group_by_expert(expert // E_loc, 0,
+                                                     ndev)
+        start = jnp.cumsum(to_dev) - to_dev                     # (ndev,)
+        slot_row = jnp.arange(S)[None, :]                       # (1, S)
+        src = jnp.minimum(start[:, None] + slot_row, S - 1)     # (ndev, S)
+        filled = slot_row < to_dev[:, None]
+        sorted_x = jnp.take(xl32, order, axis=0)
+        sorted_e = jnp.take(expert % E_loc, order)
+        send_x = jnp.where(filled[..., None], sorted_x[src], 0.0)
+        send_e = jnp.where(filled, sorted_e[src], E_loc)   # E_loc: padding
+        recv_x = lax.all_to_all(send_x, axis, 0, 0)         # (ndev, S, H)
+        recv_e = lax.all_to_all(send_e, axis, 0, 0)         # (ndev, S)
+        # my experts on everything I received; params["w1"]/["w2"] arrive
+        # as the LOCAL (E_loc, ...) expert slice (in_specs P(axis))
+        done, _ = _core.sparse_ffn(
+            recv_x.reshape(ndev * S, H), recv_e.reshape(ndev * S),
+            jnp.ones((ndev * S,), jnp.float32),
+            _gelu_experts(params["w1"].astype(jnp.float32),
+                          params["w2"].astype(jnp.float32)), 0, E_loc)
+        back = lax.all_to_all(done.reshape(ndev, S, H), axis, 0, 0)
+        # slot (d, j) holds the result of my sorted token start[d] + j
+        dev_sorted = jnp.take(expert // E_loc, order)
+        sorted_out = back[dev_sorted,
+                          jnp.arange(S) - jnp.take(start, dev_sorted)]
+        out = jnp.take(sorted_out, place, axis=0) * gate[:, None]
         return out.astype(x.dtype)
 
     in_specs = ({"router": P(), "w1": P(axis), "w2": P(axis)}, P(axis))
@@ -117,24 +125,14 @@ def moe_ffn_apply(params, x, mesh: Mesh, axis: str = "ep",
     return fn(params, x)
 
 
-def moe_ffn_ref(params, x, n_shards, capacity_factor: float = 1.25):
-    """Single-device oracle with the sharded routing semantics: tokens
-    are processed in ``n_shards`` groups, each with its own per-expert
-    capacity, exactly like the ``ep``-sharded kernel."""
-    N, H = x.shape
-    E = params["w1"].shape[0]
-    if N % n_shards:
-        raise ValueError("token count %d must divide into %d shards"
-                         % (N, n_shards))
-    S = N // n_shards
-    capacity = max(1, int(capacity_factor * S / E))
-    outs = []
-    for s in range(n_shards):
-        xl = x[s * S:(s + 1) * S]
-        dispatch, combine = _route(xl, params["router"], E, capacity)
-        ein = jnp.einsum("sec,sh->ech", dispatch, xl.astype(jnp.float32))
-        eout = _expert_ffn(params["w1"].astype(jnp.float32),
-                           params["w2"].astype(jnp.float32), ein)
-        outs.append(jnp.einsum("sec,ech->sh", combine,
-                               eout).astype(x.dtype))
-    return jnp.concatenate(outs, axis=0)
+def moe_ffn_ref(params, x):
+    """Single-device oracle: every expert on every token in a plain loop,
+    each token keeping its own expert's row times the router's gate."""
+    expert, gate = _route(x, params["router"])
+    x32 = x.astype(jnp.float32)
+    out = jnp.zeros_like(x32)
+    for e in range(params["w1"].shape[0]):
+        y = jax.nn.gelu(x32 @ params["w1"][e].astype(jnp.float32)) \
+            @ params["w2"][e].astype(jnp.float32)
+        out = out + jnp.where((expert == e)[:, None], gate[:, None] * y, 0.0)
+    return out.astype(x.dtype)

@@ -13,6 +13,7 @@ a test of this file has started — never at import, in a ``skipif`` or a
 ``parametrize``), in the test's own process, and no second file does the same
 from another worker.
 """
+import collections
 import os
 import re
 
@@ -308,3 +309,48 @@ def test_residual_tail_fuses_in_the_convolutions_layout(one_chip, case_id):
             layout_changes.append(line.strip()[:200])
     assert seen == set(tails), (seen, tails)    # the names are in the text
     assert not layout_changes, layout_changes
+
+
+def test_zaya1_layer_train_step_compiles_with_named_kernels(one_chip,
+                                                            monkeypatch):
+    """One ZAYA1 layer at the published widths (hidden 2048, 8 query on 2
+    key-value heads of 128, 8 of 16 experts of width 2048 held) under a
+    small tied head, 2 rows of 8,192 tokens, bf16 with
+    ``Adam(multi_precision=True)``: the ``DataParallelStep`` program
+    compiles for the described chip, attention goes through the streamed
+    forward and the split backward (causal, S=8192, D=128, the query heads
+    sharing their key-value head in the index maps), and the grouped
+    expert products are the compiler's own ragged-dot kernels — every
+    ``tpu_custom_call`` under a stable name."""
+    import mxnet_tpu as mx
+    from mxnet_tpu import context, gluon, parallel
+    from mxnet_tpu import random as mx_random
+
+    monkeypatch.setattr(context, "on_tpu", lambda *a: True)
+    net = gluon.model_zoo.zaya1(num_layers=1, vocab_size=8196,
+                                experts_held=(0, 8))
+    net.initialize(mx.init.Zero())
+    net.cast("bfloat16")
+    step = parallel.DataParallelStep(
+        net, gluon.loss.TiedSoftmaxCrossEntropyLoss(block_rows=8196),
+        mx.optimizer.Adam(learning_rate=1e-4, multi_precision=True))
+
+    def spec(v):
+        return jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=one_chip)
+
+    state = jax.tree_util.tree_map(
+        spec, [[p._data._data for p in step._params], step._opt_states])
+    carries = [jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
+               jax.ShapeDtypeStruct((len(step._trainable),), jnp.float32,
+                                    sharding=one_chip),
+               spec(mx_random.next_key())]
+    tokens = jax.ShapeDtypeStruct((2, 8192), jnp.int32, sharding=one_chip)
+    text = step._build().lower(*state, *carries, tokens,
+                               tokens).compile().as_text()
+    names = collections.Counter(_kernel_names(text))
+    assert names == {"flash_stream_fwd": 1, "flash_dq": 1, "flash_dkv": 1,
+                     "ragged-dot-none": 9, "ragged-dot-metadata": 2}
+    # the blocks' names ride in the instructions' op_names
+    for block in ("layer0_cca", "layer0_router", "layer0_experts",
+                  "layer0_moe_norm"):
+        assert "/%s%s/" % (net.prefix, block) in text, block

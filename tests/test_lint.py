@@ -235,8 +235,8 @@ def test_seeded_mesh_axis_bug_fails_the_gate(tmp_path):
     result = run_lint([str(clean)], baseline_path=None)
     assert not result.new, "\n".join(f.render() for f in result.new)
 
-    bugged = src.replace("recv = lax.all_to_all(send, axis,",
-                         'recv = lax.all_to_all(send, "dp",')
+    bugged = src.replace("recv_x = lax.all_to_all(send_x, axis,",
+                         'recv_x = lax.all_to_all(send_x, "dp",')
     assert bugged != src, "seeding site moved — update the test"
     bad = tmp_path / "moe_bug.py"
     bad.write_text(bugged)

@@ -1,6 +1,7 @@
-"""Expert parallelism: Switch-style MoE FFN over the ``ep`` mesh axis
-(all_to_all token exchange) vs the single-device routing oracle, on the
-virtual 8-device CPU mesh."""
+"""Expert parallelism: dropless top-1 MoE FFN over the ``ep`` mesh axis
+(all_to_all token exchange round the core of ``ops/moe.py``) vs the
+single-device oracle, a plain loop over the experts, on the virtual
+8-device CPU mesh."""
 import numpy as onp
 import jax
 import jax.numpy as jnp
@@ -31,7 +32,7 @@ def test_moe_matches_oracle(ndev, E, N, H, F):
         pytest.skip("not enough devices")
     mesh, params, x = _setup(ndev, E, N, H, F)
     got = parallel.moe_ffn_apply(params, x, mesh)
-    want = parallel.moe_ffn_ref(params, x, n_shards=ndev)
+    want = parallel.moe_ffn_ref(params, x)
     onp.testing.assert_allclose(onp.asarray(got), onp.asarray(want),
                                 rtol=1e-5, atol=1e-6)
 
@@ -43,27 +44,33 @@ def test_moe_grads_match_oracle():
     g1 = jax.grad(lambda p: jnp.sum(
         parallel.moe_ffn_apply(p, x, mesh) ** 2))(params)
     g2 = jax.grad(lambda p: jnp.sum(
-        parallel.moe_ffn_ref(p, x, ndev) ** 2))(params)
+        parallel.moe_ffn_ref(p, x) ** 2))(params)
     for k in g1:
         onp.testing.assert_allclose(onp.asarray(g1[k]),
                                     onp.asarray(g2[k]),
                                     rtol=1e-4, atol=2e-4, err_msg=k)
 
 
-def test_moe_capacity_drops_tokens():
-    """Overflowing an expert's capacity zeroes the overflow tokens'
-    output (they ride the residual), never crashes or reroutes."""
+def test_moe_overflow_drops_no_token():
+    """Routing so uneven that one expert gets most of every shard's tokens
+    — the case a per-expert capacity used to drop — computes every token:
+    the result is the oracle's and no row rides the residual as zeros."""
     ndev = 4
     if len(jax.devices()) < ndev:
         pytest.skip("not enough devices")
     mesh, params, x = _setup(ndev, 4, 32, 8, 16, seed=3)
-    # capacity_factor so low every expert can hold only 1 token per shard
-    got = parallel.moe_ffn_apply(params, x, mesh, capacity_factor=0.5)
-    want = parallel.moe_ffn_ref(params, x, ndev, capacity_factor=0.5)
+    # a router that sends nearly every token to expert 2 (on device 2)
+    params = {**params, "router": params["router"].at[:, 2].add(
+        10.0 * jnp.sign(params["router"][:, 2]))}
+    x = jnp.abs(x) * jnp.sign(params["router"][:, 2])[None, :]
+    got = parallel.moe_ffn_apply(params, x, mesh)
+    want = parallel.moe_ffn_ref(params, x)
     onp.testing.assert_allclose(onp.asarray(got), onp.asarray(want),
                                 rtol=1e-5, atol=1e-6)
-    # some token rows must actually be zero (dropped)
-    assert (onp.abs(onp.asarray(got)).sum(axis=1) == 0).any()
+    logits = onp.asarray(x @ params["router"])
+    assert (logits.argmax(-1) == 2).mean() > 0.8
+    # no token row is zero (dropped)
+    assert not (onp.abs(onp.asarray(got)).sum(axis=1) == 0).any()
 
 
 def test_moe_validation_errors():

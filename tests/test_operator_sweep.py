@@ -598,6 +598,14 @@ def test_random_ops_statistics():
 # ---------------------------------------------------------------------------
 
 COVERED_ELSEWHERE = {
+    # the decoder-block ops of PR 26 (RMS norm, partial rotary, causal
+    # convolutions over the sequence, CCA's q/k/v, the sparse experts, the
+    # blocked tied cross-entropy): tests/test_zaya.py, each against the
+    # plain float32 reference or a dense composition
+    "RMSNorm", "rms_norm", "l2_normalize", "rotary_embedding",
+    "causal_conv1d", "_contrib_cca_qkv", "cca_qkv",
+    "_contrib_sparse_experts", "sparse_experts",
+    "tied_softmax_cross_entropy",
     # exercised by dedicated test files: test_operator.py (NN core),
     # test_rnn.py (RNN), test_gluon.py (layers), test_symbol.py /
     # test_module.py (output ops), test_amp.py (amp_cast), test_loss.py,
