@@ -18,6 +18,7 @@ softmax; only an arbitrary additive ``mask`` forces the dense path.
 from __future__ import annotations
 
 from .... import initializer
+from ....ops.pallas_attention import bshd_layout_fits
 from ...block import HybridBlock
 from ...nn import Dense, Dropout, LayerNorm
 
@@ -52,49 +53,47 @@ class MultiHeadAttention(HybridBlock):
                               prefix="out_")
             self.drop = Dropout(dropout) if dropout else None
 
-    def _heads_split(self, x):
-        # (B, L, H*D) -> (B, H, L, D)
-        b, l = x.shape[0], x.shape[1]
-        d = self._units // self._heads
-        return x.reshape(b, l, self._heads, d).transpose(axes=(0, 2, 1, 3))
-
     def hybrid_forward(self, F, x, mask=None, valid_length=None):
         b, l = x.shape[0], x.shape[1]
-        qkv = self.qkv(x)                          # (B, L, 3E)
-        q, k, v = (self._heads_split(part)
-                   for part in F.split(qkv, num_outputs=3, axis=-1))
-        if mask is None:
+        d = self._units // self._heads
+        # (B, L, 3E) -> three (B, L, H, D) views: no copy
+        q, k, v = (part.reshape(b, l, self._heads, d) for part in
+                   F.split(self.qkv(x), num_outputs=3, axis=-1))
+        if mask is None and bshd_layout_fits(self._heads, d):
             # padding masks (per-row valid length) run INSIDE the flash
-            # kernel — masked inside the online softmax, fully-masked key
-            # blocks skipped — so padded batches (the normal BERT case)
-            # keep the fused path.  Layout: BHTD (explicit head
-            # transposes) — the transpose-free BSHD kernel
-            # (``flash_attention_bshd``) was measured END-TO-END slower
-            # here (BERT-base step 131.5 ms vs 121.7 ms): its 128-padded,
-            # 256-byte-strided head-column DMA costs more than the
-            # (B,L,H,D)->(B,H,L,D) transposes it avoids.  BSHD stays
-            # available for D=128 models, where neither pad nor stride
-            # penalty applies.
-            out = F.flash_attention(q, k, v, kv_lens=valid_length,
-                                    causal=self._causal)
+            # kernel — masked inside the softmax — so padded batches (the
+            # normal BERT case) keep the fused path.  Heads this wide are
+            # read where the projection left them, two 64-wide heads a
+            # 128-lane block, and the output goes straight into ``proj``:
+            # the step holds no head transpose (PERF.md §6, PR 27).
+            out = F.flash_attention_bshd(q, k, v, kv_lens=valid_length,
+                                         causal=self._causal)
         else:
-            d = self._units // self._heads
-            scores = F.batch_dot(q.reshape(-1, l, d),
-                                 k.reshape(-1, l, d),
-                                 transpose_b=True) / (d ** 0.5)
-            scores = scores.reshape(b, self._heads, l, l) + mask
-            if valid_length is not None:
-                # both given: fold the padding mask into the additive mask
-                # (keys at/after the row's valid length score -inf)
-                col = F.arange(0, l).reshape(1, 1, 1, l)
-                vl = valid_length.astype("float32").reshape(-1, 1, 1, 1)
-                scores = scores + \
-                    F.broadcast_greater_equal(col, vl) * -1e30
-            probs = F.softmax(scores, axis=-1)
-            out = F.batch_dot(probs.reshape(-1, l, l), v.reshape(-1, l, d))
-            out = out.reshape(b, self._heads, l, d)
-        out = out.transpose(axes=(0, 2, 1, 3)).reshape(b, l, self._units)
-        out = self.proj(out)
+            # (B, H, L, D): heads the lane blocks do not fit, and the
+            # dense composition an additive mask needs
+            q, k, v = (part.transpose(axes=(0, 2, 1, 3))
+                       for part in (q, k, v))
+            if mask is None:
+                out = F.flash_attention(q, k, v, kv_lens=valid_length,
+                                        causal=self._causal)
+            else:
+                scores = F.batch_dot(q.reshape(-1, l, d),
+                                     k.reshape(-1, l, d),
+                                     transpose_b=True) / (d ** 0.5)
+                scores = scores.reshape(b, self._heads, l, l) + mask
+                if valid_length is not None:
+                    # both given: fold the padding mask into the additive
+                    # mask (keys at/after the row's valid length score -inf)
+                    col = F.arange(0, l).reshape(1, 1, 1, l)
+                    vl = valid_length.astype("float32").reshape(-1, 1, 1, 1)
+                    scores = scores + \
+                        F.broadcast_greater_equal(col, vl) * -1e30
+                probs = F.softmax(scores, axis=-1)
+                out = F.batch_dot(probs.reshape(-1, l, l),
+                                  v.reshape(-1, l, d))
+                out = out.reshape(b, self._heads, l, d)
+            out = out.transpose(axes=(0, 2, 1, 3))
+        out = self.proj(out.reshape(b, l, self._units))
         if self.drop is not None:
             out = self.drop(out)
         return out

@@ -36,7 +36,8 @@ from .registry import register
 __all__ = ["flash_attention", "flash_attention_bshd",
            "pallas_flash_attention", "pallas_flash_attention_bshd",
            "pallas_flash_attention_bwd", "pallas_flash_attention_bwd_bshd",
-           "attention_dispatch", "tune_attention_blocks"]
+           "attention_dispatch", "tune_attention_blocks",
+           "bshd_layout_fits"]
 
 _NEG_INF = -1e30
 _LANES = 128
@@ -143,13 +144,44 @@ def tune_attention_blocks(seq_q, seq_k, head_dim, dtype="bfloat16"):
     return block_q, block_k
 
 
+def _bshd_heads_per_block(num_heads, head_dim, one_k_block):
+    """How many heads one lane block of a (B, T, H*D) array gives a grid
+    step of the (B, T, H, D) kernels, the array read as the projection
+    wrote it — no pad, no transpose: 1 where a head is whole 128-lane
+    tiles; 2 for 64-wide heads, an even number of them, in the kernels
+    that hold the whole K axis as one block (heads 2p and 2p+1 are lane
+    tile p); 0 where this layout does not take the shape and the entry
+    goes through the (B, H, T, D) kernels."""
+    if head_dim % _LANES == 0:
+        return 1
+    if 2 * head_dim == _LANES and num_heads % 2 == 0 and one_k_block:
+        return 2
+    return 0
+
+
+def bshd_layout_fits(num_heads, head_dim):
+    """Whether ``flash_attention_bshd`` can read heads of this width where
+    a projection left them, side by side along the lanes: the choice a
+    caller that holds (B, T, H*D) activations makes between the two
+    public ops, from the head count and width alone."""
+    return _bshd_heads_per_block(num_heads, head_dim, True) > 0
+
+
 def attention_dispatch(seq_q, seq_k, head_dim, dtype="bfloat16",
-                       on_tpu=None, census=True):
+                       on_tpu=None, census=True, bshd_heads=None):
     """Per-shape kernel choice for the public flash-attention ops.
 
     Returns ``{"kernel": "short_seq" | "streaming" | "dense_fallback",
     "block_q": int | None, "block_k": int | None, "tuner_source":
-    "table" | "searched" | "heuristic" | None}``.  ``short_seq`` is
+    "table" | "searched" | "heuristic" | None, "layout": "bhsd" | "bshd" |
+    "bshd_pair" | None, "heads_per_block": int | None}``.  ``layout`` and
+    ``heads_per_block`` say how the forward kernel addresses heads:
+    ``bhsd`` a head a (B*H, T, D) row; for a (B, T, H, D) caller, which
+    gives its head count as ``bshd_heads``, ``bshd`` a head a lane block
+    and ``bshd_pair`` two 64-wide heads a 128-lane block, or ``bhsd``
+    where that layout does not take the shape
+    (``_bshd_heads_per_block``).  The blocks and the kernel do not depend
+    on the layout.  ``short_seq`` is
     the single-pass kernel (whole K axis in one block — no online-softmax
     streaming state), ``streaming`` the K-sequential online-softmax
     kernel, ``dense_fallback`` composed XLA attention.  The heuristic is
@@ -177,7 +209,8 @@ def attention_dispatch(seq_q, seq_k, head_dim, dtype="bfloat16",
         if census:
             telemetry.inc("attention.kernel.dense_fallback")
         return {"kernel": "dense_fallback", "block_q": None,
-                "block_k": None, "tuner_source": None}
+                "block_k": None, "tuner_source": None, "layout": None,
+                "heads_per_block": None}
     cfg = _tune.table_config("attention",
                              (int(seq_q), int(seq_k), int(head_dim)),
                              dtype, quiet=not census)
@@ -189,18 +222,25 @@ def attention_dispatch(seq_q, seq_k, head_dim, dtype="bfloat16",
                                                  dtype)
         source = "heuristic"
     kernel = "short_seq" if seq_k <= block_k else "streaming"
+    per_block = 0 if bshd_heads is None else _bshd_heads_per_block(
+        bshd_heads, head_dim, kernel == "short_seq")
+    layout = ("bhsd", "bshd", "bshd_pair")[per_block]
+    per_block = max(per_block, 1)
     # per-shape dispatch accounting: this runs at TRACE time (once per
     # compiled shape, not per step), so the journal is a census of which
     # kernel every shape in the run got — and of where its blocks came
     # from (tuner_source)
     if census:
         telemetry.inc("attention.kernel.%s" % kernel)
+        telemetry.inc("attention.layout.%s" % layout)
         telemetry.event("attention_dispatch", kernel, seq_q=int(seq_q),
                         seq_k=int(seq_k), head_dim=int(head_dim),
                         dtype=str(dtype), block_q=block_q,
-                        block_k=block_k, tuner_source=source)
+                        block_k=block_k, tuner_source=source,
+                        layout=layout, heads_per_block=per_block)
     return {"kernel": kernel, "block_q": block_q, "block_k": block_k,
-            "tuner_source": source}
+            "tuner_source": source, "layout": layout,
+            "heads_per_block": per_block}
 
 
 # ---------------------------------------------------------------------------
@@ -353,15 +393,78 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, block_q, block_k,
         lse_ref[...] = lse.reshape(lse_ref.shape)
 
 
+def _head_lanes(x, h, heads):
+    """``x`` (rows, lanes) holds ``heads`` heads side by side along the
+    lanes: head ``h``'s lanes kept and the others zero, so a contraction
+    over ALL the lanes is head h's own — on a 128-deep MXU the passes a
+    64-deep contraction takes, and no lane is moved.  Static 64-lane
+    slices, the other way, tie in the forward and lose 1.8% in the
+    backward kernel (0.3386 against 0.3326 s of a 2.76 s trace; the step
+    386.2 against 387.0 samples/s: my chip runs, PR 27)."""
+    if heads == 1:
+        return x
+    width = x.shape[-1] // heads
+    lane = lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    return jnp.where((lane >= h * width) & (lane < (h + 1) * width), x,
+                     jnp.zeros_like(x))
+
+
+def _join_heads(parts):
+    """``parts[h]`` (rows, lanes) is right in head h's lanes — a product
+    with the whole block of v, k or q — and holds another head's numbers
+    elsewhere: the one lane-dense array that takes each head's lanes from
+    its own part."""
+    out = parts[-1]
+    if len(parts) > 1:
+        width = out.shape[-1] // len(parts)
+        lane = lax.broadcasted_iota(jnp.int32, out.shape, 1)
+        for h in range(len(parts) - 2, -1, -1):
+            out = jnp.where(lane < (h + 1) * width, parts[h], out)
+    return out
+
+
+def _columns_as_rows(cols):
+    """n (rows, 1) float32 columns -> (n, rows): per-row vectors laid
+    along the lanes, as the backward's transposed score blocks broadcast
+    them.  Stored as a column such a vector is one lane in 128 of its
+    tiles in HBM — four times the bytes of a 64-wide output block — and
+    XLA turns it round again before the backward.  The MXU does the turn,
+    for all the columns at once: column i goes to lane i of one
+    (rows, 128) array and an identity picks lane i into row i, at float32
+    precision."""
+    rows = cols[0].shape[0]
+    lane = lax.broadcasted_iota(jnp.int32, (rows, _LANES), 1)
+    spread = jnp.zeros((rows, _LANES), jnp.float32)
+    for i, col in enumerate(cols):
+        spread = jnp.where(lane == i, col, spread)
+    pick = (lax.broadcasted_iota(jnp.int32, (8, _LANES), 0)
+            == lax.broadcasted_iota(jnp.int32, (8, _LANES), 1))
+    return lax.dot_general(pick.astype(jnp.float32), spread,
+                           (((1,), (1,)), ((), ())),
+                           precision=lax.Precision.HIGHEST,
+                           preferred_element_type=jnp.float32)[:len(cols)]
+
+
+def _head_vector(ref, h, heads, shape):
+    """Head h's lse or delta row out of a block that holds one a head,
+    (1, heads, 1, lanes); with one head, the block's only vector."""
+    return (ref[...] if heads == 1 else ref[0, h]).reshape(shape)
+
+
 def _fwd_kernel_single(q_ref, k_ref, v_ref, *rest, scale, causal, block_q,
                        block_k, seq_k, seq_k_padded, has_lens, has_seg,
-                       pid_off=0):
+                       pid_off=0, heads=1, lse_rows=False):
     """Short-sequence forward: the whole K axis is ONE block, so the
     online-softmax streaming machinery — m/l VMEM scratch carried across
     K iterations, the per-iteration accumulator rescale, the init/
     finalize grid-edge phases — collapses to a single-pass softmax over
     one resident score tile.  Same mask ladder, same outputs (o, lse),
-    no scratch at all."""
+    no scratch at all.  ``heads`` > 1: the q, k, v and o blocks hold that
+    many heads side by side along the lanes (``_head_lanes``), the lse
+    block one vector a head; the softmax runs once a head, under one
+    mask, and the store is one lane-dense block.  ``lse_rows``: the lse
+    block is (1, heads, 1, block_q), a row a head along the lanes
+    (``_columns_as_rows``), not (…, block_q, 1) columns."""
     import jax.experimental.pallas as pl
 
     rest = list(rest)
@@ -376,43 +479,67 @@ def _fwd_kernel_single(q_ref, k_ref, v_ref, *rest, scale, causal, block_q,
     kvlen = lens_ref[bi, 0] if has_lens else None
     needs_tail = seq_k != seq_k_padded
 
+    def _mask():
+        col = lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
+        mask = col < (kvlen if has_lens else seq_k)
+        if causal:
+            row = qi * block_q + lax.broadcasted_iota(
+                jnp.int32, (block_q, block_k), 0)
+            mask = mask & (row >= col)
+        if has_seg:
+            mask = mask & (qseg_ref[0] == kseg_ref[0])
+        return mask
+
     def _compute(use_mask):
         q = q_ref[...].reshape(block_q, q_ref.shape[-1])
         k = k_ref[...].reshape(block_k, k_ref.shape[-1])
         v = v_ref[...].reshape(block_k, v_ref.shape[-1])
-        s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-        if use_mask:
-            col = lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-            mask = col < (kvlen if has_lens else seq_k)
-            if causal:
-                row = qi * block_q + lax.broadcasted_iota(
-                    jnp.int32, (block_q, block_k), 0)
-                mask = mask & (row >= col)
-            if has_seg:
-                mask = mask & (qseg_ref[0] == kseg_ref[0])
-            s = jnp.where(mask, s, _NEG_INF)
-        m = jnp.max(s, axis=-1, keepdims=True)
-        # fully-masked rows: m == _NEG_INF makes exp(s - m) == 1 on the
-        # masked entries — zero them so the row stays empty (l == 0)
-        p = jnp.exp(s - m)
-        if use_mask:
-            p = jnp.where(mask, p, 0.0)
-        l = jnp.sum(p, axis=-1, keepdims=True)
-        acc = lax.dot_general(p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                              preferred_element_type=jnp.float32)
-        o_ref[...] = (acc / jnp.where(l > 0, l, 1.0)).astype(
-            o_ref.dtype).reshape(o_ref.shape)
-        lse = jnp.where(l > 0, m + jnp.log(l), 0.0)
-        lse_ref[...] = lse.reshape(lse_ref.shape)
+        mask, outs, lses = None, [], []
+        for h in range(heads):
+            s = lax.dot_general(_head_lanes(q, h, heads), k,
+                                (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+            if use_mask:
+                mask = _mask() if mask is None else mask
+                s = jnp.where(mask, s, _NEG_INF)
+            m = jnp.max(s, axis=-1, keepdims=True)
+            # fully-masked rows: m == _NEG_INF makes exp(s - m) == 1 on the
+            # masked entries — zero them so the row stays empty (l == 0)
+            p = jnp.exp(s - m)
+            if use_mask:
+                p = jnp.where(mask, p, 0.0)
+            l = jnp.sum(p, axis=-1, keepdims=True)
+            acc = lax.dot_general(p.astype(v.dtype), v,
+                                  (((1,), (0,)), ((), ())),
+                                  preferred_element_type=jnp.float32)
+            outs.append(acc / jnp.where(l > 0, l, 1.0))
+            lses.append(jnp.where(l > 0, m + jnp.log(l), 0.0))
+        o_ref[...] = _join_heads(outs).astype(o_ref.dtype).reshape(
+            o_ref.shape)
+        if lse_rows:
+            rows = _columns_as_rows(lses)
+            for h in range(heads):
+                lse_ref[0, h] = rows[h:h + 1]
+        else:
+            lse_ref[...] = lses[0].reshape(lse_ref.shape)
 
     # run stays True: with a single K block every q block must execute
     # (its o/lse outputs have no other writer); fully-masked rows emit
     # exact zeros through the mask.  The ladder still specializes
-    # blocks nothing can mask down to the mask-free path.
-    _run_mask_specialized(pl, _compute, True, qi, ki, block_q, block_k,
-                          causal, has_lens, has_seg, needs_tail,
-                          kvlen=kvlen, seq_k=seq_k)
+    # blocks nothing can mask down to the mask-free path — but not under
+    # a length mask: with one K block its mask-free body would serve only
+    # the rows with no padding at all, and is a second copy of the
+    # kernel's code: 0.13 s more a BERT layer in every trace and lowering
+    # of the step (24 kernels, each compile and each eager call; 3 s of
+    # the cell's 38 s set-up) for a forward of 1.520 against 1.504 ms and
+    # a backward of 2.736 against 2.721 ms a call without it (my chip
+    # runs, PR 27)
+    if has_lens:
+        _compute(True)
+    else:
+        _run_mask_specialized(pl, _compute, True, qi, ki, block_q, block_k,
+                              causal, has_lens, has_seg, needs_tail,
+                              kvlen=kvlen, seq_k=seq_k)
 
 
 def _pad_qkv(q, k, v, block_q, block_k):
@@ -609,27 +736,25 @@ def pallas_flash_attention(q, k, v, causal=False, scale=None,
     return out
 
 
-def _pad_bshd(q, k, v, block_q, block_k):
-    """Pad (B, T, H, D) on T/D and merge heads into the lane dim: the
-    kernels then address head h as the Dp-wide column block (b, ti, h)
-    of a (B, Tp, H*Dp) array, so every block keeps (rows, lanes) =
-    (block, Dp) tiling — no in-kernel relayout, no host-side
-    transpose."""
-    B, Tq, H, D = q.shape
-    Tk = k.shape[1]
-    pad_q = (-Tq) % block_q
-    pad_k = (-Tk) % block_k
-    # lane-dim blocks must be 128-divisible on the TPU backend, so D pads
-    # to 128 (not 64): for D<=64 the zero columns ride the SAME 128-deep
-    # MXU pass the real columns use — no extra compute, only extra DMA,
-    # still far below the transpose traffic this layout avoids
-    pad_d = (-D) % 128
-    qp = jnp.pad(q, ((0, 0), (0, pad_q), (0, 0), (0, pad_d)))
-    kp = jnp.pad(k, ((0, 0), (0, pad_k), (0, 0), (0, pad_d)))
-    vp = jnp.pad(v, ((0, 0), (0, pad_k), (0, 0), (0, pad_d)))
-    Tqp, Tkp, Dp = Tq + pad_q, Tk + pad_k, D + pad_d
-    return (qp.reshape(B, Tqp, H * Dp), kp.reshape(B, Tkp, H * Dp),
-            vp.reshape(B, Tkp, H * Dp), Tqp, Tkp, Dp)
+def _swap_heads(x):
+    """(B, T, H, D) <-> (B, H, T, D)."""
+    return jnp.swapaxes(x, 1, 2)
+
+
+def _heads_along_lanes(x, block):
+    """(B, T, H, D) -> (B, Tp, H*D): the heads side by side along the
+    lanes, as a projection wrote them — a reshape, no copy.  The kernels
+    address a lane block of this array and the head width is never
+    padded; only a T that its block does not divide is, with zero rows."""
+    B, T, H, D = x.shape
+    x = x.reshape(B, T, H * D)
+    pad = (-T) % block
+    return jnp.pad(x, ((0, 0), (0, pad), (0, 0))) if pad else x
+
+
+def _first_rows(x, n, axis):
+    """``x`` without the rows a padded T added along ``axis``."""
+    return x if x.shape[axis] == n else lax.slice_in_dim(x, 0, n, axis=axis)
 
 
 def pallas_flash_attention_bshd(q, k, v, causal=False, scale=None,
@@ -638,13 +763,15 @@ def pallas_flash_attention_bshd(q, k, v, causal=False, scale=None,
                                 interpret: bool = False,
                                 return_lse: bool = False, kv_lens=None):
     """Flash forward on (B, T, H, D) inputs — the layout Dense-projected
-    activations already have, so callers skip the (B,T,H,D)→(B,H,T,D)
-    physical transpose XLA otherwise materializes around the kernel
-    (profiled at ~12% of the BERT train step).  Same online-softmax
-    kernel as :func:`pallas_flash_attention`, driven on a (B, H, n_q,
-    n_k) grid whose BlockSpecs address each head as a Dp-wide column
-    slice (see :func:`_pad_bshd`).  Returns (B, Tq, H, D)
-    [, lse (B, H, Tq)]."""
+    activations already have, so no (B,T,H,D)→(B,H,T,D) copy stands
+    before or after the kernel (24 ms of the 194 ms BERT-base step at 64
+    rows of 512: ledger, PR 26, `copy`).  The kernels of
+    :func:`pallas_flash_attention` on a (B, lane blocks, n_q[, n_k]) grid
+    whose BlockSpecs take a lane block of the unpadded (B, T, H*D) array:
+    one head where the head width is a multiple of 128, and with the K
+    axis in one block two 64-wide heads (``_bshd_heads_per_block``).  Any
+    other shape goes through the (B, H, T, D) kernels and their
+    transposes.  Returns (B, Tq, H, D) [, lse (B, H, Tq)]."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -657,11 +784,23 @@ def pallas_flash_attention_bshd(q, k, v, causal=False, scale=None,
         block_k = tk if block_k is None else block_k
     block_q = min(block_q, max(8, Tq))
     block_k = min(block_k, max(8, Tk))
-    qp, kp, vp, Tqp, Tkp, Dp = _pad_bshd(q, k, v, block_q, block_k)
+    single = Tk <= block_k
+    heads = _bshd_heads_per_block(H, D, single)
+    if not heads:
+        res = pallas_flash_attention(
+            _swap_heads(q), _swap_heads(k), _swap_heads(v), causal=causal,
+            scale=scale, block_q=block_q, block_k=block_k,
+            interpret=interpret, return_lse=True, kv_lens=kv_lens)
+        return (_swap_heads(res[0]), res[1]) if return_lse \
+            else _swap_heads(res[0])
+    qp = _heads_along_lanes(q, block_q)
+    kp = _heads_along_lanes(k, block_k)
+    vp = _heads_along_lanes(v, block_k)
+    Tqp, Tkp = qp.shape[1], kp.shape[1]
     n_q = Tqp // block_q
     n_k = Tkp // block_k
+    lanes = heads * D
 
-    single = n_k == 1
     extra, extra_specs = [], []
     if kv_lens is not None:
         lens = jnp.minimum(kv_lens.astype(jnp.int32), Tk).reshape(B, 1)
@@ -670,55 +809,61 @@ def pallas_flash_attention_bshd(q, k, v, causal=False, scale=None,
             lens.shape, lambda b, h, qi, ki=0: (0, 0),
             memory_space=pltpu.SMEM))
 
+    def result(out, lse):
+        # lse is (B, H, Tqp, 1) columns or (B, H, 1, Tqp) rows
+        out = _first_rows(out, Tq, 1).reshape(B, Tq, H, D)
+        if return_lse:
+            return out, _first_rows(lse.reshape(B, H, Tqp), Tq, 2)
+        return out
+
     common = dict(scale=scale, causal=causal, block_q=block_q,
                   block_k=block_k, seq_k=Tk, seq_k_padded=Tkp,
                   has_lens=kv_lens is not None, has_seg=False, pid_off=1)
     if single:
-        out, lse = pl.pallas_call(
-            functools.partial(_fwd_kernel_single, **common),
-            grid=(B, H, n_q),
+        # grid coordinate g is a lane block: ``heads`` heads of q, k, v
+        # and o, and their lse rows g*heads .. g*heads + heads - 1
+        return result(*pl.pallas_call(
+            functools.partial(_fwd_kernel_single, heads=heads,
+                              lse_rows=True, **common),
+            grid=(B, H // heads, n_q),
             in_specs=[
-                pl.BlockSpec((1, block_q, Dp),
-                             lambda b, h, qi: (b, qi, h)),
-                pl.BlockSpec((1, block_k, Dp),
-                             lambda b, h, qi: (b, 0, h)),
-                pl.BlockSpec((1, block_k, Dp),
-                             lambda b, h, qi: (b, 0, h)),
+                pl.BlockSpec((1, block_q, lanes),
+                             lambda b, g, qi: (b, qi, g)),
+                pl.BlockSpec((1, block_k, lanes),
+                             lambda b, g, qi: (b, 0, g)),
+                pl.BlockSpec((1, block_k, lanes),
+                             lambda b, g, qi: (b, 0, g)),
             ] + extra_specs,
             out_specs=[
-                pl.BlockSpec((1, block_q, Dp),
-                             lambda b, h, qi: (b, qi, h)),
-                pl.BlockSpec((1, 1, block_q, 1),
-                             lambda b, h, qi: (b, h, qi, 0)),
+                pl.BlockSpec((1, block_q, lanes),
+                             lambda b, g, qi: (b, qi, g)),
+                pl.BlockSpec((1, heads, 1, block_q),
+                             lambda b, g, qi: (b, g, 0, qi)),
             ],
             out_shape=[
-                jax.ShapeDtypeStruct((B, Tqp, H * Dp), q.dtype),
-                jax.ShapeDtypeStruct((B, H, Tqp, 1), jnp.float32),
+                jax.ShapeDtypeStruct((B, Tqp, H * D), q.dtype),
+                jax.ShapeDtypeStruct((B, H, 1, Tqp), jnp.float32),
             ],
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "parallel")),
             interpret=interpret,
-            name="flash_bshd_short_fwd",
-        )(qp, kp, vp, *extra)
-        out = out.reshape(B, Tqp, H, Dp)[:, :Tq, :, :D]
-        if return_lse:
-            return out, lse.reshape(B, H, Tqp)[:, :, :Tq]
-        return out
+            name="flash_bshd_cols_fwd",
+        )(qp, kp, vp, *extra))
 
     kernel = functools.partial(_fwd_kernel, n_k=n_k, **common)
-    out, lse = pl.pallas_call(
+    return result(*pl.pallas_call(
         kernel,
         grid=(B, H, n_q, n_k),
         in_specs=[
-            pl.BlockSpec((1, block_q, Dp),
+            pl.BlockSpec((1, block_q, D),
                          lambda b, h, qi, ki: (b, qi, h)),
-            pl.BlockSpec((1, block_k, Dp),
+            pl.BlockSpec((1, block_k, D),
                          lambda b, h, qi, ki: (b, ki, h)),
-            pl.BlockSpec((1, block_k, Dp),
+            pl.BlockSpec((1, block_k, D),
                          lambda b, h, qi, ki: (b, ki, h)),
         ] + extra_specs,
         out_specs=[
-            pl.BlockSpec((1, block_q, Dp),
+            pl.BlockSpec((1, block_q, D),
                          lambda b, h, qi, ki: (b, qi, h)),
             # trailing singleton keeps the block's last-two dims legal
             # ((block_q, 1): full-dim match on the minor axis)
@@ -726,24 +871,20 @@ def pallas_flash_attention_bshd(q, k, v, causal=False, scale=None,
                          lambda b, h, qi, ki: (b, h, qi, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, Tqp, H * Dp), q.dtype),
+            jax.ShapeDtypeStruct((B, Tqp, H * D), q.dtype),
             jax.ShapeDtypeStruct((B, H, Tqp, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, _LANES), jnp.float32),
-            pltpu.VMEM((block_q, Dp), jnp.float32),
+            pltpu.VMEM((block_q, D), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
         name="flash_bshd_stream_fwd",
-    )(qp, kp, vp, *extra)
-    out = out.reshape(B, Tqp, H, Dp)[:, :Tq, :, :D]
-    if return_lse:
-        return out, lse.reshape(B, H, Tqp)[:, :, :Tq]
-    return out
+    )(qp, kp, vp, *extra))
 
 
 # ---------------------------------------------------------------------------
@@ -777,24 +918,47 @@ def _bwd_unpack(rest, has_lens, has_seg):
     return lens_ref, qseg_ref, kseg_ref, rest
 
 
+def _delta_row(do, out):
+    """δ = rowsum(dO ∘ O) of (rows, lanes) blocks, as the (1, rows) lane
+    vector a transposed score block broadcasts; ``do`` with another
+    head's lanes zero gives one head's.  The float32 products meet a row
+    of ones on the MXU, which sums and transposes at once (a (rows, 1)
+    column of sums would need a relayout), at float32 precision."""
+    prod = do.astype(jnp.float32) * out.astype(jnp.float32)
+    return lax.dot_general(jnp.ones((8, prod.shape[-1]), jnp.float32), prod,
+                           (((1,), (1,)), ((), ())),
+                           precision=lax.Precision.HIGHEST,
+                           preferred_element_type=jnp.float32)[:1]
+
+
 def _bwd_core(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref, qseg_ref,
               kseg_ref, has_seg, use_mask, qi, ki, scale, causal,
-              block_q, block_k, seq_k, kvlen):
+              block_q, block_k, seq_k, kvlen, head=0, heads=1,
+              delta_of_out=False):
     """Shared recompute for all backward kernels: block reads, the
     transposed probability block pᵀ, and dsᵀ = pᵀ∘(dpᵀ − δ)·scale.
-    Returns (q, k, v, do, pT, dsT)."""
+    Returns (q, k, v, do, pT, dsT).  ``heads`` > 1: the blocks hold that
+    many heads side by side along the lanes, with one lse and delta row
+    a head; pT and dsT are head ``head``'s, from q and do with the other
+    heads' lanes zero (``_head_lanes``), and q, k, v, do come back
+    whole.  ``delta_of_out``: ``dlt_ref`` is the forward's output block
+    and δ is summed here (``_delta_row``), not read."""
     q = q_ref[...].reshape(block_q, q_ref.shape[-1])
     k = k_ref[...].reshape(block_k, k_ref.shape[-1])
     v = v_ref[...].reshape(block_k, v_ref.shape[-1])
     do = do_ref[...].reshape(block_q, do_ref.shape[-1])
-    lse_row = lse_ref[...].reshape(1, block_q)
-    dlt_row = dlt_ref[...].reshape(1, block_q)
-    pT = _scores_T(q, k, lse_row, scale, qi, ki, block_q, block_k,
-                   seq_k, causal, kvlen=kvlen,
+    do_head = _head_lanes(do, head, heads)
+    lse_row = _head_vector(lse_ref, head, heads, (1, block_q))
+    if delta_of_out:
+        dlt_row = _delta_row(do_head, dlt_ref[...].reshape(do.shape))
+    else:
+        dlt_row = _head_vector(dlt_ref, head, heads, (1, block_q))
+    pT = _scores_T(_head_lanes(q, head, heads), k, lse_row, scale, qi, ki,
+                   block_q, block_k, seq_k, causal, kvlen=kvlen,
                    qseg_row=qseg_ref[0] if has_seg else None,
                    kseg_col=kseg_ref[0] if has_seg else None,
                    use_mask=use_mask)
-    dpT = lax.dot_general(v, do, (((1,), (1,)), ((), ())),
+    dpT = lax.dot_general(v, do_head, (((1,), (1,)), ((), ())),
                           preferred_element_type=jnp.float32)
     dsT = pT * (dpT - dlt_row) * scale          # (block_k, block_q)
     return q, k, v, do, pT, dsT
@@ -897,12 +1061,18 @@ def _dqkv_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
 
 def _dqkv_single_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
                         *rest, scale, causal, block_q, block_k, seq_k,
-                        seq_k_padded, has_lens, has_seg):
+                        seq_k_padded, has_lens, has_seg, heads=1,
+                        delta_of_out=False):
     """Single-block backward (n_q == n_k == 1): the short-seq analogue of
     ``_dqkv_fused_kernel``.  With the whole (Tq, Tk) extent resident as
     one block there is no grid axis to stream over, so the dk/dv VMEM
     accumulators and the init/finalize phases disappear — one score/dp
-    recompute, 5 dots, three direct output writes."""
+    recompute, 5 dots, three direct output writes.  ``heads`` > 1: every
+    block holds that many heads side by side along the lanes; the 5 dots
+    run once a head against the WHOLE do, k and q blocks, and each of
+    the three writes is one lane-dense block (``_join_heads``).
+    ``delta_of_out``: the sixth operand is the forward's output block,
+    not δ (``_bwd_core``)."""
     import jax.experimental.pallas as pl
 
     lens_ref, qseg_ref, kseg_ref, rest = _bwd_unpack(rest, has_lens, has_seg)
@@ -912,26 +1082,32 @@ def _dqkv_single_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
     needs_tail = seq_k != seq_k_padded
 
     def _compute(use_mask):
-        q, k, v, do, pT, dsT = _bwd_core(
-            q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref, qseg_ref,
-            kseg_ref, has_seg, use_mask, 0, 0, scale, causal,
-            block_q, block_k, seq_k, kvlen)
-        dv_ref[...] = lax.dot_general(
-            pT.astype(do.dtype), do, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32).astype(
-                dv_ref.dtype).reshape(dv_ref.shape)
-        dq_ref[...] = lax.dot_general(
-            dsT.astype(q.dtype), k, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32).astype(
-                dq_ref.dtype).reshape(dq_ref.shape)
-        dk_ref[...] = lax.dot_general(
-            dsT.astype(q.dtype), q, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32).astype(
-                dk_ref.dtype).reshape(dk_ref.shape)
+        dv, dq, dk = [], [], []
+        for h in range(heads):
+            q, k, v, do, pT, dsT = _bwd_core(
+                q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref, qseg_ref,
+                kseg_ref, has_seg, use_mask, 0, 0, scale, causal,
+                block_q, block_k, seq_k, kvlen, head=h, heads=heads,
+                delta_of_out=delta_of_out)
+            dv.append(lax.dot_general(
+                pT.astype(do.dtype), do, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32))
+            dq.append(lax.dot_general(
+                dsT.astype(q.dtype), k, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32))
+            dk.append(lax.dot_general(
+                dsT.astype(q.dtype), q, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32))
+        for ref, parts in ((dv_ref, dv), (dq_ref, dq), (dk_ref, dk)):
+            ref[...] = _join_heads(parts).astype(ref.dtype).reshape(
+                ref.shape)
 
-    _run_mask_specialized(pl, _compute, True, 0, 0, block_q, block_k,
-                          causal, has_lens, has_seg, needs_tail,
-                          kvlen=kvlen, seq_k=seq_k)
+    if has_lens:
+        _compute(True)      # one body, as in _fwd_kernel_single
+    else:
+        _run_mask_specialized(pl, _compute, True, 0, 0, block_q, block_k,
+                              causal, has_lens, has_seg, needs_tail,
+                              kvlen=kvlen, seq_k=seq_k)
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref, *rest,
@@ -1238,8 +1414,13 @@ def pallas_flash_attention_bwd_bshd(q, k, v, out, lse, do, causal=False,
                                     block_k: Optional[int] = None,
                                     interpret: bool = False, kv_lens=None):
     """Flash backward on (B, T, H, D) operands (lse from the BSHD
-    forward, (B, H, Tq)): (dq, dk, dv) in BSHD, no physical transposes —
-    heads are addressed as Dp-wide column blocks (``_pad_bshd``)."""
+    forward, (B, H, Tq)): (dq, dk, dv) in BSHD, written as lane blocks of
+    (B, T, H*D) arrays — no pad, no slice, no transpose
+    (:func:`pallas_flash_attention_bshd` has the layout).  Where the
+    whole extent is one q and one K block, ONE kernel a lane block
+    (``_dqkv_single_kernel``, two 64-wide heads or one head of a multiple
+    of 128); past that, heads of a multiple of 128 run the split dq +
+    dk/dv pair, and any other shape the (B, H, T, D) kernels."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -1252,23 +1433,34 @@ def pallas_flash_attention_bwd_bshd(q, k, v, out, lse, do, causal=False,
         block_k = tk if block_k is None else block_k
     block_q = min(block_q, max(8, Tq))
     block_k = min(block_k, max(8, Tk))
-    block_q = _bwd_block_q(block_q, block_k, D + (-D) % 128,
-                           q.dtype.itemsize)
+    # the working set is reckoned at whole 128-lane blocks
+    bwd_block_q = _bwd_block_q(block_q, block_k, D + (-D) % _LANES,
+                               q.dtype.itemsize)
+    single = Tq <= bwd_block_q and Tk <= block_k
+    heads = _bshd_heads_per_block(H, D, single)
+    if not heads:
+        grads = pallas_flash_attention_bwd(
+            _swap_heads(q), _swap_heads(k), _swap_heads(v),
+            _swap_heads(out), lse, _swap_heads(do), causal=causal,
+            scale=scale, block_q=block_q, block_k=block_k,
+            interpret=interpret, kv_lens=kv_lens)
+        return tuple(_swap_heads(g) for g in grads)
+    block_q = bwd_block_q
 
-    # delta = rowsum(dO ∘ O), emitted directly in (B, H, Tq) order — the
-    # einsum output order makes XLA fuse the transpose into the reduce
-    delta = jnp.einsum("bqhd,bqhd->bhq", do.astype(jnp.float32),
-                       out.astype(jnp.float32))
+    qp = _heads_along_lanes(q, block_q)
+    dop = _heads_along_lanes(do, block_q)
+    kp = _heads_along_lanes(k, block_k)
+    vp = _heads_along_lanes(v, block_k)
+    Tqp, Tkp = qp.shape[1], kp.shape[1]
 
-    qp, kp, vp, Tqp, Tkp, Dp = _pad_bshd(q, k, v, block_q, block_k)
-    pad_q = Tqp - Tq
-    dop = jnp.pad(do, ((0, 0), (0, pad_q), (0, 0), (0, Dp - D))).reshape(
-        B, Tqp, H * Dp)
-    # rows (B, H, 1, Tqp): lse/delta along lanes, head-major like the grid
-    lsep = jnp.pad(lse, ((0, 0), (0, 0), (0, pad_q))).reshape(
-        B, H, 1, Tqp)
-    dltp = jnp.pad(delta, ((0, 0), (0, 0), (0, pad_q))).reshape(
-        B, H, 1, Tqp)
+    def lane_rows(x):
+        # (B, H, Tq) -> (B, H, 1, Tqp): a vector a head along the lanes,
+        # head-major like the grid
+        pad = Tqp - Tq
+        return (jnp.pad(x, ((0, 0), (0, 0), (0, pad))) if pad
+                else x).reshape(B, H, 1, Tqp)
+
+    lsep = lane_rows(lse)
     n_q = Tqp // block_q
     n_k = Tkp // block_k
 
@@ -1278,34 +1470,79 @@ def pallas_flash_attention_bwd_bshd(q, k, v, out, lse, do, causal=False,
 
     common = dict(scale=scale, causal=causal, block_q=block_q,
                   block_k=block_k, seq_k=Tk, seq_k_padded=Tkp,
-                  has_lens=lens is not None, has_seg=False, pid_off=1)
+                  has_lens=lens is not None, has_seg=False)
 
     def lens_specs():
         if lens is None:
             return [], []
         return [lens], [pl.BlockSpec(lens.shape,
-                                     lambda b, h, i, j: (0, 0),
+                                     lambda b, h, i=0, j=0: (0, 0),
                                      memory_space=pltpu.SMEM)]
 
+    def result(dq, dk, dv):
+        return (_first_rows(dq, Tq, 1).reshape(B, Tq, H, D),
+                _first_rows(dk, Tk, 1).reshape(B, Tk, H, D),
+                _first_rows(dv, Tk, 1).reshape(B, Tk, H, D))
+
     lops, lspecs = lens_specs()
+    if single:
+        # one kernel a lane block g: ``heads`` heads of every operand and
+        # gradient and their lse rows g*heads .. g*heads + heads - 1; the
+        # kernel sums δ = rowsum(dO ∘ O) itself from the forward's output
+        # block (in XLA that sum over 64 of 768 lanes is a relayout of a
+        # float32 (B, T, H*D) array: 100 MB a BERT-base layer)
+        lanes = heads * D
+        return result(*pl.pallas_call(
+            functools.partial(_dqkv_single_kernel, heads=heads,
+                              delta_of_out=True, **common),
+            grid=(B, H // heads),
+            in_specs=[
+                pl.BlockSpec((1, block_q, lanes), lambda b, g: (b, 0, g)),
+                pl.BlockSpec((1, block_k, lanes), lambda b, g: (b, 0, g)),
+                pl.BlockSpec((1, block_k, lanes), lambda b, g: (b, 0, g)),
+                pl.BlockSpec((1, block_q, lanes), lambda b, g: (b, 0, g)),
+                pl.BlockSpec((1, heads, 1, block_q),
+                             lambda b, g: (b, g, 0, 0)),
+                pl.BlockSpec((1, block_q, lanes), lambda b, g: (b, 0, g)),
+            ] + lspecs,
+            out_specs=[
+                pl.BlockSpec((1, block_q, lanes), lambda b, g: (b, 0, g)),
+                pl.BlockSpec((1, block_k, lanes), lambda b, g: (b, 0, g)),
+                pl.BlockSpec((1, block_k, lanes), lambda b, g: (b, 0, g)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((B, Tqp, H * D), q.dtype),
+                jax.ShapeDtypeStruct((B, Tkp, H * D), k.dtype),
+                jax.ShapeDtypeStruct((B, Tkp, H * D), v.dtype),
+            ],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel")),
+            interpret=interpret,
+            name="flash_bshd_cols_dqkv",
+        )(qp, kp, vp, dop, lsep, _heads_along_lanes(out, block_q), *lops))
+
+    # delta = rowsum(dO ∘ O), emitted directly in (B, H, Tq) order — the
+    # einsum output order makes XLA fuse the transpose into the reduce
+    dltp = lane_rows(jnp.einsum("bqhd,bqhd->bhq", do.astype(jnp.float32),
+                                out.astype(jnp.float32)))
     qkv_specs = [
-        pl.BlockSpec((1, block_q, Dp), lambda b, h, qi, ki: (b, qi, h)),
-        pl.BlockSpec((1, block_k, Dp), lambda b, h, qi, ki: (b, ki, h)),
-        pl.BlockSpec((1, block_k, Dp), lambda b, h, qi, ki: (b, ki, h)),
-        pl.BlockSpec((1, block_q, Dp), lambda b, h, qi, ki: (b, qi, h)),
+        pl.BlockSpec((1, block_q, D), lambda b, h, qi, ki: (b, qi, h)),
+        pl.BlockSpec((1, block_k, D), lambda b, h, qi, ki: (b, ki, h)),
+        pl.BlockSpec((1, block_k, D), lambda b, h, qi, ki: (b, ki, h)),
+        pl.BlockSpec((1, block_q, D), lambda b, h, qi, ki: (b, qi, h)),
         pl.BlockSpec((1, 1, 1, block_q),
                      lambda b, h, qi, ki: (b, h, 0, qi)),
         pl.BlockSpec((1, 1, 1, block_q),
                      lambda b, h, qi, ki: (b, h, 0, qi)),
     ] + lspecs
     dq = pl.pallas_call(
-        functools.partial(_dq_kernel, n_k=n_k, **common),
+        functools.partial(_dq_kernel, n_k=n_k, pid_off=1, **common),
         grid=(B, H, n_q, n_k),
         in_specs=qkv_specs,
-        out_specs=pl.BlockSpec((1, block_q, Dp),
+        out_specs=pl.BlockSpec((1, block_q, D),
                                lambda b, h, qi, ki: (b, qi, h)),
-        out_shape=jax.ShapeDtypeStruct((B, Tqp, H * Dp), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, Dp), jnp.float32)],
+        out_shape=jax.ShapeDtypeStruct((B, Tqp, H * D), q.dtype),
+        scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
@@ -1315,42 +1552,38 @@ def pallas_flash_attention_bwd_bshd(q, k, v, out, lse, do, causal=False,
 
     lops, lspecs = lens_specs()
     kv_specs = [
-        pl.BlockSpec((1, block_q, Dp), lambda b, h, ki, qi: (b, qi, h)),
-        pl.BlockSpec((1, block_k, Dp), lambda b, h, ki, qi: (b, ki, h)),
-        pl.BlockSpec((1, block_k, Dp), lambda b, h, ki, qi: (b, ki, h)),
-        pl.BlockSpec((1, block_q, Dp), lambda b, h, ki, qi: (b, qi, h)),
+        pl.BlockSpec((1, block_q, D), lambda b, h, ki, qi: (b, qi, h)),
+        pl.BlockSpec((1, block_k, D), lambda b, h, ki, qi: (b, ki, h)),
+        pl.BlockSpec((1, block_k, D), lambda b, h, ki, qi: (b, ki, h)),
+        pl.BlockSpec((1, block_q, D), lambda b, h, ki, qi: (b, qi, h)),
         pl.BlockSpec((1, 1, 1, block_q),
                      lambda b, h, ki, qi: (b, h, 0, qi)),
         pl.BlockSpec((1, 1, 1, block_q),
                      lambda b, h, ki, qi: (b, h, 0, qi)),
     ] + lspecs
     dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, n_q=n_q, **common),
+        functools.partial(_dkv_kernel, n_q=n_q, pid_off=1, **common),
         grid=(B, H, n_k, n_q),
         in_specs=kv_specs,
         out_specs=[
-            pl.BlockSpec((1, block_k, Dp),
+            pl.BlockSpec((1, block_k, D),
                          lambda b, h, ki, qi: (b, ki, h)),
-            pl.BlockSpec((1, block_k, Dp),
+            pl.BlockSpec((1, block_k, D),
                          lambda b, h, ki, qi: (b, ki, h)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, Tkp, H * Dp), k.dtype),
-            jax.ShapeDtypeStruct((B, Tkp, H * Dp), v.dtype),
+            jax.ShapeDtypeStruct((B, Tkp, H * D), k.dtype),
+            jax.ShapeDtypeStruct((B, Tkp, H * D), v.dtype),
         ],
-        scratch_shapes=[pltpu.VMEM((block_k, Dp), jnp.float32),
-                        pltpu.VMEM((block_k, Dp), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32),
+                        pltpu.VMEM((block_k, D), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
         name="flash_bshd_dkv",
     )(qp, kp, vp, dop, lsep, dltp, *lops)
-
-    dq = dq.reshape(B, Tqp, H, Dp)[:, :Tq, :, :D]
-    dk = dk.reshape(B, Tkp, H, Dp)[:, :Tk, :, :D]
-    dv = dv.reshape(B, Tkp, H, Dp)[:, :Tk, :, :D]
-    return dq, dk, dv
+    return result(dq, dk, dv)
 
 
 # ---------------------------------------------------------------------------
@@ -1493,15 +1726,17 @@ def _flash_attention_op(queries, keys, values, causal: bool = False,
 def flash_attention_bshd(q, k, v, causal=False, scale=None, kv_lens=None):
     """Fused attention over (B, T, H, D) operands — the natural layout of
     Dense-projected activations.  Functionally identical to
-    :func:`flash_attention` on the transposed inputs, but the Pallas
-    kernels address heads as lane-column blocks so neither forward nor
-    backward materializes a (B,T,H,D)↔(B,H,T,D) transpose."""
+    :func:`flash_attention` on the transposed inputs, but where
+    ``bshd_layout_fits`` the Pallas kernels address heads as lane blocks
+    of the (B, T, H*D) arrays, so neither forward nor backward
+    materializes a (B,T,H,D)↔(B,H,T,D) transpose."""
     return _flash_bshd_fwd(q, k, v, causal, scale, kv_lens)[0]
 
 
 def _flash_bshd_fwd(q, k, v, causal, scale, kv_lens):
     plan = attention_dispatch(q.shape[1], k.shape[1], q.shape[3], q.dtype,
-                              on_tpu=_context.on_tpu(q))
+                              on_tpu=_context.on_tpu(q),
+                              bshd_heads=q.shape[2])
     if plan["kernel"] != "dense_fallback":
         out, lse = per_batch_shard(
             lambda q, k, v, kl: pallas_flash_attention_bshd(
@@ -1510,10 +1745,10 @@ def _flash_bshd_fwd(q, k, v, causal, scale, kv_lens):
                 kv_lens=kl),
             (q, k, v, kv_lens))
         return out, (q, k, v, out, lse, kv_lens)
-    bhtd = lambda x: jnp.swapaxes(x, 1, 2)
-    out = _reference_attention(bhtd(q), bhtd(k), bhtd(v), causal, scale,
-                               kv_lens, None, None)
-    return bhtd(out), (q, k, v, None, None, kv_lens)
+    out = _reference_attention(_swap_heads(q), _swap_heads(k),
+                               _swap_heads(v), causal, scale, kv_lens,
+                               None, None)
+    return _swap_heads(out), (q, k, v, None, None, kv_lens)
 
 
 def _flash_bshd_bwd(causal, scale, res, g):
@@ -1531,11 +1766,10 @@ def _flash_bshd_bwd(causal, scale, res, g):
                 kv_lens=kl),
             (q, k, v, out, lse, g, kv_lens))
     else:
-        bhtd = lambda x: jnp.swapaxes(x, 1, 2)
         _, vjp = jax.vjp(
-            lambda q_, k_, v_: bhtd(_reference_attention(
-                bhtd(q_), bhtd(k_), bhtd(v_), causal, scale, kv_lens,
-                None, None)),
+            lambda q_, k_, v_: _swap_heads(_reference_attention(
+                _swap_heads(q_), _swap_heads(k_), _swap_heads(v_), causal,
+                scale, kv_lens, None, None)),
             q, k, v)
         dq, dk, dv = vjp(g)
     return dq, dk, dv, _int_zero_cotangent(kv_lens)

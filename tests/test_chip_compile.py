@@ -101,6 +101,8 @@ _ATTENTION = [
     ("s4096_f32_segments", "bhsd", 2, 8, 4096, 64, "float32", 0, 0, 1),
     ("bshd_s512_kvlens", "bshd", 24, 12, 512, 64, "bfloat16", 0, 1, 0),
     ("bshd_s4096_causal", "bshd", 2, 8, 4096, 64, "bfloat16", 1, 0, 0),
+    ("bshd_s4096_d128_causal", "bshd", 2, 4, 4096, 128, "bfloat16", 1, 0, 0),
+    ("bshd_s512_d128_kvlens", "bshd", 24, 6, 512, 128, "bfloat16", 0, 1, 0),
 ]
 
 
@@ -174,10 +176,14 @@ _KERNELS = {
     "s4096_causal_kvlens_segments": _STREAM,
     "s8192_d128_causal": _STREAM,
     "s4096_f32_segments": _STREAM,
-    "bshd_s512_kvlens": (["flash_bshd_short_fwd"],
-                         ["flash_bshd_dkv", "flash_bshd_dq"]),
-    "bshd_s4096_causal": (["flash_bshd_stream_fwd"],
-                          ["flash_bshd_dkv", "flash_bshd_dq"]),
+    # two 64-wide heads a lane block: ONE backward kernel, as in BHSD
+    "bshd_s512_kvlens": (["flash_bshd_cols_fwd"], ["flash_bshd_cols_dqkv"]),
+    # 64-wide heads past one K block: not taken in this layout
+    "bshd_s4096_causal": _STREAM,
+    "bshd_s4096_d128_causal": (["flash_bshd_stream_fwd"],
+                               ["flash_bshd_dkv", "flash_bshd_dq"]),
+    "bshd_s512_d128_kvlens": (["flash_bshd_cols_fwd"],
+                              ["flash_bshd_cols_dqkv"]),
     "layernorm_bert": (["layernorm_fwd"], ["layernorm_bwd"]),
 }
 
@@ -203,13 +209,15 @@ def test_kernels_carry_their_stable_names(compiled, case_id):
             sorted(_kernel_names(bwd_text))) == _KERNELS[case_id]
 
 
-def test_flash_attention_compiles_inside_a_dp4_sharded_program(topo,
-                                                               monkeypatch):
+@pytest.mark.parametrize("layout", ["bhsd", "bshd"])
+def test_flash_attention_compiles_inside_a_dp4_sharded_program(
+        topo, monkeypatch, layout):
     """A Mosaic kernel cannot be partitioned automatically: inside a jitted
     program over dp-sharded operands (the ``DataParallelStep`` layout on a
     four-chip host) the custom-vjp op has to wrap it per shard.  BERT-base
     attention shape, global batch 24 over four described chips, forward and
-    backward through the public op."""
+    backward through the public op of either layout — in (B, T, H, D) two
+    64-wide heads a lane block, one kernel each way."""
     import numpy as onp
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
     from mxnet_tpu import context
@@ -218,12 +226,16 @@ def test_flash_attention_compiles_inside_a_dp4_sharded_program(topo,
     monkeypatch.setattr(context, "on_tpu", lambda *a: True)
     mesh = Mesh(onp.array(topo.devices), ("dp",))
     over_dp = NamedSharding(mesh, P("dp"))
-    qkv = jax.ShapeDtypeStruct((24, 12, 512, 64), jnp.bfloat16,
-                               sharding=over_dp)
+    op, shape, kernels = {
+        "bhsd": (PA.flash_attention, (24, 12, 512, 64),
+                 ["flash_dqkv_single", "flash_short_fwd"]),
+        "bshd": (PA.flash_attention_bshd, (24, 512, 12, 64),
+                 ["flash_bshd_cols_dqkv", "flash_bshd_cols_fwd"])}[layout]
+    qkv = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=over_dp)
     lens = jax.ShapeDtypeStruct((24,), jnp.int32, sharding=over_dp)
 
     def loss(q, k, v, kv_lens):
-        out = PA.flash_attention(q, k, v, False, None, kv_lens)
+        out = op(q, k, v, False, None, kv_lens)
         return out.astype(jnp.float32).sum()
 
     def program(q, k, v, kv_lens):
@@ -232,8 +244,90 @@ def test_flash_attention_compiles_inside_a_dp4_sharded_program(topo,
             return jax.grad(loss, argnums=(0, 1, 2))(q, k, v, kv_lens)
 
     text = _compile(program, qkv, qkv, qkv, lens)
-    assert sorted(_kernel_names(text)) == ["flash_dqkv_single",
-                                           "flash_short_fwd"]
+    assert sorted(_kernel_names(text)) == kernels
+
+
+def _instructions(text):
+    """(opcode, elements of the result, the line) of every instruction of
+    a compiled program's text that has an array result."""
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = \w+\[([\d,]*)\]\S* "
+                     r"([\w\-]+)\(", line)
+        if m:
+            elements = 1
+            for d in filter(None, m.group(1).split(",")):
+                elements *= int(d)
+            yield m.group(2), elements, line.strip()
+
+
+def test_bert_layer_train_step_holds_no_head_transposes(one_chip,
+                                                        monkeypatch):
+    """One BERT-base encoder layer (hidden 768, 12 heads of 64, FFN 3072)
+    at the cell's 64 rows of 512 tokens, bf16 with padded rows, as a
+    ``DataParallelStep`` under Adam, compiled for the described chip:
+    attention is exactly one forward and ONE backward kernel in the
+    projections' own layout, and round them the program holds no
+    transpose or copy of a head-split activation, no pad of an operand
+    and no slice but the three-way split of ``qkv`` — the forward's
+    output goes into the backward kernel and the output projection as
+    the kernel wrote it."""
+    import numpy as onp
+    import mxnet_tpu as mx
+    from mxnet_tpu import context, gluon, parallel
+    from mxnet_tpu import random as mx_random
+    from mxnet_tpu.gluon.contrib.nn import TransformerEncoderCell
+
+    monkeypatch.setattr(context, "on_tpu", lambda *a: True)
+
+    class Layer(gluon.HybridBlock):
+        def __init__(self):
+            super().__init__()
+            with self.name_scope():
+                self.cell = TransformerEncoderCell(768, 3072, 12,
+                                                   prefix="layer0_")
+
+        def hybrid_forward(self, F, x, valid_length):
+            return self.cell(x, None, valid_length)
+
+    net = Layer()
+    net.initialize(mx.init.Zero())
+    # deferred shapes do not depend on the batch: 8 tokens complete them
+    # (too short for a kernel: composed attention, here on the CPU)
+    net(mx.nd.zeros((1, 8, 768)),
+        mx.nd.array(onp.array([8], "int32"), dtype="int32"))
+    net.cast("bfloat16")
+    step = parallel.DataParallelStep(
+        net, gluon.loss.L2Loss(), mx.optimizer.Adam(learning_rate=1e-4))
+
+    def spec(v):
+        return jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=one_chip)
+
+    state = jax.tree_util.tree_map(
+        spec, [[p._data._data for p in step._params], step._opt_states])
+    carries = [jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
+               jax.ShapeDtypeStruct((len(step._trainable),), jnp.float32,
+                                    sharding=one_chip),
+               spec(mx_random.next_key())]
+    x = jax.ShapeDtypeStruct((64, 512, 768), jnp.bfloat16,
+                             sharding=one_chip)
+    lens = jax.ShapeDtypeStruct((64,), jnp.int32, sharding=one_chip)
+    text = step._build().lower(*state, *carries, (x, lens),
+                               x).compile().as_text()
+    assert collections.Counter(_kernel_names(text)) == {
+        "flash_bshd_cols_fwd": 1, "flash_bshd_cols_dqkv": 1}
+    activation = 64 * 512 * 768
+    bad = []
+    for opcode, elements, line in _instructions(text):
+        head_split = "[64,12,512,64]" in line or "[64,512,12,64]" in line
+        if opcode in ("copy", "transpose") and (
+                head_split or (elements >= activation and "_attn/" in line)):
+            bad.append(line[:240])
+        if opcode == "pad" and elements >= activation:
+            bad.append(line[:240])
+        if opcode in ("slice", "dynamic-slice") and elements >= activation \
+                and not re.search(r'op_name="[^"]*_attn/split"', line):
+            bad.append(line[:240])
+    assert not bad, bad
 
 
 # the residual units of ResNet-50's first and last stage at batch 128:

@@ -253,6 +253,60 @@ def test_transformer_valid_length_routes_flash():
     assert onp.abs(got[1, :40] - want[1, :40]).max() < 2e-5
 
 
+@pytest.mark.parametrize("heads,width,layout,per_block", [
+    (12, 64, "bshd_pair", 2),       # BERT-base's heads: two a lane block
+    (4, 32, "bhsd", 1),             # 32-wide heads stay head-major
+])
+def test_mha_chooses_the_layout_from_its_heads(monkeypatch, heads, width,
+                                               layout, per_block):
+    """``MultiHeadAttention`` with a padding mask runs the flash kernels
+    (interpret mode here) in the layout its head width and count call
+    for, says so in the ``attention_dispatch`` event, and gives the
+    output and every parameter gradient of its own dense path under an
+    all-zero additive mask."""
+    import functools
+    from mxnet_tpu import autograd, context, telemetry
+    from mxnet_tpu.gluon.contrib.nn import MultiHeadAttention
+    monkeypatch.setattr(context, "on_tpu", lambda *a: True)
+    for name in ("pallas_flash_attention", "pallas_flash_attention_bwd",
+                 "pallas_flash_attention_bshd",
+                 "pallas_flash_attention_bwd_bshd"):
+        monkeypatch.setattr(P, name, functools.partial(getattr(P, name),
+                                                       interpret=True))
+    B, L = 2, 128
+    rs = onp.random.RandomState(11)
+    mx.random.seed(3)
+    attn = MultiHeadAttention(units=heads * width, num_heads=heads)
+    attn.initialize()
+    x = mx.nd.array(rs.uniform(-1, 1, (B, L, heads * width))
+                    .astype("float32"))
+    weight = mx.nd.array(rs.uniform(-1, 1, (B, L, heads * width))
+                         .astype("float32"))
+    vl = mx.nd.array(onp.array([L, 37]), dtype="int32")
+    params = [p for _, p in sorted(attn.collect_params().items())]
+
+    def run(mask):
+        with autograd.record():
+            out = attn(x, mask, vl)
+            loss = (out * weight).sum()
+        loss.backward()
+        return out.asnumpy(), [p.grad().asnumpy().copy() for p in params]
+
+    telemetry.reset()
+    out, grads = run(None)
+    events = [e for e in telemetry.snapshot()["events"]
+              if e["kind"] == "attention_dispatch"]
+    assert events and all(e["layout"] == layout
+                          and e["heads_per_block"] == per_block
+                          for e in events), events
+    assert telemetry.counter("attention.layout.%s" % layout) == len(events)
+    want, want_grads = run(mx.nd.zeros((B, 1, 1, L)))
+    assert onp.abs(out - want).max() < 2e-5
+    assert len(grads) == 4
+    for g, w in zip(grads, want_grads):
+        assert onp.abs(g - w).max() < 2e-4 * max(1.0, onp.abs(w).max())
+
+
 def test_flash_attention_op_and_grad_fallback():
     """The registered op (jnp fallback off-TPU) forward + custom-vjp grad."""
     shape = (1, 2, 128, 32)
@@ -280,31 +334,100 @@ def _bshd(x):
     return jnp.swapaxes(x, 1, 2)
 
 
-@pytest.mark.parametrize("lens", [None, (100, 128)])
+def _program(fn, *args):
+    """(names of the Pallas kernels, names of every primitive) in the
+    program ``fn(*args)`` traces to."""
+    eqns = jax.make_jaxpr(fn)(*args).jaxpr.eqns
+    return ([e.params["name"] for e in eqns
+             if e.primitive.name == "pallas_call"],
+            {e.primitive.name for e in eqns})
+
+
+# id -> (B, H, T, D, block_q, block_k, forward kernel, backward kernels)
+_BSHD_CASES = {
+    # an odd head count of 64-wide heads, K in two blocks: not taken in the
+    # lane-block layout, the entry goes through the (B, H, T, D) kernels
+    "odd_heads": (2, 3, 128, 64, 64, 64,
+                  "flash_stream_fwd", ["flash_dq", "flash_dkv"]),
+    # two 64-wide heads a 128-lane block, one kernel each way
+    "pair": (2, 4, 128, 64, 128, 128,
+             "flash_bshd_cols_fwd", ["flash_bshd_cols_dqkv"]),
+    # ... T not a multiple of the block: zero rows pad T, never D
+    "pair_ragged_t": (2, 2, 200, 64, 256, 256,
+                      "flash_bshd_cols_fwd", ["flash_bshd_cols_dqkv"]),
+    # ... two q blocks: the forward's grid walks them, the backward past
+    # one q block is the (B, H, T, D) kernels'
+    "pair_q_blocks": (1, 2, 256, 64, 128, 256,
+                      "flash_bshd_cols_fwd", ["flash_dqkv_fused"]),
+    # a head of whole lane tiles: one head a block, same kernels
+    "d128": (2, 2, 128, 128, 128, 128,
+             "flash_bshd_cols_fwd", ["flash_bshd_cols_dqkv"]),
+    # ... K in two blocks: the streamed forward, the split backward
+    "d128_stream": (1, 2, 128, 128, 64, 64,
+                    "flash_bshd_stream_fwd",
+                    ["flash_bshd_dq", "flash_bshd_dkv"]),
+}
+
+
+# kv_lens: none; a row shorter than the sequence and a full one; a row
+# shorter than one block and a row of length 0 (its output and gradients
+# are exactly zero)
+@pytest.mark.parametrize("lens", [None, (100, 128), (40, 0)])
 @pytest.mark.parametrize("causal", [False, True])
-def test_bshd_kernels_match_bhtd(causal, lens):
+@pytest.mark.parametrize("case", list(_BSHD_CASES))
+def test_bshd_kernels_match_bhtd(case, causal, lens):
     """The (B,T,H,D)-layout kernels compute exactly what the flat-grid
-    BHTD kernels do, fwd and bwd (no transposes on either side)."""
-    B, H, T, D = 2, 3, 128, 64
+    BHTD kernels do, fwd and bwd (no transposes on either side) — one
+    head a lane block or two 64-wide heads a 128-lane block — and a shape
+    the layout does not take runs the BHTD kernels themselves."""
+    B, H, T, D, block_q, block_k, fwd_name, bwd_names = _BSHD_CASES[case]
+    B = max(B, 2) if lens else B
     q, k, v = (_rand((B, H, T, D), i) for i in range(3))
     kv = jnp.asarray(lens, jnp.int32) if lens else None
-    o1, l1 = P.pallas_flash_attention(
-        q, k, v, causal=causal, return_lse=True, interpret=True,
-        block_q=64, block_k=64, kv_lens=kv)
-    o2, l2 = P.pallas_flash_attention_bshd(
-        _bshd(q), _bshd(k), _bshd(v), causal=causal, return_lse=True,
-        interpret=True, block_q=64, block_k=64, kv_lens=kv)
+    kw = dict(causal=causal, interpret=True, block_q=block_q,
+              block_k=block_k, kv_lens=kv)
+    o1, l1 = P.pallas_flash_attention(q, k, v, return_lse=True, **kw)
+    fwd = lambda q, k, v: P.pallas_flash_attention_bshd(
+        q, k, v, return_lse=True, **kw)
+    o2, l2 = fwd(_bshd(q), _bshd(k), _bshd(v))
+    assert _program(fwd, _bshd(q), _bshd(k), _bshd(v))[0] == [fwd_name]
     assert float(jnp.max(jnp.abs(_bshd(o2) - o1))) < 1e-6
     assert float(jnp.max(jnp.abs(l2 - l1))) < 1e-6
+    if lens and 0 in lens:
+        assert float(jnp.max(jnp.abs(o2[lens.index(0)]))) == 0.0
     do = _rand((B, H, T, D), 7)
-    g1 = P.pallas_flash_attention_bwd(q, k, v, o1, l1, do, causal=causal,
-                                      interpret=True, block_q=64,
-                                      block_k=64, kv_lens=kv)
-    g2 = P.pallas_flash_attention_bwd_bshd(
-        _bshd(q), _bshd(k), _bshd(v), o2, l2, _bshd(do), causal=causal,
-        interpret=True, block_q=64, block_k=64, kv_lens=kv)
+    g1 = P.pallas_flash_attention_bwd(q, k, v, o1, l1, do, **kw)
+    bwd = lambda q, k, v, o, l, do: P.pallas_flash_attention_bwd_bshd(
+        q, k, v, o, l, do, **kw)
+    operands = (_bshd(q), _bshd(k), _bshd(v), o2, l2, _bshd(do))
+    g2 = bwd(*operands)
+    assert _program(bwd, *operands)[0] == bwd_names
     for a, b in zip(g1, g2):
         assert float(jnp.max(jnp.abs(_bshd(b) - a))) < 5e-6
+
+
+@pytest.mark.parametrize("heads,width", [(4, 64), (2, 128)])
+def test_bshd_lane_blocks_build_no_padded_array(heads, width):
+    """Where T divides into its blocks the lane-block kernels read the
+    (B, T, H, D) operands as they are and write the results as they are
+    returned: the traced program is reshapes round ONE kernel — no pad of
+    the head width, no slice of an output, no transpose."""
+    x = jnp.zeros((2, 128, heads, width), jnp.float32)
+    lse = jnp.zeros((2, heads, 128), jnp.float32)
+    kw = dict(interpret=True, block_q=128, block_k=128,
+              kv_lens=jnp.asarray((100, 128), jnp.int32))
+    names, prims = _program(lambda q, k, v: P.pallas_flash_attention_bshd(
+        q, k, v, return_lse=True, **kw), x, x, x)
+    assert names == ["flash_bshd_cols_fwd"]
+    assert not prims & {"pad", "slice", "dynamic_slice", "transpose",
+                        "concatenate", "gather"}, prims
+    names, prims = _program(
+        lambda q, k, v, o, l, do: P.pallas_flash_attention_bwd_bshd(
+            q, k, v, o, l, do, **kw), x, x, x, x, lse, x)
+    assert names == ["flash_bshd_cols_dqkv"]
+    assert not prims & {"pad", "slice", "dynamic_slice", "transpose",
+                        "concatenate", "gather", "dot_general",
+                        "reduce_sum"}, prims
 
 
 def test_flash_attention_bshd_fallback_grads_match_dense():
