@@ -67,7 +67,6 @@ from . import visualization as viz
 from . import runtime
 from . import engine
 from . import subgraph
-from . import tune
 from . import attribute
 from . import name
 from .attribute import AttrScope

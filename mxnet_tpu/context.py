@@ -131,8 +131,7 @@ def _accel_devices():
 
 def on_tpu(*arrays) -> bool:
     """True when the computation runs on a TPU — THE platform probe for
-    kernel dispatch (Pallas vs composed XLA) and the autotune table's
-    interpret-record refusal.
+    kernel dispatch (Pallas vs composed XLA).
 
     An eager op runs where its operands are committed, and a TPU machine
     has a host CPU backend too: a model that has not been moved yet

@@ -2,7 +2,7 @@
 
 ``checkpoint.atomic_path`` owns the checkpoint/manifest commit
 discipline, but it lives in a module that imports ``telemetry`` — so
-telemetry exports, cost tables, bench JSON and recordio indexes could
+telemetry exports and recordio indexes could
 not reuse it without an import cycle.  This module is the stdlib-only
 bottom of that stack: the same tmp + ``os.replace`` discipline with no
 package imports at module scope, usable from anywhere.

@@ -67,35 +67,31 @@ class _FusedUpdate:
         # ``materialize_states()`` gathers them back (Trainer.save_states
         # does this), ``invalidate_sharded()`` drops the mirror after an
         # external state load.
-        self._shard_opt = bool(shard_optimizer)
-        # "auto" defers the final call to _shard_ready: measured via the
-        # prog_zero cost-table entry when one exists, else today's
-        # shard-when-possible heuristic
+        # The knob is validated eagerly; it resolves against the live
+        # process mesh in _shard_ready, by the rule DataParallelStep
+        # shares (parallel.collectives.resolve_shard_optimizer).
+        from ..parallel.collectives import (SHARD_OFF,
+                                            resolve_shard_optimizer)
+        resolve_shard_optimizer(shard_optimizer, None)
+        self._shard_opt = shard_optimizer not in SHARD_OFF
         self._shard_knob = shard_optimizer
-        self._auto_decided = False
         self._sharded = {}       # index -> flat dp-sharded state leaves
         self._shard_mesh = None
         self._shard_n = 0
         self._shard_skip_reported = False
         # Compressed gradient wire for the sharded leg (see
         # parallel/compression.py): the knob is validated eagerly, the
-        # MODE resolves once sharding engages (_shard_ready) — the dp
-        # extent and the prog_compress cost-table key need the live
-        # mesh.  Error-feedback residuals ride as one extra flat leaf
-        # at the END of each index's sharded mirror; they are
-        # mirror-only (materialize_states' zip-shortest drops them, so
-        # Trainer.save_states never sees them — a restore simply
-        # restarts error feedback from zero, which is numerics-safe).
-        from ..parallel.compression import MODES as _CMODES
-        if grad_compression in (None, False, "", 0, "0", "off"):
-            grad_compression = None
-        elif grad_compression not in _CMODES + ("auto",):
-            raise ValueError(
-                "grad_compression must be one of %s, None or 'auto', "
-                "got %r" % (_CMODES, grad_compression))
+        # MODE resolves once sharding engages (_shard_ready) — it needs
+        # the live dp extent.  Error-feedback residuals ride as one
+        # extra flat leaf at the END of each index's sharded mirror;
+        # they are mirror-only (materialize_states' zip-shortest drops
+        # them, so Trainer.save_states never sees them — a restore
+        # simply restarts error feedback from zero, which is
+        # numerics-safe).
+        from ..parallel.compression import resolve_grad_compression
+        resolve_grad_compression(grad_compression)
         self._compress_knob = grad_compression
         self._compress = ""
-        self._compress_decided = False
 
     def __getstate__(self):
         # the jitted executables are not picklable (and are cheap to
@@ -114,7 +110,6 @@ class _FusedUpdate:
         # mesh-dependent: re-resolved (and re-journaled) when sharding
         # re-engages on the unpickled trainer
         state["_compress"] = ""
-        state["_compress_decided"] = False
         return state
 
     # -- ZeRO sharded-state mirror --------------------------------------
@@ -129,40 +124,14 @@ class _FusedUpdate:
             return False
         if self._shard_mesh is not None:
             return True
+        from ..parallel import compression as _comp
+        from ..parallel.collectives import resolve_shard_optimizer
         from ..parallel.mesh import get_mesh
         import jax.sharding as jsh
         mesh = get_mesh()
-        if mesh is None or "dp" not in mesh.axis_names or \
-                mesh.shape["dp"] <= 1:
+        n = resolve_shard_optimizer(self._shard_knob, mesh)
+        if n < 2:
             return False
-        if self._shard_knob == "auto" and not self._auto_decided:
-            # decided once per trainer (first eligible step), journaled
-            # with the path taken — mirrors DataParallelStep's
-            # _auto_shard_decision
-            self._auto_decided = True
-            shard, path, src = True, "heuristic", "heuristic"
-            try:
-                pcount = sum(int(onp.prod(w.shape)) for w in weights)
-            except Exception:
-                pcount = 0
-            if pcount > 0:
-                try:
-                    from ..tune import program as _prog
-                    cfg = _prog.program_config(
-                        "prog_zero",
-                        (_prog.canon_param_count(pcount),
-                         int(mesh.shape["dp"])))
-                except Exception:
-                    cfg = None
-                if cfg is not None:
-                    shard = bool(cfg["shard"])
-                    path, src = "measured", cfg.get("source", "table")
-            telemetry.event("zero", "trainer_auto_decision", path=path,
-                            shard=bool(shard), params=int(pcount),
-                            dp=int(mesh.shape["dp"]), tuner_source=src)
-            if not shard:
-                self._shard_opt = False
-                return False
         repl = jsh.NamedSharding(mesh, jsh.PartitionSpec())
         for w in weights:
             sh = getattr(w._data, "sharding", None)
@@ -179,71 +148,16 @@ class _FusedUpdate:
             except Exception:
                 return False
         self._shard_mesh = mesh
-        self._shard_n = int(mesh.shape["dp"])
-        if not self._compress_decided:
-            self._compress_decided = True
-            self._compress = self._resolve_compress(weights)
+        self._shard_n = n
+        self._compress = _comp.resolve_grad_compression(
+            self._compress_knob, n)
+        if self._compress:
+            _comp.journal_decision(
+                self._compress_knob, self._compress, n,
+                sum(int(onp.prod(w.shape)) for w in weights),
+                str(onp.dtype(weights[0].dtype)) if weights
+                else "float32")
         return True
-
-    def _resolve_compress(self, weights):
-        """Resolve the ``grad_compression`` knob against the live dp
-        extent — mirrors ``DataParallelStep._resolve_grad_compression``
-        (same journal record, same "auto" cost-table key) but sized
-        from the trainer's weight list."""
-        knob = self._compress_knob
-        if not knob:
-            return ""
-        if self._shard_n < 2:
-            # the 1-device degenerate sharded layout has no gradient
-            # wire to narrow — quietly disable, journal why (mirrors
-            # DataParallelStep's layout disable)
-            telemetry.event(
-                "compress", "decision", mode="off", requested=str(knob),
-                path="disabled", tuner_source="layout",
-                dp=int(self._shard_n), params=0, dtype="float32",
-                wire_bytes=0, scale_bytes=0, f32_bytes=0, ratio=1.0)
-            return ""
-        try:
-            pcount = sum(int(onp.prod(w.shape)) for w in weights)
-            dtype = str(onp.dtype(weights[0].dtype)) if weights \
-                else "float32"
-        except Exception:
-            pcount, dtype = 0, "float32"
-        if knob == "auto":
-            # compression changes numerics: "auto" engages only on a
-            # MEASURED prog_compress entry (bench A/B or offline
-            # search), never by heuristic
-            mode, path, src = "", "heuristic", "heuristic"
-            if pcount > 0:
-                try:
-                    from ..tune import program as _prog
-                    cfg = _prog.program_config(
-                        "prog_compress",
-                        (_prog.canon_param_count(pcount),
-                         self._shard_n), dtype=dtype)
-                except Exception:
-                    cfg = None
-                if cfg is not None:
-                    from ..tune.program import MODE_CODES
-                    mode = MODE_CODES[int(cfg["mode"])]
-                    path, src = "measured", cfg.get("source", "table")
-        else:
-            mode, path, src = knob, "forced", "arg"
-        from ..parallel import compression as _comp
-        base = _comp.wire_bytes(pcount, None)
-        wire = _comp.wire_bytes(pcount, mode or None)
-        scale = _comp.scale_bytes(pcount, mode or None)
-        telemetry.gauge("compression.bytes_saved",
-                        max(0, base - wire - scale))
-        telemetry.gauge("compression.scale_bytes", scale)
-        telemetry.event(
-            "compress", "decision", mode=mode or "off",
-            requested=str(knob), path=path, tuner_source=src,
-            dp=int(self._shard_n), params=int(pcount), dtype=dtype,
-            wire_bytes=int(wire), scale_bytes=int(scale),
-            f32_bytes=int(base),
-            ratio=round(base / float(wire), 3) if wire else 1.0)
-        return mode
 
     def _shard_sharding(self, replicated=False):
         import jax.sharding as jsh
@@ -311,13 +225,11 @@ class _FusedUpdate:
         self._shard_mesh = None
         self._shard_n = 0
         self._shard_skip_reported = False
-        # compression re-resolves at the NEW dp extent (the "auto"
-        # cost-table key and the journaled wire arithmetic both depend
-        # on it); residuals restart from zero — numerics-safe, the
-        # error-feedback carry is a convergence refinement, not state
-        # correctness
+        # compression re-resolves at the NEW dp extent (the journaled
+        # wire arithmetic depends on it); residuals restart from zero —
+        # numerics-safe, the error-feedback carry is a convergence
+        # refinement, not state correctness
         self._compress = ""
-        self._compress_decided = False
         self._cache.clear()
 
     def __call__(self, indices, grads, weights):
@@ -504,13 +416,12 @@ class Trainer:
         after ``step()`` the old gradient buffers are consumed, so the
         caller must not read ``param.grad()`` until the next
         ``backward()`` rebinds them.
-    grad_compression : {"int8", "fp8", "auto", None}, default None —
+    grad_compression : {"int8", "fp8", None}, default None —
         narrow the ZeRO gradient wire when ``shard_optimizer`` engages
         (``parallel/compression.py``: per-chunk symmetric quantization
         with error-feedback residuals carried as an extra dp-sharded
-        mirror leaf).  ``"auto"`` consults the ``prog_compress`` cost
-        table at the engage point; without the sharded update the knob
-        is inert (there is no gradient reduce-scatter to narrow).
+        mirror leaf).  Without the sharded update the knob is inert
+        (there is no gradient reduce-scatter to narrow).
         Distinct from ``compression_params`` (the reference kvstore
         2-bit push/pull compression API).
     """

@@ -83,22 +83,6 @@ class DevicePrefetchIter(DataIter):
 
         self._base = base
         self._dtype = dtype
-        # depth=None asks the program cost table (tune.program
-        # ``prog_prefetch``, keyed on batch size) for the measured
-        # depth; a miss keeps the historical default of 2, so an
-        # untuned process is bit-identical to passing nothing
-        self.tuner_source = "explicit"
-        if depth is None:
-            depth, self.tuner_source = 2, "heuristic"
-            try:
-                from ..tune import program as _prog
-                cfg = _prog.program_config(
-                    "prog_prefetch", (self.batch_size,))
-            except Exception:
-                cfg = None
-            if cfg is not None:
-                depth = int(cfg["depth"])
-                self.tuner_source = cfg.get("source", "table")
         self._depth = max(1, int(depth))
         self._device = device or jax.devices()[0]
         self._mesh = mesh

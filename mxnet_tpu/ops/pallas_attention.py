@@ -108,10 +108,9 @@ def _bwd_block_q(block_q, block_k, Dp, itemsize):
 def _blocks_fit(block_q, block_k, Dp, itemsize):
     """THE validity predicate for an attention (block_q, block_k): the
     forward fits its budget at these blocks and the backward fits its
-    own at some q block (``_bwd_block_q`` finds it).  The heuristic, the
-    autotuner's candidate pruning and the cost-table re-validation all
-    go through here, so no plan reaches a kernel the chip's compiler
-    refuses for VMEM."""
+    own at some q block (``_bwd_block_q`` finds it).
+    ``tune_attention_blocks`` halves its blocks until this holds, so no
+    plan reaches a kernel the chip's compiler refuses for VMEM."""
     return _fwd_vmem_bytes(block_q, block_k, Dp, itemsize) <= _VMEM_CLAMP \
         and _bwd_vmem_bytes(min(block_q, _BWD_MIN_BLOCK_Q), block_k, Dp,
                             itemsize) <= _VMEM_CLAMP
@@ -172,9 +171,9 @@ def attention_dispatch(seq_q, seq_k, head_dim, dtype="bfloat16",
     """Per-shape kernel choice for the public flash-attention ops.
 
     Returns ``{"kernel": "short_seq" | "streaming" | "dense_fallback",
-    "block_q": int | None, "block_k": int | None, "tuner_source":
-    "table" | "searched" | "heuristic" | None, "layout": "bhsd" | "bshd" |
-    "bshd_pair" | None, "heads_per_block": int | None}``.  ``layout`` and
+    "block_q": int | None, "block_k": int | None, "layout": "bhsd" |
+    "bshd" | "bshd_pair" | None, "heads_per_block": int | None}``.
+    ``layout`` and
     ``heads_per_block`` say how the forward kernel addresses heads:
     ``bhsd`` a head a (B*H, T, D) row; for a (B, T, H, D) caller, which
     gives its head count as ``bshd_heads``, ``bshd`` a head a lane block
@@ -189,38 +188,22 @@ def attention_dispatch(seq_q, seq_k, head_dim, dtype="bfloat16",
     (min(Tq, Tk) < _DENSE_MIN_SEQ) go dense, Tk <= _SHORT_SEQ_MAX_TK
     single-pass, longer streams.
 
-    Blocks come from the autotuner's persistent cost table when it has
-    this (shape, dtype, chip) instance (``mxnet_tpu.tune`` — an
-    on-miss measured search needs the ``MXNET_AUTOTUNE=1`` opt-in;
-    default mode measures nothing), else from the
-    ``tune_attention_blocks`` heuristic.  Either way the chosen blocks
-    fit VMEM forward and backward — table entries are re-validated
-    against the same ``_blocks_fit`` predicate the heuristic honours.
+    Blocks come from ``tune_attention_blocks`` and nowhere else, so they
+    fit VMEM forward and backward (``_blocks_fit``).
 
     ``census=False`` is the secondary-lookup spelling (the custom-vjp
     backward re-reading the forward's decision): same answer, but no
-    counters/journal (the shape was censused at the forward trace) and
-    never an on-miss search — a quiet table lookup only."""
+    counters and no event (the shape was counted at the forward
+    trace)."""
     from .. import telemetry
-    from .. import tune as _tune
     if on_tpu is None:
         on_tpu = _context.on_tpu()
     if not on_tpu or min(seq_q, seq_k) < _DENSE_MIN_SEQ:
         if census:
             telemetry.inc("attention.kernel.dense_fallback")
         return {"kernel": "dense_fallback", "block_q": None,
-                "block_k": None, "tuner_source": None, "layout": None,
-                "heads_per_block": None}
-    cfg = _tune.table_config("attention",
-                             (int(seq_q), int(seq_k), int(head_dim)),
-                             dtype, quiet=not census)
-    if cfg is not None:
-        block_q, block_k = cfg["block_q"], cfg["block_k"]
-        source = cfg["source"]
-    else:
-        block_q, block_k = tune_attention_blocks(seq_q, seq_k, head_dim,
-                                                 dtype)
-        source = "heuristic"
+                "block_k": None, "layout": None, "heads_per_block": None}
+    block_q, block_k = tune_attention_blocks(seq_q, seq_k, head_dim, dtype)
     kernel = "short_seq" if seq_k <= block_k else "streaming"
     per_block = 0 if bshd_heads is None else _bshd_heads_per_block(
         bshd_heads, head_dim, kernel == "short_seq")
@@ -228,19 +211,17 @@ def attention_dispatch(seq_q, seq_k, head_dim, dtype="bfloat16",
     per_block = max(per_block, 1)
     # per-shape dispatch accounting: this runs at TRACE time (once per
     # compiled shape, not per step), so the journal is a census of which
-    # kernel every shape in the run got — and of where its blocks came
-    # from (tuner_source)
+    # kernel every shape in the run got
     if census:
         telemetry.inc("attention.kernel.%s" % kernel)
         telemetry.inc("attention.layout.%s" % layout)
         telemetry.event("attention_dispatch", kernel, seq_q=int(seq_q),
                         seq_k=int(seq_k), head_dim=int(head_dim),
                         dtype=str(dtype), block_q=block_q,
-                        block_k=block_k, tuner_source=source,
-                        layout=layout, heads_per_block=per_block)
+                        block_k=block_k, layout=layout,
+                        heads_per_block=per_block)
     return {"kernel": kernel, "block_q": block_q, "block_k": block_k,
-            "tuner_source": source, "layout": layout,
-            "heads_per_block": per_block}
+            "layout": layout, "heads_per_block": per_block}
 
 
 # ---------------------------------------------------------------------------
@@ -572,8 +553,6 @@ def _kv_group(q, k):
 
 def _kv_row(group):
     """Grid batch coordinate of q -> row of k/v."""
-    # graftlint: disable-next=trace-tracer-branch -- group is a Python int
-    # from the operands' static shapes
     return (lambda b: b) if group == 1 else (lambda b: b // group)
 
 
@@ -1357,8 +1336,6 @@ def pallas_flash_attention_bwd(q, k, v, out, lse, do, causal=False,
     # the q blocks of every query head of the group in turn (j // n_q is
     # the head within the group, j % n_q its q block), so the group's sum
     # is the kernel's own accumulation
-    # graftlint: disable-next=trace-tracer-branch -- group is a Python int
-    # from the operands' static shapes
     if group == 1:
         q_row, q_blk = (lambda b, ki, j: b), (lambda j: j)
         kv_extra, kv_especs = extra_for(lambda i, j: i, lambda i, j: j)
@@ -1678,13 +1655,9 @@ def _flash_fwd(q, k, v, causal, scale, kv_lens, q_segments, kv_segments):
 def _flash_bwd(causal, scale, res, g):
     q, k, v, out, lse, kv_lens, q_segments, kv_segments = res
     if lse is not None:
-        # re-consult the dispatcher (trace-time, deterministic: the
-        # cost-table lookup that served the forward serves the same
-        # blocks here) so tuned configs reach the backward kernels too —
-        # custom_vjp residuals cannot carry static ints, and the A/B
-        # acceptance leg times tuned fwd+bwd together.  census=False:
-        # the shape was counted at the forward trace; this is a quiet
-        # lookup (no double census, never a second search)
+        # re-consult the dispatcher (trace-time, deterministic) for the
+        # forward's blocks: custom_vjp residuals cannot carry static
+        # ints.  census=False: the shape was counted at the forward trace
         plan = attention_dispatch(q.shape[2], k.shape[2], q.shape[3],
                                   q.dtype, census=False)
         dq, dk, dv = per_batch_shard(
@@ -1754,8 +1727,8 @@ def _flash_bshd_fwd(q, k, v, causal, scale, kv_lens):
 def _flash_bshd_bwd(causal, scale, res, g):
     q, k, v, out, lse, kv_lens = res
     if lse is not None:
-        # same tuned-block threading as _flash_bwd (BSHD layout: T is
-        # axis 1, D axis 3); census=False — quiet secondary lookup
+        # the forward's blocks, as in _flash_bwd (BSHD layout: T is axis
+        # 1, D axis 3)
         plan = attention_dispatch(q.shape[1], k.shape[1], q.shape[3],
                                   q.dtype, census=False)
         dq, dk, dv = per_batch_shard(

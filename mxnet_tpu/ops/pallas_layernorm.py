@@ -1,6 +1,6 @@
 """Fused LayerNorm Pallas kernels for TPU (forward AND backward).
 
-Profiling the BERT-base train step (tools/profile_probe.py) showed the
+A profile of the BERT-base train step on an earlier machine showed the
 XLA-composed LayerNorm chains at ~38% of device time — each of the 25 LN
 sites expands into separate convert/subtract/reduce fusions that re-read
 the (B, S, C) activation several times in fp32.  The fused kernels make
@@ -175,32 +175,12 @@ def pallas_layer_norm_bwd(x2d, gamma, mu, rstd, ct2d,
 _VMEM_BUDGET = 6 * 1024 * 1024
 
 
-def _pick_block_rows_heuristic(C):
+def _pick_block_rows(C):
     """Largest multiple-of-8 row block whose bwd working set fits the
-    VMEM budget; None when even 8 rows do not fit (fall back to XLA).
-    Pure — the autotuner's search anchors on this and its candidates
-    are pruned by the same budget."""
+    VMEM budget; None when even 8 rows do not fit (fall back to XLA)."""
     rows = _VMEM_BUDGET // (3 * 4 * C)
     rows = min(_BLOCK_ROWS, (rows // 8) * 8)
     return rows if rows >= 8 else None
-
-
-def _pick_block_rows(C, rows, quiet=False):
-    """Row block for an instance: the autotuner's cost table when it has
-    this (rows, C) shape (validated against the same VMEM budget), else
-    the heuristic.  ``rows`` is required — it is half the table key; a
-    defaulted placeholder would silently look up a shape no tuning run
-    ever records.  ``quiet``: the forward censuses the decision once,
-    the backward re-reads it quietly.  With no table and no
-    ``MXNET_AUTOTUNE`` opt-in this is exactly
-    ``_pick_block_rows_heuristic`` (bit-identical default,
-    regression-tested)."""
-    from .. import tune as _tune
-    tuned = _tune.table_blocks("layernorm", (int(rows), int(C)),
-                               "float32", quiet=quiet)
-    if tuned is not None:
-        return tuned
-    return _pick_block_rows_heuristic(C)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
@@ -229,7 +209,7 @@ def _jnp_ln(data, gamma, beta, eps):
 
 def _fln_fwd(data, gamma, beta, eps):
     C = data.shape[-1]
-    block = _pick_block_rows(C, rows=data.size // C)
+    block = _pick_block_rows(C)
     if not _context.on_tpu(data) or block is None:
         out = _jnp_ln(data, gamma, beta, eps)
         return out, (data, gamma, beta, None, None)
@@ -250,7 +230,7 @@ def _fln_bwd(eps, res, ct):
         _, vjp = jax.vjp(lambda d, g, b: _jnp_ln(d, g, b, eps),
                          data, gamma, beta)
         return vjp(ct)
-    block = _pick_block_rows(C, rows=data.size // C, quiet=True)
+    block = _pick_block_rows(C)
     # dgamma/dbeta are partial sums per shard
     dx2, dg, db = per_batch_shard(
         lambda x, g, mu, rs, ct: pallas_layer_norm_bwd(

@@ -53,8 +53,8 @@ crash window):
 
 * ``artifact_write_crash``   — ``fsutil.atomic_write_path`` raises
   between the tmp write and the commit: the generic-artifact twin of
-  ``checkpoint_write_crash`` for telemetry exports, cost tables, bench
-  JSON and recordio indexes.
+  ``checkpoint_write_crash`` for telemetry exports and recordio
+  indexes.
 
 ``MODES`` below is the machine-readable registry of all of the above —
 ``tools.lint.chaos_coverage`` parses it (as a literal, without

@@ -21,7 +21,7 @@ from jax import lax
 __all__ = ["psum", "pmean", "all_gather", "reduce_scatter", "ppermute",
            "allreduce", "flatten_pad", "unflatten", "padded_size",
            "reduce_scatter_padded", "all_gather_unpad",
-           "zero_sharded_update"]
+           "zero_sharded_update", "resolve_shard_optimizer"]
 
 
 def _is_traced(x) -> bool:
@@ -108,6 +108,28 @@ def unflatten(flat, shape):
     for d in shape:
         n *= int(d)
     return val[:n].reshape(shape)
+
+
+SHARD_OFF = (False, None, 0, "0", "off")
+SHARD_FORCED = (True, 1, "1", "on")
+
+
+def resolve_shard_optimizer(knob, mesh):
+    """The ``dp`` extent a ``shard_optimizer`` knob shards the optimizer
+    state over on ``mesh``; 0 keeps the replicated update.  The one rule
+    for ``DataParallelStep`` and ``Trainer``: ``"auto"`` shards where
+    the mesh has a ``dp`` axis larger than 1; ``True`` takes any ``dp``
+    axis (size 1 is a no-op layout, handy on one device); without a
+    ``dp`` axis there is nothing to shard over."""
+    if knob in SHARD_OFF:
+        return 0
+    if knob != "auto" and knob not in SHARD_FORCED:
+        raise ValueError("shard_optimizer must be True/False/'auto', "
+                         "got %r" % (knob,))
+    if mesh is None or "dp" not in mesh.axis_names:
+        return 0
+    n = int(mesh.shape["dp"])
+    return 0 if knob == "auto" and n <= 1 else n
 
 
 def reduce_scatter_padded(x, axis_name: str = "dp", axis_size: int = None,

@@ -39,11 +39,11 @@ import jax.numpy as jnp
 __all__ = ["MODES", "CHUNK", "INT8_MAX", "FP8_MAX", "fp8_wire_dtype",
            "num_chunks", "quantize_chunked", "dequantize_chunked",
            "compress_decompose", "wire_bytes", "scale_bytes",
-           "wire_ratio", "quantize_2bit", "dequantize_2bit",
-           "pack_2bit", "unpack_2bit"]
+           "wire_ratio", "resolve_grad_compression", "journal_decision",
+           "quantize_2bit", "dequantize_2bit", "pack_2bit", "unpack_2bit"]
 
 # the compressed wire modes DataParallelStep/Trainer accept (besides
-# None/"off" and "auto")
+# None/"off")
 MODES = ("int8", "fp8")
 
 CHUNK = 256          # elements per max-abs scale chunk
@@ -156,6 +156,40 @@ def scale_bytes(n, mode=None):
 def wire_ratio(n, mode):
     """f32 payload bytes / compressed payload bytes (4.0 for int8/fp8)."""
     return wire_bytes(n, None) / float(wire_bytes(n, mode))
+
+
+def resolve_grad_compression(knob, shard_n=0):
+    """The wire mode — "" (uncompressed) or a ``MODES`` member — a
+    ``grad_compression`` knob gives an update sharded ``shard_n`` ways.
+    The one rule for ``DataParallelStep`` and ``Trainer``.  Compression
+    IS the narrow ZeRO wire: with the sharded update off or over one
+    replica there is no gradient reduce-scatter to narrow, and a valid
+    mode quietly disables."""
+    if knob in (None, False, "", 0, "0", "off"):
+        return ""
+    if knob not in MODES:
+        raise ValueError("grad_compression must be one of %s or None, "
+                         "got %r" % (MODES, knob))
+    return knob if shard_n >= 2 else ""
+
+
+def journal_decision(requested, mode, shard_n, params, dtype):
+    """One ``compress/decision`` journal record + the byte gauges for a
+    knob that asked for a mode: what the wire will carry per step vs
+    the f32 baseline for ``params`` gradient elements (schedule
+    arithmetic, the same discipline as reduce_scatter_bytes)."""
+    from .. import telemetry
+    base = wire_bytes(params, None)
+    wire = wire_bytes(params, mode or None)
+    scale = scale_bytes(params, mode or None)
+    telemetry.gauge("compression.bytes_saved", max(0, base - wire - scale))
+    telemetry.gauge("compression.scale_bytes", scale)
+    telemetry.event(
+        "compress", "decision", mode=mode or "off",
+        requested=str(requested), path="forced" if mode else "disabled",
+        dp=int(shard_n), params=int(params), dtype=dtype,
+        wire_bytes=int(wire), scale_bytes=int(scale), f32_bytes=int(base),
+        ratio=round(base / float(wire), 3) if wire else 1.0)
 
 
 # ---------------------------------------------------------------------------

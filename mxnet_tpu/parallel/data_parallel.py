@@ -118,16 +118,14 @@ class DataParallelStep:
     grads, update the local 1/N shard, all-gather params — cutting
     per-chip optimizer-state memory ~N-fold.  See docs/PERF.md.
 
-    ``grad_compression="int8"|"fp8"|None|"auto"`` narrows the sharded
+    ``grad_compression="int8"|"fp8"|None`` narrows the sharded
     path's gradient wire (parallel/compression.py): the flat padded
     gradient is chunk-quantized to a 1-byte payload before the
     reduce-scatter and dequantized-with-error-feedback on the local
     shard — the residual rides as an extra dp-sharded state leaf, so
     it re-shards and checkpoints with the rest of the ZeRO state.
-    ``"auto"`` consults the ``prog_compress`` cost-table family
-    (lookup only); with no measured entry the heuristic keeps the
-    wire uncompressed.  Requires the sharded update — on a 1-device
-    or unsharded layout compression quietly disables.
+    Requires the sharded update — on a 1-device or unsharded layout
+    compression quietly disables.
     """
 
     def __init__(self, net, loss_fn, optimizer, mesh=None, donate=True,
@@ -149,8 +147,15 @@ class DataParallelStep:
         # backprop.  ``False`` (default) keeps today's replicated path
         # bit-identical; ``"auto"`` turns it on when the mesh has a dp
         # axis of size > 1; ``True`` forces it (size-1 dp degenerates to
-        # a no-op layout, handy for CPU tests).
-        self._shard_n = self._resolve_shard_optimizer(shard_optimizer)
+        # a no-op layout, handy for CPU tests).  The raw knobs are kept
+        # for elastic re-formation: reshard() re-resolves both against
+        # the NEW mesh's dp extent.
+        self._shard_knob = shard_optimizer
+        # compressed gradient wire (parallel/compression.py): "" (off)
+        # or a compression.MODES member.  Only meaningful on the sharded
+        # update.
+        self._compress_knob = grad_compression
+        self._resolve_knobs()
         # donate_batch additionally donates the data/label buffers: the
         # step is their last reader (a fresh batch arrives every call),
         # so XLA reuses their HBM pages for step outputs instead of
@@ -180,16 +185,6 @@ class DataParallelStep:
         # half weight, the update applies to the master in fp32, and
         # the half weight is re-quantized from it each step — small
         # updates accumulate instead of rounding away.
-        # the raw knob is kept for elastic re-formation: reshard() must
-        # re-resolve "auto" against the NEW mesh's dp extent
-        self._shard_knob = shard_optimizer
-        # compressed gradient wire (parallel/compression.py): resolved
-        # to "" (off) or a compression.MODES member; "auto" is a
-        # prog_compress cost-table lookup keyed (params, dp, dtype).
-        # Only meaningful on the sharded update — the knob re-resolves
-        # on reshard() together with shard_optimizer.
-        self._compress_knob = grad_compression
-        self._compress = self._resolve_grad_compression(grad_compression)
         # chaos: device-resident grad_compress_corrupt operands (1.0 =
         # clean, inf = garbled chunk-0 scale), lazily built per process
         self._corrupt_ok_dev = None
@@ -259,65 +254,31 @@ class DataParallelStep:
     # ------------------------------------------------------------------
     # ZeRO-style sharded weight update (arxiv 2004.13336)
     # ------------------------------------------------------------------
-    def _resolve_shard_optimizer(self, knob):
-        """Resolve the ``shard_optimizer`` knob to the dp-axis size the
-        state is sharded over (0 = replicated path, untouched)."""
-        if knob in (False, None, 0, "0", "off"):
-            return 0
-        if knob not in (True, 1, "1", "on", "auto"):
-            raise ValueError("shard_optimizer must be True/False/'auto', "
-                             "got %r" % (knob,))
-        mesh = self._mesh
-        if mesh is None or "dp" not in mesh.axis_names:
-            if knob == "auto":
-                return 0
+    def _resolve_knobs(self):
+        """``_shard_n`` (the dp extent the state is sharded over, 0 =
+        replicated path, untouched) and ``_compress`` ("" or a wire
+        mode) from the two knobs and the mesh, by the rules ``Trainer``
+        shares (``collectives.resolve_shard_optimizer``,
+        ``compression.resolve_grad_compression``); a knob that asks for
+        a wire mode journals one ``compress/decision`` event."""
+        from . import compression as _comp
+        from .collectives import SHARD_FORCED, resolve_shard_optimizer
+        self._shard_n = resolve_shard_optimizer(self._shard_knob,
+                                                self._mesh)
+        if not self._shard_n and self._shard_knob in SHARD_FORCED:
             import warnings
             warnings.warn("shard_optimizer=True needs a mesh with a 'dp' "
                           "axis; falling back to the replicated update")
-            return 0
-        n = mesh.shape["dp"]
-        if knob == "auto":
-            if n <= 1:
-                return 0     # nothing to shard over; keep the proven path
-            return int(n) if self._auto_shard_decision(int(n)) else 0
-        return int(n)
-
-    def _auto_shard_decision(self, n):
-        """``"auto"`` with a dp>1 mesh: MEASURED when the program cost
-        table holds a ``prog_zero`` entry for this (canonical param
-        count, dp extent) — the offline ``python -m mxnet_tpu.tune
-        --program`` search or a bench writes one — else today's
-        heuristic (shard).  Which path decided, and what it decided, is
-        journaled as a ``zero``/``auto_decision`` event so the census
-        can tell a measured schedule from a guessed one."""
-        from .. import telemetry
-        shard, path, src = True, "heuristic", "heuristic"
-        pcount = 0
-        try:
-            pcount = sum(
-                int(onp.prod(p._data.shape))
-                for _, p in self._net.collect_params().items()
-                if p._data is not None and p.grad_req != "null")
-        except Exception:
-            pcount = 0
-        if pcount > 0:
-            try:
-                from ..tune import program as _prog
-                cfg = _prog.program_config(
-                    "prog_zero", (_prog.canon_param_count(pcount), n))
-            except Exception:
-                cfg = None
-            if cfg is not None:
-                shard = bool(cfg["shard"])
-                path, src = "measured", cfg.get("source", "table")
-        telemetry.event("zero", "auto_decision", path=path,
-                        shard=bool(shard), params=int(pcount), dp=int(n),
-                        tuner_source=src)
-        return shard
+        self._compress = _comp.resolve_grad_compression(
+            self._compress_knob, self._shard_n)
+        if self._compress_knob in _comp.MODES:
+            _comp.journal_decision(self._compress_knob, self._compress,
+                                   self._shard_n,
+                                   *self._trainable_param_stats())
 
     def _trainable_param_stats(self):
         """(param count, dominant dtype string) of the trainable set —
-        the workload key the compression decision is made on."""
+        what the ``compress/decision`` record sizes the wire by."""
         pcount, dtype = 0, "float32"
         try:
             for _, p in sorted(self._net.collect_params().items()):
@@ -329,75 +290,6 @@ class DataParallelStep:
         except Exception:
             pcount = 0
         return pcount, dtype
-
-    def _resolve_grad_compression(self, knob):
-        """Resolve the ``grad_compression`` knob to "" (uncompressed)
-        or a wire mode; every resolution journals one
-        ``compress/decision`` event (the census's per-decision record:
-        mode, ratio, which path decided)."""
-        from .compression import MODES
-        if knob in (None, False, "", 0, "0", "off"):
-            return ""
-        if knob not in MODES + ("auto",):
-            raise ValueError(
-                "grad_compression must be one of %s, None or 'auto', "
-                "got %r" % (MODES, knob))
-        if self._shard_n < 2:
-            # compression IS the narrow ZeRO wire: with the sharded
-            # update off (no dp axis, shard_optimizer off) or the
-            # 1-device degenerate (no wire at all) there is no gradient
-            # reduce-scatter to narrow — quietly disable, journal why
-            self._journal_compress_decision("", knob, "disabled",
-                                            "layout")
-            return ""
-        if knob == "auto":
-            mode, path, src = self._auto_compress_decision(self._shard_n)
-        else:
-            mode, path, src = knob, "forced", "arg"
-        self._journal_compress_decision(mode, knob, path, src)
-        return mode
-
-    def _auto_compress_decision(self, n):
-        """``"auto"``: MEASURED when the cost table holds a
-        ``prog_compress`` entry for this (canonical param count, dp
-        extent, dtype) — compression changes numerics, so the
-        heuristic default is OFF until a measured entry (the bench's
-        A/B or the offline search) says the wire win is real."""
-        pcount, dtype = self._trainable_param_stats()
-        mode, path, src = "", "heuristic", "heuristic"
-        if pcount > 0:
-            try:
-                from ..tune import program as _prog
-                cfg = _prog.program_config(
-                    "prog_compress",
-                    (_prog.canon_param_count(pcount), n), dtype=dtype)
-            except Exception:
-                cfg = None
-            if cfg is not None:
-                from ..tune.program import MODE_CODES
-                mode = MODE_CODES[int(cfg["mode"])]
-                path, src = "measured", cfg.get("source", "table")
-        return mode, path, src
-
-    def _journal_compress_decision(self, mode, requested, path, src):
-        """One ``compress/decision`` journal record + the byte gauges:
-        what the wire will carry per step vs the f32 baseline (schedule
-        arithmetic, the same discipline as reduce_scatter_bytes)."""
-        from . import compression as _comp
-        pcount, dtype = self._trainable_param_stats()
-        base = _comp.wire_bytes(pcount, None)
-        wire = _comp.wire_bytes(pcount, mode or None)
-        scale = _comp.scale_bytes(pcount, mode or None)
-        telemetry.gauge("compression.bytes_saved",
-                        max(0, base - wire - scale))
-        telemetry.gauge("compression.scale_bytes", scale)
-        telemetry.event(
-            "compress", "decision", mode=mode or "off",
-            requested=str(requested), path=path, tuner_source=src,
-            dp=int(self._shard_n or 0), params=int(pcount), dtype=dtype,
-            wire_bytes=int(wire), scale_bytes=int(scale),
-            f32_bytes=int(base),
-            ratio=round(base / float(wire), 3) if wire else 1.0)
 
     def _shard_sharding(self, replicated=False):
         import jax.sharding as jsh
@@ -505,7 +397,7 @@ class DataParallelStep:
         """Static per-chip HBM estimate of this step's resident leaves
         (params, optimizer state, batch), computed from shapes/dtypes
         and the per-slot layout flags via ``tools.lint.hbm`` — the SAME
-        arithmetic graftlint and the autotuner use, independently of
+        arithmetic graftlint uses, independently of
         what the runtime allocated (cross-checked against the
         ``optimizer_state_bytes_per_chip`` gauges in
         ``tests/test_hbm_estimator.py``).
@@ -641,11 +533,10 @@ class DataParallelStep:
         naturals = [self._materialize_slot(slot)
                     for slot in range(len(self._opt_states))]
         self._mesh = mesh
-        self._shard_n = self._resolve_shard_optimizer(self._shard_knob)
-        # the compression knob re-resolves against the NEW layout ("auto"
-        # may flip with the dp extent; losing the sharded update disables
-        # the wire) — _place_slot reconciles residual leaves either way
-        self._compress = self._resolve_grad_compression(self._compress_knob)
+        # both knobs re-resolve against the NEW layout ("auto" follows
+        # the dp extent; losing the sharded update disables the wire) —
+        # _place_slot reconciles residual leaves either way
+        self._resolve_knobs()
         moved = 0
         repl = self._shard_sharding(replicated=True) \
             if mesh is not None else None

@@ -19,7 +19,7 @@ discipline:
   Every compile reports to the telemetry recompile detector under a
   per-bucket key (``serve.<name>.b<N>``), so a steady-state recompile
   is *observable* — ``telemetry.compile_deltas`` over a post-start
-  snapshot is the hard gate ``bench.py serving_latency`` enforces.
+  snapshot is the hard gate (``steady_state_recompiles()``).
   A compiled executable REFUSES a wrong shape (raises, never retraces),
   so the zero-recompile property cannot silently erode.
 
@@ -32,6 +32,7 @@ processes.
 """
 from __future__ import annotations
 
+import os
 import time
 from typing import Optional, Sequence
 
@@ -41,7 +42,7 @@ from .. import telemetry
 from ..base import MXNetError
 
 __all__ = ["pick_bucket", "plan_buckets", "pad_batch", "AotModel",
-           "default_bucket_menu"]
+           "default_bucket_menu", "validate_menu"]
 
 # per-process de-dup of model display names: two AotModel instances
 # sharing a name would share recompile-detector keys, so the second
@@ -57,32 +58,50 @@ def _unique_name(name):
 
 def default_bucket_menu(max_batch: int = 8, feature_shape=(),
                         dtype="float32", budget=None):
-    """``(menu, tuner_source)`` for a served max batch of ``max_batch``:
-    the measured ``prog_buckets`` schedule when the program cost table
-    holds one (``python -m mxnet_tpu.tune --program`` writes it), else
-    the geometric heuristic (powers of two up to ``max_batch`` — the
-    historical ``(1, 2, 4, 8)`` default, so an untuned process serves
-    the same menu it always did).  Either way the menu is pre-validated
-    against the static HBM estimator (``tune.program.validate_menu``
-    over ``tools.lint.hbm`` arithmetic) BEFORE any executable is
+    """The bucket menu for a served max batch of ``max_batch``: the top
+    four powers of two up to ``max_batch`` rounded up to one (8 ->
+    ``[1, 2, 4, 8]``, 32 -> ``[4, 8, 16, 32]``), checked against the
+    serving HBM budget (:func:`validate_menu`) BEFORE any executable is
     compiled — an over-budget menu sheds its largest buckets here, not
     at compile time."""
-    from ..tune import program as _prog
-
     mb = 1 << max(0, (int(max_batch) - 1).bit_length())
-    heur = _prog.menu_from_config(
-        _prog.heuristic_config("prog_buckets", (mb,)))
-    source = "heuristic"
+    menu = [mb >> i for i in range(min(4, mb.bit_length()))]
+    return validate_menu(menu, feature_shape, dtype, budget=budget)
+
+
+def validate_menu(menu: Sequence[int], feature_shape: Sequence[int],
+                  dtype="float32", budget: Optional[int] = None) -> list:
+    """Drop menu buckets whose padded batch I/O cannot fit the serving
+    HBM budget, using the static estimator's arithmetic
+    (``tools.lint.hbm.dtype_itemsize``): each bucket's executable
+    holds its input and output batch resident, and every bucket's
+    buffers coexist at startup (compile_all touches them all).  Budget:
+    ``MXNET_SERVE_HBM_BUDGET`` bytes, default 2 GiB — deliberately a
+    fraction of a chip, since the model's own weights are not ours to
+    spend.  Largest buckets are dropped first; the menu never empties
+    below its smallest bucket."""
     try:
-        cfg = _prog.program_config("prog_buckets", (mb,))
+        from tools.lint.hbm import dtype_itemsize
+        item = dtype_itemsize(dtype)
     except Exception:
-        cfg = None
-    menu = heur
-    if cfg is not None:
-        menu = _prog.menu_from_config(cfg)
-        source = cfg.get("source", "table")
-    menu = _prog.validate_menu(menu, feature_shape, dtype, budget=budget)
-    return (menu or heur[:1]), source
+        item = onp.dtype(dtype).itemsize
+    if budget is None:
+        try:
+            budget = int(os.environ.get("MXNET_SERVE_HBM_BUDGET",
+                                        2 * 1024 ** 3))
+        except ValueError:
+            budget = 2 * 1024 ** 3
+    feat = 1
+    for d in feature_shape:
+        feat *= int(d)
+    menu = sorted(set(int(b) for b in menu if int(b) >= 1))
+
+    def total(m):
+        return sum(2 * b * feat * item for b in m)   # in + out per bucket
+
+    while len(menu) > 1 and total(menu) > budget:
+        menu.pop()          # largest first
+    return menu
 
 
 def pick_bucket(n: int, buckets: Sequence[int],

@@ -221,19 +221,15 @@ class InferenceServer:
                 model, feature_shape=feature_shape, dtype=dtype,
                 name=name)
         self.name = self._model.name
-        self.bucket_source = "explicit"
         if self._cfg.buckets == "auto":
-            # measured menu when the program cost table has one, the
-            # historical geometric default otherwise — HBM-validated
-            # either way (buckets.default_bucket_menu)
+            # the default menu, checked against the serving HBM budget
+            # (buckets.default_bucket_menu)
             from .buckets import default_bucket_menu
-            menu, self.bucket_source = default_bucket_menu(
+            self._cfg.buckets = tuple(default_bucket_menu(
                 feature_shape=self._model.feature_shape,
-                dtype=self._model.dtype)
-            self._cfg.buckets = tuple(menu)
+                dtype=self._model.dtype))
             telemetry.event("serve", "bucket_menu", model=self.name,
-                            buckets=list(self._cfg.buckets),
-                            tuner_source=self.bucket_source)
+                            buckets=list(self._cfg.buckets))
         self._lock = threading.Lock()
         self._q = queue.Queue(maxsize=self._cfg.max_queue)
         self._dq = queue.Queue(maxsize=2)

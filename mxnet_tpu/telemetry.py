@@ -11,7 +11,7 @@ Everything here is host-side and cheap (a ``perf_counter`` pair and a
 few dict writes per record, no device sync, no allocation on the hot
 path beyond one small dict), so it stays ON in production runs; the
 ``MXNET_TELEMETRY=0`` env kills it to a near-no-op for A/B overhead
-measurement (``bench.py telemetry_overhead`` gates the delta at 2%).
+measurement.
 
 Primitives
 ----------
@@ -44,13 +44,12 @@ Primitives
   (``python -m mxnet_tpu.telemetry_collect``).
 * ``hist_observe()`` / ``Histogram`` — online log-bucketed histograms:
   fixed memory forever, mergeable across processes, honest p50/p99
-  without raw sample lists (``bench.py serving_latency`` reads these).
+  without raw sample lists.
 
 Exporters
 ---------
 * ``snapshot()`` — in-process dict (counters, gauges, span aggregates,
-  compile counts, recent events); ``bench.py`` embeds it in BENCH
-  artifacts.
+  compile counts, recent events).
 * the profiler's own trace — scoped spans are written into whatever
   ``jax.profiler`` capture is running (see ``span``); there is no second
   timeline file.
@@ -764,8 +763,8 @@ def compile_deltas(baseline):
     """``{fn: extra compiles}`` for every function whose compile count
     grew past a ``compile_counts()`` snapshot — the steady-state
     zero-recompile gate's measurement (``serve.InferenceServer``
-    snapshots at start; ``bench.py serving_latency`` HARD-fails when
-    any ``serve.*`` entry appears here during the load phase)."""
+    snapshots at start; ``steady_state_recompiles()`` reads any
+    ``serve.*`` entry that appears here during the load phase)."""
     cur = compile_counts()
     return {k: v - baseline.get(k, 0) for k, v in cur.items()
             if v > baseline.get(k, 0)}

@@ -14,16 +14,6 @@ _flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in _flags:
     os.environ["XLA_FLAGS"] = (_flags + " --xla_force_host_platform_device_count=8").strip()
 
-import tempfile
-
-# isolate the autotune cost table: a developer-baked
-# <repo>/.autotune/cost_table.jsonl (gitignored, persists locally) must
-# not leak tuned configs into dispatch assertions — the suite reads an
-# empty per-session table unless a test repoints it itself
-os.environ["MXNET_AUTOTUNE_TABLE"] = os.path.join(
-    tempfile.mkdtemp(prefix="mxtpu_test_autotune_"), "cost_table.jsonl")
-os.environ.pop("MXNET_AUTOTUNE", None)
-
 # reuse compiled executables across test runs (compiles dominate the
 # suite's wall time; the cache is keyed by HLO so it is semantics-safe)
 from mxnet_tpu.engine import enable_compilation_cache  # noqa: E402

@@ -154,21 +154,6 @@ def test_save_optimizer_states_atomic(tmp_path):
     assert _no_tmp_litter(str(tmp_path)) == []
 
 
-def test_cost_table_write_rides_artifact_crash_window(tmp_path):
-    from mxnet_tpu.tune.cost_table import CostTable
-    path = str(tmp_path / "cost_table.jsonl")
-    t = CostTable(path)
-    t.record("layernorm", (64, 8), "float32", {"block_rows": 8},
-             best_ms=1.0, platform="cpu-test")
-    committed = open(path).read()
-    chaos.install("artifact_write_crash", times=1)
-    with pytest.raises(chaos.ChaosError):
-        t.record("layernorm", (128, 8), "float32", {"block_rows": 16},
-                 best_ms=2.0, platform="cpu-test")
-    assert open(path).read() == committed
-    assert _no_tmp_litter(str(tmp_path)) == []
-
-
 def test_legacy_save_atomic_under_crash(tmp_path):
     import numpy as onp
     from mxnet_tpu.ndarray import legacy_io

@@ -4,8 +4,7 @@ The dispatcher (``attention_dispatch``) picks short_seq / streaming /
 dense_fallback per shape; the short-seq kernel is the single-pass
 forward (no online-softmax streaming state) plus the no-scratch
 single-block dqkv backward.  Numerics run in interpret mode on CPU —
-the same kernels compile on a real TPU (bench.py attention records the
-dispatch choice and gates flash_speedup >= 1.0 at S=512 on-chip).
+the same kernels compile for a described TPU in ``test_chip_compile.py``.
 """
 import numpy as onp
 import jax
@@ -46,35 +45,59 @@ def test_dispatch_dense_fallback_off_tpu():
     assert P.attention_dispatch(512, 512, 64)["kernel"] == "dense_fallback"
 
 
-def test_dispatch_table_on_tpu():
-    d = lambda s: P.attention_dispatch(s, s, 64, "bfloat16", on_tpu=True)
-    assert d(64)["kernel"] == "dense_fallback"      # tiny: dense wins
-    p512 = d(512)
-    assert p512["kernel"] == "short_seq"            # the BERT config shape
-    assert p512["block_k"] == 512                   # whole K axis, one block
-    assert d(384)["kernel"] == "short_seq"
-    assert d(4096)["kernel"] == "streaming"
+def _plan(kernel, block_q=None, block_k=None, layout=None, heads=None):
+    return {"kernel": kernel, "block_q": block_q, "block_k": block_k,
+            "layout": layout, "heads_per_block": heads}
 
 
-def test_dispatch_short_seq_blocks_cover_whole_k_axis():
-    for s in (128, 256, 384, 512, 1000):
-        plan = P.attention_dispatch(s, s, 64, "bfloat16", on_tpu=True)
-        if plan["kernel"] == "short_seq":
-            assert plan["block_k"] >= s
+# (S, D, heads of a (B, T, H, D) caller or None) -> the whole plan, bf16
+# on the chip: lengths either side of every threshold, then the cells'
+# shapes (BERT 512 x 64 x 12 heads, ZAYA1 8192 x 128) and the shapes the
+# (B, T, H, D) layout hands back to the (B, H, T, D) kernels
+_PLANS = [
+    ((64, 64, None), _plan("dense_fallback")),       # tiny: dense wins
+    ((256, 64, None), _plan("short_seq", 256, 256, "bhsd", 1)),
+    ((384, 64, None), _plan("short_seq", 384, 384, "bhsd", 1)),
+    ((512, 64, None), _plan("short_seq", 512, 512, "bhsd", 1)),
+    ((1000, 64, None), _plan("short_seq", 512, 1024, "bhsd", 1)),
+    ((4096, 64, None), _plan("streaming", 512, 2048, "bhsd", 1)),
+    ((512, 64, 12), _plan("short_seq", 512, 512, "bshd_pair", 2)),
+    ((8192, 128, None), _plan("streaming", 512, 2048, "bhsd", 1)),
+    ((512, 128, 8), _plan("short_seq", 512, 512, "bshd", 1)),
+    ((512, 64, 11), _plan("short_seq", 512, 512, "bhsd", 1)),   # odd heads
+    ((512, 32, 12), _plan("short_seq", 512, 512, "bhsd", 1)),   # D=32
+]
 
 
-def test_dispatch_never_exceeds_vmem_clamp():
-    """No dispatched kernel's padded blocks may exceed the VMEM clamp."""
-    for s in (128, 384, 512, 1024, 2048, 4096, 8192):
-        for d in (32, 64, 128, 256):
-            for dt in ("float32", "bfloat16"):
-                plan = P.attention_dispatch(s, s, d, dt, on_tpu=True)
-                if plan["kernel"] == "dense_fallback":
-                    continue
-                Dp = d + (-d) % 64
-                used = P._fwd_vmem_bytes(plan["block_q"], plan["block_k"],
-                                         Dp, jnp.dtype(dt).itemsize)
-                assert used <= P._VMEM_CLAMP, (s, d, dt, plan, used)
+@pytest.mark.parametrize("shape,want", _PLANS,
+                         ids=["S%d-D%d-H%s" % s for s, _ in _PLANS])
+def test_dispatch_table_on_tpu(shape, want):
+    s, d, heads = shape
+    assert P.attention_dispatch(s, s, d, "bfloat16", on_tpu=True,
+                                census=False, bshd_heads=heads) == want
+
+
+@pytest.mark.parametrize("dt", ["float32", "bfloat16"])
+@pytest.mark.parametrize("d", [32, 64, 128, 256])
+@pytest.mark.parametrize("s", [128, 384, 512, 1024, 2048, 4096, 8192])
+def test_dispatch_never_exceeds_vmem_clamp(s, d, dt):
+    """``tune_attention_blocks`` is the only source of blocks: every
+    plan's forward working set, and its backward's at the q block
+    ``_bwd_block_q`` gives it, honour the VMEM clamp, and the
+    single-pass kernel holds the whole K axis in its one block."""
+    plan = P.attention_dispatch(s, s, d, dt, on_tpu=True, census=False)
+    assert plan["kernel"] in ("short_seq", "streaming")
+    bq, bk = plan["block_q"], plan["block_k"]
+    assert (bq, bk) == P.tune_attention_blocks(s, s, d, dt)
+    Dp = d + (-d) % 64
+    itemsize = jnp.dtype(dt).itemsize
+    assert P._blocks_fit(bq, bk, Dp, itemsize), plan
+    assert P._fwd_vmem_bytes(bq, bk, Dp, itemsize) <= P._VMEM_CLAMP, plan
+    bwd_q = P._bwd_block_q(bq, bk, Dp, itemsize)
+    assert P._bwd_vmem_bytes(bwd_q, bk, Dp, itemsize) <= P._VMEM_CLAMP, \
+        (plan, bwd_q)
+    if plan["kernel"] == "short_seq":
+        assert bk >= s
 
 
 # --- short-seq kernel numerics --------------------------------------------

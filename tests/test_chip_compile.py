@@ -154,7 +154,7 @@ def _layernorm_programs(one_chip):
     x = jax.ShapeDtypeStruct((N, C), jnp.bfloat16, sharding=one_chip)
     g = jax.ShapeDtypeStruct((C,), jnp.bfloat16, sharding=one_chip)
     stat = jax.ShapeDtypeStruct((N, 1), jnp.float32, sharding=one_chip)
-    block = LN._pick_block_rows(C, rows=N, quiet=True)
+    block = LN._pick_block_rows(C)
     return [(lambda x, g, b: LN.pallas_layer_norm_fwd(
                 x, g, b, 1e-5, block_rows=block), (x, g, g)),
             (lambda x, g, mu, rs, ct: LN.pallas_layer_norm_bwd(

@@ -1,6 +1,6 @@
 """Flash-attention op + Pallas kernel tests (CPU: interpret mode / jnp
-fallback; the same kernels run compiled on a real TPU — see bench.py's
-attention microbench for the on-chip numbers).
+fallback; the same kernels compile for a described TPU in
+``test_chip_compile.py``).
 
 Reference capability: ``src/operator/contrib/transformer.cc``
 (interleaved matmul self-attention pipeline).

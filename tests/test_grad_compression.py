@@ -5,8 +5,9 @@ collectives"): per-chunk symmetric quantization with error-feedback
 residuals tracks the uncompressed sharded update within the parity
 band, the residual rides as the LAST dp-sharded state leaf and
 round-trips BITWISE through elastic reshard and checkpoint restore,
-``"auto"`` engages only on a measured ``prog_compress`` table entry,
-the 1-device degenerate quietly disables (journaled), the compressed
+the knob has one rule for both builders
+(``compression.resolve_grad_compression``), the 1-device degenerate
+quietly disables (journaled), the compressed
 leg stays finite/drift-free under NumericsSanitizer, the
 ``grad_compress_corrupt`` chaos fault is caught as non-finite params,
 and the ``compress/decision`` census round-trips through
@@ -298,7 +299,7 @@ def test_uncompressed_checkpoint_restores_into_compressed(mesh8,
 
 
 # ---------------------------------------------------------------------------
-# knob resolution: degenerate layouts, "auto", validation, journal
+# knob resolution: degenerate layouts, validation, journal
 # ---------------------------------------------------------------------------
 
 def test_one_device_degenerate_disables_and_journals():
@@ -312,7 +313,7 @@ def test_one_device_degenerate_disables_and_journals():
         assert st._compress == ""
         ev = _last_decision()
         assert ev and ev["mode"] == "off" and ev["path"] == "disabled"
-        assert ev["tuner_source"] == "layout" and ev["requested"] == "int8"
+        assert ev["requested"] == "int8" and ev["dp"] == 1
         # no residual leaf, training still works
         _run(st, 2)
         # shard_optimizer off entirely: same quiet disable
@@ -331,36 +332,31 @@ def test_invalid_knob_rejected_eagerly(mesh8):
         _FusedUpdate(None, grad_compression="2bit")
 
 
-def test_auto_engages_only_on_measured_entry(mesh8, tmp_path,
-                                             monkeypatch):
-    """'auto' is off by heuristic (compression changes numerics); a
-    measured prog_compress table entry flips it on — and the decision
-    journal says which path fired."""
-    from mxnet_tpu import tune
-    from mxnet_tpu.tune import program as prog
-    monkeypatch.setenv("MXNET_AUTOTUNE_TABLE",
-                       str(tmp_path / "cost_table.jsonl"))
-    tune._reset_for_tests()
-    try:
-        telemetry.reset()
-        _, st = _build_step(mesh8, "auto")
-        assert st._compress == ""
-        ev = _last_decision()
-        assert ev and ev["path"] == "heuristic" and ev["mode"] == "off"
-        pcount = 9 * 7 + 7 + 7 * 4 + 4          # the probe net
-        key = (prog.canon_param_count(pcount), 8)
-        tune.get_table().record("prog_compress", key, "float32",
-                                {"mode": 1}, best_ms=1.0,
-                                source="searched")
-        _, st2 = _build_step(mesh8, "auto")
-        assert st2._compress == "int8"
-        ev = _last_decision()
-        assert ev["path"] == "measured" and ev["mode"] == "int8"
-        assert ev["tuner_source"] == "table"
-        _run(st2, 1)
-    finally:
-        tune._reset_for_tests()
-        telemetry.reset()
+@pytest.mark.parametrize("knob,want", [
+    (None, ""), ("off", ""), ("int8", "int8"), ("fp8", "fp8"),
+    ("auto", ValueError), ("int4", ValueError)], ids=repr)
+def test_grad_compression_knob_has_one_rule(mesh8, knob, want):
+    """``compression.resolve_grad_compression`` is the one rule for
+    ``DataParallelStep`` and ``Trainer``: a wire mode or off, nothing
+    else — ``"auto"`` had only a cost table to ask and went with it."""
+    from mxnet_tpu.gluon.trainer import _FusedUpdate
+    if want is ValueError:
+        for build in (lambda: comp.resolve_grad_compression(knob, 8),
+                      lambda: _build_step(mesh8, knob),
+                      lambda: _FusedUpdate(None, shard_optimizer=True,
+                                           grad_compression=knob)):
+            with pytest.raises(ValueError, match="grad_compression"):
+                build()
+        return
+    assert comp.resolve_grad_compression(knob, 8) == want
+    assert comp.resolve_grad_compression(knob, 1) == ""
+    net, st = _build_step(mesh8, knob)
+    assert st._compress == want
+    fused = _FusedUpdate(None, shard_optimizer=True,
+                         grad_compression=knob)
+    weights = [parallel.replicate(p.data(), mesh8)
+               for _, p in net.collect_params().items()]
+    assert fused._shard_ready(weights) and fused._compress == want
 
 
 def test_decision_event_and_gauges(mesh8):
@@ -487,8 +483,7 @@ def test_trainer_compressed_parity_sanitizer_and_states(mesh8,
         assert leaves[-1].ndim == 1 and leaves[-1].shape[0] % 8 == 0
     # Adam at lr=0.05 amplifies the per-step quantization delta more
     # than the SGD probe — the parity band here is looser than the
-    # DataParallelStep test's (the hard parity gate lives in bench.py
-    # on the loss trajectory, where error feedback keeps it tight)
+    # DataParallelStep test's
     for (ka, pa), (_, pb) in zip(na.collect_params().items(),
                                  nb.collect_params().items()):
         onp.testing.assert_allclose(pa.data().asnumpy(),

@@ -60,7 +60,7 @@ def test_leaf_arithmetic():
 
 
 def _bench_net(hidden=2048):
-    """The PR-5 zero_sharded_update bench leg (bench.py): 123-feature
+    """The PR-5 zero_sharded_update bench leg: 123-feature
     input, Dense(hidden)->Dense(hidden//2)->Dense(10), fp32, Adam."""
     onp.random.seed(7)
     mx.random.seed(7)

@@ -43,8 +43,7 @@ def _lint_fixture(name):
 @pytest.mark.parametrize("name", ["fx_trace.py", "fx_retrace.py",
                                   "fx_donation.py", "fx_pallas.py",
                                   "fx_sharding.py", "fx_concurrency.py",
-                                  "fx_numerics.py", "fx_tune.py",
-                                  "fx_errorflow.py"])
+                                  "fx_numerics.py", "fx_errorflow.py"])
 def test_fixture_rules_and_lines(name):
     path, result = _lint_fixture(name)
     got = {(f.rule, f.line) for f in result.new}

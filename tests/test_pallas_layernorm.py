@@ -92,8 +92,8 @@ def test_layer_norm_op_routes_axis_and_mean_var():
 def test_huge_channel_falls_back_to_generic_path():
     """C too large for the VMEM budget routes to the jnp path instead of
     a Mosaic compile failure (block picker returns None)."""
-    assert pln._pick_block_rows(768, rows=512) is not None
-    assert pln._pick_block_rows(10 ** 6, rows=512) is None
+    assert pln._pick_block_rows(768) is not None
+    assert pln._pick_block_rows(10 ** 6) is None
     x = jnp.asarray(onp.random.RandomState(0).randn(4, 8).astype("f"))
     g = jnp.ones(8); b = jnp.zeros(8)
     out = pln.fused_layer_norm(x, g, b, 1e-5)  # CPU: fallback either way

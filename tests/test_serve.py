@@ -75,6 +75,49 @@ def test_pad_batch_pads_and_refuses_overflow():
         pad_batch(_rows(3), 2, FEAT, "float32")
 
 
+@pytest.mark.parametrize("max_batch,menu", [
+    (1, [1]), (2, [1, 2]), (3, [1, 2, 4]), (8, [1, 2, 4, 8]),
+    (12, [2, 4, 8, 16]), (32, [4, 8, 16, 32])])
+def test_default_bucket_menu(max_batch, menu):
+    """The top four powers of two up to ``max_batch`` rounded up to
+    one: what ``ServeConfig(buckets="auto")`` compiles."""
+    from mxnet_tpu.serve.buckets import default_bucket_menu
+    assert default_bucket_menu(max_batch) == menu
+
+
+@pytest.mark.parametrize("budget,dtype,menu", [
+    (2 * 4096 * 7, "float32", [1, 2, 4]),   # 8 does not fit beside 1+2+4
+    (2 * 2048 * 7, "bfloat16", [1, 2, 4]),  # the same rows at half width
+    (1, "float32", [1])])                   # never below the smallest
+def test_over_budget_menu_sheds_largest_buckets_first(budget, dtype, menu,
+                                                      monkeypatch):
+    """Every bucket holds its input and output batch resident: a menu
+    over the serving HBM budget loses its largest buckets before any
+    executable is compiled — by argument or by
+    ``MXNET_SERVE_HBM_BUDGET``."""
+    from mxnet_tpu.serve.buckets import default_bucket_menu
+    assert default_bucket_menu(8, (1024,), dtype, budget=budget) == menu
+    monkeypatch.setenv("MXNET_SERVE_HBM_BUDGET", str(budget))
+    assert default_bucket_menu(8, (1024,), dtype) == menu
+
+
+def test_auto_buckets_resolve_at_construction(monkeypatch):
+    """``buckets="auto"`` becomes the budget-checked default menu where
+    the model's feature shape is known, and is journaled once."""
+    telemetry.reset()
+    srv = InferenceServer(_fn, feature_shape=FEAT,
+                          config=ServeConfig(buckets="auto"))
+    assert srv._cfg.buckets == (1, 2, 4, 8)
+    ev = [e for e in telemetry.snapshot()["events"]
+          if e.get("kind") == "serve" and e.get("name") == "bucket_menu"]
+    assert len(ev) == 1 and ev[0]["buckets"] == [1, 2, 4, 8]
+    monkeypatch.setenv("MXNET_SERVE_HBM_BUDGET", "1")
+    srv = InferenceServer(_fn, feature_shape=FEAT,
+                          config=ServeConfig(buckets="auto"))
+    assert srv._cfg.buckets == (1,)
+    telemetry.reset()
+
+
 # -- serving happy path -----------------------------------------------------
 
 def test_serves_correct_results_zero_steady_state_recompiles():

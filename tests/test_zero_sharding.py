@@ -162,31 +162,64 @@ def test_one_device_degenerate_mesh():
     _params_close(net_a, net_b)
 
 
-def test_auto_knob_resolution(mesh8):
-    """'auto' = on for dp>1, off for dp=1 or no mesh; True without a
-    mesh warns and falls back."""
-    _, st = _build_step(mesh8, "auto")
-    assert st._shard_n == 8
-    mesh1 = parallel.device_mesh((1,), ("dp",),
-                                 devices=jax.devices()[:1])
-    _, st1 = _build_step(mesh1, "auto")
-    assert st1._shard_n == 0
-    with pytest.raises(ValueError):
-        _build_step(mesh8, "sometimes")
+def _knob_mesh(kind):
+    if kind == "none":
+        return None
+    n = int(kind[2:])
+    return parallel.device_mesh((n,), ("dp",), devices=jax.devices()[:n])
 
 
-def test_auto_knob_without_mesh():
-    """No mesh anywhere: 'auto' stays off, True warns and falls back."""
+# knob spelling -> the dp extent the state is sharded over on (no mesh,
+# dp=1, dp=8): "auto" = on for dp>1 only; True takes any dp axis (the
+# 1-device degenerate layout) and falls back without one
+_KNOB_WANT = {False: (0, 0, 0), "off": (0, 0, 0), None: (0, 0, 0),
+              True: (0, 1, 8), "on": (0, 1, 8), "auto": (0, 0, 8)}
+
+
+@pytest.mark.parametrize("kind", ["none", "dp1", "dp8"])
+@pytest.mark.parametrize("knob", list(_KNOB_WANT), ids=repr)
+def test_shard_optimizer_knob_has_one_rule(knob, kind):
+    """``collectives.resolve_shard_optimizer`` is the one rule:
+    ``DataParallelStep`` shards over what it says, and ``Trainer``
+    engages its mirror over the same extent wherever that is more than
+    one replica (its weights mesh-replicated)."""
+    import warnings
+    from mxnet_tpu.gluon.trainer import _FusedUpdate
+    want = _KNOB_WANT[knob][("none", "dp1", "dp8").index(kind)]
+    mesh = _knob_mesh(kind)
+    assert coll.resolve_shard_optimizer(knob, mesh) == want
     old = parallel.get_mesh()
-    parallel.set_mesh(None)
+    parallel.set_mesh(mesh)
     try:
-        _, st_none = _build_step(None, "auto")
-        assert st_none._shard_n == 0
-        with pytest.warns(UserWarning, match="shard_optimizer"):
-            _, st_forced = _build_step(None, True)
-        assert st_forced._shard_n == 0
+        with warnings.catch_warnings(record=True) as caught:
+            warnings.simplefilter("always")
+            net, st = _build_step(mesh, knob)
+        assert st._shard_n == want
+        # a forced knob with nothing to shard over says so, once
+        fell_back = knob in (True, "on") and mesh is None
+        assert any("shard_optimizer" in str(w.message)
+                   for w in caught) == fell_back
+        weights = [p.data() if mesh is None
+                   else parallel.replicate(p.data(), mesh)
+                   for _, p in net.collect_params().items()]
+        fused = _FusedUpdate(None, shard_optimizer=knob)
+        assert fused._shard_ready(weights) == (want > 1)
+        assert fused._shard_n == (want if want > 1 else 0)
     finally:
         parallel.set_mesh(old)
+
+
+@pytest.mark.parametrize("builder", ["resolver", "DataParallelStep",
+                                     "Trainer"])
+def test_unknown_shard_optimizer_knob_refused(mesh8, builder):
+    from mxnet_tpu.gluon.trainer import _FusedUpdate
+    with pytest.raises(ValueError, match="shard_optimizer"):
+        if builder == "resolver":
+            coll.resolve_shard_optimizer("sometimes", mesh8)
+        elif builder == "DataParallelStep":
+            _build_step(mesh8, "sometimes")
+        else:
+            _FusedUpdate(None, shard_optimizer="sometimes")
 
 
 def test_shard_layout_telemetry(mesh8):

@@ -9,14 +9,10 @@ padding math mirrors ``collectives.padded_size`` exactly, so the
 estimate agrees with the runtime ``optimizer_state_bytes_per_chip``
 gauges (cross-checked in ``tests/test_hbm_estimator.py``).
 
-Consumers:
-
-* ``DataParallelStep.hbm_estimate()`` journals a ``hbm/estimate``
-  telemetry event per jitted program (rendered by
-  ``tools/parse_log.py``);
-* the Pallas autotuner (ROADMAP item 4) and the 3D-parallelism
-  composition (item 5) use it as the validity predicate for candidate
-  layouts before anything is compiled.
+Consumers: ``DataParallelStep.hbm_estimate()`` journals a
+``hbm/estimate`` telemetry event per jitted program (rendered by
+``tools/parse_log.py``); ``serve.buckets.validate_menu`` sizes a bucket
+menu's batch buffers with ``dtype_itemsize``.
 """
 from __future__ import annotations
 

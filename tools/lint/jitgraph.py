@@ -633,10 +633,8 @@ class PackageIndex:
         a shape read, a folded constant) is trace-time config, not a
         tracer.  Monotone — config only grows, taint only shrinks.
         Runs to convergence (bound = #functions, the longest possible
-        caller->helper chain): two sweeps covered the pre-autotune
-        package, but config-hood must reach the bottom of deep
-        trace-time helper chains like dispatch -> cost-table lookup ->
-        search -> candidate enumeration."""
+        caller->helper chain): config-hood must reach the bottom of
+        deep trace-time helper chains."""
         for _ in range(max(2, len(self.functions))):
             self._taint_cache = {}
             changed = False
@@ -678,9 +676,8 @@ class PackageIndex:
             # a caller that is not jit-reachable executes host-side
             # only — its arguments are plain Python values by
             # construction and cannot carry tracers into the callee.
-            # Without this, host-only entry points (the tune CLI, the
-            # v2 model/program lookup APIs) poison config-hood of the
-            # shared dispatch -> cost-table -> search chain.
+            # Without this, host-only entry points poison config-hood
+            # of the trace-time helpers they share with a jitted caller.
             return True
         t = self.taint(cs.scope)
         return t is not None and not t.expr(expr)

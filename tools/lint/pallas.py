@@ -84,34 +84,6 @@ def _global_clamp(index: PackageIndex) -> int:
     return _DEFAULT_CLAMP
 
 
-# the tune-package lookup spellings whose ``default=`` literal is the
-# config a caller is sized at on a miss: the plain table lookup, the
-# v2 model-ranked lookup (same tuple contract, learned-model fallback),
-# and the program-knob lookup (whole-program schedule knobs — folded so
-# a knob that feeds kernel sizing still resolves)
-_TUNE_LOOKUPS = ("table_blocks", "model_blocks", "program_knobs")
-
-
-def _fold_tune_lookup(expr: ast.expr, env) -> Optional[object]:
-    """Blocks that arrive via an autotune cost-table lookup instead of a
-    literal clamp chain: ``table_blocks(family, shape, dtype,
-    default=(bq, bk))`` (mxnet_tpu.tune) — or its v2 siblings
-    ``model_blocks`` / ``program_knobs`` — folds to its ``default=``
-    fallback config — the config the caller is sized at on a table
-    miss, and the declared anchor the measured search prunes around
-    with the same VMEM predicate this rule checks statically.  (The
-    model/table legs only ever serve configs from the statically-pruned
-    candidate grid, so the ``default=`` literal is the one config the
-    lookup can return that the search machinery never validated.)"""
-    if not isinstance(expr, ast.Call) or \
-            call_target_name(expr) not in _TUNE_LOOKUPS:
-        return None
-    for kw in expr.keywords:
-        if kw.arg == "default":
-            return fold_or_none(kw.value, env)
-    return None
-
-
 def _local_env(module, fi, call_line, base: Dict[str, object]
                ) -> Dict[str, object]:
     """Fold the enclosing function's assignments (source order, up to the
@@ -129,15 +101,11 @@ def _local_env(module, fi, call_line, base: Dict[str, object]
         t = stmt.targets[0]
         if isinstance(t, ast.Name):
             v = fold_or_none(stmt.value, env)
-            if v is None:
-                v = _fold_tune_lookup(stmt.value, env)
             if v is not None:
                 env[t.id] = v
         elif isinstance(t, ast.Tuple) and \
                 all(isinstance(e, ast.Name) for e in t.elts):
             v = fold_or_none(stmt.value, env)
-            if v is None:
-                v = _fold_tune_lookup(stmt.value, env)
             if isinstance(v, tuple) and len(v) == len(t.elts):
                 for e, x in zip(t.elts, v):
                     env[e.id] = x

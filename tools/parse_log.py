@@ -151,12 +151,9 @@ def parse_jsonl(lines):
     hbm = {}
     lockorder = []
     numerics = {}
-    autotune = []
     histograms = {}
     traces = {}
     incidents = []
-    model = {"errors": [], "fallbacks": {}, "picks": 0}
-    program = []
     elastic = []
     compress = []
     serve = {"events": {}, "batches": 0, "fill_pct_sum": 0.0,
@@ -220,55 +217,6 @@ def parse_jsonl(lines):
             n["nonfinite"] += bad
             if bad and n["first_bad_step"] is None:
                 n["first_bad_step"] = rec.get("step")
-        elif kind == "autotune":
-            # one event per dispatch decision (mxnet_tpu.tune): name is
-            # the source (hit|miss|search|fallback), payload the
-            # instance key + chosen config — the per-shape census.
-            # v2 adds the learned-cost-model stream on the same kind:
-            # "model" (one per ranked search, predicted-vs-measured
-            # error stats), "model_fallback" (model unusable, reason)
-            # and "model_pick" (dispatch served a model-predicted
-            # config without timing)
-            name = rec.get("name")
-            if name == "model":
-                model["errors"].append(
-                    {k: rec.get(k) for k in
-                     ("family", "shape", "dtype", "n", "mean_err_pct",
-                      "max_err_pct", "cv_error", "n_samples")})
-            elif name == "model_fallback":
-                r = str(rec.get("reason"))
-                model["fallbacks"][r] = model["fallbacks"].get(r, 0) + 1
-            elif name == "model_pick":
-                model["picks"] += 1
-            else:
-                autotune.append({"source": name,
-                                 "family": rec.get("family"),
-                                 "shape": rec.get("shape"),
-                                 "dtype": rec.get("dtype"),
-                                 "config": rec.get("config"),
-                                 "reason": rec.get("reason")})
-        elif kind == "autotune_program":
-            # whole-program schedule lookups (mxnet_tpu.tune.program):
-            # one event per consumer decision (prefetch depth, scan
-            # window, ZeRO on/off, bucket menu) stamped with where the
-            # knob came from
-            program.append({"event": "program/%s" % rec.get("name"),
-                            "family": rec.get("family"),
-                            "shape": rec.get("shape"),
-                            "source": rec.get("tuner_source"),
-                            "config": rec.get("config"),
-                            "detail": rec.get("strategy")
-                            or rec.get("reason")})
-        elif kind == "zero" and rec.get("name") in (
-                "auto_decision", "trainer_auto_decision"):
-            # shard_optimizer="auto" resolutions (DataParallelStep /
-            # Trainer): measured table decision or heuristic fallback
-            program.append({"event": "zero/%s" % rec.get("name"),
-                            "family": "prog_zero",
-                            "shape": [rec.get("params"), rec.get("dp")],
-                            "source": rec.get("tuner_source"),
-                            "config": {"shard": rec.get("shard")},
-                            "detail": rec.get("path")})
         elif kind == "compress":
             # compressed-collective decisions (parallel/compression.py
             # wire, journaled by DataParallelStep / Trainer at each
@@ -277,9 +225,9 @@ def parse_jsonl(lines):
             if rec.get("name") == "decision":
                 compress.append(
                     {k: rec.get(k) for k in
-                     ("mode", "requested", "path", "tuner_source", "dp",
-                      "params", "dtype", "wire_bytes", "scale_bytes",
-                      "f32_bytes", "ratio")})
+                     ("mode", "requested", "path", "dp", "params",
+                      "dtype", "wire_bytes", "scale_bytes", "f32_bytes",
+                      "ratio")})
         elif kind in ("elastic", "ckpt"):
             # elastic-transition / checkpoint journal events (one per
             # detect/reshard/write/restore — mxnet_tpu.parallel.elastic
@@ -316,17 +264,6 @@ def parse_jsonl(lines):
                 serve["states"].append(
                     "%s->%s" % (rec.get("state_from"),
                                 rec.get("state_to")))
-            elif name == "bucket_menu":
-                # buckets="auto" resolution — also a program-schedule
-                # decision (the menu came from the prog_buckets table
-                # or its heuristic)
-                program.append({"event": "serve/bucket_menu",
-                                "family": "prog_buckets",
-                                "shape": None,
-                                "source": rec.get("tuner_source"),
-                                "config": {"buckets":
-                                           rec.get("buckets")},
-                                "detail": rec.get("model")})
         elif kind == "lint" and rec.get("name") == "gate":
             lint_gate = rec
         elif kind == "lint" and rec.get("name") == "chaos_audit":
@@ -348,7 +285,6 @@ def parse_jsonl(lines):
     return {"spans": spans, "counters": counters, "gauges": gauges,
             "recompiles": recompiles, "steps": steps, "hbm": hbm,
             "lockorder": lockorder, "numerics": numerics,
-            "autotune": autotune, "model": model, "program": program,
             "elastic": elastic, "compress": compress, "serve": serve,
             "lint_gate": lint_gate,
             "chaos_audit": chaos_audit, "histograms": histograms,
@@ -415,11 +351,6 @@ def render_jsonl(agg, fmt="markdown"):
         for e in agg["lockorder"]:
             out.append("  %s -> %s" % (e["src"], e["dst"]))
     out.extend(_render_numerics(agg.get("numerics") or {}, fmt))
-    out.extend(_render_autotune(agg.get("autotune") or [],
-                                agg.get("counters") or {}, fmt))
-    out.extend(_render_model(agg.get("model") or {},
-                             agg.get("counters") or {}, fmt))
-    out.extend(_render_program(agg.get("program") or [], fmt))
     out.extend(_render_compress(agg.get("compress") or [],
                                 agg.get("gauges") or {}, fmt))
     out.extend(_render_elastic(agg.get("elastic") or [], fmt))
@@ -686,7 +617,7 @@ def _render_elastic(elastic, fmt="markdown"):
 
 def _render_compress(compress, gauges, fmt="markdown"):
     """Gradient-compression census from the compress/decision journal:
-    one row per knob resolution (mode, who decided, dp extent, and the
+    one row per knob resolution (mode, forced or disabled, dp extent, and the
     schedule-arithmetic bytes on the wire vs the f32 baseline), headed
     by the final wire-savings gauges."""
     if not compress and not any(k.startswith("compression.")
@@ -701,8 +632,8 @@ def _render_compress(compress, gauges, fmt="markdown"):
                       "%.6g" % scale if scale is not None else "-"))
     if not compress:
         return out
-    header = ["mode", "requested", "path", "source", "dp", "params",
-              "dtype", "wire-B", "scale-B", "f32-B", "ratio"]
+    header = ["mode", "requested", "path", "dp", "params", "dtype",
+              "wire-B", "scale-B", "f32-B", "ratio"]
     if fmt == "markdown":
         out.append("| " + " | ".join(header) + " |")
         out.append("| " + " | ".join("---" for _ in header) + " |")
@@ -712,109 +643,10 @@ def _render_compress(compress, gauges, fmt="markdown"):
 
     for d in compress:
         vals = [cell(d.get("mode")), cell(d.get("requested")),
-                cell(d.get("path")), cell(d.get("tuner_source")),
-                cell(d.get("dp")), cell(d.get("params")),
-                cell(d.get("dtype")), cell(d.get("wire_bytes")),
+                cell(d.get("path")), cell(d.get("dp")),
+                cell(d.get("params")), cell(d.get("dtype")), cell(d.get("wire_bytes")),
                 cell(d.get("scale_bytes")), cell(d.get("f32_bytes")),
                 cell(d.get("ratio"))]
-        out.append("| " + " | ".join(vals) + " |" if fmt == "markdown"
-                   else "\t".join(vals))
-    return out
-
-
-def _render_autotune(autotune, counters, fmt="markdown"):
-    """Per-shape chosen-config table from the autotune journal events
-    (one per dispatch decision) headed by the hit/miss/search/fallback
-    counter line — where every shape's kernel config came from."""
-    if not autotune and not any(k.startswith("autotune.")
-                                for k in counters):
-        return []
-    counts = " ".join("%s=%d" % (k.split(".", 1)[1], counters[k])
-                      for k in sorted(counters)
-                      if k.startswith("autotune."))
-    out = ["", "autotune decisions (cost-table census%s):"
-           % (": " + counts if counts else "")]
-    if not autotune:
-        return out
-    header = ["family", "shape", "dtype", "source", "config"]
-    if fmt == "markdown":
-        out.append("| " + " | ".join(header) + " |")
-        out.append("| " + " | ".join("---" for _ in header) + " |")
-    for e in autotune:
-        cfg = e.get("config")
-        cfg_s = " ".join("%s=%s" % (k, cfg[k]) for k in sorted(cfg)) \
-            if isinstance(cfg, dict) else (e.get("reason") or "-")
-        vals = [str(e.get("family", "?")),
-                "x".join(str(d) for d in (e.get("shape") or [])) or "-",
-                str(e.get("dtype", "?")), str(e.get("source", "?")),
-                cfg_s]
-        out.append("| " + " | ".join(vals) + " |" if fmt == "markdown"
-                   else "\t".join(vals))
-    return out
-
-
-def _render_model(model, counters, fmt="markdown"):
-    """Learned-cost-model census: the rank/hit/fallback counter line,
-    the per-search predicted-vs-measured error table (one row per
-    model-ranked search — how well the model ordered the candidates it
-    was trusted to prune) and the fallback-reason tally."""
-    errors = (model or {}).get("errors") or []
-    fallbacks = (model or {}).get("fallbacks") or {}
-    have_counts = any(k.startswith("autotune.model")
-                      for k in counters)
-    if not errors and not fallbacks and not have_counts:
-        return []
-    counts = " ".join("%s=%d" % (k.split(".", 1)[1], counters[k])
-                      for k in sorted(counters)
-                      if k.startswith("autotune.model"))
-    out = ["", "autotune cost model (predicted vs measured%s):"
-           % (": " + counts if counts else "")]
-    if errors:
-        header = ["family", "shape", "dtype", "timed", "mean-err%",
-                  "max-err%", "cv-err", "samples"]
-        if fmt == "markdown":
-            out.append("| " + " | ".join(header) + " |")
-            out.append("| " + " | ".join("---" for _ in header) + " |")
-        def pct(v):
-            return "%.4g" % float(v) if v is not None else "-"
-
-        for e in errors:
-            vals = [str(e.get("family", "?")),
-                    "x".join(str(d) for d in (e.get("shape") or []))
-                    or "-",
-                    str(e.get("dtype", "?")), str(e.get("n", "-")),
-                    pct(e.get("mean_err_pct")),
-                    pct(e.get("max_err_pct")), pct(e.get("cv_error")),
-                    str(e.get("n_samples", "-"))]
-            out.append("| " + " | ".join(vals) + " |"
-                       if fmt == "markdown" else "\t".join(vals))
-    for reason in sorted(fallbacks):
-        out.append("  fallback[%s]=%d" % (reason, fallbacks[reason]))
-    return out
-
-
-def _render_program(program, fmt="markdown"):
-    """Whole-program schedule decision census: one row per consumer
-    lookup (prefetch depth, scan window, ZeRO auto resolution, serving
-    bucket menu) with the knob's provenance
-    (table|model|searched|heuristic)."""
-    if not program:
-        return []
-    header = ["event", "family", "shape", "source", "config", "detail"]
-    out = ["", "program schedule decisions:"]
-    if fmt == "markdown":
-        out.append("| " + " | ".join(header) + " |")
-        out.append("| " + " | ".join("---" for _ in header) + " |")
-    for e in program:
-        cfg = e.get("config")
-        if isinstance(cfg, dict):
-            cfg_s = " ".join("%s=%s" % (k, cfg[k]) for k in sorted(cfg))
-        else:
-            cfg_s = "-" if cfg is None else str(cfg)
-        vals = [str(e.get("event", "?")), str(e.get("family", "?")),
-                "x".join(str(d) for d in (e.get("shape") or [])) or "-",
-                str(e.get("source", "?")), cfg_s,
-                "-" if e.get("detail") is None else str(e["detail"])]
         out.append("| " + " | ".join(vals) + " |" if fmt == "markdown"
                    else "\t".join(vals))
     return out
