@@ -169,11 +169,19 @@ class TiedSoftmaxCrossEntropyLoss(Loss):
     """Softmax cross-entropy of a head TIED to the embedding, without the
     logits: ``pred`` is the pair ``(hidden (B, S, D), weight (V, D))`` a
     language model returns in training, ``label`` (B, S) the target ids.
-    The rows of ``weight`` are taken in blocks of about ``block_rows``
-    (``ops.nn.tied_softmax_cross_entropy``), so a 131k-row head over 16k
-    tokens never holds more than one block's logits.  A label outside
-    0..V-1 (``ignore_label``, -1) marks a position that predicts nothing;
-    the loss of a row is the mean over its other positions."""
+    The head's gradients are made in its forward pass, a block of tokens
+    against all V rows at a time (``ops.nn.tied_softmax_cross_entropy``:
+    three products a step, no logits formed twice), so a 131k-row head
+    over 16k tokens never holds more logits than ``block_rows`` rows of
+    the vocabulary against all tokens would.  A label outside 0..V-1
+    (``ignore_label``, -1) marks a position that predicts nothing; the
+    loss of a row is the mean over its other positions.  Every
+    per-position weight — that mask, ``sample_weight``, the loss's
+    ``weight``, one over the row's count — goes INTO the op as its
+    ``scale`` and a row is only summed here, so the cotangent the op sees
+    under a batch mean or a scaled loss is one number; weighting the
+    per-position losses of the op OUTSIDE it stays exact and costs the
+    backward a second pass over the logits."""
 
     def __init__(self, block_rows=8192, ignore_label=-1, weight=None,
                  batch_axis=0, **kwargs):
@@ -189,11 +197,11 @@ class TiedSoftmaxCrossEntropyLoss(Loss):
         def fn(h, w, l, sw):
             lab = l.astype(jnp.int32)
             counted = (lab != self._ignore).astype(jnp.float32)
-            loss = tied_softmax_cross_entropy(
-                h, w, lab, block_rows=self._block_rows) * counted
-            loss = _w(loss, self._weight, sw)
-            return jnp.sum(loss, axis=-1) / jnp.maximum(
-                jnp.sum(counted, axis=-1), 1.0)
+            scale = _w(counted, self._weight, sw) / jnp.maximum(
+                jnp.sum(counted, axis=-1, keepdims=True), 1.0)
+            return jnp.sum(tied_softmax_cross_entropy(
+                h, w, lab, scale=scale, block_rows=self._block_rows),
+                axis=-1)
         return self._dispatch(fn, [hidden, table, label, sample_weight],
                               "tied_softmax_ce")
 

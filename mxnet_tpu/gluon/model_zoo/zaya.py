@@ -27,8 +27,11 @@ experts; the rule spreads them over its first hundred or so steps.
 
 Training: ``net(tokens)`` returns ``(hidden, embedding)`` for
 ``gluon.loss.TiedSoftmaxCrossEntropyLoss``, which never forms the
-(tokens x vocabulary) logits; ``net(tokens, positions)`` returns the logits
-at ``positions`` (B, P) of each row::
+(tokens x vocabulary) logits: it takes a block of tokens against the whole
+vocabulary at a time and makes the head's gradients while the block's
+logits are there, three products a step and no logits formed twice;
+``net(tokens, positions)`` returns the logits at ``positions`` (B, P) of
+each row::
 
     net = gluon.model_zoo.zaya1(num_layers=4, vocab_size=131136,
                                 experts_held=(0, 8), bias_update_rate=1e-4)

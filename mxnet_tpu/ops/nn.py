@@ -13,6 +13,7 @@ assignment re-tiles for the TPU's native layouts internally.
 from __future__ import annotations
 
 import functools
+import math
 import os
 
 import jax
@@ -957,43 +958,83 @@ def vocab_block_rows(vocab, target):
     return next(r for r in range(target, 0, -1) if vocab % r == 0)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _tied_ce(hidden, weight, label, block_rows):
-    return _tied_ce_fwd(hidden, weight, label, block_rows)[0]
+def token_block_positions(seq, vocab, block_rows):
+    """The positions of a row one block of TOKENS covers: the largest
+    divisor of ``seq`` whose (B, positions, V) logits are no more than the
+    (B * seq, block_rows) a block of vocabulary rows holds."""
+    return vocab_block_rows(seq, seq * block_rows // vocab)
 
 
-def _tied_ce_fwd(hidden, weight, label, block_rows):
-    v, d = weight.shape
-    n = hidden.shape[0]
-    blocks = weight.reshape(v // block_rows, block_rows, d)
+def _tied_ce_token_blocks(hidden, label, scale, weight, block_rows, grads):
+    """The cross-entropy and the log-sum-exp of every position of
+    ``hidden`` (B, S, D), a block of positions against ALL rows of
+    ``weight`` at a time — a block's log-sum-exp is whole while its logits
+    are still there.  With ``grads`` also the gradients of
+    ``sum(scale * ce)``: dh in float32, each block written once, and dW
+    summed over the blocks in ONE float32 (V, D) accumulator that the loop
+    carries."""
+    b, s, _ = hidden.shape
+    v = weight.shape[0]
+    s_blk = token_block_positions(s, v, block_rows)
 
-    def body(carry, xs):
-        m, s, picked = carry
-        i, w = xs
-        logits = lax.dot_general(hidden, w, (((1,), (1,)), ((), ())),
+    def block(x, i):
+        return lax.dynamic_slice_in_dim(x, i * s_blk, s_blk, axis=1)
+
+    def body(dw, i):
+        h, lab = block(hidden, i), block(label, i)
+        logits = lax.dot_general(h, weight, (((2,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        local = label - i * block_rows
-        here = (local >= 0) & (local < block_rows)
-        got = jnp.take_along_axis(
-            logits, jnp.clip(local, 0, block_rows - 1)[:, None], axis=1)[:, 0]
-        m_new = jnp.maximum(m, jnp.max(logits, axis=1))
-        s = s * jnp.exp(m - m_new) + jnp.sum(
-            jnp.exp(logits - m_new[:, None]), axis=1)
-        return (m_new, s, picked + jnp.where(here, got, 0.0)), None
+        m = jnp.max(logits, axis=-1)
+        lse = m + jnp.log(jnp.sum(jnp.exp(logits - m[..., None]), axis=-1))
+        picked = jnp.take_along_axis(
+            logits, jnp.clip(lab, 0, v - 1)[..., None], axis=-1)[..., 0]
+        ce = lse - jnp.where((lab >= 0) & (lab < v), picked, 0.0)
+        if not grads:
+            return dw, (ce, lse)
+        col = lax.broadcasted_iota(jnp.int32, logits.shape, 2)
+        dl = (jnp.exp(logits - lse[..., None])
+              - (col == lab[..., None]).astype(jnp.float32)
+              ) * block(scale, i)[..., None]
+        dl = dl.astype(hidden.dtype)
+        dh = lax.dot_general(dl, weight, (((2,), (0,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+        dw = dw + lax.dot_general(dl, h, (((0, 1), (0, 1)), ((), ())),
+                                  preferred_element_type=jnp.float32)
+        return dw, (ce, lse, dh)
 
-    init = (jnp.full((n,), -jnp.inf, jnp.float32),
-            jnp.zeros((n,), jnp.float32), jnp.zeros((n,), jnp.float32))
-    (m, s, picked), _ = lax.scan(
-        body, init, (jnp.arange(v // block_rows), blocks))
-    lse = m + jnp.log(s)
-    return lse - picked, (hidden, weight, label, lse)
+    dw, out = lax.scan(
+        body, jnp.zeros(weight.shape if grads else (), jnp.float32),
+        jnp.arange(s // s_blk))
+    # (blocks, B, s_blk, ...) -> (B, S, ...)
+    out = tuple(jnp.moveaxis(x, 0, 1).reshape((b, s) + x.shape[3:])
+                for x in out)
+    return out + (dw,) if grads else out
 
 
-def _tied_ce_bwd(block_rows, res, g):
-    hidden, weight, label, lse = res
+def _tied_ce_per_shard(hidden, weight, label, scale, block_rows, grads):
+    """``_tied_ce_token_blocks`` once a shard of the batch where the
+    program being traced has one sharded (the batch axis is never merged
+    with the sequence's, so a shard's rows stay its own): each shard sums
+    its own dW over its blocks and the shards' sums are added ONCE —
+    left to the partitioner the whole (V, D) accumulator would be reduced
+    over the mesh every block."""
+    from .pallas_attention import per_batch_shard
+    return per_batch_shard(
+        functools.partial(_tied_ce_token_blocks, block_rows=block_rows,
+                          grads=grads),
+        [hidden, label, scale, weight], replicated=(3,),
+        summed=(False, False, False, True) if grads else (False, False))
+
+
+def _tied_ce_recompute(hidden, weight, label, lse, g, block_rows):
+    """dh and dW of ``sum(g * ce)`` over (N, D) tokens with every logit
+    formed a SECOND time, a block of ``block_rows`` vocabulary rows
+    against all tokens at a time: each block's part of dW is whole when
+    the block is done, dh is summed over the blocks in float32."""
+    from .. import telemetry
+    telemetry.inc("tied_ce.path.recompute")
     v, d = weight.shape
     blocks = weight.reshape(v // block_rows, block_rows, d)
-    g = g.astype(jnp.float32)
 
     def body(dh, xs):
         i, w = xs
@@ -1012,30 +1053,86 @@ def _tied_ce_bwd(block_rows, res, g):
 
     dh, dw = lax.scan(body, jnp.zeros(hidden.shape, jnp.float32),
                       (jnp.arange(v // block_rows), blocks))
+    return dh.astype(hidden.dtype), dw.reshape(v, d)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _tied_ce(hidden, weight, label, scale, block_rows):
+    return scale * _tied_ce_per_shard(hidden, weight, label, scale,
+                                      block_rows, grads=False)[0]
+
+
+def _tied_ce_fwd(hidden, weight, label, scale, block_rows):
+    # trace time: once a differentiated shape, as attention.kernel.*
+    from .. import telemetry
+    telemetry.inc("tied_ce.path.forward_grads")
+    ce, lse, dh, dw = _tied_ce_per_shard(hidden, weight, label, scale,
+                                         block_rows, grads=True)
+    # held until the backward in the dtypes of the gradients they become
+    return scale * ce, (hidden, weight, label, scale, ce, lse,
+                        dh.astype(hidden.dtype), dw.astype(weight.dtype))
+
+
+def _tied_ce_bwd(block_rows, res, g):
+    """The forward's gradients times the cotangent where that is ONE
+    number for every position (the mean of a batch, a scaled loss) —
+    tested on the device, since no trace can know it; any other cotangent
+    takes the recomputing loop, so every gradient is exact."""
+    hidden, weight, label, scale, ce, lse, dh_u, dw_u = res
+    g = g.astype(jnp.float32)
+    g0 = g.reshape(-1)[0]
+
+    def scaled():
+        return ((dh_u * g0).astype(hidden.dtype),
+                (dw_u * g0).astype(weight.dtype))
+
+    def recomputed():
+        dh, dw = _tied_ce_recompute(
+            hidden.reshape(-1, hidden.shape[-1]), weight, label.reshape(-1),
+            lse.reshape(-1), (g * scale).reshape(-1), block_rows)
+        return dh.reshape(hidden.shape), dw
+
+    dh, dw = lax.cond(jnp.all(g == g0), scaled, recomputed)
     import numpy as onp
-    return (dh.astype(hidden.dtype), dw.reshape(v, d),
-            onp.zeros(label.shape, jax.dtypes.float0))
+    return dh, dw, onp.zeros(label.shape, jax.dtypes.float0), g * ce
 
 
 _tied_ce.defvjp(_tied_ce_fwd, _tied_ce_bwd)
 
 
 @register("tied_softmax_cross_entropy")
-def tied_softmax_cross_entropy(hidden, weight, label,
+def tied_softmax_cross_entropy(hidden, weight, label, scale=None,
                                block_rows: int = 8192):
-    """Per-row softmax cross-entropy of ``hidden @ weight.T`` against
-    ``label`` — the head TIED to the (V, D) embedding ``weight`` —
-    without the (N, V) logits: the rows of ``weight`` are taken in blocks
-    of the largest divisor of V that is at most ``block_rows``, the
-    log-sum-exp carried from block to block, and the backward recomputes
-    each block's logits, so each block's part of the weight's gradient is
-    whole when the block is done (no (V, D) fp32 accumulator) and only the
-    hidden states' gradient is summed over blocks, in fp32.  ``hidden`` is
-    (..., D), ``label`` (...) integer ids (float ids are cast); a label
-    outside 0..V-1 (say -1) picks no logit: mask such rows' loss."""
+    """``scale`` times the per-position softmax cross-entropy of
+    ``hidden @ weight.T`` against ``label`` — the head TIED to the (V, D)
+    embedding ``weight`` — without the (N, V) logits, in THREE products a
+    training step: under differentiation the forward takes a block of
+    tokens against all V rows, so that a block's log-sum-exp is whole
+    while its logits are there, and makes the block's gradients on the
+    spot (``softmax - onehot`` times ``scale``, rounded to the hidden
+    states' dtype; dh written once; dW summed in one float32 (V, D)
+    accumulator); the backward only multiplies them by the cotangent.
+    That needs the cotangent to be ONE number for every position, which
+    the backward tests on the device: put per-position weights (a mask
+    over ignored labels, a sample weight, one over a row's count) in
+    ``scale`` (...), and the mean of a batch or a scaled loss leaves it
+    so.  Any other cotangent takes a second loop over blocks of
+    ``weight``'s rows that forms the logits again (a fourth product):
+    exact, and a quarter slower.  Without differentiation only the loss
+    is computed.  ``block_rows`` is the budget of one block of logits: a
+    block of tokens — as many positions of every row as divide the last
+    leading axis of ``hidden`` — holds at most ``N * block_rows`` of
+    them, a block of the second loop the largest divisor of V at most
+    ``block_rows`` rows against all N tokens.  ``hidden`` is (..., D),
+    ``label`` (...) integer ids (float ids are cast); a label outside
+    0..V-1 (say -1) picks no logit: give such positions the ``scale``
+    0."""
     lead = hidden.shape[:-1]
-    loss = _tied_ce(hidden.reshape(-1, hidden.shape[-1]), weight,
-                    label.reshape(-1).astype(jnp.int32),
+    shape = (math.prod(lead[:-1]), lead[-1]) if lead else (1, 1)
+    scale = jnp.ones(shape, jnp.float32) if scale is None else \
+        jnp.broadcast_to(scale, lead).astype(jnp.float32).reshape(shape)
+    loss = _tied_ce(hidden.reshape(shape + hidden.shape[-1:]), weight,
+                    label.reshape(shape).astype(jnp.int32), scale,
                     vocab_block_rows(weight.shape[0], block_rows))
     return loss.reshape(lead)
 

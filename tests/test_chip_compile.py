@@ -448,3 +448,83 @@ def test_zaya1_layer_train_step_compiles_with_named_kernels(one_chip,
     for block in ("layer0_cca", "layer0_router", "layer0_experts",
                   "layer0_moe_norm"):
         assert "/%s%s/" % (net.prefix, block) in text, block
+
+
+def _computations(text):
+    """name -> body text of every computation of a compiled program."""
+    comps = {}
+    for m in re.finditer(r"^(?:ENTRY )?%([\w.\-]+) \(.*?^}", text,
+                         re.S | re.M):
+        comps["ENTRY" if m.group(0).startswith("ENTRY") else m.group(1)] = \
+            m.group(0)
+    return comps
+
+
+def _reachable(comps, name):
+    """The text of ``name`` and of every computation it calls (fusions,
+    loop bodies), but not through a ``conditional``'s branches."""
+    seen, todo = [], [name]
+    while todo:
+        body = comps[todo.pop()]
+        seen.append(body)
+        todo += [c for c in re.findall(r"(?:calls|body)=%([\w.\-]+)", body)
+                 if c in comps]
+    return "\n".join(seen)
+
+
+# compiled temporaries of the head alone (sandbox compile, described v5e,
+# PR 29) plus 5%: under the row mean the compiler proves the cotangent
+# uniform and drops the recomputing branch; under a loss scale it learns
+# only when it runs, both branches are held
+_HEAD_CASES = {"row_mean": (False, 1.209e9 * 1.05),
+               "row_mean_times_a_runtime_scale": (True, 3.493e9 * 1.05)}
+
+
+@pytest.mark.parametrize("case_id", list(_HEAD_CASES))
+def test_zaya1_head_makes_its_gradients_in_one_loop_of_three_products(
+        one_chip, case_id):
+    """The tied head alone at the cell's shapes (2 x 8,192 x 2,048 against
+    131,136 rows, bf16, ``block_rows=8196``) under ``value_and_grad``: the
+    path taken is ONE ``while`` over 1,024-token blocks holding three
+    products — logits, dh, dW into the carried float32 accumulator — and
+    no second logits product; the recomputing loop exists only under a
+    ``conditional`` (where the compiler cannot prove the cotangent one
+    number), and the temporaries stay under the stated ceiling."""
+    from mxnet_tpu.ops import nn as nn_ops
+
+    runtime_scale, ceiling = _HEAD_CASES[case_id]
+    rows, seq, units, vocab = 2, 8192, 2048, 131136
+
+    def loss(h, w, lab, k):
+        counted = (lab != -1).astype(jnp.float32)
+        scale = counted / jnp.maximum(counted.sum(-1, keepdims=True), 1.0)
+        row = jnp.sum(nn_ops.tied_softmax_cross_entropy(
+            h, w, lab, scale=scale, block_rows=8196), axis=-1)
+        return jnp.mean(row) * (k if runtime_scale else 1.0)
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(jax.value_and_grad(loss, (0, 1))).lower(
+        spec((rows, seq, units), jnp.bfloat16),
+        spec((vocab, units), jnp.bfloat16), spec((rows, seq), jnp.int32),
+        spec((), jnp.float32)).compile()
+    comps = _computations(compiled.as_text())
+    taken = _reachable(comps, "ENTRY")
+    assert len(re.findall(r" while\(", taken)) == 1
+    blocks = "f32[%d,%d,%d]" % (rows, 512, vocab)    # 1,024 tokens' logits
+    products = re.findall(r"= (\S+) convolution\(", taken)
+    assert len(products) == 3, products
+    assert sum(p.startswith(blocks) for p in products) == 1
+    assert "f32[%d,%d]" % (rows * seq, 8196) not in taken
+    branches = [b for line in re.findall(r" conditional\(.*", taken)
+                for b in re.findall(r"%([\w.\-]+)", line.split(
+                    "branch_computations=")[1].split("}")[0])]
+    assert bool(branches) == runtime_scale
+    if branches:
+        fallback = "\n".join(_reachable(comps, b) for b in branches)
+        assert len(re.findall(r" while\(", fallback)) == 1
+        again = re.findall(r"= (\S+) convolution\(", fallback)
+        assert sum(p.startswith("f32[%d,%d]" % (rows * seq, 8196))
+                   for p in again) == 1, again
+    assert compiled.memory_analysis().temp_size_in_bytes <= ceiling

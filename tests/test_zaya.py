@@ -453,21 +453,56 @@ def test_gqa_through_flash_attention_op():
         PA.flash_attention(q, k[:, :1].repeat(3, 1), v, True, None)
 
 
-@pytest.mark.parametrize("block_rows", [8, 24, 96, 1000])
-def test_blocked_tied_cross_entropy_matches_unblocked(block_rows):
+def _head_inputs(dtype="float32", seq=8):
+    """(hidden, weight, labels with two ignored positions, the per-position
+    scale a loss makes of them and a sample weight, a per-position
+    cotangent) of a 3 x ``seq`` head over 96 rows."""
     rs = onp.random.RandomState(0)
-    h = jnp.asarray(rs.randn(3, 7, 16).astype("float32"))
-    w = jnp.asarray(rs.randn(96, 16).astype("float32") * 0.5)
-    lab = jnp.asarray(rs.randint(0, 96, (3, 7)))
-    g = jnp.asarray(rs.rand(3, 7).astype("float32"))
+    h = jnp.asarray(rs.randn(3, seq, 16).astype("float32")).astype(dtype)
+    w = jnp.asarray(rs.randn(96, 16).astype("float32") * 0.5).astype(dtype)
+    lab = rs.randint(0, 96, (3, seq))
+    g = jnp.asarray(rs.rand(3, seq).astype("float32"))
+    ignored = lab.copy()
+    ignored[0, 2] = ignored[2, seq - 1] = -1
+    counted = (ignored != -1).astype("float32")
+    scale = counted * rs.rand(3, 1).astype("float32") \
+        / counted.sum(-1, keepdims=True)
+    return h, w, jnp.asarray(lab), jnp.asarray(ignored), \
+        jnp.asarray(scale), g
+
+
+def _plain_ce(h, w, lab):
+    logp = jax.nn.log_softmax(
+        h.astype(jnp.float32) @ w.astype(jnp.float32).T, axis=-1)
+    return -jnp.take_along_axis(
+        logp, jnp.clip(lab, 0, None)[..., None], axis=-1)[..., 0]
+
+
+@pytest.mark.parametrize("block_rows", [8, 24, 96, 1000])
+@pytest.mark.parametrize("scaled", [False, True],
+                         ids=["no_scale", "scale_with_ignored_labels"])
+@pytest.mark.parametrize("cotangent", ["uniform", "per_position"])
+def test_blocked_tied_cross_entropy_matches_unblocked(cotangent, scaled,
+                                                      block_rows):
+    """Loss and both gradients against the plain ``log_softmax``: a
+    cotangent of one number for every position multiplies the gradients
+    the forward made, any other goes through the loop that forms the
+    logits again — both exact."""
+    h, w, lab, ignored, scale, g = _head_inputs(seq=7)
+    if scaled:
+        lab = ignored
+    else:
+        scale = None
+    if cotangent == "uniform":
+        g = jnp.full(g.shape, 0.3, jnp.float32)
 
     def plain(h, w):
-        logp = jax.nn.log_softmax(h @ w.T, axis=-1)
-        return -jnp.take_along_axis(logp, lab[..., None], axis=-1)[..., 0]
+        ce = _plain_ce(h, w, lab)
+        return ce if scale is None else ce * scale
 
     def blocked(h, w):
-        return nn_ops.tied_softmax_cross_entropy(h, w, lab,
-                                                 block_rows=block_rows)
+        return nn_ops.tied_softmax_cross_entropy(
+            h, w, lab, scale=scale, block_rows=block_rows)
 
     onp.testing.assert_allclose(blocked(h, w), plain(h, w), rtol=1e-5,
                                 atol=1e-5)
@@ -477,6 +512,110 @@ def test_blocked_tied_cross_entropy_matches_unblocked(block_rows):
         onp.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5)
     assert nn_ops.vocab_block_rows(131136, 8196) == 8196
     assert nn_ops.vocab_block_rows(96, block_rows) in (8, 24, 96)
+
+
+@pytest.mark.parametrize("seq,block_rows,positions", [
+    (8192, 8196, 512), (8, 24, 2), (8, 48, 4), (8, 96, 8), (7, 24, 1),
+    (8, 1, 1)])
+def test_token_blocks_hold_the_logits_a_block_of_rows_would(
+        seq, block_rows, positions):
+    """``block_rows`` is the budget of one block of logits whichever way
+    the blocks lie: a block of tokens is the largest divisor of the
+    sequence with B x positions x V <= N x block_rows (the cell: 512
+    positions of 2 rows, 16 blocks), and the scale's gradient is the
+    cross-entropy itself."""
+    vocab = 131136 if seq == 8192 else 96
+    assert nn_ops.token_block_positions(seq, vocab, block_rows) == positions
+    if seq == 8192:
+        return
+    h, w, _, lab, scale, g = _head_inputs(seq=seq)
+    got = jax.grad(lambda s: (nn_ops.tied_softmax_cross_entropy(
+        h, w, lab, scale=s, block_rows=block_rows) * g).sum())(scale)
+    counted = onp.asarray(lab) != -1     # an ignored label picks no logit
+    onp.testing.assert_allclose(
+        onp.asarray(got)[counted],
+        onp.asarray(_plain_ce(h, w, lab) * g)[counted], rtol=1e-5, atol=1e-5)
+
+
+def test_bfloat16_forward_gradients_equal_the_recomputed_ones():
+    """bf16 hidden states and table, the SAME inputs down both paths: the
+    gradients made in the forward (a uniform cotangent) against the
+    recomputing loop's (the same cotangent, told apart from uniform by one
+    position whose scale is 0) agree to bf16's rounding, and both with
+    the float32 reference on the rounded inputs."""
+    h, w, _, lab, scale, _ = _head_inputs("bfloat16")
+
+    def loss(g):
+        return lambda h, w: (nn_ops.tied_softmax_cross_entropy(
+            h, w, lab, scale=scale, block_rows=24) * g).sum()
+
+    uniform = jnp.full(lab.shape, 0.25, jnp.float32)
+    # position (0, 2) is ignored (scale 0): its cotangent changes nothing
+    odd = uniform.at[0, 2].set(7.0)
+    before = telemetry.counter("tied_ce.path.recompute")
+    fast = jax.jit(jax.grad(loss(uniform), (0, 1)))(h, w)
+    slow = jax.jit(jax.grad(loss(odd), (0, 1)))(h, w)
+    assert telemetry.counter("tied_ce.path.recompute") == before + 2
+    want = jax.grad(lambda h, w: (_plain_ce(h, w, lab) * scale
+                                  * uniform).sum(), (0, 1))(
+        h.astype(jnp.float32), w.astype(jnp.float32))
+    for a, b, c in zip(fast, slow, want):
+        assert a.dtype == b.dtype == jnp.bfloat16
+        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+        top = float(jnp.abs(c).max())
+        # one bf16 rounding of each (2 ** -9 relative), dl's rounding
+        # summed over the products
+        assert float(jnp.abs(a - b).max()) <= 2 ** -7 * top
+        assert float(jnp.abs(a - c).max()) <= 2 ** -6 * top
+        assert float(jnp.abs(b - c).max()) <= 2 ** -6 * top
+
+
+@pytest.mark.parametrize("mesh_shape", [None, (2,)], ids=["one_device",
+                                                          "dp2"])
+def test_data_parallel_step_takes_the_heads_gradients_from_its_forward(
+        mesh_shape):
+    """A ``DataParallelStep`` trace bumps ``tied_ce.path.forward_grads``
+    once (and ``recompute`` once, for the fallback traced under the
+    conditional beside it); a forward without differentiation bumps
+    neither; the step's SGD update is the reference's gradient.  Over a
+    ``dp`` mesh each shard sums its own dW: no (V, D) all-reduce inside
+    the head's loop."""
+    from mxnet_tpu import parallel
+
+    sizes, net, tokens, labels = _toy()
+    _, want = M.reference_loss_and_grads(M.host_params(net), tokens, labels,
+                                         sizes)
+    before = {name[len(net.prefix):]: p.data().asnumpy()
+              for name, p in net.collect_params().items()}
+    loss_fn = gluon.loss.TiedSoftmaxCrossEntropyLoss(
+        block_rows=sizes["train"]["loss_block_rows"])
+    counts = lambda: [telemetry.counter("tied_ce.path." + k)
+                      for k in ("forward_grads", "recompute")]
+    start = counts()
+    loss_fn(net(_ids(tokens)), _ids(labels))
+    assert counts() == start
+    mesh = mesh_shape and parallel.device_mesh(
+        mesh_shape, ("dp",), devices=jax.devices()[:mesh_shape[0]])
+    step = parallel.DataParallelStep(
+        net, loss_fn, mx.optimizer.SGD(learning_rate=1.0), mesh=mesh)
+    data, label = _ids(tokens), _ids(labels)
+    if mesh is not None:
+        data, label = (parallel.shard_batch(x, mesh) for x in (data, label))
+    step(data, label)
+    assert counts() == [start[0] + 1, start[1] + 1]
+    for name, grad in want.items():
+        moved = before[name] - net.collect_params()[
+            net.prefix + name].data().asnumpy()
+        # a difference of float32 parameters: their own rounding over a
+        # gradient a hundredth their size
+        assert _rel(moved, grad) <= 1e-4, name
+    if mesh is not None:
+        text = step.lower(data, label).compile().as_text()
+        vocab, units = before["embed_weight"].shape
+        for body in re.findall(r"\n%?[\w.\-]*(?:body|region)[\w.\-]* \(.*?\n}",
+                               text, re.S):
+            assert not re.search(r"f32\[%d,%d\][^=\n]* all-reduce\("
+                                 % (vocab, units), body)
 
 
 def test_model_flops_counts_the_share():
