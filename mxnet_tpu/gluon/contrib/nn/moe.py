@@ -3,10 +3,10 @@ and a ``SparseExperts`` block that is told which of them it holds.
 
 One chip of an expert-parallel deployment holds a slice of each layer's
 experts.  ``SparseExperts(..., num_experts=16, experts_held=(0, 8))`` routes
-every token over all 16, computes experts 0..7 for the tokens routed to
-them — all of them, whatever the imbalance: nothing has a capacity — and
-returns ONLY that part of the layer's output; tokens routed to experts held
-elsewhere get zero.  That partial result is what the caller adds to the
+every token over all 16 (``experts_per_token`` routes a token), computes
+experts 0..7 for the routes that go to them — all of them, whatever the
+imbalance: nothing has a capacity — and returns ONLY that part of the
+layer's output; routes to experts held elsewhere add zero.  That partial result is what the caller adds to the
 residual and hands to the next layer; the shares of all the chips add up to
 the whole layer (``tests/test_zaya.py``).  ``experts_held=None`` holds them
 all.  Across chips, ``parallel.moe`` puts an ``ep`` all-to-all round the
@@ -27,7 +27,8 @@ from .... import autograd, telemetry
 from ...block import HybridBlock
 from ...nn import Dense, RMSNorm
 
-__all__ = ["DepthRouter", "SparseExperts", "publish_routing_counts"]
+__all__ = ["DepthRouter", "LinearRouter", "SparseExperts",
+           "publish_routing_counts"]
 
 _LIVE = weakref.WeakSet()      # the SparseExperts blocks of this process
 
@@ -66,14 +67,44 @@ class DepthRouter(HybridBlock):
         return F.softmax(logits.astype("float32"), axis=-1), r
 
 
+class LinearRouter(HybridBlock):
+    """A router that is one linear map: ``scoring(x W)`` over ALL
+    ``num_experts``, in float32 (no bias).  ``scoring`` is ``"sigmoid"``
+    (each expert's score on its own: DeepSeek-V3, arXiv:2412.19437
+    section 2.1.2) or ``"softmax"``."""
+
+    def __init__(self, units, num_experts, scoring="sigmoid", **kwargs):
+        super().__init__(**kwargs)
+        if scoring not in ("sigmoid", "softmax"):
+            raise ValueError("scoring=%r is neither sigmoid nor softmax"
+                             % (scoring,))
+        self._scoring = scoring
+        with self.name_scope():
+            self.weight = self.params.get("weight",
+                                          shape=(num_experts, units))
+
+    def hybrid_forward(self, F, x, weight):
+        logits = F.FullyConnected(
+            x.astype("float32"), weight.astype("float32"), no_bias=True,
+            flatten=False, num_hidden=weight.shape[0])
+        return F.sigmoid(logits) if self._scoring == "sigmoid" \
+            else F.softmax(logits, axis=-1)
+
+
 class SparseExperts(HybridBlock):
-    """Dropless top-1 gated-SiLU experts, a slice of them held here.
+    """Dropless top-k experts, a slice of them held here.
 
     ``forward(x, probs)``: ``x`` (B, S, units), ``probs`` (B, S,
-    num_experts) the router's softmax.  Token t goes to ``e = argmax(probs
-    + balance_bias)`` and, if ``e`` is held, gets ``probs[e] *
-    Wdown_e(silu(Wgate_e x) * Wup_e x)``; otherwise zero (see the module's
-    docstring).
+    num_experts) the router's scores (a softmax, or sigmoids).  Token t
+    goes to the ``experts_per_token`` largest of ``probs + balance_bias``
+    and gets, from each of them that is held, ``gate_e * Expert_e(x)``;
+    from the others zero (see the module's docstring).  ``gate_e`` is
+    ``probs[e]`` — the bias moves the choice, never the gate — divided by
+    the sum over ALL the chosen where ``normalize_gates``, times
+    ``gate_scale``.  The expert network is ``Wdown(act(Wgate x) * Wup x)``
+    where ``gated`` and ``Wdown act(Wup x)`` otherwise, ``activation`` one
+    of ``silu``, ``gelu``, ``relu2``.  The defaults are one gated-SiLU
+    expert a token.
 
     ``balance_bias`` takes no gradient (``grad_req="null"``) and starts at
     zero.  It is kept by the auxiliary-loss-free balancing rule (Wang et
@@ -86,16 +117,23 @@ class SparseExperts(HybridBlock):
     ``probs``: the published 1e-3 goes with scores that spread over tenths.
 
     State, not trained, rewritten by every training step: ``expert_load``
-    (num_experts,) the tokens routed to each expert of the layer, and
-    ``rows_computed`` (held,) the rows each held expert's products
-    covered.  ``last_expert`` is each token's expert (B, S) at the last
-    EAGER call, for whoever wants to look at the routing itself (a compiled
-    step keeps none: its shape follows the batch)."""
+    (num_experts,) the ROUTES to each expert of the layer
+    (``experts_per_token`` a token), and ``rows_computed`` (held,) the rows
+    each held expert's products covered.  ``last_expert`` is each token's
+    experts at the last EAGER call — (B, S) with one a token, (B, S, k)
+    otherwise — for whoever wants to look at the routing itself (a
+    compiled step keeps none: its shape follows the batch)."""
 
     def __init__(self, units, hidden_size, num_experts, experts_held=None,
-                 bias_update_rate=0.0, **kwargs):
+                 bias_update_rate=0.0, experts_per_token=1, gated=True,
+                 activation="silu", normalize_gates=False, gate_scale=1.0,
+                 **kwargs):
         super().__init__(**kwargs)
         self._bias_update_rate = float(bias_update_rate)
+        self.experts_per_token = int(experts_per_token)
+        self._network = dict(k=self.experts_per_token,
+                             normalize=bool(normalize_gates),
+                             scale=float(gate_scale), activation=activation)
         first, end = experts_held or (0, num_experts)
         if not 0 <= first < end <= num_experts:
             raise ValueError("experts_held=%r is no slice of %d experts"
@@ -104,8 +142,9 @@ class SparseExperts(HybridBlock):
         self.last_expert = None
         held = end - first
         with self.name_scope():
-            self.gate_weight = self.params.get(
-                "gate_weight", shape=(held, units, hidden_size))
+            if gated:
+                self.gate_weight = self.params.get(
+                    "gate_weight", shape=(held, units, hidden_size))
             self.up_weight = self.params.get(
                 "up_weight", shape=(held, units, hidden_size))
             self.down_weight = self.params.get(
@@ -127,12 +166,12 @@ class SparseExperts(HybridBlock):
         for p in (self.expert_load, self.rows_computed, self.balance_bias):
             p.cast("float32")
 
-    def hybrid_forward(self, F, x, probs, gate_weight, up_weight,
-                       down_weight, balance_bias, expert_load,
-                       rows_computed):
+    def hybrid_forward(self, F, x, probs, up_weight, down_weight,
+                       balance_bias, expert_load, rows_computed,
+                       gate_weight=None):
         y, load, rows, expert = F.sparse_experts(
             x, probs, gate_weight, up_weight, down_weight, balance_bias,
-            first=self.experts_held[0])
+            first=self.experts_held[0], **self._network)
         if not isinstance(expert._data, jax.core.Tracer):
             self.last_expert = expert
         if autograd.is_training():
@@ -149,11 +188,13 @@ def publish_routing_counts():
     """Read the routing counts every live ``SparseExperts`` block kept at
     its last training step into ``telemetry`` and return them.
 
-    Gauges (summed over the blocks): ``moe.tokens_routed`` (routes to any
+    The counts are of ROUTES, ``experts_per_token`` a token.  Gauges
+    (summed over the blocks): ``moe.tokens_routed`` (routes to any
     expert), ``moe.tokens_local`` (routes to held experts),
     ``moe.dropped`` (routes to held experts that no product covered:
-    0 — the layer has no capacity to overflow).  ``moe.expert_load`` is one
-    event a block with its vector.  Returns ``{block name: {"load":
+    0 — the layer has no capacity to overflow), ``moe.routes_per_token``
+    (the largest ``experts_per_token`` among the blocks).
+    ``moe.expert_load`` is one event a block with its vector.  Returns ``{block name: {"load":
     [...], "held": (first, end), "rows": [...]}}``, empty before the first
     training step.  One device read a block, after the window: nothing is
     called back from inside the step."""
@@ -166,13 +207,17 @@ def publish_routing_counts():
         if load.sum() == 0:
             continue
         out[block.name] = {"load": load.tolist(), "rows": rows.tolist(),
-                           "held": block.experts_held}
+                           "held": block.experts_held,
+                           "routes_per_token": block.experts_per_token}
         telemetry.event("moe.expert_load", block.name, load=load.tolist(),
-                        held=list(block.experts_held))
+                        held=list(block.experts_held),
+                        routes_per_token=block.experts_per_token)
     held = [sum(v["load"][v["held"][0]:v["held"][1]]) for v in out.values()]
     telemetry.gauge("moe.tokens_routed",
                     sum(sum(v["load"]) for v in out.values()))
     telemetry.gauge("moe.tokens_local", sum(held))
+    telemetry.gauge("moe.routes_per_token", max(
+        (v["routes_per_token"] for v in out.values()), default=0))
     telemetry.gauge("moe.dropped",
                     sum(held) - sum(sum(v["rows"]) for v in out.values()))
     return out

@@ -22,7 +22,7 @@ from ....ops.pallas_attention import bshd_layout_fits
 from ...block import HybridBlock
 from ...nn import Dense, Dropout, LayerNorm
 
-__all__ = ["MultiHeadAttention", "PositionwiseFFN",
+__all__ = ["MultiHeadAttention", "GroupedQueryAttention", "PositionwiseFFN",
            "TransformerEncoderCell", "TransformerEncoder",
            "CompressedConvAttention"]
 
@@ -99,16 +99,62 @@ class MultiHeadAttention(HybridBlock):
         return out
 
 
+class GroupedQueryAttention(HybridBlock):
+    """Causal self-attention with fewer key-value heads than query heads:
+    ``softmax(q k^T / sqrt(head_dim)) v`` over ``num_heads`` query heads of
+    ``head_dim``, every ``num_heads / num_kv_heads`` of them reading one
+    key-value head, no bias, no position embedding (a model that wants
+    one rotates q and k itself).  One fused projection to [q | k | v];
+    attention runs in the flash kernels, the query heads sharing their
+    key-value head through the kernels' index maps (no repeated K/V)."""
+
+    def __init__(self, units, num_heads, num_kv_heads, head_dim, **kwargs):
+        super().__init__(**kwargs)
+        if num_heads % num_kv_heads:
+            raise ValueError("%d query heads do not divide over %d "
+                             "key-value heads" % (num_heads, num_kv_heads))
+        self._heads = (num_heads, num_kv_heads, head_dim)
+        with self.name_scope():
+            self.qkv = Dense((num_heads + 2 * num_kv_heads) * head_dim,
+                             flatten=False, use_bias=False, in_units=units,
+                             prefix="qkv_")
+            self.proj = Dense(units, flatten=False, use_bias=False,
+                              in_units=num_heads * head_dim, prefix="out_")
+
+    def hybrid_forward(self, F, x):
+        heads, kv_heads, d = self._heads
+        b, s = x.shape[0], x.shape[1]
+        qkv = self.qkv(x)
+
+        def part(begin, end):            # (B, S, h d) -> (B, h, S, d)
+            return F.slice_axis(qkv, axis=-1, begin=begin * d,
+                                end=end * d).reshape(
+                b, s, end - begin, d).transpose(axes=(0, 2, 1, 3))
+
+        out = F.flash_attention(part(0, heads),
+                                part(heads, heads + kv_heads),
+                                part(heads + kv_heads, heads + 2 * kv_heads),
+                                causal=True)
+        return self.proj(out.transpose(axes=(0, 2, 1, 3)).reshape(b, s, -1))
+
+
 class PositionwiseFFN(HybridBlock):
-    """The transformer MLP: Dense→activation→Dense (+dropout)."""
+    """The dense feed-forward block: Dense→activation→Dense (+dropout).
+    ``activation`` is any of ``Activation``'s (``gelu``, ``relu2`` — the
+    squared ReLU — ...); ``use_bias=False`` leaves both biases out;
+    ``in_units`` (the input's width: ``units``) fixes the shapes at
+    construction, 0 defers them to the first call as ``Dense`` does."""
 
     def __init__(self, units, hidden_size, activation="gelu", dropout=0.0,
-                 **kwargs):
+                 use_bias=True, in_units=0, **kwargs):
         super().__init__(**kwargs)
         with self.name_scope():
             self.expand = Dense(hidden_size, flatten=False,
-                                activation=activation, prefix="fc1_")
-            self.contract = Dense(units, flatten=False, prefix="fc2_")
+                                activation=activation, use_bias=use_bias,
+                                in_units=in_units, prefix="fc1_")
+            self.contract = Dense(units, flatten=False, use_bias=use_bias,
+                                  in_units=hidden_size if in_units else 0,
+                                  prefix="fc2_")
             self.drop = Dropout(dropout) if dropout else None
 
     def hybrid_forward(self, F, x):

@@ -10,6 +10,8 @@ from . import bert  # noqa: F401
 from .bert import BERTModel, bert_base, bert_small  # noqa: F401
 from . import zaya  # noqa: F401
 from .zaya import ZAYA1Model, zaya1  # noqa: F401
+from . import nemotron_h as _nemotron_h  # noqa: F401
+from .nemotron_h import NemotronHModel, nemotron_h  # noqa: F401
 
 __all__ = ["vision", "bert", "BERTModel", "bert_base", "bert_small",
-           "zaya", "ZAYA1Model", "zaya1"]
+           "zaya", "ZAYA1Model", "zaya1", "NemotronHModel", "nemotron_h"]

@@ -306,13 +306,20 @@ class LayerNorm(HybridBlock):
 
 class RMSNorm(HybridBlock):
     """``x / sqrt(mean(x^2) + epsilon) * gamma`` over ``axis``: the
-    normalisation of pre-norm decoder blocks (no mean, no shift)."""
+    normalisation of pre-norm decoder blocks (no mean, no shift).  With
+    ``groups`` > 1 each of that many equal parts of the last axis has its
+    own mean; ``forward(x, gate)`` norms ``x * silu(gate)`` (the gated
+    group norm at the end of a Mamba-2 mixer)."""
 
     def __init__(self, axis=-1, epsilon=1e-5, gamma_initializer="ones",
-                 in_channels=0, prefix=None, params=None):
+                 in_channels=0, groups=1, prefix=None, params=None):
         super().__init__(prefix=prefix, params=params)
+        if groups > 1 and axis != -1:
+            raise ValueError("groups divide the last axis, got axis=%d"
+                             % axis)
         self._axis = axis
         self._epsilon = epsilon
+        self._groups = groups
         self.gamma = self.params.get(
             "gamma", shape=(in_channels,), init=gamma_initializer,
             allow_deferred_init=True)
@@ -320,8 +327,9 @@ class RMSNorm(HybridBlock):
     def infer_shape(self, x, *args):
         self.gamma._finish_deferred_init((x.shape[self._axis],))
 
-    def hybrid_forward(self, F, x, gamma):
-        return F.RMSNorm(x, gamma, axis=self._axis, eps=self._epsilon)
+    def hybrid_forward(self, F, x, gate=None, gamma=None):
+        return F.RMSNorm(x, gamma, gate, axis=self._axis,
+                         eps=self._epsilon, groups=self._groups)
 
 
 class GroupNorm(HybridBlock):

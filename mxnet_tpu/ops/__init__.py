@@ -18,5 +18,6 @@ from . import detection     # noqa: F401
 from . import quantization  # noqa: F401
 from . import pallas_attention  # noqa: F401
 from . import moe           # noqa: F401
+from . import ssm           # noqa: F401
 
 __all__ = ["OpDef", "register", "get_op", "list_ops", "alias"]

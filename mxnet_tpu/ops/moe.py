@@ -1,11 +1,13 @@
-"""The sparse-expert core: dropless top-1 routing, the tokens grouped by
-expert, one grouped matrix product per weight over the experts HELD here.
+"""The sparse-expert core: dropless top-k routing, the ROUTES (k a token)
+grouped by expert, one grouped matrix product per weight over the experts
+HELD here, the k parts of a token summed with their gates.
 
 Both callers go through it: ``gluon.contrib.nn.SparseExperts`` (a block on
 one chip, told which slice of the experts it holds) and
 ``parallel.moe.moe_ffn_apply`` (the same grouping round an ``ep``
-all-to-all).  Nothing has a capacity: a token routed to a held expert is
-always computed, however uneven the routing.
+all-to-all).  Nothing has a capacity: a route to a held expert is always
+computed, however uneven the routing.  Top-1 is the case of one route a
+token, through the same grouping, gathers and products.
 
 The grouped product is ``lax.ragged_dot``: the v5e compiler lowers it to
 its own Mosaic kernel (``ragged-dot-none`` in the step program: tiles of
@@ -14,14 +16,19 @@ have a group), forward and both gradients.
 """
 from __future__ import annotations
 
+import functools
+from typing import Callable, NamedTuple
+
 import jax
 import jax.numpy as jnp
 from jax import lax
 
+from .nn import activation as _activation
 from .registry import register
 
-__all__ = ["top1_route", "group_by_expert", "permute_rows", "grouped_matmul",
-           "sparse_ffn"]
+__all__ = ["top1_route", "topk_route", "group_by_expert", "spread_rows",
+           "collect_rows", "grouped_matmul", "sparse_ffn", "gated_experts",
+           "mlp_experts"]
 
 
 def top1_route(probs, scores=None):
@@ -34,12 +41,26 @@ def top1_route(probs, scores=None):
     return expert, gate
 
 
-def group_by_expert(expert, first, held):
-    """Group N routed tokens by the ``held`` experts ``first..first+held-1``.
+def topk_route(probs, k, scores=None, normalize=False):
+    """The ``k`` largest of ``scores`` ((N, E); ``probs`` itself where none
+    are given; ties go to the lower index), gated by ``probs``: returns
+    (expert (N, k) int32, gate (N, k)).  ``normalize`` divides a token's
+    gates by their sum over ALL k chosen (+ 1e-20), held here or not.  The
+    scores take no gradient (a choice has none)."""
+    pick = probs if scores is None else scores
+    expert = lax.top_k(pick, k)[1].astype(jnp.int32)
+    gate = jnp.take_along_axis(probs, expert, axis=1)
+    if normalize:
+        gate = gate / (jnp.sum(gate, axis=-1, keepdims=True) + 1e-20)
+    return expert, gate
 
-    Returns ``order`` (N,): token indices, those of the first held expert
-    first, tokens of experts not held last; ``place`` (N,): its inverse
-    (where token i went); ``sizes`` (held,): tokens per held expert, so the
+
+def group_by_expert(expert, first, held):
+    """Group N routes by the ``held`` experts ``first..first+held-1``.
+
+    Returns ``order`` (N,): route indices, those of the first held expert
+    first, routes to experts not held last; ``place`` (N,): its inverse
+    (where route i went); ``sizes`` (held,): routes per held expert, so the
     first ``sizes.sum()`` rows of ``x[order]`` are the held experts'."""
     local = expert - first
     key = jnp.where((local >= 0) & (local < held), local, held)
@@ -51,26 +72,57 @@ def group_by_expert(expert, first, held):
     return order, place, sizes
 
 
-@jax.custom_vjp
-def permute_rows(x, order, place):
-    """``x[order]`` where ``place`` is the inverse permutation: a gather
-    forward and a gather backward (autodiff's scatter-add would serialise
-    on the chip)."""
-    return jnp.take(x, order, axis=0)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _gather_rows(x, index, back, fold, masked):
+    """``x[index]`` with ``back`` the way home: a gather forward and a
+    gather backward (autodiff's scatter-add would serialise on the chip).
+    The cotangent of ``x`` is the output's at ``back``, summed over
+    ``fold`` consecutive equal parts (the k routes of a token).  With
+    ``masked`` an index under zero reads nothing: zero, either way."""
+    return _take(x, index, masked)
 
 
-def _permute_fwd(x, order, place):
-    return jnp.take(x, order, axis=0), (order, place)
+def _take(rows, index, masked):
+    if not masked:
+        return jnp.take(rows, index, axis=0)
+    return jnp.where((index >= 0)[:, None],
+                     jnp.take(rows, jnp.maximum(index, 0), axis=0),
+                     jnp.zeros((), rows.dtype))
 
 
-def _permute_bwd(res, g):
+def _gather_fwd(x, index, back, fold, masked):
+    return _take(x, index, masked), (index, back)
+
+
+def _gather_bwd(fold, masked, res, g):
     import numpy as onp
-    order, place = res
-    zero = onp.zeros(order.shape, jax.dtypes.float0)
-    return jnp.take(g, place, axis=0), zero, zero
+    index, back = res
+    grad = _take(g, back, masked)
+    if fold > 1:
+        grad = jnp.sum(grad.reshape((fold, -1) + grad.shape[1:]), axis=0,
+                       dtype=jnp.float32).astype(g.dtype)
+    return (grad, onp.zeros(index.shape, jax.dtypes.float0),
+            onp.zeros(back.shape, jax.dtypes.float0))
 
 
-permute_rows.defvjp(_permute_fwd, _permute_bwd)
+_gather_rows.defvjp(_gather_fwd, _gather_bwd)
+
+
+def spread_rows(x, order, place):
+    """``x[order]``: the routes sorted by expert.  ``place`` is ``order``'s
+    inverse.  Where ``order`` permutes k routes a row of ``x`` (k N entries
+    over N rows, route r of token r % N), the rows come out once a route
+    and the backward sums a token's k."""
+    n = x.shape[0]
+    fold = order.shape[0] // n
+    return _gather_rows(x, order % n if fold > 1 else order, place, fold,
+                        False)
+
+
+def collect_rows(out, order, place):
+    """``spread_rows`` undone: the sorted rows back in route order,
+    ``out[place]``."""
+    return _gather_rows(out, place, order, 1, False)
 
 
 def grouped_matmul(rows, weights, sizes):
@@ -80,7 +132,12 @@ def grouped_matmul(rows, weights, sizes):
     UNWRITTEN — whatever the buffer held, NaN included — in the product
     and in the gradient it hands back for ``rows`` alike, so they are
     masked on the way in (which masks that gradient) and on the way
-    out."""
+    out.  Rows already laid out in blocks, (B, S, K) against (B, K, M)
+    — each block's owner's weight — are one dense batched product: every
+    slot is computed, the caller reads the ones it filled."""
+    if rows.ndim == 3:      # blocks of slots: static in a compiled step
+        return jnp.einsum("gsk,gkm->gsm", rows, weights,
+                          preferred_element_type=rows.dtype)
     grouped = lax.broadcasted_iota(jnp.int32, (rows.shape[0], 1), 0) \
         < jnp.sum(sizes)
     out = lax.ragged_dot(jnp.where(grouped, rows, jnp.zeros((), rows.dtype)),
@@ -88,52 +145,216 @@ def grouped_matmul(rows, weights, sizes):
     return jnp.where(grouped, out, jnp.zeros((), out.dtype))
 
 
-def sparse_ffn(x, expert, gate, ffn, first, held):
-    """The held experts' part of a top-1 expert layer.
-
-    ``x`` (N, D) tokens, ``expert`` / ``gate`` from ``top1_route``,
-    ``ffn(rows, sizes)`` the experts' network on rows sorted by expert
-    (built from ``grouped_matmul``).  Returns ``(y, sizes)``: ``y`` (N, D)
-    is ``gate * Expert_e(x)`` for tokens whose expert is held and zero for
-    the others — the partial result an expert-parallel layer sums over its
-    shares — and ``sizes`` (held,) the rows each held expert computed."""
-    order, place, sizes = group_by_expert(expert, first, held)
-    out = ffn(permute_rows(x, order, place), sizes)
-    y = permute_rows(out, place, order)
-    return y * gate.astype(y.dtype)[:, None], sizes
+_ROWS_OVER_EVEN = 4        # slots in all, over an even router's held routes
 
 
-def gated_experts(w_gate, w_up, w_down):
-    """``ffn(rows, sizes)`` of gated SiLU experts: ``(silu(x Wg) * x Wu)
-    Wd`` with Wg, Wu (held, D, F) and Wd (held, F, D)."""
-    def ffn(rows, sizes):
+def _on_rows(network, x, gates, weights, local, grouped):
+    """The experts on ALL the routes sorted by expert (one ragged product
+    a weight), back in route order and gated: (k N, D)."""
+    order, place, sizes = grouped
+    out = network(spread_rows(x, order, place), sizes, *weights)
+    return collect_rows(out, order, place) * gates.astype(out.dtype)[:, None]
+
+
+def _on_blocks(network, blocks, x, gates, weights, local, grouped):
+    """The same on ``blocks = (count, width)`` blocks of slots,
+    (count, width, D): an expert takes as many blocks as its routes need,
+    a block computes against its owner's weights, and the experts are one
+    dense batched product a weight whose time does not follow the routing.
+    Right where the experts' needs add up to no more than ``count``
+    blocks.  ``local`` (k N,) is each route's expert counted from the first
+    held.  A slot that no route fills holds some other route's row, is
+    computed and is never read: its cotangent is zero."""
+    order, place, sizes = grouped
+    count, width = blocks
+    held, n, total = sizes.shape[0], x.shape[0], local.shape[0]
+    start = jnp.cumsum(sizes) - sizes             # an expert's first route
+    need = (sizes + width - 1) // width           # blocks an expert
+    first = jnp.cumsum(need) - need               # an expert's first block
+    block = jnp.arange(count, dtype=jnp.int32)
+    owner = jnp.minimum(jnp.searchsorted(
+        jnp.cumsum(need), block, side="right").astype(jnp.int32), held - 1)
+    offset = (block - jnp.take(first, owner))[:, None] * width \
+        + jnp.arange(width, dtype=jnp.int32)[None, :]
+    filled = offset < jnp.take(sizes, owner)[:, None]
+    slot_route = jnp.take(order, jnp.clip(
+        jnp.take(start, owner)[:, None] + offset, 0, total - 1))
+    mine = (local >= 0) & (local < held)
+    local = jnp.clip(local, 0, held - 1)
+    within = place - jnp.take(start, local)
+    route_slot = jnp.where(
+        mine, (jnp.take(first, local) + within // width) * width
+        + within % width, -1)
+    rows = _gather_rows(x, (slot_route % n).reshape(-1), route_slot,
+                        total // n, True)
+    out = network(rows.reshape(count, width, -1), sizes,
+                  *(jnp.take(w, owner, axis=0) for w in weights))
+    out = _gather_rows(out.reshape(count * width, -1), route_slot,
+                       jnp.where(filled, slot_route, -1).reshape(-1), 1, True)
+    return out * gates.astype(out.dtype)[:, None]
+
+
+def _blocks_fit(blocks, sizes):
+    count, width = blocks
+    return jnp.sum((sizes + width - 1) // width) <= count
+
+
+def _either(network, blocks, sizes, run):
+    """``run`` on ``_on_blocks`` where the held experts' routes fit the
+    ``blocks`` — tested on the device — and on ``_on_rows`` otherwise
+    (always, with no blocks)."""
+    full = functools.partial(_on_rows, network)
+    if blocks is None:
+        return run(full)
+    return lax.cond(_blocks_fit(blocks, sizes),
+                    lambda: run(functools.partial(_on_blocks, network,
+                                                  blocks)),
+                    lambda: run(full))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _budgeted(network, blocks, x, gates, weights, local, grouped):
+    """The experts on blocks or on sorted rows (``_either``): the same
+    result either way.  The backward recomputes the side that ran from
+    these inputs; no residual crosses the ``lax.cond`` (autodiff through
+    a ``cond`` would hand back both sides' residuals, weights included,
+    the side not taken as zeros)."""
+    return _either(network, blocks, grouped[2], lambda side: side(
+        x, gates, weights, local, grouped))
+
+
+def _budgeted_fwd(network, blocks, *args):
+    return _budgeted(network, blocks, *args), args
+
+
+def _budgeted_bwd(network, blocks, args, g):
+    import numpy as onp
+    x, gates, weights, local, grouped = args
+
+    def back(side):
+        return jax.vjp(lambda x, gates, weights: side(
+            x, gates, weights, local, grouped), x, gates, weights)[1](g)
+
+    return _either(network, blocks, grouped[2], back) + tuple(
+        onp.zeros(t.shape, jax.dtypes.float0) if not isinstance(t, tuple)
+        else tuple(onp.zeros(u.shape, jax.dtypes.float0) for u in t)
+        for t in (local, grouped))
+
+
+_budgeted.defvjp(_budgeted_fwd, _budgeted_bwd)
+
+
+def sparse_ffn(x, expert, gate, ffn, first, held, num_experts=None):
+    """The held experts' part of an expert layer.
+
+    ``x`` (N, D) tokens, ``expert`` / ``gate`` (N,) from ``top1_route`` or
+    (N, k) from ``topk_route``, ``ffn`` the experts' network with its
+    weights (``gated_experts`` / ``mlp_experts``: ``ffn.network(rows,
+    sizes, *ffn.weights)`` on rows sorted by expert, built from
+    ``grouped_matmul``).  The k N routes go through ONE grouping: a
+    token's row is gathered once a route, and its k results are summed
+    with their gates.  Returns ``(y, sizes)``: ``y`` (N, D) is the sum of
+    ``gate * Expert_e(x)`` over the token's routes to held experts, zero
+    where none is held — the partial result an expert-parallel layer sums
+    over its shares — and ``sizes`` (held,) the rows each held expert
+    computed.
+
+    With more than one route a token, what autodiff would keep for the
+    backward — the gathered rows, the experts' activations and outputs,
+    each k N rows — is k times the layer's own activations (0.7 GB a layer
+    at 8,192 tokens x 6), most of it rows of experts held elsewhere; the
+    routes' part is recomputed in the backward from ``x`` instead.  At one
+    route a token it is kept.
+
+    Dropless needs room for all k N routes, but an even router sends the
+    held experts k N held / num_experts of them: where the layer says how
+    many experts there are, the experts run on FOUR times that many slots,
+    in 2 held blocks that an expert takes by need (a lumpy router gives
+    one expert several times its share, the held experts together much
+    less), as one dense batched product a weight — constant work, whatever
+    the routing — while the experts' needs fit the blocks; a step that
+    needs more runs the ragged products on all k N sorted rows (one
+    ``lax.cond`` on the device, exact on both sides)."""
+    # one route a token (N,) or k of them (N, k): static in a compiled step
+    # graftlint: disable-next=retrace-shape-branch -- rank dispatch
+    if expert.ndim == 1:
+        grouped = group_by_expert(expert, first, held)
+        return _on_rows(ffn.network, x, gate, ffn.weights, None,
+                        grouped), grouped[2]
+    routes = expert.T.reshape(-1)
+    grouped = group_by_expert(routes, first, held)
+    blocks, total = None, routes.shape[0]
+    if num_experts is not None:
+        # two blocks a held expert, each half the rows in all over held
+        width = -(-_ROWS_OVER_EVEN * total // num_experts // 256) * 128
+        if 2 * held * width < total:    # fewer rows than all the routes
+            blocks = (2 * held, width)
+    y = _budgeted(ffn.network, blocks, x, gate.T.reshape(-1), ffn.weights,
+                  routes - first, grouped)
+    return jnp.sum(y.reshape(expert.shape[1], x.shape[0], -1), axis=0,
+                   dtype=jnp.float32).astype(y.dtype), grouped[2]
+
+
+class _Experts(NamedTuple):
+    network: Callable          # (rows, sizes, *weights) -> rows
+    weights: tuple
+
+
+def gated_experts(w_gate, w_up, w_down, activation=jax.nn.silu):
+    """Gated experts: ``(activation(x Wg) * x Wu) Wd`` with Wg, Wu
+    (held, D, F) and Wd (held, F, D)."""
+    def network(rows, sizes, w_gate, w_up, w_down):
         gate = grouped_matmul(rows, w_gate, sizes)
         up = grouped_matmul(rows, w_up, sizes)
-        return grouped_matmul(jax.nn.silu(gate) * up, w_down, sizes)
-    return ffn
+        return grouped_matmul(activation(gate) * up, w_down, sizes)
+    return _Experts(network, (w_gate, w_up, w_down))
+
+
+def mlp_experts(w_up, w_down, activation):
+    """Experts without a gate matrix: ``activation(x Wu) Wd`` with Wu
+    (held, D, F) and Wd (held, F, D)."""
+    def network(rows, sizes, w_up, w_down):
+        return grouped_matmul(activation(grouped_matmul(rows, w_up, sizes)),
+                              w_down, sizes)
+    return _Experts(network, (w_up, w_down))
 
 
 @register("_contrib_sparse_experts", num_outputs=4,
           aliases=("sparse_experts",))
-def sparse_experts(data, probs, w_gate, w_up, w_down, bias, first: int = 0):
-    """Top-1 gated-SiLU experts, the slice ``first..first+held-1`` of them
-    held here (held = ``w_gate.shape[0]``): ``data`` (..., S, D), ``probs``
-    (..., S, E) the router's softmax over ALL E experts, ``bias`` (E,) its
-    balancing bias.  A token goes to ``argmax(probs + bias)`` and is gated
-    by ``probs`` of that expert.  Returns the held experts' part of the
-    layer's output, the tokens routed to each of the E experts (fp32
-    counts), the rows each held expert computed, and each token's expert
-    (..., S) int32."""
+def sparse_experts(data, probs, w_gate, w_up, w_down, bias, first: int = 0,
+                   k: int = 1, normalize: bool = False, scale: float = 1.0,
+                   activation: str = "silu"):
+    """Dropless top-``k`` experts, the slice ``first..first+held-1`` of
+    them held here (held = ``w_up.shape[0]``): ``data`` (..., S, D),
+    ``probs`` (..., S, E) the router's scores of ALL E experts (a softmax,
+    or sigmoids), ``bias`` (E,) its balancing bias.  A token goes to the
+    ``k`` largest of ``probs + bias`` and is gated by ``probs`` of each —
+    the bias moves the choice, never the gate — divided by their sum over
+    the k chosen where ``normalize``, times ``scale``.  With ``w_gate``
+    the experts are gated, ``Wd(act(Wg x) * Wu x)``; with ``w_gate=None``
+    they are ``Wd act(Wu x)``; ``activation`` is any ``act_type`` of the
+    ``Activation`` operator (``silu``, ``relu2``, ...).  Returns the held experts' part of the layer's output, the
+    ROUTES to each of the E experts (fp32 counts: k a token), the rows
+    each held expert computed, and each token's experts, (..., S) int32
+    at ``k = 1`` and (..., S, k) otherwise."""
     lead, d = data.shape[:-1], data.shape[-1]
     n_experts = probs.shape[-1]
     probs = probs.reshape(-1, n_experts)
-    expert, gate = top1_route(
-        probs, lax.stop_gradient(probs.astype(jnp.float32))
-        + bias.astype(jnp.float32))
-    y, sizes = sparse_ffn(data.reshape(-1, d), expert, gate,
-                          gated_experts(w_gate, w_up, w_down), first,
-                          w_gate.shape[0])
-    load = jnp.sum(expert[:, None] == jnp.arange(n_experts)[None, :],
+    pick = lax.stop_gradient(probs.astype(jnp.float32)) \
+        + bias.astype(jnp.float32)
+    if k == 1 and not normalize:
+        expert, gate = top1_route(probs, pick)
+    else:
+        expert, gate = topk_route(probs, k, pick, normalize)
+    if scale != 1.0:
+        gate = gate * scale
+    act = functools.partial(_activation, act_type=activation)
+    ffn = mlp_experts(w_up, w_down, act) if w_gate is None else \
+        gated_experts(w_gate, w_up, w_down, act)
+    y, sizes = sparse_ffn(data.reshape(-1, d), expert, gate, ffn, first,
+                          w_up.shape[0], n_experts)
+    load = jnp.sum(expert.reshape(-1)[:, None]
+                   == jnp.arange(n_experts)[None, :],
                    axis=0, dtype=jnp.float32)
     return (y.reshape(lead + (d,)), lax.stop_gradient(load),
-            sizes.astype(jnp.float32), expert.reshape(lead))
+            sizes.astype(jnp.float32), expert.reshape(lead + expert.shape[1:]))

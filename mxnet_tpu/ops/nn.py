@@ -432,6 +432,11 @@ def activation(data, act_type: str = "relu"):
         # the reference exposes gelu via LeakyReLU(act_type='gelu'); also
         # accepted here so Dense(activation='gelu') composes directly
         return jax.nn.gelu(data, approximate=False)
+    if act_type == "silu":
+        return jax.nn.silu(data)
+    if act_type == "relu2":
+        # squared ReLU (So et al. arXiv:2109.08668)
+        return jnp.square(jax.nn.relu(data))
     raise ValueError("unknown act_type %r" % act_type)
 
 
@@ -879,10 +884,31 @@ def logistic_regression_output(data, label, grad_scale: float = 1.0):
 # embedding taken in blocks of its rows
 # ---------------------------------------------------------------------------
 
+def _gated_group_rms_norm(data, gamma, gate, eps, groups):
+    x32 = data.astype(jnp.float32)
+    if gate is not None:
+        x32 = x32 * jax.nn.silu(gate.astype(jnp.float32))
+    parts = x32.reshape(x32.shape[:-1] + (groups, -1))
+    inv = lax.rsqrt(jnp.mean(parts * parts, axis=-1, keepdims=True) + eps)
+    return ((parts * inv).reshape(x32.shape)
+            * gamma.astype(jnp.float32)).astype(data.dtype)
+
+
 @register("RMSNorm", aliases=("rms_norm",))
-def rms_norm(data, gamma, axis: int = -1, eps: float = 1e-5):
+def rms_norm(data, gamma, gate=None, axis: int = -1, eps: float = 1e-5,
+             groups: int = 1):
     """``x / sqrt(mean(x^2) + eps) * gamma`` over ``axis``; statistics in
-    fp32 whatever the input, one cast out."""
+    fp32 whatever the input, one cast out.  With ``groups`` > 1 the mean
+    is taken over each of that many equal parts of the LAST axis on its
+    own (``gamma`` still one gain a channel); with ``gate`` the input is
+    ``x * silu(gate)`` first — the gate BEFORE the norm, as a Mamba-2
+    mixer's output norm has it."""
+    if gate is not None or groups > 1:
+        # recomputed in the backward: what autodiff would keep are three
+        # float32 copies of the input, six times its bfloat16 bytes
+        return jax.checkpoint(functools.partial(
+            _gated_group_rms_norm, eps=eps, groups=groups))(data, gamma,
+                                                            gate)
     x32 = data.astype(jnp.float32)
     inv = lax.rsqrt(jnp.mean(x32 * x32, axis=axis, keepdims=True) + eps)
     shape = [1] * data.ndim
@@ -925,13 +951,14 @@ def rotary_embedding(data, rotary_dim: int = 0, theta: float = 10000.0):
 
 
 @register("causal_conv1d")
-def causal_conv1d(data, weight, groups: int = 1):
+def causal_conv1d(data, weight, bias=None, groups: int = 1):
     """Causal convolution over the sequence of a (B, S, C) tensor,
     left-padded with zeros so that the output at t reads inputs t-K+1..t.
     ``weight`` is (C_out, C_in / groups, K) as ``Conv1D``'s; tap K-1
-    meets the input at t.  ``groups == C`` is the depthwise case (a
-    multiply-add per tap); otherwise one (C/g x C/g) product per group and
-    tap, accumulated in fp32."""
+    meets the input at t; ``bias`` (C_out,) where one is given.
+    ``groups == C`` is the depthwise case (a multiply-add per tap);
+    otherwise one (C/g x C/g) product per group and tap, accumulated in
+    fp32."""
     b, s, c = data.shape
     c_out, c_in_g, k = weight.shape
     out = None
@@ -948,6 +975,8 @@ def causal_conv1d(data, weight, groups: int = 1):
                 "bsgi,goi->bsgo", xs.reshape(b, s, groups, c_in_g), wj,
                 preferred_element_type=jnp.float32).reshape(b, s, c_out)
         out = term if out is None else out + term
+    if bias is not None:
+        out = out + bias.astype(jnp.float32)
     return out.astype(data.dtype)
 
 

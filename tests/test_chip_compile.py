@@ -528,3 +528,67 @@ def test_zaya1_head_makes_its_gradients_in_one_loop_of_three_products(
         assert sum(p.startswith("f32[%d,%d]" % (rows * seq, 8196))
                    for p in again) == 1, again
     assert compiled.memory_analysis().temp_size_in_bytes <= ceiling
+
+
+# one layer of the Nemotron-H cell at the published widths: what a layer's
+# compiled train step holds (kernel names) and needs (temporaries, GB)
+_NEMOTRON_LAYERS = {
+    # the chunked scan is plain XLA: no kernel; its (Q, Q) decay blocks are
+    # recomputed in the backward, not kept
+    "M": ({}, 1.370),
+    # while the held experts' routes fit 16 blocks of 768 slots the experts
+    # are dense batched products (no kernel); the side that runs when they
+    # do not holds the compiler's own ragged-dot kernel: two grouped
+    # products forward, again in the recomputed backward, four gradients.
+    # The routes' buffers are recomputed, not kept
+    "E": ({"ragged-dot-none": 8, "ragged-dot-metadata": 3}, 2.073),
+}
+
+
+@pytest.mark.parametrize("kind", list(_NEMOTRON_LAYERS))
+def test_nemotron_layer_train_step_compiles(one_chip, monkeypatch, kind):
+    """One Mamba-2 layer and one expert layer (8 of 128 relu2 experts held,
+    6 routes a token, the shared expert) of Nemotron-3-Nano at the published
+    widths under a small untied head, 1 row of 8,192 tokens, bf16 with
+    ``Adam(multi_precision=True)``: the ``DataParallelStep`` program
+    compiles for the described chip, every ``tpu_custom_call`` under a
+    stable name, the scan's four phases and the blocks' names in the
+    instructions' ``op_name``s, the temporaries within 5% of what the
+    recomputing backward needs."""
+    import mxnet_tpu as mx
+    from mxnet_tpu import context, gluon, parallel
+    from mxnet_tpu import random as mx_random
+
+    monkeypatch.setattr(context, "on_tpu", lambda *a: True)
+    net = gluon.model_zoo.nemotron_h(pattern=kind, vocab_size=2048,
+                                     experts_held=(0, 8),
+                                     bias_update_rate=1e-3)
+    net.initialize(mx.init.Zero())
+    net.cast("bfloat16")
+    step = parallel.DataParallelStep(
+        net, gluon.loss.TiedSoftmaxCrossEntropyLoss(block_rows=2048),
+        mx.optimizer.Adam(learning_rate=1e-4, multi_precision=True))
+
+    def spec(v):
+        return jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=one_chip)
+
+    state = jax.tree_util.tree_map(
+        spec, [[p._data._data for p in step._params], step._opt_states])
+    carries = [jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
+               jax.ShapeDtypeStruct((len(step._trainable),), jnp.float32,
+                                    sharding=one_chip),
+               spec(mx_random.next_key())]
+    tokens = jax.ShapeDtypeStruct((1, 8192), jnp.int32, sharding=one_chip)
+    compiled = step._build().lower(*state, *carries, tokens,
+                                   tokens).compile()
+    text = compiled.as_text()
+    names, temp_gb = _NEMOTRON_LAYERS[kind]
+    assert collections.Counter(_kernel_names(text)) == names
+    blocks = {"M": ("layer0_mamba_in", "layer0_mamba_norm", "ssd.in_chunk",
+                    "ssd.chunk_states", "ssd.state_passing", "ssd.output"),
+              "E": ("layer0_router", "layer0_experts", "layer0_shared_fc1")}
+    for block in blocks[kind]:
+        assert re.search(r"[/_]%s/" % re.escape(block), text), block
+    temp = compiled.memory_analysis().temp_size_in_bytes / 1e9
+    print("temporaries of the %s layer's step: %.3f GB" % (kind, temp))
+    assert temp <= temp_gb * 1.05, temp

@@ -1,4 +1,4 @@
-"""Expert parallelism: dropless top-1 MoE FFN over the ``ep`` mesh axis
+"""Expert parallelism: dropless top-k MoE FFN over the ``ep`` mesh axis
 (all_to_all token exchange round the core of ``ops/moe.py``) vs the
 single-device oracle, a plain loop over the experts, on the virtual
 8-device CPU mesh."""
@@ -35,6 +35,27 @@ def test_moe_matches_oracle(ndev, E, N, H, F):
     want = parallel.moe_ffn_ref(params, x)
     onp.testing.assert_allclose(onp.asarray(got), onp.asarray(want),
                                 rtol=1e-5, atol=1e-6)
+
+
+def test_moe_two_experts_a_token_matches_oracle():
+    """k = 2 through the same core: 2 S routes a device go through one
+    grouping, a token's two results come back and are summed with their
+    gates; value and gradients against the plain loop."""
+    mesh, params, x = _setup(4, 8, 48, 8, 16)
+    got = parallel.moe_ffn_apply(params, x, mesh, k=2)
+    want = parallel.moe_ffn_ref(params, x, k=2)
+    onp.testing.assert_allclose(onp.asarray(got), onp.asarray(want),
+                                rtol=1e-5, atol=1e-6)
+    assert onp.abs(onp.asarray(want - parallel.moe_ffn_ref(params, x))
+                   ).max() > 1e-3          # the second expert adds something
+    g1 = jax.grad(lambda p: jnp.sum(
+        parallel.moe_ffn_apply(p, x, mesh, k=2) ** 2))(params)
+    g2 = jax.grad(lambda p: jnp.sum(
+        parallel.moe_ffn_ref(p, x, k=2) ** 2))(params)
+    for name in g1:
+        onp.testing.assert_allclose(onp.asarray(g1[name]),
+                                    onp.asarray(g2[name]),
+                                    rtol=1e-4, atol=2e-4, err_msg=name)
 
 
 def test_moe_grads_match_oracle():

@@ -606,6 +606,9 @@ COVERED_ELSEWHERE = {
     "causal_conv1d", "_contrib_cca_qkv", "cca_qkv",
     "_contrib_sparse_experts", "sparse_experts",
     "tied_softmax_cross_entropy",
+    # the chunked state-space scan of PR 30: tests/test_nemotron_h.py,
+    # value and every gradient against the step-by-step recurrence
+    "_contrib_ssd_chunk_scan", "ssd_chunk_scan",
     # exercised by dedicated test files: test_operator.py (NN core),
     # test_rnn.py (RNN), test_gluon.py (layers), test_symbol.py /
     # test_module.py (output ops), test_amp.py (amp_cast), test_loss.py,
