@@ -1,5 +1,8 @@
 """The state-space scan of a Mamba-2 mixer (SSD, Dao & Gu arXiv:2405.21060),
-computed in chunks: plain ``jax.numpy`` / ``lax``, no kernel.
+computed in chunks: on a TPU, at shapes that are whole lane blocks, by the
+Pallas kernels of ``ops/pallas_ssd.py`` (``pallas_ssd.ssd_dispatch``
+decides from the platform and the shapes); everywhere else by ``_chunked``
+here, plain ``jax.numpy`` / ``lax``, which is also the kernels' reference.
 
 The recurrence, one head (state ``h`` (P, N), ``A`` a negative scalar)::
 
@@ -17,15 +20,21 @@ chunks meet only through their states (``a_t = dt_t A``)::
 Decays, cumulative sums and the states are float32 whatever the inputs
 (a bfloat16 state or cumulative decay drifts over thousands of steps);
 the products take their operands in the inputs' dtype and accumulate in
-float32.  The four phases are ``jax.named_scope``s (``ssd.in_chunk``,
-``ssd.chunk_states``, ``ssd.state_passing``, ``ssd.output``), so every
-device operation's ``op_name`` says which phase it belongs to.
+float32 — on both paths.  ``_chunked``'s four phases are
+``jax.named_scope``s (``ssd.in_chunk``, ``ssd.chunk_states``,
+``ssd.state_passing``, ``ssd.output``), so every device operation's
+``op_name`` says which phase it belongs to; the kernels are named
+``ssd_fwd``, ``ssd_states``, ``ssd_bwd``.
 
-Differentiation is autodiff through the chunked form under
+Differentiation of ``_chunked`` is autodiff through the chunked form under
 ``jax.checkpoint``: the backward recomputes the chunked forward from the
 operator's INPUTS, because what autodiff would keep otherwise — the (Q, Q)
 decay and score matrices of every head and chunk, in float32 — is 0.8 GB a
-layer at 8,192 tokens and 64 heads against 0.1 GB of inputs.
+layer at 8,192 tokens and 64 heads against 0.1 GB of inputs.  The kernel
+path keeps the same residuals (a ``custom_vjp`` that makes the chunk
+states again).  Which path a traced shape took: the counters
+``ssm.scan.kernel`` / ``ssm.scan.chunked`` and ``path`` on the ``ssm.scan``
+event.
 """
 from __future__ import annotations
 
@@ -35,6 +44,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from .. import context as _context
 from .registry import register
 
 __all__ = ["ssd_chunk_scan"]
@@ -111,8 +121,11 @@ def ssd_chunk_scan(data, dt, a_log, b, c, d_skip, dt_bias, chunk: int = 128):
     sequence that is no multiple of ``chunk`` is padded to one inside —
     steps of ``dt = 0`` and ``x = 0`` leave the state as it is — and the
     padding cut off again; one shorter than a chunk is one chunk of its
-    own length."""
+    own length.  On a TPU a shape ``pallas_ssd.ssd_dispatch`` accepts runs
+    as Pallas kernels, forward and backward; the result, the dtype
+    contract and the gradients are the same."""
     from .. import telemetry
+    from . import pallas_ssd
 
     bt, s, h, p = data.shape
     g, n = b.shape[-2:]
@@ -120,24 +133,29 @@ def ssd_chunk_scan(data, dt, a_log, b, c, d_skip, dt_bias, chunk: int = 128):
         raise ValueError("%d heads do not divide over %d groups" % (h, g))
     chunk = min(int(chunk), s)
     pad = -s % chunk
+    path = pallas_ssd.ssd_dispatch(s + pad, chunk, h, p, g, n, data.dtype,
+                                   on_tpu=_context.on_tpu(data))
     # trace time: once a traced shape, as attention.kernel.*
-    telemetry.inc("ssm.scan.chunked")
-    telemetry.event("ssm.scan", "chunked", seq_len=int(s), chunk=chunk,
-                    heads=int(h), state=int(n), groups=int(g),
+    telemetry.inc("ssm.scan.%s" % path)
+    telemetry.event("ssm.scan", path, path=path, seq_len=int(s),
+                    chunk=chunk, heads=int(h), state=int(n), groups=int(g),
                     head_dim=int(p), padded=bool(pad))
+    x = data
+    if pad:
+        # softplus(-inf) = 0: a padded step neither decays nor writes
+        x, b, c = (jnp.pad(t, ((0, 0), (0, pad), (0, 0), (0, 0)))
+                   for t in (x, b, c))
+        dt = jnp.pad(dt.astype(jnp.float32), ((0, 0), (0, pad), (0, 0)),
+                     constant_values=-jnp.inf)
+    if path == "kernel":
+        y = pallas_ssd.ssd_scan_kernels(x, dt, a_log, b, c, d_skip, dt_bias,
+                                        chunk)
+        return y[:, :s]
 
     def grouped(t):                      # (..., H) -> (..., G, R)
         return t.reshape(t.shape[:-1] + (g, h // g))
 
-    x = data.reshape(bt, s, g, h // g, p)
-    dt = grouped(dt)
-    if pad:
-        # softplus(-inf) = 0: a padded step neither decays nor writes
-        x, b, c = (jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
-                   for t in (x, b, c))
-        dt = jnp.pad(dt.astype(jnp.float32), ((0, 0), (0, pad), (0, 0),
-                                              (0, 0)),
-                     constant_values=-jnp.inf)
     y = jax.checkpoint(functools.partial(_chunked, chunk=chunk))(
-        x, dt, grouped(a_log), b, c, grouped(d_skip), grouped(dt_bias))
+        x.reshape(bt, s + pad, g, h // g, p), grouped(dt), grouped(a_log),
+        b, c, grouped(d_skip), grouped(dt_bias))
     return y[:, :s].reshape(bt, s, h, p)

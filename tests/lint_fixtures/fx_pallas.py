@@ -66,3 +66,18 @@ def tidy(x):
         out_specs=[pl.BlockSpec((1, 128), lambda i: (i, 0))],
         out_shape=[jax.ShapeDtypeStruct((4, 128), jnp.float32)],
     )(x)
+
+
+def squeezed_dims(x, rows, lanes):
+    # clean: a None block dim is squeezed — one element a grid step, not
+    # the stand-in for a dim the folder cannot read (128**5 elements here)
+    return pl.pallas_call(
+        _k,
+        grid=(2, 8, 64),
+        in_specs=[pl.BlockSpec((None, None, None, rows, lanes),
+                               lambda b, g, c: (b, g, c, 0, 0))],
+        out_specs=[pl.BlockSpec((None, None, None, rows, lanes),
+                                lambda b, g, c: (b, g, c, 0, 0))],
+        out_shape=[jax.ShapeDtypeStruct((2, 8, 64, 128, 512),
+                                        jnp.float32)],
+    )(x)

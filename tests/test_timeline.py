@@ -268,7 +268,9 @@ def test_every_pallas_call_passes_a_stable_name():
     ``jvp__`` before the scopes existed, a block's name since — and would
     hide in the device trace under it."""
     sites = _pallas_call_names()
-    assert len(sites) >= 12
+    assert len(sites) >= 16
+    # the scan's three kernels (PR 31), by the names a trace shows
+    assert {"ssd_fwd", "ssd_states", "ssd_bwd"} <= {n for _, n in sites}
     bad = [(where, name) for where, name in sites
            if not (isinstance(name, str)
                    and re.fullmatch(r"[a-z][a-z0-9]*(_[a-z0-9]+)+", name))]

@@ -188,6 +188,8 @@ def _fold_dims(expr: Optional[ast.expr], env) -> Optional[List[int]]:
         v = fold_or_none(e, env)
         if isinstance(v, (int, float)):
             dims.append(int(v))
+        elif isinstance(e, ast.Constant) and e.value is None:
+            dims.append(1)      # a squeezed dim: one element a grid step
         else:
             dims.append(_DEFAULT_DIM)
     return dims
