@@ -17,14 +17,20 @@ softmax; only an arbitrary additive ``mask`` forces the dense path.
 """
 from __future__ import annotations
 
-from .... import initializer
+import weakref
+
+import numpy as onp
+
+from .... import autograd, initializer, telemetry
 from ....ops.pallas_attention import bshd_layout_fits
 from ...block import HybridBlock
 from ...nn import Dense, Dropout, LayerNorm
 
 __all__ = ["MultiHeadAttention", "GroupedQueryAttention", "PositionwiseFFN",
            "TransformerEncoderCell", "TransformerEncoder",
-           "CompressedConvAttention"]
+           "CompressedConvAttention", "MaskTileCount", "publish_mask_tiles"]
+
+_TILE_COUNTS = weakref.WeakSet()   # the MaskTileCount blocks of this process
 
 
 class MultiHeadAttention(HybridBlock):
@@ -100,42 +106,123 @@ class MultiHeadAttention(HybridBlock):
 
 
 class GroupedQueryAttention(HybridBlock):
-    """Causal self-attention with fewer key-value heads than query heads:
-    ``softmax(q k^T / sqrt(head_dim)) v`` over ``num_heads`` query heads of
-    ``head_dim``, every ``num_heads / num_kv_heads`` of them reading one
-    key-value head, no bias, no position embedding (a model that wants
-    one rotates q and k itself).  One fused projection to [q | k | v];
-    attention runs in the flash kernels, the query heads sharing their
-    key-value head through the kernels' index maps (no repeated K/V)."""
+    """Self-attention with fewer key-value heads than query heads:
+    ``softmax(q k^T / sqrt(head_dim) + mask) v`` over ``num_heads`` query
+    heads of ``head_dim``, every ``num_heads / num_kv_heads`` of them
+    reading one key-value head, no bias.  One fused projection to
+    [q | k | v]; attention runs in the flash kernels, the query heads
+    sharing their key-value head through the kernels' index maps (no
+    repeated K/V).
 
-    def __init__(self, units, num_heads, num_kv_heads, head_dim, **kwargs):
+    ``qk_norm``: q and k get an RMS norm over ``head_dim`` before anything
+    else, each with one learned gain of ``head_dim`` that its heads share.
+    ``rope_theta``: q and k are rotated (rotate-half, all of ``head_dim``)
+    at the token's place in the row, or at ``position_ids`` (B, S) where
+    the call gives them; None: no position embedding.
+
+    ``forward(x)`` is causal.  ``forward(x, position_ids, q_mask,
+    kv_mask)`` takes the mask as data, (B, S, 2) integers each — a
+    query's [reach, own], a key's [rank, own], ``flash_attention`` has
+    the rule — and is causal only if they say so."""
+
+    def __init__(self, units, num_heads, num_kv_heads, head_dim,
+                 qk_norm=False, rope_theta=None, epsilon=1e-6, **kwargs):
         super().__init__(**kwargs)
         if num_heads % num_kv_heads:
             raise ValueError("%d query heads do not divide over %d "
                              "key-value heads" % (num_heads, num_kv_heads))
         self._heads = (num_heads, num_kv_heads, head_dim)
+        self._rope_theta, self._epsilon = rope_theta, epsilon
         with self.name_scope():
             self.qkv = Dense((num_heads + 2 * num_kv_heads) * head_dim,
                              flatten=False, use_bias=False, in_units=units,
                              prefix="qkv_")
             self.proj = Dense(units, flatten=False, use_bias=False,
                               in_units=num_heads * head_dim, prefix="out_")
+            if qk_norm:
+                self.q_norm_gamma = self.params.get(
+                    "q_norm_gamma", shape=(head_dim,), init="ones")
+                self.k_norm_gamma = self.params.get(
+                    "k_norm_gamma", shape=(head_dim,), init="ones")
 
-    def hybrid_forward(self, F, x):
+    def hybrid_forward(self, F, x, position_ids=None, q_mask=None,
+                       kv_mask=None, q_norm_gamma=None, k_norm_gamma=None):
         heads, kv_heads, d = self._heads
         b, s = x.shape[0], x.shape[1]
         qkv = self.qkv(x)
 
-        def part(begin, end):            # (B, S, h d) -> (B, h, S, d)
-            return F.slice_axis(qkv, axis=-1, begin=begin * d,
-                                end=end * d).reshape(
-                b, s, end - begin, d).transpose(axes=(0, 2, 1, 3))
+        def part(begin, end, gamma=None, rotate=False):
+            # (B, S, h d) -> (B, h, S, d)
+            out = F.slice_axis(qkv, axis=-1, begin=begin * d,
+                               end=end * d).reshape(b, s, end - begin, d)
+            if gamma is not None:
+                out = F.RMSNorm(out, gamma, eps=self._epsilon)
+            out = out.transpose(axes=(0, 2, 1, 3))
+            if rotate and self._rope_theta is not None:
+                out = F.rotary_embedding(out, position_ids,
+                                         theta=self._rope_theta)
+            return out
 
-        out = F.flash_attention(part(0, heads),
-                                part(heads, heads + kv_heads),
-                                part(heads + kv_heads, heads + 2 * kv_heads),
-                                causal=True)
+        out = F.flash_attention(
+            part(0, heads, q_norm_gamma, True),
+            part(heads, heads + kv_heads, k_norm_gamma, True),
+            part(heads + kv_heads, heads + 2 * kv_heads),
+            causal=q_mask is None, q_mask=q_mask, kv_mask=kv_mask)
         return self.proj(out.transpose(axes=(0, 2, 1, 3)).reshape(b, s, -1))
+
+
+class MaskTileCount(HybridBlock):
+    """What a mask given as data leaves of the attention kernels' tiles,
+    observable without a callback in the step: ``forward(q_mask,
+    kv_mask)`` counts, from the per-tile summary the kernels skip by
+    (``ops.pallas_attention.mask_tiles``), the tiles a head row of one
+    ``flash_attention`` call visits and has — forward, ``dq`` and
+    ``dk/dv`` together — times ``calls`` (the layers a model runs under
+    the one mask), and a TRAINING step keeps the pair as non-trainable
+    state, as ``SparseExperts`` keeps its loads; ``publish_mask_tiles``
+    reads it.  Zeros where the kernels stream no K blocks (short rows,
+    no TPU)."""
+
+    def __init__(self, head_dim, calls=1, dtype="bfloat16", **kwargs):
+        super().__init__(**kwargs)
+        self._plan = dict(head_dim=int(head_dim), dtype=dtype)
+        self._calls = float(calls)
+        with self.name_scope():
+            self.tiles = self.params.get(
+                "tiles", shape=(2,), init="zeros", grad_req="null",
+                differentiable=False)
+        _TILE_COUNTS.add(self)
+
+    def cast(self, dtype):
+        """The counts stay float32; the kernels' blocks follow ``dtype``."""
+        super().cast(dtype)
+        self.tiles.cast("float32")
+        self._plan["dtype"] = str(dtype)
+
+    def hybrid_forward(self, F, q_mask, kv_mask, tiles):
+        visited, total = F.attention_mask_tiles(q_mask, kv_mask,
+                                                **self._plan)
+        counts = F.stack(visited, total) * self._calls
+        if autograd.is_training():
+            with autograd.pause():
+                self.tiles.set_data(counts)
+        return counts
+
+
+def publish_mask_tiles():
+    """Read what every live ``MaskTileCount`` kept at its last training
+    step into ``telemetry`` — gauges ``attention.mask.tiles_visited`` and
+    ``attention.mask.tiles_total``, summed over the blocks — and return
+    the pair.  One device read a block, after the window: nothing is
+    called back from inside the step."""
+    visited = total = 0.0
+    for block in _TILE_COUNTS:
+        if block.tiles._data is not None:
+            pair = onp.asarray(block.tiles.data().asnumpy(), "float64")
+            visited, total = visited + pair[0], total + pair[1]
+    telemetry.gauge("attention.mask.tiles_visited", visited)
+    telemetry.gauge("attention.mask.tiles_total", total)
+    return visited, total
 
 
 class PositionwiseFFN(HybridBlock):

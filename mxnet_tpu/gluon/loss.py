@@ -18,7 +18,7 @@ from .block import HybridBlock
 
 __all__ = ["Loss", "L2Loss", "L1Loss", "SigmoidBinaryCrossEntropyLoss",
            "SigmoidBCELoss", "SoftmaxCrossEntropyLoss", "SoftmaxCELoss",
-           "TiedSoftmaxCrossEntropyLoss",
+           "TiedSoftmaxCrossEntropyLoss", "BlockDiffusionLoss",
            "KLDivLoss", "CTCLoss", "HuberLoss", "HingeLoss",
            "SquaredHingeLoss", "LogisticLoss", "TripletLoss",
            "PoissonNLLLoss", "CosineEmbeddingLoss"]
@@ -189,6 +189,12 @@ class TiedSoftmaxCrossEntropyLoss(Loss):
         self._block_rows = block_rows
         self._ignore = ignore_label
 
+    @staticmethod
+    def _per_row(counted):
+        """What a row's weighted sum is divided by: its counted
+        positions."""
+        return jnp.maximum(jnp.sum(counted, axis=-1, keepdims=True), 1.0)
+
     def hybrid_forward(self, F, pred, label, sample_weight=None):
         from ..ops.nn import tied_softmax_cross_entropy
 
@@ -197,13 +203,41 @@ class TiedSoftmaxCrossEntropyLoss(Loss):
         def fn(h, w, l, sw):
             lab = l.astype(jnp.int32)
             counted = (lab != self._ignore).astype(jnp.float32)
-            scale = _w(counted, self._weight, sw) / jnp.maximum(
-                jnp.sum(counted, axis=-1, keepdims=True), 1.0)
+            scale = _w(counted, self._weight, sw) / self._per_row(counted)
             return jnp.sum(tied_softmax_cross_entropy(
                 h, w, lab, scale=scale, block_rows=self._block_rows),
                 axis=-1)
         return self._dispatch(fn, [hidden, table, label, sample_weight],
                               "tied_softmax_ce")
+
+
+class BlockDiffusionLoss(TiedSoftmaxCrossEntropyLoss):
+    """The block-diffusion objective (BD3-LM, arXiv:2503.09573) on the
+    noised half of a row, through the blocked cross-entropy: ``pred`` is
+    ``(hidden (B, L, D), head weight (V, D))``, position i of the noised
+    half predicting token i; ``label`` (B, L) the clean ids at the masked
+    positions and ``ignore_label`` elsewhere; ``sample_weight`` (B, L) a
+    position's ``1 / t_b``.  The loss of a row is ``(1 / L) sum_i w_i *
+    -log p(x0_i | row)`` over its masked positions: one over ALL L
+    positions, not over the masked ones — with the weights the sum is an
+    unbiased bound on the row's likelihood whatever the noise drew.  The
+    weights go into the operator's ``scale`` with the rest.
+
+    A step builder that carries ONE label array
+    (``parallel.DataParallelStep``) gives ``label`` as (B, 2, L) float32,
+    the ids stacked on the weights, and no ``sample_weight``
+    (``model_zoo.block_diffusion_row`` makes it)."""
+
+    def hybrid_forward(self, F, pred, label, sample_weight=None):
+        # graftlint: disable-next=retrace-shape-branch -- rank dispatch: the
+        # stacked label of a step builder that carries one array
+        if sample_weight is None and len(label.shape) == 3:
+            label, sample_weight = label[:, 0], label[:, 1]
+        return super().hybrid_forward(F, pred, label, sample_weight)
+
+    @staticmethod
+    def _per_row(counted):
+        return jnp.float32(counted.shape[-1])
 
 
 class KLDivLoss(Loss):

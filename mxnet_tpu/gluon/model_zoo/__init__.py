@@ -12,6 +12,11 @@ from . import zaya  # noqa: F401
 from .zaya import ZAYA1Model, zaya1  # noqa: F401
 from . import nemotron_h as _nemotron_h  # noqa: F401
 from .nemotron_h import NemotronHModel, nemotron_h  # noqa: F401
+from . import sdar as _sdar  # noqa: F401
+from .sdar import (SDARModel, sdar, block_diffusion_mask,  # noqa: F401
+                   block_diffusion_row)
 
 __all__ = ["vision", "bert", "BERTModel", "bert_base", "bert_small",
-           "zaya", "ZAYA1Model", "zaya1", "NemotronHModel", "nemotron_h"]
+           "zaya", "ZAYA1Model", "zaya1", "NemotronHModel", "nemotron_h",
+           "SDARModel", "sdar", "block_diffusion_mask",
+           "block_diffusion_row"]

@@ -932,16 +932,23 @@ def l2_normalize(data, scale=None, axis: int = -1, eps: float = 1e-12):
 
 
 @register("rotary_embedding")
-def rotary_embedding(data, rotary_dim: int = 0, theta: float = 10000.0):
+def rotary_embedding(data, positions=None, rotary_dim: int = 0,
+                     theta: float = 10000.0):
     """Rotary position embedding on the first ``rotary_dim`` of the D
     dimensions of a (B, H, S, D) tensor (0: all of them), the rest passed
     through.  Rotate-half pairing: dimension i turns with i + rotary_dim/2
-    by the angle ``t * theta ** (-2 i / rotary_dim)``, t from 0."""
+    by the angle ``t * theta ** (-2 i / rotary_dim)``; t is the token's
+    place in the row, from 0, or where ``positions`` (B, S) is given the
+    token's own position id (ids may repeat: a row that holds a sequence
+    twice)."""
     r = rotary_dim or data.shape[-1]
     half = r // 2
-    pos = jnp.arange(data.shape[-2], dtype=jnp.float32)
+    if positions is None:
+        pos = jnp.arange(data.shape[-2], dtype=jnp.float32)
+    else:
+        pos = positions.astype(jnp.float32)[:, None, :]      # (B, 1, S)
     freq = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / r)
-    ang = pos[:, None] * freq[None, :]                       # (S, r/2)
+    ang = pos[..., None] * freq[None, :]                     # (.., S, r/2)
     cos, sin = jnp.cos(ang), jnp.sin(ang)
     x32 = data.astype(jnp.float32)
     x1, x2, rest = x32[..., :half], x32[..., half:r], x32[..., r:]

@@ -37,7 +37,7 @@ __all__ = ["flash_attention", "flash_attention_bshd",
            "pallas_flash_attention", "pallas_flash_attention_bshd",
            "pallas_flash_attention_bwd", "pallas_flash_attention_bwd_bshd",
            "attention_dispatch", "tune_attention_blocks",
-           "bshd_layout_fits"]
+           "bshd_layout_fits", "mask_tiles"]
 
 _NEG_INF = -1e30
 _LANES = 128
@@ -58,6 +58,22 @@ _SHORT_SEQ_MAX_TK = 1024
 _DENSE_MIN_SEQ = 128
 _VMEM_CLAMP = 12 * 1024 * 1024
 _BWD_MIN_BLOCK_Q = 128
+# A mask given as data (``q_mask`` / ``kv_mask``, see flash_attention):
+#  * _RANK_NEVER: the rank of a key no reach sees (a padded key's), and
+#    _REACH_NONE the reach of a query that sees no rank (a padded row's);
+#  * _MASKED_BLOCKS: the (q, K) blocks of a streamed K axis under such a
+#    mask, halved like the others until they fit.  A tile is the unit the
+#    mask skips by, and a grid step that skips still costs its ~0.35 us:
+#    on a block-diffusion row of 2 x 4096 (a quarter of the pairs live)
+#    1024 x 1024 tiles visit 37.5% of the square in a quarter of the
+#    steps that 512 x 512 tiles need for their 31%, and 512 x 2048 visit
+#    half.  Forward + backward of one layer's call (32 heads on 4, D=128,
+#    the kernels alone): 14.2 ms at 1024 x 1024, 16.1 at 512 x 1024, 17.5
+#    at 1024 x 512, 18.6 at 512 x 512, 19.5 at 512 x 2048, 25.3 at 256 x
+#    512 (my chip runs, PR 33).
+_RANK_NEVER = 2 ** 31 - 1
+_REACH_NONE = -2 ** 31
+_MASKED_BLOCKS = (1024, 1024)
 
 
 def _fwd_vmem_bytes(block_q, block_k, Dp, itemsize):
@@ -116,14 +132,16 @@ def _blocks_fit(block_q, block_k, Dp, itemsize):
                             itemsize) <= _VMEM_CLAMP
 
 
-def tune_attention_blocks(seq_q, seq_k, head_dim, dtype="bfloat16"):
+def tune_attention_blocks(seq_q, seq_k, head_dim, dtype="bfloat16",
+                          masked=False):
     """Default (block_q, block_k) for a (S, D, dtype) attention shape.
 
     Short K axes (<= _SHORT_SEQ_MAX_TK) take the whole axis as one
     lane-aligned block so the single-pass kernel applies; long axes take
     (512, 2048) — the largest blocks today's v5e compiler accepts with
-    every mask variant, forward and backward — halved until the working
-    sets honour their VMEM budgets (large D / fp32 shapes)."""
+    every mask variant, forward and backward — or, under a mask given as
+    data (``masked``), ``_MASKED_BLOCKS``; halved until the working sets
+    honour their VMEM budgets (large D / fp32 shapes)."""
     itemsize = jnp.dtype(dtype).itemsize
     Dp = head_dim + (-head_dim) % 64
     if seq_k <= _SHORT_SEQ_MAX_TK:
@@ -134,6 +152,8 @@ def tune_attention_blocks(seq_q, seq_k, head_dim, dtype="bfloat16"):
             block_q //= 2
         return block_q, block_k
     block_q, block_k = 512, 2048
+    if masked:
+        block_q, block_k = _MASKED_BLOCKS
     while block_k > 512 and \
             not _blocks_fit(block_q, block_k, Dp, itemsize):
         block_k //= 2
@@ -167,7 +187,8 @@ def bshd_layout_fits(num_heads, head_dim):
 
 
 def attention_dispatch(seq_q, seq_k, head_dim, dtype="bfloat16",
-                       on_tpu=None, census=True, bshd_heads=None):
+                       on_tpu=None, census=True, bshd_heads=None,
+                       masked=False, tiles_visited=None):
     """Per-shape kernel choice for the public flash-attention ops.
 
     Returns ``{"kernel": "short_seq" | "streaming" | "dense_fallback",
@@ -191,6 +212,14 @@ def attention_dispatch(seq_q, seq_k, head_dim, dtype="bfloat16",
     Blocks come from ``tune_attention_blocks`` and nowhere else, so they
     fit VMEM forward and backward (``_blocks_fit``).
 
+    ``masked``: the call carries a mask as data (``q_mask`` / ``kv_mask``).
+    A long K axis then takes ``tune_attention_blocks``' masked blocks, the
+    unit the mask skips by; the plan says so (``"masked"``) and counts the
+    forward's ``"tiles"`` a head row.  ``tiles_visited`` is the caller's
+    count of those the mask leaves live, where the mask is there to be
+    read at trace time (an eager call; None under ``jit``): it goes into
+    the event, no further.
+
     ``census=False`` is the secondary-lookup spelling (the custom-vjp
     backward re-reading the forward's decision): same answer, but no
     counters and no event (the shape was counted at the forward
@@ -203,25 +232,35 @@ def attention_dispatch(seq_q, seq_k, head_dim, dtype="bfloat16",
             telemetry.inc("attention.kernel.dense_fallback")
         return {"kernel": "dense_fallback", "block_q": None,
                 "block_k": None, "layout": None, "heads_per_block": None}
-    block_q, block_k = tune_attention_blocks(seq_q, seq_k, head_dim, dtype)
+    block_q, block_k = tune_attention_blocks(seq_q, seq_k, head_dim, dtype,
+                                             masked)
     kernel = "short_seq" if seq_k <= block_k else "streaming"
     per_block = 0 if bshd_heads is None else _bshd_heads_per_block(
         bshd_heads, head_dim, kernel == "short_seq")
     layout = ("bhsd", "bshd", "bshd_pair")[per_block]
     per_block = max(per_block, 1)
+    plan_mask = {}
+    if masked:
+        plan_mask = {"masked": True,
+                     "tiles": -(-seq_q // block_q) * -(-seq_k // block_k)}
     # per-shape dispatch accounting: this runs at TRACE time (once per
     # compiled shape, not per step), so the journal is a census of which
     # kernel every shape in the run got
     if census:
         telemetry.inc("attention.kernel.%s" % kernel)
+        if masked:
+            telemetry.inc("attention.kernel.masked")
         telemetry.inc("attention.layout.%s" % layout)
         telemetry.event("attention_dispatch", kernel, seq_q=int(seq_q),
                         seq_k=int(seq_k), head_dim=int(head_dim),
                         dtype=str(dtype), block_q=block_q,
                         block_k=block_k, layout=layout,
-                        heads_per_block=per_block)
-    return {"kernel": kernel, "block_q": block_q, "block_k": block_k,
-            "layout": layout, "heads_per_block": per_block}
+                        heads_per_block=per_block,
+                        **(dict(plan_mask, tiles_visited=tiles_visited)
+                           if masked else {}))
+    return dict({"kernel": kernel, "block_q": block_q, "block_k": block_k,
+                 "layout": layout, "heads_per_block": per_block},
+                **plan_mask)
 
 
 # ---------------------------------------------------------------------------
@@ -280,14 +319,63 @@ def _run_mask_specialized(pl, compute, run, qi, ki, block_q, block_k,
         pl.when(run & jnp.logical_not(full))(lambda: compute(True))
 
 
+def _visible(qm, km):
+    """The mask given as data, on one tile: ``qm`` (block_q, 2) holds a
+    query's [reach, own] and ``km`` (2, block_k) a key's [rank, own]; a
+    query sees a key whose rank is at most its reach, or whose ``own`` is
+    its own (the operands arrive with -1 and -2 for "none", which never
+    meet).  Integer compares, (block_q, block_k) bool."""
+    return (km[0:1, :] <= qm[:, 0:1]) | (km[1:2, :] == qm[:, 1:2])
+
+
+def _visible_T(qm, km):
+    """``_visible`` for the backward's transposed score block:
+    ``qm`` (2, block_q), ``km`` (block_k, 2) -> (block_k, block_q)."""
+    return (km[:, 0:1] <= qm[0:1, :]) | (km[:, 1:2] == qm[1:2, :])
+
+
+def _run_streamed_block(pl, compute, tile, qi, ki, block_q, block_k, causal,
+                        has_lens, has_seg, needs_tail, kvlen, seq_k):
+    """Whether and how a streamed kernel's grid step computes its block.
+    Under a mask given as data ``tile`` is the block's entry in the
+    scalar-prefetched summary — 0 no live pair (nothing runs), 1 some (the
+    mask is applied), 2 all (it is not).  Otherwise (``tile`` None) a
+    block wholly above the causal diagonal or wholly past the row's valid
+    length is skipped, and the ladder picks the mask."""
+    if tile is not None:
+        pl.when(tile == 2)(lambda: compute(False))
+        pl.when(tile == 1)(lambda: compute(True))
+        return
+    run = True
+    if causal:
+        run = (qi * block_q + block_q - 1) >= (ki * block_k)
+    if has_lens:
+        run = run & (ki * block_k < kvlen)
+    _run_mask_specialized(pl, compute, run, qi, ki, block_q, block_k,
+                          causal, has_lens, has_seg, needs_tail,
+                          kvlen=kvlen, seq_k=seq_k)
+
+
+def _tiles_first(kernel):
+    """Under a scalar-prefetch grid a kernel is handed the prefetched
+    arrays first: the tile summary goes on as ``tiles=``; the second, the
+    table of blocks to fetch, is the index maps' alone."""
+    def call(tiles_ref, fetch_ref, *refs):
+        return kernel(*refs, tiles=tiles_ref)
+    return call
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, block_q, block_k,
-                seq_k, seq_k_padded, n_k, has_lens, has_seg, pid_off=0):
+                seq_k, seq_k_padded, n_k, has_lens, has_seg, pid_off=0,
+                tiles=None, mask_heads=1):
     import jax.experimental.pallas as pl
 
     rest = list(rest)
     lens_ref = rest.pop(0) if has_lens else None
     qseg_ref = rest.pop(0) if has_seg else None
     kseg_ref = rest.pop(0) if has_seg else None
+    qm_ref = rest.pop(0) if tiles is not None else None
+    km_ref = rest.pop(0) if tiles is not None else None
     o_ref, lse_ref, m_ref, l_ref, acc_ref = rest
 
     # pid_off=1 on the BSHD grid (B, H, n_q, n_k); 0 on (B*H, n_q, n_k).
@@ -320,8 +408,12 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, block_q, block_k,
                             preferred_element_type=jnp.float32) * scale
 
         # mask: padded K tail, plus causal upper triangle, plus the
-        # variable-length / segment masks when present
-        if use_mask:
+        # variable-length / segment masks when present; or the mask given
+        # as data, whose padding is in the operands
+        if use_mask and tiles is not None:
+            mask = _visible(qm_ref[0], km_ref[0])
+            s = jnp.where(mask, s, _NEG_INF)
+        elif use_mask:
             col = ki * block_k + lax.broadcasted_iota(jnp.int32,
                                                       (block_q, block_k), 1)
             mask = col < (kvlen if has_lens else seq_k)
@@ -351,16 +443,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, block_q, block_k,
         m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
         l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
 
-    run = True
-    if causal:
-        # skip blocks entirely above the diagonal
-        run = (qi * block_q + block_q - 1) >= (ki * block_k)
-    if has_lens:
-        # skip K blocks entirely past this batch row's valid length
-        run = run & (ki * block_k < kvlen)
-    _run_mask_specialized(pl, _compute, run, qi, ki, block_q, block_k,
-                          causal, has_lens, has_seg, needs_tail,
-                          kvlen=kvlen, seq_k=seq_k)
+    tile = None if tiles is None else tiles[
+        (bi // mask_heads * pl.num_programs(1 + pid_off) + qi) * n_k + ki]
+    _run_streamed_block(pl, _compute, tile, qi, ki, block_q, block_k, causal,
+                        has_lens, has_seg, needs_tail, kvlen, seq_k)
 
     @pl.when(ki == n_k - 1)
     def _finalize():
@@ -434,7 +520,7 @@ def _head_vector(ref, h, heads, shape):
 
 def _fwd_kernel_single(q_ref, k_ref, v_ref, *rest, scale, causal, block_q,
                        block_k, seq_k, seq_k_padded, has_lens, has_seg,
-                       pid_off=0, heads=1, lse_rows=False):
+                       pid_off=0, heads=1, lse_rows=False, has_mask=False):
     """Short-sequence forward: the whole K axis is ONE block, so the
     online-softmax streaming machinery — m/l VMEM scratch carried across
     K iterations, the per-iteration accumulator rescale, the init/
@@ -445,13 +531,17 @@ def _fwd_kernel_single(q_ref, k_ref, v_ref, *rest, scale, causal, block_q,
     block one vector a head; the softmax runs once a head, under one
     mask, and the store is one lane-dense block.  ``lse_rows``: the lse
     block is (1, heads, 1, block_q), a row a head along the lanes
-    (``_columns_as_rows``), not (…, block_q, 1) columns."""
+    (``_columns_as_rows``), not (…, block_q, 1) columns.  ``has_mask``:
+    the mask given as data (``_visible``), on every tile: one K block
+    leaves nothing to skip."""
     import jax.experimental.pallas as pl
 
     rest = list(rest)
     lens_ref = rest.pop(0) if has_lens else None
     qseg_ref = rest.pop(0) if has_seg else None
     kseg_ref = rest.pop(0) if has_seg else None
+    qm_ref = rest.pop(0) if has_mask else None
+    km_ref = rest.pop(0) if has_mask else None
     o_ref, lse_ref = rest
 
     bi = pl.program_id(0)
@@ -461,6 +551,8 @@ def _fwd_kernel_single(q_ref, k_ref, v_ref, *rest, scale, causal, block_q,
     needs_tail = seq_k != seq_k_padded
 
     def _mask():
+        if has_mask:
+            return _visible(qm_ref[0], km_ref[0])
         col = lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
         mask = col < (kvlen if has_lens else seq_k)
         if causal:
@@ -515,7 +607,7 @@ def _fwd_kernel_single(q_ref, k_ref, v_ref, *rest, scale, causal, block_q,
     # the cell's 38 s set-up) for a forward of 1.520 against 1.504 ms and
     # a backward of 2.736 against 2.721 ms a call without it (my chip
     # runs, PR 27)
-    if has_lens:
+    if has_lens or has_mask:
         _compute(True)
     else:
         _run_mask_specialized(pl, _compute, True, qi, ki, block_q, block_k,
@@ -588,11 +680,111 @@ def _expand_mask_operands(kv_lens, q_segments, kv_segments, B, H, Tqp, Tkp,
     return lens, qs, ks
 
 
+def _check_mask_alone(q_mask, kv_mask, causal, kv_lens, q_segments):
+    if (q_mask is None) != (kv_mask is None):
+        raise ValueError("q_mask and kv_mask go together")
+    if q_mask is not None and (causal or kv_lens is not None
+                               or q_segments is not None):
+        raise ValueError(
+            "q_mask / kv_mask stand alone: say causal order, valid lengths "
+            "and segments in the ranks, reaches and owns themselves")
+
+
+def _mask_operands(q_mask, kv_mask, Tqp, Tkp):
+    """The mask given as data, as the kernels read it: ``q_mask``
+    (B, Tq, 2) [reach, own] and ``kv_mask`` (B, Tk, 2) [rank, own] ->
+    int32 (B, Tqp, 2) and (B, Tkp, 2).  A negative ``own`` (none) becomes
+    -1 on a query and -2 on a key, so that equality alone decides; a
+    padded query gets the reach no rank is under and a padded key the
+    rank no reach is over, so that padding needs no mask of its own."""
+    qm, km = q_mask.astype(jnp.int32), kv_mask.astype(jnp.int32)
+    reach = jnp.minimum(qm[..., 0], _RANK_NEVER - 1)
+    q_own = jnp.where(qm[..., 1] < 0, -1, qm[..., 1])
+    k_own = jnp.where(km[..., 1] < 0, -2, km[..., 1])
+    pad_q, pad_k = Tqp - qm.shape[1], Tkp - km.shape[1]
+
+    def padded(x, pad, value):
+        return jnp.pad(x, ((0, 0), (0, pad)), constant_values=value)
+    return (jnp.stack([padded(reach, pad_q, _REACH_NONE),
+                       padded(q_own, pad_q, -1)], axis=-1),
+            jnp.stack([padded(km[..., 0], pad_k, _RANK_NEVER),
+                       padded(k_own, pad_k, -2)], axis=-1))
+
+
+def _tile_states(qm, km, block_q, block_k):
+    """The per-tile summary of ``_mask_operands``' arrays, (B, n_q, n_k)
+    int32, from each block's extremes and never from the pairs: 2 where
+    every pair of the tile is live (the largest rank is under the
+    smallest reach), 0 where none can be (the smallest rank is over the
+    largest reach and the blocks' ranges of ``own`` do not meet), else 1.
+    A 0 is always true; a 1 may hold no live pair where the ``own``s of a
+    block are not consecutive integers — such a tile is visited and
+    masked to nothing."""
+    B = qm.shape[0]
+    reach, q_own = (qm[..., i].reshape(B, -1, block_q) for i in (0, 1))
+    rank, k_own = (km[..., i].reshape(B, -1, block_k) for i in (0, 1))
+
+    def own_range(own):
+        some = own >= 0
+        return (jnp.where(some, own, _RANK_NEVER).min(-1),
+                jnp.where(some, own, -1).max(-1))
+    (q_lo, q_hi), (k_lo, k_hi) = own_range(q_own), own_range(k_own)
+    every = rank.max(-1)[:, None, :] <= reach.min(-1)[:, :, None]
+    some = (rank.min(-1)[:, None, :] <= reach.max(-1)[:, :, None]) | (
+        (q_lo[:, :, None] <= k_hi[:, None, :])
+        & (k_lo[:, None, :] <= q_hi[:, :, None]))
+    return jnp.where(every, 2, some.astype(jnp.int32))
+
+
+def _states_at(q_mask, kv_mask, block_q, block_k):
+    """``_tile_states`` of the public operands padded to whole blocks."""
+    Tq, Tk = q_mask.shape[1], kv_mask.shape[1]
+    return _tile_states(*_mask_operands(
+        q_mask, kv_mask, Tq + (-Tq) % block_q, Tk + (-Tk) % block_k),
+        block_q, block_k)
+
+
+def _resident_block(live):
+    """For each step of the last axis of ``live`` (bool), the block that
+    should be in VMEM then: the step's own where it is live, else the
+    last live one before it — the index map repeats it and nothing is
+    fetched — or, before the first live one, that first."""
+    n = live.shape[-1]
+    idx = jnp.where(live, jnp.arange(n, dtype=jnp.int32), -1)
+    last = lax.cummax(idx, axis=live.ndim - 1)
+    first = jnp.argmax(live, axis=-1).astype(jnp.int32)[..., None]
+    return jnp.where(last >= 0, last, first)
+
+
+def mask_tiles(q_mask, kv_mask, head_dim, dtype="bfloat16"):
+    """``(visited, total)`` tiles of one masked ``flash_attention`` call
+    a head row, forward and backward together (the forward's, ``flash_dq``'s
+    and ``flash_dkv``'s grids), at the blocks ``attention_dispatch`` plans
+    and from the summary the kernels skip by; float32 scalars.  (0, 0)
+    where no streamed kernel would run (one K block, or no TPU: there is
+    nothing to skip)."""
+    Tq, Tk = q_mask.shape[1], kv_mask.shape[1]
+    plan = attention_dispatch(Tq, Tk, head_dim, dtype, census=False,
+                              masked=True)
+    if plan["kernel"] != "streaming":
+        return jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32)
+    block_q, block_k = plan["block_q"], plan["block_k"]
+    Dp = head_dim + (-head_dim) % 64
+    visited = total = 0
+    for bq, calls in ((block_q, 1), (_bwd_block_q(
+            block_q, block_k, Dp, jnp.dtype(dtype).itemsize), 2)):
+        states = _states_at(q_mask, kv_mask, bq, block_k)
+        visited = visited + calls * jnp.sum(states > 0)
+        total = total + calls * states.size
+    return visited.astype(jnp.float32), jnp.float32(total)
+
+
 def pallas_flash_attention(q, k, v, causal=False, scale=None,
                            block_q: Optional[int] = None,
                            block_k: Optional[int] = None,
                            interpret: bool = False, return_lse: bool = False,
-                           kv_lens=None, q_segments=None, kv_segments=None):
+                           kv_lens=None, q_segments=None, kv_segments=None,
+                           q_mask=None, kv_mask=None):
     # Default blocks come from tune_attention_blocks: (1024, 2048) on the
     # streaming path (v5e S=2048, D=64 fwd+bwd sweep: ~61 TF/s vs ~35 TF/s
     # for XLA dense attention), the whole lane-aligned K axis as one block
@@ -607,7 +799,15 @@ def pallas_flash_attention(q, k, v, causal=False, scale=None,
     K blocks wholly past it are skipped, the partial block is masked
     inside the online softmax.  ``q_segments``/``kv_segments`` (B, T) int
     ids restrict attention to equal segments (packed-sequence masking,
-    ref transformer.cc's masked softmax).  Fully-masked rows emit 0."""
+    ref transformer.cc's masked softmax).  Fully-masked rows emit 0.
+
+    ``q_mask`` (B, Tq, 2) / ``kv_mask`` (B, Tk, 2): a mask given as data
+    (``flash_attention`` has the rule), in place of the others.  With the
+    K axis streamed the kernel is ``flash_masked_fwd``: a per-tile summary
+    of the operands (``_tile_states``) is scalar-prefetched, a tile with
+    no live pair runs nothing and fetches nothing — its K/V index map
+    repeats the block that is resident — a tile wholly live skips the
+    mask, and a partial one applies it in float32."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -616,9 +816,11 @@ def pallas_flash_attention(q, k, v, causal=False, scale=None,
     scale = scale if scale is not None else D ** -0.5
     if (q_segments is None) != (kv_segments is None):
         raise ValueError("q_segments and kv_segments go together")
+    _check_mask_alone(q_mask, kv_mask, causal, kv_lens, q_segments)
 
     if block_q is None or block_k is None:
-        tq, tk = tune_attention_blocks(Tq, Tk, D, q.dtype)
+        tq, tk = tune_attention_blocks(Tq, Tk, D, q.dtype,
+                                       q_mask is not None)
         block_q = tq if block_q is None else block_q
         block_k = tk if block_k is None else block_k
     block_q = min(block_q, max(8, Tq))
@@ -631,7 +833,17 @@ def pallas_flash_attention(q, k, v, causal=False, scale=None,
                                          B, H, Tqp, Tkp, true_tk=Tk)
 
     single = n_k == 1
+    masked = q_mask is not None
+    if masked:
+        qm, km_cols = _mask_operands(q_mask, kv_mask, Tqp, Tkp)
+        km = jnp.swapaxes(km_cols, 1, 2)      # keys along the lanes
     extra, extra_specs = [], []
+    if masked and single:
+        extra += [qm, km]
+        extra_specs += [
+            pl.BlockSpec((1, block_q, 2), lambda b, qi: (b // H, qi, 0)),
+            pl.BlockSpec((1, 2, block_k), lambda b, qi: (b // H, 0, 0)),
+        ]
     if lens is not None:
         extra.append(lens)
         extra_specs.append(pl.BlockSpec(
@@ -655,7 +867,7 @@ def pallas_flash_attention(q, k, v, causal=False, scale=None,
                   has_lens=lens is not None, has_seg=qs is not None)
     if single:
         out, lse = pl.pallas_call(
-            functools.partial(_fwd_kernel_single, **common),
+            functools.partial(_fwd_kernel_single, has_mask=masked, **common),
             grid=(B * H, n_q),
             in_specs=[
                 pl.BlockSpec((1, block_q, Dp), lambda b, qi: (b, qi, 0)),
@@ -681,6 +893,51 @@ def pallas_flash_attention(q, k, v, causal=False, scale=None,
         return out
 
     kernel = functools.partial(_fwd_kernel, n_k=n_k, **common)
+    out_shape = [jax.ShapeDtypeStruct((B * H, Tqp, Dp), q.dtype),
+                 jax.ShapeDtypeStruct((B * H, Tqp, 1), jnp.float32)]
+    scratch = [pltpu.VMEM((block_q, _LANES), jnp.float32),
+               pltpu.VMEM((block_q, _LANES), jnp.float32),
+               pltpu.VMEM((block_q, Dp), jnp.float32)]
+    if masked:
+        states = _tile_states(qm, km_cols, block_q, block_k)
+        fetch = _resident_block(states > 0)
+
+        def kblk(b, qi, ki, tiles, fetch):
+            return fetch[(b // H * n_q + qi) * n_k + ki]
+        out, lse = pl.pallas_call(
+            _tiles_first(functools.partial(kernel, mask_heads=H)),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2,
+                grid=(B * H, n_q, n_k),
+                in_specs=[
+                    pl.BlockSpec((1, block_q, Dp),
+                                 lambda b, qi, ki, *_: (b, qi, 0)),
+                    pl.BlockSpec((1, block_k, Dp), lambda b, qi, ki, *t:
+                                 (kvb(b), kblk(b, qi, ki, *t), 0)),
+                    pl.BlockSpec((1, block_k, Dp), lambda b, qi, ki, *t:
+                                 (kvb(b), kblk(b, qi, ki, *t), 0)),
+                    pl.BlockSpec((1, block_q, 2),
+                                 lambda b, qi, ki, *_: (b // H, qi, 0)),
+                    pl.BlockSpec((1, 2, block_k), lambda b, qi, ki, *t:
+                                 (b // H, 0, kblk(b, qi, ki, *t))),
+                ],
+                out_specs=[
+                    pl.BlockSpec((1, block_q, Dp),
+                                 lambda b, qi, ki, *_: (b, qi, 0)),
+                    pl.BlockSpec((1, block_q, 1),
+                                 lambda b, qi, ki, *_: (b, qi, 0)),
+                ],
+                scratch_shapes=scratch),
+            out_shape=out_shape,
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary")),
+            interpret=interpret,
+            name="flash_masked_fwd",
+        )(states.reshape(-1), fetch.reshape(-1), qp, kp, vp, qm, km)
+        out = out.reshape(B, H, Tqp, Dp)[:, :, :Tq, :D]
+        if return_lse:
+            return out, lse.reshape(B, H, Tqp)[:, :, :Tq]
+        return out
     out, lse = pl.pallas_call(
         kernel,
         grid=(B * H, n_q, n_k),
@@ -695,15 +952,8 @@ def pallas_flash_attention(q, k, v, causal=False, scale=None,
             pl.BlockSpec((1, block_q, Dp), lambda b, qi, ki: (b, qi, 0)),
             pl.BlockSpec((1, block_q, 1), lambda b, qi, ki: (b, qi, 0)),
         ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B * H, Tqp, Dp), q.dtype),
-            jax.ShapeDtypeStruct((B * H, Tqp, 1), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, _LANES), jnp.float32),
-            pltpu.VMEM((block_q, _LANES), jnp.float32),
-            pltpu.VMEM((block_q, Dp), jnp.float32),
-        ],
+        out_shape=out_shape,
+        scratch_shapes=scratch,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
@@ -871,11 +1121,16 @@ def pallas_flash_attention_bshd(q, k, v, causal=False, scale=None,
 # ---------------------------------------------------------------------------
 
 def _scores_T(q, k, lse_row, scale, qi, ki, block_q, block_k, seq_k, causal,
-              kvlen=None, qseg_row=None, kseg_col=None, use_mask=True):
-    """Recomputed transposed probability block pᵀ (block_k, block_q)."""
+              kvlen=None, qseg_row=None, kseg_col=None, use_mask=True,
+              visible=None):
+    """Recomputed transposed probability block pᵀ (block_k, block_q).
+    ``visible``: the (block_k, block_q) mask given as data
+    (``_visible_T``), in place of the others."""
     sT = lax.dot_general(k, q, (((1,), (1,)), ((), ())),
                          preferred_element_type=jnp.float32) * scale
-    if use_mask:
+    if use_mask and visible is not None:
+        sT = jnp.where(visible, sT, _NEG_INF)
+    elif use_mask:
         kcol = ki * block_k + lax.broadcasted_iota(jnp.int32,
                                                    (block_k, block_q), 0)
         mask = kcol < (seq_k if kvlen is None else kvlen)
@@ -889,12 +1144,13 @@ def _scores_T(q, k, lse_row, scale, qi, ki, block_q, block_k, seq_k, causal,
     return jnp.exp(sT - lse_row)           # lse_row: (1, block_q)
 
 
-def _bwd_unpack(rest, has_lens, has_seg):
+def _bwd_unpack(rest, has_lens, has_seg, has_mask=False):
     rest = list(rest)
     lens_ref = rest.pop(0) if has_lens else None
     qseg_ref = rest.pop(0) if has_seg else None
     kseg_ref = rest.pop(0) if has_seg else None
-    return lens_ref, qseg_ref, kseg_ref, rest
+    mask_refs = (rest.pop(0), rest.pop(0)) if has_mask else None
+    return lens_ref, qseg_ref, kseg_ref, mask_refs, rest
 
 
 def _delta_row(do, out):
@@ -913,7 +1169,7 @@ def _delta_row(do, out):
 def _bwd_core(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref, qseg_ref,
               kseg_ref, has_seg, use_mask, qi, ki, scale, causal,
               block_q, block_k, seq_k, kvlen, head=0, heads=1,
-              delta_of_out=False):
+              delta_of_out=False, mask_refs=None):
     """Shared recompute for all backward kernels: block reads, the
     transposed probability block pᵀ, and dsᵀ = pᵀ∘(dpᵀ − δ)·scale.
     Returns (q, k, v, do, pT, dsT).  ``heads`` > 1: the blocks hold that
@@ -921,7 +1177,8 @@ def _bwd_core(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref, qseg_ref,
     a head; pT and dsT are head ``head``'s, from q and do with the other
     heads' lanes zero (``_head_lanes``), and q, k, v, do come back
     whole.  ``delta_of_out``: ``dlt_ref`` is the forward's output block
-    and δ is summed here (``_delta_row``), not read."""
+    and δ is summed here (``_delta_row``), not read.  ``mask_refs``: the
+    (2, block_q) and (block_k, 2) blocks of a mask given as data."""
     q = q_ref[...].reshape(block_q, q_ref.shape[-1])
     k = k_ref[...].reshape(block_k, k_ref.shape[-1])
     v = v_ref[...].reshape(block_k, v_ref.shape[-1])
@@ -936,7 +1193,9 @@ def _bwd_core(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref, qseg_ref,
                    block_q, block_k, seq_k, causal, kvlen=kvlen,
                    qseg_row=qseg_ref[0] if has_seg else None,
                    kseg_col=kseg_ref[0] if has_seg else None,
-                   use_mask=use_mask)
+                   use_mask=use_mask,
+                   visible=_visible_T(mask_refs[0][0], mask_refs[1][0])
+                   if use_mask and mask_refs is not None else None)
     dpT = lax.dot_general(v, do_head, (((1,), (1,)), ((), ())),
                           preferred_element_type=jnp.float32)
     dsT = pT * (dpT - dlt_row) * scale          # (block_k, block_q)
@@ -945,10 +1204,11 @@ def _bwd_core(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref, qseg_ref,
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref, *rest,
                scale, causal, block_q, block_k, seq_k, seq_k_padded, n_k,
-               has_lens, has_seg, pid_off=0):
+               has_lens, has_seg, pid_off=0, tiles=None, mask_heads=1):
     import jax.experimental.pallas as pl
 
-    lens_ref, qseg_ref, kseg_ref, rest = _bwd_unpack(rest, has_lens, has_seg)
+    lens_ref, qseg_ref, kseg_ref, mask_refs, rest = _bwd_unpack(
+        rest, has_lens, has_seg, tiles is not None)
     dq_ref, acc_ref = rest
 
     qi = pl.program_id(1 + pid_off)
@@ -964,19 +1224,16 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref, *rest,
         q, k, v, do, pT, dsT = _bwd_core(
             q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref, qseg_ref,
             kseg_ref, has_seg, use_mask, qi, ki, scale, causal,
-            block_q, block_k, seq_k, kvlen)
+            block_q, block_k, seq_k, kvlen, mask_refs=mask_refs)
         acc_ref[...] += lax.dot_general(
             dsT.astype(q.dtype), k, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    run = True
-    if causal:
-        run = (qi * block_q + block_q - 1) >= (ki * block_k)
-    if has_lens:
-        run = run & (ki * block_k < kvlen)
-    _run_mask_specialized(pl, _compute, run, qi, ki, block_q, block_k,
-                          causal, has_lens, has_seg, needs_tail,
-                          kvlen=kvlen, seq_k=seq_k)
+    tile = None if tiles is None else tiles[
+        (pl.program_id(0) // mask_heads * pl.num_programs(1 + pid_off) + qi)
+        * n_k + ki]
+    _run_streamed_block(pl, _compute, tile, qi, ki, block_q, block_k, causal,
+                        has_lens, has_seg, needs_tail, kvlen, seq_k)
 
     @pl.when(ki == n_k - 1)
     def _finalize():
@@ -986,14 +1243,16 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref, *rest,
 
 def _dqkv_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
                        *rest, scale, causal, block_q, block_k, seq_k,
-                       seq_k_padded, n_q, has_lens, has_seg, pid_off=0):
+                       seq_k_padded, n_q, has_lens, has_seg, pid_off=0,
+                       has_mask=False):
     """Single-K-block backward (n_k == 1): the score/dp recompute is
     shared, so the whole backward is 5 dots (s, dv, dp, dq, dk) instead
     of the split kernels' 7.  Grid (BH, n_q) sequential over q blocks:
     dq writes per-block, dk/dv accumulate in VMEM scratch."""
     import jax.experimental.pallas as pl
 
-    lens_ref, qseg_ref, kseg_ref, rest = _bwd_unpack(rest, has_lens, has_seg)
+    lens_ref, qseg_ref, kseg_ref, mask_refs, rest = _bwd_unpack(
+        rest, has_lens, has_seg, has_mask)
     dq_ref, dk_ref, dv_ref, dk_acc, dv_acc = rest
 
     qi = pl.program_id(1 + pid_off)
@@ -1010,7 +1269,7 @@ def _dqkv_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
         q, k, v, do, pT, dsT = _bwd_core(
             q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref, qseg_ref,
             kseg_ref, has_seg, use_mask, qi, ki, scale, causal,
-            block_q, block_k, seq_k, kvlen)
+            block_q, block_k, seq_k, kvlen, mask_refs=mask_refs)
         dv_acc[...] += lax.dot_general(
             pT.astype(do.dtype), do, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -1027,7 +1286,7 @@ def _dqkv_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
     # through pT == 0.  The ladder still specializes causal full-blocks
     # to the mask-free path.
     _run_mask_specialized(pl, _compute, True, qi, ki, block_q, block_k,
-                          causal, has_lens, has_seg, needs_tail,
+                          causal, has_lens, has_seg or has_mask, needs_tail,
                           kvlen=kvlen, seq_k=seq_k)
 
     @pl.when(qi == n_q - 1)
@@ -1041,7 +1300,7 @@ def _dqkv_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
 def _dqkv_single_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
                         *rest, scale, causal, block_q, block_k, seq_k,
                         seq_k_padded, has_lens, has_seg, heads=1,
-                        delta_of_out=False):
+                        delta_of_out=False, has_mask=False):
     """Single-block backward (n_q == n_k == 1): the short-seq analogue of
     ``_dqkv_fused_kernel``.  With the whole (Tq, Tk) extent resident as
     one block there is no grid axis to stream over, so the dk/dv VMEM
@@ -1054,7 +1313,8 @@ def _dqkv_single_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
     not δ (``_bwd_core``)."""
     import jax.experimental.pallas as pl
 
-    lens_ref, qseg_ref, kseg_ref, rest = _bwd_unpack(rest, has_lens, has_seg)
+    lens_ref, qseg_ref, kseg_ref, mask_refs, rest = _bwd_unpack(
+        rest, has_lens, has_seg, has_mask)
     dq_ref, dk_ref, dv_ref = rest
 
     kvlen = lens_ref[pl.program_id(0), 0] if has_lens else None
@@ -1067,7 +1327,7 @@ def _dqkv_single_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
                 q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref, qseg_ref,
                 kseg_ref, has_seg, use_mask, 0, 0, scale, causal,
                 block_q, block_k, seq_k, kvlen, head=h, heads=heads,
-                delta_of_out=delta_of_out)
+                delta_of_out=delta_of_out, mask_refs=mask_refs)
             dv.append(lax.dot_general(
                 pT.astype(do.dtype), do, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32))
@@ -1081,7 +1341,7 @@ def _dqkv_single_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
             ref[...] = _join_heads(parts).astype(ref.dtype).reshape(
                 ref.shape)
 
-    if has_lens:
+    if has_lens or has_mask:
         _compute(True)      # one body, as in _fwd_kernel_single
     else:
         _run_mask_specialized(pl, _compute, True, 0, 0, block_q, block_k,
@@ -1091,10 +1351,12 @@ def _dqkv_single_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref, *rest,
                 scale, causal, block_q, block_k, seq_k, seq_k_padded, n_q,
-                has_lens, has_seg, pid_off=0, group=1):
+                has_lens, has_seg, pid_off=0, group=1, tiles=None,
+                mask_heads=1):
     import jax.experimental.pallas as pl
 
-    lens_ref, qseg_ref, kseg_ref, rest = _bwd_unpack(rest, has_lens, has_seg)
+    lens_ref, qseg_ref, kseg_ref, mask_refs, rest = _bwd_unpack(
+        rest, has_lens, has_seg, tiles is not None)
     dk_ref, dv_ref, dk_acc, dv_acc = rest
 
     ki = pl.program_id(1 + pid_off)
@@ -1114,7 +1376,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref, *rest,
         q, k, v, do, pT, dsT = _bwd_core(
             q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref, qseg_ref,
             kseg_ref, has_seg, use_mask, qi, ki, scale, causal,
-            block_q, block_k, seq_k, kvlen)
+            block_q, block_k, seq_k, kvlen, mask_refs=mask_refs)
         dv_acc[...] += lax.dot_general(
             pT.astype(do.dtype), do, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -1122,15 +1384,13 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref, *rest,
             dsT.astype(q.dtype), q, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    run = True
-    if causal:
-        run = (qi * block_q + block_q - 1) >= (ki * block_k)
-    if has_lens:
-        # dk/dv of keys past the valid length are zero — skip the block
-        run = run & (ki * block_k < kvlen)
-    _run_mask_specialized(pl, _compute, run, qi, ki, block_q, block_k,
-                          causal, has_lens, has_seg, needs_tail,
-                          kvlen=kvlen, seq_k=seq_k)
+    # ``mask_heads`` is the key-value heads a batch row here; dk/dv of keys
+    # past the valid length are zero: such a block is skipped too
+    tile = None if tiles is None else tiles[
+        (pl.program_id(0) // mask_heads * n_q + qi)
+        * pl.num_programs(1 + pid_off) + ki]
+    _run_streamed_block(pl, _compute, tile, qi, ki, block_q, block_k, causal,
+                        has_lens, has_seg, needs_tail, kvlen, seq_k)
 
     @pl.when(step == group * n_q - 1)
     def _finalize():
@@ -1140,21 +1400,136 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref, *rest,
             dv_ref.shape)
 
 
+def _masked_split_bwd(pl, pltpu, operands, masks, common, dims, interpret):
+    """``flash_masked_dq`` and ``flash_masked_dkv``: the split backward
+    under a mask given as data, on ``pallas_flash_attention_bwd``'s padded
+    operands.  Both grids are the unmasked kernels'; each step reads its
+    tile's state from the scalar-prefetched summary, and the operand that
+    the sequential axis streams — K and V for dq, the q side for dk/dv —
+    is addressed through the table of resident blocks, so a dead tile's
+    step fetches nothing.  Returns the padded (dq, dk, dv)."""
+    qp, kp, vp, dop, lsep, dltp = operands
+    qm, qm_rows, km = masks
+    B, H, group, n_q, n_k, Dp = dims
+    Hkv = H // group
+    kvb = _kv_row(group)
+    block_q, block_k = common["block_q"], common["block_k"]
+    states = _tile_states(qm, km, block_q, block_k)       # (B, n_q, n_k)
+    live = states > 0
+    tiles = states.reshape(-1)
+
+    def kblk(b, qi, ki, tiles, fetch):
+        return fetch[(b // H * n_q + qi) * n_k + ki]
+    dq = pl.pallas_call(
+        _tiles_first(functools.partial(_dq_kernel, n_k=n_k, mask_heads=H,
+                                       **common)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(B * H, n_q, n_k),
+            in_specs=[
+                pl.BlockSpec((1, block_q, Dp),
+                             lambda b, qi, ki, *_: (b, qi, 0)),
+                pl.BlockSpec((1, block_k, Dp), lambda b, qi, ki, *t:
+                             (kvb(b), kblk(b, qi, ki, *t), 0)),
+                pl.BlockSpec((1, block_k, Dp), lambda b, qi, ki, *t:
+                             (kvb(b), kblk(b, qi, ki, *t), 0)),
+                pl.BlockSpec((1, block_q, Dp),
+                             lambda b, qi, ki, *_: (b, qi, 0)),
+                pl.BlockSpec((1, 1, block_q),
+                             lambda b, qi, ki, *_: (b, 0, qi)),
+                pl.BlockSpec((1, 1, block_q),
+                             lambda b, qi, ki, *_: (b, 0, qi)),
+                pl.BlockSpec((1, 2, block_q),
+                             lambda b, qi, ki, *_: (b // H, 0, qi)),
+                pl.BlockSpec((1, block_k, 2), lambda b, qi, ki, *t:
+                             (b // H, kblk(b, qi, ki, *t), 0)),
+            ],
+            out_specs=pl.BlockSpec((1, block_q, Dp),
+                                   lambda b, qi, ki, *_: (b, qi, 0)),
+            scratch_shapes=[pltpu.VMEM((block_q, Dp), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct(qp.shape, qp.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+        name="flash_masked_dq",
+    )(tiles, _resident_block(live).reshape(-1), qp, kp, vp, dop, lsep, dltp,
+      qm_rows, km)
+
+    # dk/dv: a key-value head's sequential axis walks the q blocks of its
+    # ``group`` query heads in turn (step j: head j // n_q, block
+    # j % n_q), every head under the same column of the summary
+    steps = group * n_q
+    walk = jnp.tile(jnp.swapaxes(live, 1, 2), (1, 1, group))  # (B,n_k,steps)
+
+    def step(b, ki, j, tiles, fetch):
+        return fetch[(b // Hkv * n_k + ki) * steps + j]
+
+    def q_block(b, ki, j, *t):
+        at = step(b, ki, j, *t)
+        return b * group + at // n_q, at % n_q
+
+    def rows(index):                 # (row, q block) -> a block index
+        return lambda b, ki, j, *t: index(*q_block(b, ki, j, *t))
+    dk, dv = pl.pallas_call(
+        _tiles_first(functools.partial(_dkv_kernel, n_q=n_q, group=group,
+                                       mask_heads=Hkv, **common)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(B * Hkv, n_k, steps),
+            in_specs=[
+                pl.BlockSpec((1, block_q, Dp), rows(lambda r, i: (r, i, 0))),
+                pl.BlockSpec((1, block_k, Dp),
+                             lambda b, ki, j, *_: (b, ki, 0)),
+                pl.BlockSpec((1, block_k, Dp),
+                             lambda b, ki, j, *_: (b, ki, 0)),
+                pl.BlockSpec((1, block_q, Dp), rows(lambda r, i: (r, i, 0))),
+                pl.BlockSpec((1, 1, block_q), rows(lambda r, i: (r, 0, i))),
+                pl.BlockSpec((1, 1, block_q), rows(lambda r, i: (r, 0, i))),
+                pl.BlockSpec((1, 2, block_q), lambda b, ki, j, *t: (
+                    b // Hkv, 0, step(b, ki, j, *t) % n_q)),
+                pl.BlockSpec((1, block_k, 2),
+                             lambda b, ki, j, *_: (b // Hkv, ki, 0)),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, block_k, Dp),
+                             lambda b, ki, j, *_: (b, ki, 0)),
+                pl.BlockSpec((1, block_k, Dp),
+                             lambda b, ki, j, *_: (b, ki, 0)),
+            ],
+            scratch_shapes=[pltpu.VMEM((block_k, Dp), jnp.float32),
+                            pltpu.VMEM((block_k, Dp), jnp.float32)]),
+        out_shape=[jax.ShapeDtypeStruct(kp.shape, kp.dtype),
+                   jax.ShapeDtypeStruct(vp.shape, vp.dtype)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+        name="flash_masked_dkv",
+    )(tiles, _resident_block(walk).reshape(-1), qp, kp, vp, dop, lsep, dltp,
+      qm_rows, km)
+    return dq, dk, dv
+
+
 def pallas_flash_attention_bwd(q, k, v, out, lse, do, causal=False,
                                scale=None, block_q: Optional[int] = None,
                                block_k: Optional[int] = None,
                                interpret: bool = False,
                                kv_lens=None, q_segments=None,
-                               kv_segments=None):
-    """Flash backward: (dq, dk, dv) without materialising (Tq, Tk)."""
+                               kv_segments=None, q_mask=None, kv_mask=None):
+    """Flash backward: (dq, dk, dv) without materialising (Tq, Tk).
+    Under ``q_mask`` / ``kv_mask`` with the K axis streamed the split
+    kernels are ``flash_masked_dq`` and ``flash_masked_dkv``: each skips
+    the tiles with no live pair by the summary at ITS blocks, and its
+    index maps fetch nothing for them (``pallas_flash_attention``)."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     B, H, Tq, D = q.shape
     Tk = k.shape[2]
     scale = scale if scale is not None else D ** -0.5
+    _check_mask_alone(q_mask, kv_mask, causal, kv_lens, q_segments)
     if block_q is None or block_k is None:
-        tq, tk = tune_attention_blocks(Tq, Tk, D, q.dtype)
+        tq, tk = tune_attention_blocks(Tq, Tk, D, q.dtype,
+                                       q_mask is not None)
         block_q = tq if block_q is None else block_q
         block_k = tk if block_k is None else block_k
     block_q = min(block_q, max(8, Tq))
@@ -1192,6 +1567,11 @@ def pallas_flash_attention_bwd(q, k, v, out, lse, do, causal=False,
     common = dict(scale=scale, causal=causal, block_q=block_q,
                   block_k=block_k, seq_k=Tk, seq_k_padded=Tkp,
                   has_lens=lens is not None, has_seg=qs_row is not None)
+    masked = q_mask is not None
+    if masked:
+        # queries along the lanes, keys down the sublanes
+        qm, km = _mask_operands(q_mask, kv_mask, Tqp, Tkp)
+        qm_rows = jnp.swapaxes(qm, 1, 2)
 
     def per_kv_head(d):
         """(B*H, Tkp, Dp) dk or dv of the one-K-block kernels, which
@@ -1223,8 +1603,15 @@ def pallas_flash_attention_bwd(q, k, v, out, lse, do, causal=False,
                     pl.BlockSpec((1, 1, block_q), lambda b: (b, 0, 0)),
                     pl.BlockSpec((1, block_k, 1), lambda b: (b, 0, 0)),
                 ]
+            if masked:
+                fused_extra += [qm_rows, km]
+                fused_especs += [
+                    pl.BlockSpec((1, 2, block_q), lambda b: (b // H, 0, 0)),
+                    pl.BlockSpec((1, block_k, 2), lambda b: (b // H, 0, 0)),
+                ]
             dq, dk, dv = pl.pallas_call(
-                functools.partial(_dqkv_single_kernel, **common),
+                functools.partial(_dqkv_single_kernel, has_mask=masked,
+                                  **common),
                 grid=(B * H,),
                 in_specs=[
                     pl.BlockSpec((1, block_q, Dp), lambda b: (b, 0, 0)),
@@ -1257,8 +1644,15 @@ def pallas_flash_attention_bwd(q, k, v, out, lse, do, causal=False,
                 pl.BlockSpec((1, 1, block_q), lambda b, qi: (b, 0, qi)),
                 pl.BlockSpec((1, block_k, 1), lambda b, qi: (b, 0, 0)),
             ]
+        if masked:
+            fused_extra += [qm_rows, km]
+            fused_especs += [
+                pl.BlockSpec((1, 2, block_q), lambda b, qi: (b // H, 0, qi)),
+                pl.BlockSpec((1, block_k, 2), lambda b, qi: (b // H, 0, 0)),
+            ]
         dq, dk, dv = pl.pallas_call(
-            functools.partial(_dqkv_fused_kernel, n_q=n_q, **common),
+            functools.partial(_dqkv_fused_kernel, n_q=n_q, has_mask=masked,
+                              **common),
             grid=(B * H, n_q),
             in_specs=[
                 pl.BlockSpec((1, block_q, Dp), lambda b, qi: (b, qi, 0)),
@@ -1287,6 +1681,16 @@ def pallas_flash_attention_bwd(q, k, v, out, lse, do, causal=False,
         )(qp, kp, vp, dop, lsep, dltp, *fused_extra)
         dq = dq.reshape(B, H, Tqp, Dp)[:, :, :Tq, :D]
         return dq, per_kv_head(dk), per_kv_head(dv)
+
+    def unpadded(dq, dk, dv):
+        return (dq.reshape(B, H, Tqp, Dp)[:, :, :Tq, :D],
+                dk.reshape(B, Hkv, Tkp, Dp)[:, :, :Tk, :D],
+                dv.reshape(B, Hkv, Tkp, Dp)[:, :, :Tk, :D])
+
+    if masked:
+        return unpadded(*_masked_split_bwd(
+            pl, pltpu, (qp, kp, vp, dop, lsep, dltp), (qm, qm_rows, km),
+            common, (B, H, group, n_q, n_k, Dp), interpret))
 
     def extra_for(kv_idx, q_idx, q_row=lambda b, i, j: b, lens=lens,
                   ks_col=ks_col):
@@ -1380,10 +1784,7 @@ def pallas_flash_attention_bwd(q, k, v, out, lse, do, causal=False,
         name="flash_dkv",
     )(qp, kp, vp, dop, lsep, dltp, *kv_extra)
 
-    dq = dq.reshape(B, H, Tqp, Dp)[:, :, :Tq, :D]
-    dk = dk.reshape(B, Hkv, Tkp, Dp)[:, :, :Tk, :D]
-    dv = dv.reshape(B, Hkv, Tkp, Dp)[:, :, :Tk, :D]
-    return dq, dk, dv
+    return unpadded(dq, dk, dv)
 
 
 def pallas_flash_attention_bwd_bshd(q, k, v, out, lse, do, causal=False,
@@ -1586,7 +1987,8 @@ def _int_zero_cotangent(x):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 def flash_attention(q, k, v, causal=False, scale=None, kv_lens=None,
-                    q_segments=None, kv_segments=None):
+                    q_segments=None, kv_segments=None, q_mask=None,
+                    kv_mask=None):
     """Fused attention: Pallas kernels on TPU, jnp blockwise elsewhere.
 
     softmax(q·kᵀ·scale [+ masks])·v over (B, H, T, D) inputs; ``k`` and
@@ -1596,13 +1998,25 @@ def flash_attention(q, k, v, causal=False, scale=None, kv_lens=None,
     ``causal`` (static), ``kv_lens`` (B,) per-row valid key length
     (padding mask — blocks past the length are skipped, not just masked),
     and ``q_segments``/``kv_segments`` (B, T) packed-sequence ids.
-    Rows with no visible key return 0."""
+    Rows with no visible key return 0.
+
+    Or a mask given as data, in place of the three: ``q_mask`` (B, Tq, 2)
+    holds each query's [reach, own] and ``kv_mask`` (B, Tk, 2) each key's
+    [rank, own], integers; query i sees key j iff ``rank[j] <= reach[i]``
+    or ``own[j] == own[i] >= 0`` (a negative ``own`` is none).  Causal
+    order is rank = reach = position; packed documents are ``own``s;
+    block diffusion's row of clean and noised halves is both (a clean
+    key's rank its block, a noised key's ``2**31 - 1`` — never —, a
+    noised query's reach the block before its own, and the noised
+    tokens' ``own`` their block).  On the chip the kernels visit only the
+    tiles that hold a live pair (``pallas_flash_attention``)."""
     return _flash_fwd(q, k, v, causal, scale, kv_lens, q_segments,
-                      kv_segments)[0]
+                      kv_segments, q_mask, kv_mask)[0]
 
 
 def _reference_attention(q, k, v, causal, scale, kv_lens=None,
-                         q_segments=None, kv_segments=None):
+                         q_segments=None, kv_segments=None, q_mask=None,
+                         kv_mask=None):
     group = _kv_group(q, k)
     # graftlint: disable-next=trace-tracer-branch -- group is a Python int
     # from the operands' static shapes
@@ -1610,7 +2024,7 @@ def _reference_attention(q, k, v, causal, scale, kv_lens=None,
         # off the chip the shared heads are repeated (the kernels' index
         # maps share them instead); autodiff sums the group's gradients
         k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
-    if kv_lens is None and q_segments is None:
+    if kv_lens is None and q_segments is None and q_mask is None:
         from ..parallel.ring_attention import blockwise_attention
         return blockwise_attention(q, k, v, causal=causal, scale=scale)
     # masked dense oracle (test/CPU path): additive -inf mask, fp32 softmax
@@ -1627,6 +2041,10 @@ def _reference_attention(q, k, v, causal, scale, kv_lens=None,
                        == kv_segments[:, None, None, :])
     if causal:
         mask = mask & (jnp.arange(Tq)[:, None] >= jnp.arange(Tk)[None, :])
+    if q_mask is not None:
+        reach, q_own = q_mask[:, None, :, None, 0], q_mask[:, None, :, None, 1]
+        rank, k_own = kv_mask[:, None, None, :, 0], kv_mask[:, None, None, :, 1]
+        mask = mask & ((rank <= reach) | ((k_own == q_own) & (q_own >= 0)))
     s = jnp.where(mask, s, _NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     # fully-masked rows: uniform softmax garbage -> force exact zeros,
@@ -1636,46 +2054,78 @@ def _reference_attention(q, k, v, causal, scale, kv_lens=None,
     return jnp.einsum("bhqk,bhkd->bhqd", p, v)
 
 
-def _flash_fwd(q, k, v, causal, scale, kv_lens, q_segments, kv_segments):
+def _tiles_visited(q, k, q_mask, kv_mask, on_tpu):
+    """The forward's tiles a head row that a mask given as data leaves
+    live, at the planned blocks, where the mask is there to be read — an
+    eager call on a TPU with the K axis streamed; None otherwise."""
+    if isinstance(q_mask, jax.core.Tracer) \
+            or isinstance(kv_mask, jax.core.Tracer):
+        return None
     plan = attention_dispatch(q.shape[2], k.shape[2], q.shape[3], q.dtype,
-                              on_tpu=_context.on_tpu(q))
+                              on_tpu=on_tpu, census=False, masked=True)
+    if plan["kernel"] != "streaming":
+        return None
+    states = _states_at(q_mask, kv_mask, plan["block_q"], plan["block_k"])
+    # graftlint: disable-next=trace-host-sync -- eager calls only: a traced
+    # mask returned above
+    return int(jnp.sum(states > 0))
+
+
+def _flash_fwd(q, k, v, causal, scale, kv_lens, q_segments, kv_segments,
+               q_mask=None, kv_mask=None):
+    on_tpu = _context.on_tpu(q)
+    plan = attention_dispatch(
+        q.shape[2], k.shape[2], q.shape[3], q.dtype, on_tpu=on_tpu,
+        masked=q_mask is not None,
+        tiles_visited=None if q_mask is None else _tiles_visited(
+            q, k, q_mask, kv_mask, on_tpu))
+    # graftlint: disable-next=trace-tracer-branch -- the plan is Python
+    # values: the dispatcher reads shapes, a dtype and a host-side count
     if plan["kernel"] != "dense_fallback":
         out, lse = per_batch_shard(
-            lambda q, k, v, kl, qs, ks: pallas_flash_attention(
+            lambda q, k, v, kl, qs, ks, qm, km: pallas_flash_attention(
                 q, k, v, causal=causal, scale=scale, return_lse=True,
                 block_q=plan["block_q"], block_k=plan["block_k"],
-                kv_lens=kl, q_segments=qs, kv_segments=ks),
-            (q, k, v, kv_lens, q_segments, kv_segments))
-        return out, (q, k, v, out, lse, kv_lens, q_segments, kv_segments)
+                kv_lens=kl, q_segments=qs, kv_segments=ks, q_mask=qm,
+                kv_mask=km),
+            (q, k, v, kv_lens, q_segments, kv_segments, q_mask, kv_mask))
+        return out, (q, k, v, out, lse, kv_lens, q_segments, kv_segments,
+                     q_mask, kv_mask)
+    _check_mask_alone(q_mask, kv_mask, causal, kv_lens, q_segments)
     out = _reference_attention(q, k, v, causal, scale, kv_lens, q_segments,
-                               kv_segments)
-    return out, (q, k, v, None, None, kv_lens, q_segments, kv_segments)
+                               kv_segments, q_mask, kv_mask)
+    return out, (q, k, v, None, None, kv_lens, q_segments, kv_segments,
+                 q_mask, kv_mask)
 
 
 def _flash_bwd(causal, scale, res, g):
-    q, k, v, out, lse, kv_lens, q_segments, kv_segments = res
+    q, k, v, out, lse, kv_lens, q_segments, kv_segments, q_mask, kv_mask = res
     if lse is not None:
         # re-consult the dispatcher (trace-time, deterministic) for the
         # forward's blocks: custom_vjp residuals cannot carry static
         # ints.  census=False: the shape was counted at the forward trace
         plan = attention_dispatch(q.shape[2], k.shape[2], q.shape[3],
-                                  q.dtype, census=False)
+                                  q.dtype, census=False,
+                                  masked=q_mask is not None)
         dq, dk, dv = per_batch_shard(
-            lambda q, k, v, out, lse, g, kl, qs, ks:
+            lambda q, k, v, out, lse, g, kl, qs, ks, qm, km:
             pallas_flash_attention_bwd(
                 q, k, v, out, lse, g, causal=causal, scale=scale,
                 block_q=plan["block_q"], block_k=plan["block_k"],
-                kv_lens=kl, q_segments=qs, kv_segments=ks),
-            (q, k, v, out, lse, g, kv_lens, q_segments, kv_segments))
+                kv_lens=kl, q_segments=qs, kv_segments=ks, q_mask=qm,
+                kv_mask=km),
+            (q, k, v, out, lse, g, kv_lens, q_segments, kv_segments, q_mask,
+             kv_mask))
     else:
         # recompute-based VJP through the memory-linear jnp path
         _, vjp = jax.vjp(
             lambda q_, k_, v_: _reference_attention(
-                q_, k_, v_, causal, scale, kv_lens, q_segments, kv_segments),
+                q_, k_, v_, causal, scale, kv_lens, q_segments, kv_segments,
+                q_mask, kv_mask),
             q, k, v)
         dq, dk, dv = vjp(g)
-    return (dq, dk, dv, _int_zero_cotangent(kv_lens),
-            _int_zero_cotangent(q_segments), _int_zero_cotangent(kv_segments))
+    return (dq, dk, dv) + tuple(_int_zero_cotangent(m) for m in (
+        kv_lens, q_segments, kv_segments, q_mask, kv_mask))
 
 
 flash_attention.defvjp(_flash_fwd, _flash_bwd)
@@ -1684,13 +2134,23 @@ flash_attention.defvjp(_flash_fwd, _flash_bwd)
 @register("_contrib_flash_attention", aliases=("flash_attention",))
 def _flash_attention_op(queries, keys, values, causal: bool = False,
                         scale: Optional[float] = None, kv_lens=None,
-                        q_segments=None, kv_segments=None):
+                        q_segments=None, kv_segments=None, q_mask=None,
+                        kv_mask=None):
     """Fused multi-head attention op (TPU-native counterpart of the
     reference's ``_contrib_interleaved_matmul_selfatt_*`` pipeline,
     src/operator/contrib/transformer.cc).  The mask operands follow
     causal/scale so pre-mask positional callers keep working."""
     return flash_attention(queries, keys, values, causal, scale, kv_lens,
-                           q_segments, kv_segments)
+                           q_segments, kv_segments, q_mask, kv_mask)
+
+
+@register("_contrib_attention_mask_tiles", num_outputs=2,
+          differentiable=False, aliases=("attention_mask_tiles",))
+def _attention_mask_tiles_op(q_mask, kv_mask, head_dim: int = 128,
+                             dtype: str = "bfloat16"):
+    """``mask_tiles``: the tiles a masked ``flash_attention`` call visits
+    and has, a head row, forward and backward."""
+    return mask_tiles(q_mask, kv_mask, head_dim, dtype)
 
 
 # --- BSHD (batch, seq, heads, head_dim) entry: no layout transposes ----

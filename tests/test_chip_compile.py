@@ -199,8 +199,53 @@ def _scan_programs(case):
     return programs
 
 
+# (id, B, H, key-value heads, S, D, dtype): a mask given as data (q_mask,
+# kv_mask)
+_MASKED = [
+    # the SDAR cell's call: a [clean ; noised] row of 2 x 4096, GQA 32 on 4
+    ("masked_s8192_d128_gqa8", 1, 32, 4, 8192, 128, "bfloat16"),
+    # float32 operands at the same blocks: the largest working set
+    ("masked_s4096_d128_f32", 1, 8, 8, 4096, 128, "float32"),
+    # one K block, q streamed, and one block in all: the mask on every tile
+    ("masked_s1024_d128_gqa8", 1, 32, 4, 1024, 128, "bfloat16"),
+    ("masked_s256_d64", 2, 4, 4, 256, 64, "bfloat16"),
+]
+
+
+def _masked_programs(case):
+    """Forward and backward under ``q_mask`` / ``kv_mask`` at the blocks
+    ``attention_dispatch`` plans for a masked call."""
+    _, B, H, Hkv, S, D, dtype = case
+    dtype = jnp.dtype(dtype)
+
+    def programs(one_chip):
+        plan = PA.attention_dispatch(S, S, D, dtype, on_tpu=True,
+                                     census=False, masked=True)
+        blocks = dict(block_q=plan["block_q"], block_k=plan["block_k"])
+
+        def sds(shape, dt=dtype):
+            return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+        q, kv = sds((B, H, S, D)), sds((B, Hkv, S, D))
+        mask = sds((B, S, 2), jnp.int32)
+
+        def fwd(q, k, v, qm, km):
+            return PA.pallas_flash_attention(
+                q, k, v, return_lse=True, q_mask=qm, kv_mask=km, **blocks)
+
+        def bwd(q, k, v, out, lse, do, qm, km):
+            return PA.pallas_flash_attention_bwd(
+                q, k, v, out, lse, do, q_mask=qm, kv_mask=km, **blocks)
+
+        return [(fwd, (q, kv, kv, mask, mask)),
+                (bwd, (q, kv, kv, q, sds((B, H, S), jnp.float32), q, mask,
+                       mask))]
+    return programs
+
+
 # case id -> one_chip -> [(function, specs)]: a forward and its backward
 _PROGRAMS = {c[0]: _attention_programs(c) for c in _ATTENTION}
+_PROGRAMS.update({c[0]: _masked_programs(c) for c in _MASKED})
 _PROGRAMS["layernorm_bert"] = _layernorm_programs
 _PROGRAMS.update({c[0]: _scan_programs(c) for c in _SCANS})
 
@@ -227,11 +272,28 @@ _KERNELS = {
 }
 _KERNELS.update({c[0]: (["ssd_fwd"], ["ssd_bwd", "ssd_states"])
                  for c in _SCANS})
+# a streamed K axis under a mask as data has kernels of its own names (the
+# tile summary is scalar-prefetched); the one-block kernels take the mask
+# as two more operands under the names they have
+_KERNELS.update({
+    "masked_s8192_d128_gqa8": (["flash_masked_fwd"],
+                               ["flash_masked_dkv", "flash_masked_dq"]),
+    "masked_s4096_d128_f32": (["flash_masked_fwd"],
+                              ["flash_masked_dkv", "flash_masked_dq"]),
+    "masked_s1024_d128_gqa8": (["flash_short_fwd"], ["flash_dqkv_fused"]),
+    "masked_s256_d64": (["flash_short_fwd"], ["flash_dqkv_single"]),
+})
 
 
 @pytest.mark.parametrize("case_id", [c[0] for c in _ATTENTION])
 def test_flash_attention_fwd_bwd_compiles_at_dispatcher_blocks(compiled,
                                                                case_id):
+    compiled(case_id)
+
+
+@pytest.mark.parametrize("case_id", [c[0] for c in _MASKED])
+def test_flash_attention_compiles_under_a_mask_given_as_data(compiled,
+                                                             case_id):
     compiled(case_id)
 
 
@@ -684,3 +746,64 @@ def test_nemotron_layer_train_step_compiles(one_chip, monkeypatch, kind):
     temp = compiled.memory_analysis().temp_size_in_bytes / 1e9
     print("temporaries of the %s layer's step: %.3f GB" % (kind, temp))
     assert temp <= temp_gb, temp
+
+
+def test_sdar_layer_train_step_compiles_to_the_masked_kernels(one_chip,
+                                                              monkeypatch):
+    """One layer of the SDAR cell at the published widths (32 query on 4
+    key-value heads of 128 with q/k norm and rotary at position ids, 16 of
+    128 gated-SiLU experts of width 768 held, 8 routes a token) under a
+    small untied head, one [clean ; noised] row of 2 x 4,096 tokens, bf16
+    with ``Adam(multi_precision=True)`` and the block-diffusion loss: the
+    ``DataParallelStep`` program compiles for the described chip; its
+    attention is EXACTLY the three masked kernels — no unmasked flash
+    kernel, and no (8192, 8192) score or mask array anywhere outside
+    them —; the experts' other kernels are the compiler's ragged
+    products on the side of the ``conditional`` that a lumpy router
+    takes; the blocks' names are in the instructions' ``op_name``s."""
+    import mxnet_tpu as mx
+    from mxnet_tpu import context, gluon, parallel
+    from mxnet_tpu import random as mx_random
+
+    monkeypatch.setattr(context, "on_tpu", lambda *a: True)
+    net = gluon.model_zoo.sdar(num_layers=1, vocab_size=2048,
+                               experts_held=(0, 16))
+    net.initialize(mx.init.Zero())
+    net.cast("bfloat16")
+    step = parallel.DataParallelStep(
+        net, gluon.loss.BlockDiffusionLoss(block_rows=2048),
+        mx.optimizer.Adam(learning_rate=1e-4, multi_precision=True))
+
+    def spec(v):
+        return jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=one_chip)
+
+    def ints(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    state = jax.tree_util.tree_map(
+        spec, [[p._data._data for p in step._params], step._opt_states])
+    carries = [jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
+               jax.ShapeDtypeStruct((len(step._trainable),), jnp.float32,
+                                    sharding=one_chip),
+               spec(mx_random.next_key())]
+    data = (ints(1, 8192), ints(1, 8192), ints(1, 8192, 2),
+            ints(1, 8192, 2))
+    label = jax.ShapeDtypeStruct((1, 2, 4096), jnp.float32,
+                                 sharding=one_chip)
+    compiled = step._build().lower(*state, *carries, data, label).compile()
+    text = compiled.as_text()
+    names = collections.Counter(_kernel_names(text))
+    assert {n: c for n, c in names.items() if n.startswith("flash")} == {
+        "flash_masked_fwd": 1, "flash_masked_dq": 1, "flash_masked_dkv": 1}
+    assert set(names) - {"flash_masked_fwd", "flash_masked_dq",
+                         "flash_masked_dkv"} \
+        == {"ragged-dot-none", "ragged-dot-metadata"}
+    assert not re.findall(r"\w+\[(?:\d+,)*8192,8192\]", text)
+    for block in ("layer0_attn_qkv", "layer0_attn/flash_masked_fwd",
+                  "layer0_attn/flash_masked_dq",
+                  "layer0_attn/flash_masked_dkv", "layer0_router",
+                  "layer0_experts", "mask"):
+        assert re.search(r"[/_]%s/" % re.escape(block), text), block
+    temp = compiled.memory_analysis().temp_size_in_bytes / 1e9
+    print("temporaries of the SDAR layer's step: %.3f GB" % temp)
+    assert temp <= 2.089 * 1.05, temp

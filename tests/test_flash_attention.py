@@ -477,3 +477,211 @@ def test_fused_single_kblock_bwd_matches_split(config):
                                            block_k=64, **kw)    # n_k=2
     for a, b in zip(g_fused, g_split):
         assert float(jnp.max(jnp.abs(a - b))) < 2e-5
+
+
+# ---------------------------------------------------------------------------
+# a mask given as data: q_mask [reach, own], kv_mask [rank, own]
+# ---------------------------------------------------------------------------
+
+_NEVER = 2 ** 31 - 1
+
+
+def _block_diffusion_operands(length, block, drop=0):
+    """A ``[clean ; noised]`` row of 2 x ``length`` tokens in blocks of
+    ``block`` (``gluon.model_zoo.block_diffusion_mask`` has the rule),
+    its last ``drop`` tokens cut off so that the row is ragged."""
+    from mxnet_tpu.gluon.model_zoo import block_diffusion_mask
+    q_mask, kv_mask = block_diffusion_mask(length, block)
+    keep = 2 * length - drop
+    return jnp.asarray(q_mask[:, :keep]), jnp.asarray(kv_mask[:, :keep])
+
+
+def _visible(q_mask, kv_mask):
+    reach, q_own = q_mask[:, :, None, 0], q_mask[:, :, None, 1]
+    rank, k_own = kv_mask[:, None, :, 0], kv_mask[:, None, :, 1]
+    return onp.asarray((rank <= reach) | ((k_own == q_own) & (q_own >= 0)))
+
+
+def _masked_dense(q, k, v, q_mask, kv_mask):
+    """Plain float32 attention under the dense mask, GQA by repetition;
+    a query that sees no key gives zero."""
+    group = q.shape[1] // k.shape[1]
+    k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
+    seen = jnp.asarray(_visible(q_mask, kv_mask))[:, None]
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) * q.shape[-1] ** -0.5
+    p = jax.nn.softmax(jnp.where(seen, s, -1e30), axis=-1)
+    p = jnp.where(seen.any(-1, keepdims=True), p, 0.0)
+    return jnp.einsum("bhqk,bhkd->bhqd", p, v)
+
+
+# (block_q, block_k): streamed with square and oblong tiles, one K block
+# with q streamed (the fused backward), and one block in all
+@pytest.mark.parametrize("blocks", [(64, 64), (64, 128), (128, 64),
+                                    (64, 512), (512, 512)])
+def test_masked_kernels_match_dense_fwd_and_three_gradients(blocks):
+    """The mask as data in every BHSD kernel, 8 query heads on 1
+    key-value head, a ragged row (padded tiles): at 64 x 64 the 6 x 6
+    tiles are dead, whole and partial by turns, and the noised half's
+    diagonal tiles are live in one 4 x 4 square in 256."""
+    q_mask, kv_mask = _block_diffusion_operands(192, 4, drop=5)
+    s = q_mask.shape[1]
+    q = _rand((1, 8, s, 64), 0)
+    k, v = _rand((1, 1, s, 64), 1), _rand((1, 1, s, 64), 2)
+    g = _rand((1, 8, s, 64), 3)
+    bq, bk = blocks
+    out, lse = P.pallas_flash_attention(
+        q, k, v, block_q=bq, block_k=bk, interpret=True, return_lse=True,
+        q_mask=q_mask, kv_mask=kv_mask)
+    want, vjp = jax.vjp(
+        lambda q, k, v: _masked_dense(q, k, v, q_mask, kv_mask), q, k, v)
+    assert float(jnp.abs(out - want).max()) < 2e-5
+    # the op's own reference takes the same operands
+    ref = P._reference_attention(q, k, v, False, None, q_mask=q_mask,
+                                 kv_mask=kv_mask)
+    assert float(jnp.abs(ref - want).max()) < 2e-5
+    grads = P.pallas_flash_attention_bwd(
+        q, k, v, out, lse, g, block_q=bq, block_k=bk, interpret=True,
+        q_mask=q_mask, kv_mask=kv_mask)
+    for got, exp, name in zip(grads, vjp(g), "qkv"):
+        assert got.shape == exp.shape
+        assert float(jnp.abs(got - exp).max()) < 5e-5, name
+
+
+def test_tile_summary_is_exact_for_block_diffusion():
+    """``_tile_states`` against the pairs themselves: 0 exactly where a
+    tile holds no live pair, 2 exactly where all are live."""
+    q_mask, kv_mask = _block_diffusion_operands(256, 4)
+    qm, km = P._mask_operands(q_mask, kv_mask, 512, 512)
+    seen = _visible(q_mask, kv_mask)[0]
+    for bq, bk in ((64, 64), (128, 32), (32, 256)):
+        states = onp.asarray(P._tile_states(qm, km, bq, bk))[0]
+        tiles = seen.reshape(512 // bq, bq, 512 // bk, bk)
+        some, every = tiles.any((1, 3)), tiles.all((1, 3))
+        onp.testing.assert_array_equal(states > 0, some)
+        onp.testing.assert_array_equal(states == 2, every)
+    # 64 x 64: 8 tiles a side, 4 clean and 4 noised
+    states = onp.asarray(P._tile_states(qm, km, 64, 64))[0]
+    assert (states > 0).sum() == 10 + 10 + 4
+    assert (states == 2).sum() == 6 + 6
+
+
+def test_a_dead_tile_fetches_the_block_that_is_resident():
+    live = jnp.asarray([[False, False, True, False, True, False],
+                        [True, False, False, False, False, True],
+                        [False] * 6])
+    onp.testing.assert_array_equal(
+        onp.asarray(P._resident_block(live)),
+        [[2, 2, 2, 2, 4, 4], [0, 0, 0, 0, 0, 5], [0] * 6])
+
+
+def test_rows_and_tiles_wholly_dead_give_zero_and_finite_gradients():
+    """Queries that see nothing at all (a reach under every rank, no
+    ``own``) sit in tiles that are wholly dead: they return 0, take no
+    gradient, and leave the others' untouched."""
+    s = 256
+    pos = onp.arange(s)
+    q_mask = onp.stack([onp.where(pos < 128, -1, pos), -onp.ones(s, int)],
+                       -1)[None].astype("int32")
+    kv_mask = onp.stack([pos, -onp.ones(s, int)], -1)[None].astype("int32")
+    q_mask, kv_mask = jnp.asarray(q_mask), jnp.asarray(kv_mask)
+    q, k, v, g = (_rand((1, 2, s, 64), i) for i in range(4))
+    out, lse = P.pallas_flash_attention(
+        q, k, v, block_q=64, block_k=64, interpret=True, return_lse=True,
+        q_mask=q_mask, kv_mask=kv_mask)
+    assert not onp.asarray(out[:, :, :128]).any()
+    want = _dense(q, k, v, causal=True)
+    assert float(jnp.abs(out[:, :, 128:] - want[:, :, 128:]).max()) < 2e-5
+    dq, dk, dv = P.pallas_flash_attention_bwd(
+        q, k, v, out, lse, g, block_q=64, block_k=64, interpret=True,
+        q_mask=q_mask, kv_mask=kv_mask)
+    assert all(bool(jnp.isfinite(x).all()) for x in (dq, dk, dv))
+    assert not onp.asarray(dq[:, :, :128]).any()
+
+
+def test_causal_order_and_packed_documents_as_data():
+    """rank = reach = position is ``causal=True``; ``own`` = document id
+    with no reach is the segment mask: the same kernels, the mask given."""
+    s = 256
+    pos = onp.arange(s)
+    none = -onp.ones(s, int)
+    q, k, v = (_rand((1, 2, s, 64), 5 + i) for i in range(3))
+
+    def masked(q_cols, k_cols):
+        return P.pallas_flash_attention(
+            q, k, v, block_q=64, block_k=64, interpret=True,
+            q_mask=jnp.asarray(onp.stack(q_cols, -1)[None], jnp.int32),
+            kv_mask=jnp.asarray(onp.stack(k_cols, -1)[None], jnp.int32))
+    causal = P.pallas_flash_attention(q, k, v, causal=True, block_q=64,
+                                      block_k=64, interpret=True)
+    assert float(jnp.abs(masked((pos, none), (pos, none)) - causal).max()) \
+        < 2e-6
+    seg = jnp.asarray((pos // 100)[None], jnp.int32)
+    packed = P.pallas_flash_attention(
+        q, k, v, block_q=64, block_k=64, interpret=True, q_segments=seg,
+        kv_segments=seg)
+    got = masked((onp.full(s, -1), pos // 100),
+                 (onp.full(s, _NEVER), pos // 100))
+    assert float(jnp.abs(got - packed).max()) < 2e-6
+
+
+def test_mask_as_data_stands_alone():
+    q_mask, kv_mask = _block_diffusion_operands(64, 4)
+    q = k = v = _rand((1, 1, 128, 64), 0)
+    with pytest.raises(ValueError, match="go together"):
+        P.pallas_flash_attention(q, k, v, interpret=True, q_mask=q_mask)
+    for other in (dict(causal=True), dict(kv_lens=jnp.asarray([100]))):
+        with pytest.raises(ValueError, match="stand alone"):
+            P.pallas_flash_attention(q, k, v, interpret=True, q_mask=q_mask,
+                                     kv_mask=kv_mask, **other)
+    with pytest.raises(ValueError, match="stand alone"):
+        P.flash_attention(q, k, v, True, None, None, None, None, q_mask,
+                          kv_mask)
+
+
+def test_flash_attention_op_takes_the_mask_and_differentiates():
+    """The public op off the chip (the dense path) with the operands, as
+    ``F.flash_attention`` and under ``jax.grad``."""
+    q_mask, kv_mask = _block_diffusion_operands(32, 4)
+    q = _rand((1, 4, 64, 16), 0)
+    k, v = _rand((1, 2, 64, 16), 1), _rand((1, 2, 64, 16), 2)
+    want, vjp = jax.vjp(
+        lambda q, k, v: _masked_dense(q, k, v, q_mask, kv_mask), q, k, v)
+    got = mx.nd.flash_attention(
+        mx.nd.array(q), mx.nd.array(k), mx.nd.array(v),
+        q_mask=mx.nd.array(q_mask, dtype="int32"),
+        kv_mask=mx.nd.array(kv_mask, dtype="int32")).asnumpy()
+    assert onp.abs(got - onp.asarray(want)).max() < 2e-5
+    g = _rand(want.shape, 3)
+    grads = jax.grad(lambda q, k, v: jnp.sum(P.flash_attention(
+        q, k, v, False, None, None, None, None, q_mask, kv_mask) * g),
+        argnums=(0, 1, 2))(q, k, v)
+    for got, exp in zip(grads, vjp(g)):
+        assert float(jnp.abs(got - exp).max()) < 5e-5
+
+
+def test_dispatch_plans_the_mask():
+    """A streamed K axis under a mask takes 1024 x 1024 blocks, the unit
+    the mask skips by; the plan counts the forward's tiles; an unmasked
+    plan is what it was."""
+    plain = P.attention_dispatch(8192, 8192, 128, on_tpu=True, census=False)
+    assert plain == {"kernel": "streaming", "block_q": 512, "block_k": 2048,
+                     "layout": "bhsd", "heads_per_block": 1}
+    plan = P.attention_dispatch(8192, 8192, 128, on_tpu=True, census=False,
+                                masked=True, tiles_visited=80)
+    assert (plan["block_q"], plan["block_k"]) == (1024, 1024)
+    assert (plan["masked"], plan["tiles"]) == (True, 64)
+    short = P.attention_dispatch(512, 512, 64, on_tpu=True, census=False,
+                                 masked=True)
+    assert (short["kernel"], short["block_k"], short["tiles"]) \
+        == ("short_seq", 512, 1)
+    from mxnet_tpu import telemetry
+    before = telemetry.snapshot()["counters"].get(
+        "attention.kernel.masked", 0)
+    P.attention_dispatch(8192, 8192, 128, on_tpu=True, masked=True,
+                         tiles_visited=80)
+    assert telemetry.snapshot()["counters"]["attention.kernel.masked"] \
+        == before + 1
+    event = [e for e in telemetry.snapshot()["events"]
+             if e.get("kind") == "attention_dispatch"][-1]
+    assert (event["masked"], event["tiles"], event["tiles_visited"]) \
+        == (True, 64, 80)

@@ -609,6 +609,9 @@ COVERED_ELSEWHERE = {
     # the chunked state-space scan of PR 30: tests/test_nemotron_h.py,
     # value and every gradient against the step-by-step recurrence
     "_contrib_ssd_chunk_scan", "ssd_chunk_scan",
+    # the tile count of a mask given as data (PR 33): tests/test_sdar.py,
+    # against the kernels' own summary at the planned blocks
+    "_contrib_attention_mask_tiles", "attention_mask_tiles",
     # exercised by dedicated test files: test_operator.py (NN core),
     # test_rnn.py (RNN), test_gluon.py (layers), test_symbol.py /
     # test_module.py (output ops), test_amp.py (amp_cast), test_loss.py,

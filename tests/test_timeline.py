@@ -268,9 +268,11 @@ def test_every_pallas_call_passes_a_stable_name():
     ``jvp__`` before the scopes existed, a block's name since — and would
     hide in the device trace under it."""
     sites = _pallas_call_names()
-    assert len(sites) >= 16
-    # the scan's three kernels (PR 31), by the names a trace shows
-    assert {"ssd_fwd", "ssd_states", "ssd_bwd"} <= {n for _, n in sites}
+    assert len(sites) >= 19
+    # the scan's three kernels (PR 31) and the three under a mask given as
+    # data (PR 33), by the names a trace shows
+    assert {"ssd_fwd", "ssd_states", "ssd_bwd", "flash_masked_fwd",
+            "flash_masked_dq", "flash_masked_dkv"} <= {n for _, n in sites}
     bad = [(where, name) for where, name in sites
            if not (isinstance(name, str)
                    and re.fullmatch(r"[a-z][a-z0-9]*(_[a-z0-9]+)+", name))]
