@@ -24,6 +24,7 @@ import jax
 import numpy as onp
 
 from .... import autograd, telemetry
+from ....ops import moe as _core
 from ...block import HybridBlock
 from ...nn import Dense, RMSNorm
 
@@ -166,6 +167,19 @@ class SparseExperts(HybridBlock):
         for p in (self.expert_load, self.rows_computed, self.balance_bias):
             p.cast("float32")
 
+    def ran_on_blocks(self, load, rows):
+        """Which side of ``ops.moe.sparse_ffn`` a step with ``load``
+        (num_experts,) routes and ``rows`` (held,) of them computed here
+        ran: True the dense products over blocks of slots, False the
+        ragged products, None where the layer has no blocks (one flat route
+        a token, or a held share too large for them)."""
+        if _core.flat_routes(self._network["k"], self._network["normalize"]):
+            return None
+        blocks = _core.plan_blocks(int(load.sum()), len(rows),
+                                   self.num_experts)
+        return None if blocks is None else bool(_core.blocks_fit(
+            blocks, rows.astype("int64")))
+
     def hybrid_forward(self, F, x, probs, up_weight, down_weight,
                        balance_bias, expert_load, rows_computed,
                        gate_weight=None):
@@ -193,9 +207,16 @@ def publish_routing_counts():
     expert), ``moe.tokens_local`` (routes to held experts),
     ``moe.dropped`` (routes to held experts that no product covered:
     0 — the layer has no capacity to overflow), ``moe.routes_per_token``
-    (the largest ``experts_per_token`` among the blocks).
+    (the largest ``experts_per_token`` among the blocks),
+    ``moe.layers_with_blocks`` (the blocks whose experts have blocks of
+    slots to run on: ``ops.moe.plan_blocks`` of the step's routes) and
+    ``moe.layers_on_blocks`` (those of them whose held routes FIT the
+    blocks at the last step — ``ops.moe.blocks_fit`` of ``rows``, the rule
+    the step tests on the device — and so ran the dense products; the
+    others ran the ragged side of ``sparse_ffn``'s ``lax.cond``).
     ``moe.expert_load`` is one event a block with its vector.  Returns ``{block name: {"load":
-    [...], "held": (first, end), "rows": [...]}}``, empty before the first
+    [...], "held": (first, end), "rows": [...], "on_blocks": True, False
+    or None with no blocks}}``, empty before the first
     training step.  One device read a block, after the window: nothing is
     called back from inside the step."""
     out = {}
@@ -208,7 +229,8 @@ def publish_routing_counts():
             continue
         out[block.name] = {"load": load.tolist(), "rows": rows.tolist(),
                            "held": block.experts_held,
-                           "routes_per_token": block.experts_per_token}
+                           "routes_per_token": block.experts_per_token,
+                           "on_blocks": block.ran_on_blocks(load, rows)}
         telemetry.event("moe.expert_load", block.name, load=load.tolist(),
                         held=list(block.experts_held),
                         routes_per_token=block.experts_per_token)
@@ -220,4 +242,8 @@ def publish_routing_counts():
         (v["routes_per_token"] for v in out.values()), default=0))
     telemetry.gauge("moe.dropped",
                     sum(held) - sum(sum(v["rows"]) for v in out.values()))
+    sides = [v["on_blocks"] for v in out.values()
+             if v["on_blocks"] is not None]
+    telemetry.gauge("moe.layers_with_blocks", len(sides))
+    telemetry.gauge("moe.layers_on_blocks", sum(sides))
     return out

@@ -27,7 +27,8 @@ from .nn import activation as _activation
 from .registry import register
 
 __all__ = ["top1_route", "topk_route", "group_by_expert", "spread_rows",
-           "collect_rows", "grouped_matmul", "sparse_ffn", "gated_experts",
+           "collect_rows", "dispatch", "combine", "flat_routes", "plan_blocks",
+           "blocks_fit", "grouped_matmul", "sparse_ffn", "gated_experts",
            "mlp_experts"]
 
 
@@ -72,40 +73,35 @@ def group_by_expert(expert, first, held):
     return order, place, sizes
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _gather_rows(x, index, back, fold, masked):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _gather_rows(x, index, back, fold):
     """``x[index]`` with ``back`` the way home: a gather forward and a
     gather backward (autodiff's scatter-add would serialise on the chip).
     The cotangent of ``x`` is the output's at ``back``, summed over
-    ``fold`` consecutive equal parts (the k routes of a token).  With
-    ``masked`` an index under zero reads nothing: zero, either way."""
-    return _take(x, index, masked)
+    ``fold`` consecutive equal parts (the k routes of a token)."""
+    return jnp.take(x, index, axis=0)
 
 
-def _take(rows, index, masked):
-    if not masked:
-        return jnp.take(rows, index, axis=0)
-    return jnp.where((index >= 0)[:, None],
-                     jnp.take(rows, jnp.maximum(index, 0), axis=0),
-                     jnp.zeros((), rows.dtype))
+def _gather_fwd(x, index, back, fold):
+    return jnp.take(x, index, axis=0), (index, back)
 
 
-def _gather_fwd(x, index, back, fold, masked):
-    return _take(x, index, masked), (index, back)
-
-
-def _gather_bwd(fold, masked, res, g):
-    import numpy as onp
+def _gather_bwd(fold, res, g):
     index, back = res
-    grad = _take(g, back, masked)
+    grad = jnp.take(g, back, axis=0)
     if fold > 1:
         grad = jnp.sum(grad.reshape((fold, -1) + grad.shape[1:]), axis=0,
                        dtype=jnp.float32).astype(g.dtype)
-    return (grad, onp.zeros(index.shape, jax.dtypes.float0),
-            onp.zeros(back.shape, jax.dtypes.float0))
+    return grad, _no_grad(index), _no_grad(back)
 
 
 _gather_rows.defvjp(_gather_fwd, _gather_bwd)
+
+
+def _no_grad(index):
+    """The cotangent of an array of integers."""
+    import numpy as onp
+    return onp.zeros(index.shape, jax.dtypes.float0)
 
 
 def spread_rows(x, order, place):
@@ -115,14 +111,13 @@ def spread_rows(x, order, place):
     and the backward sums a token's k."""
     n = x.shape[0]
     fold = order.shape[0] // n
-    return _gather_rows(x, order % n if fold > 1 else order, place, fold,
-                        False)
+    return _gather_rows(x, order % n if fold > 1 else order, place, fold)
 
 
 def collect_rows(out, order, place):
     """``spread_rows`` undone: the sorted rows back in route order,
     ``out[place]``."""
-    return _gather_rows(out, place, order, 1, False)
+    return _gather_rows(out, place, order, 1)
 
 
 def grouped_matmul(rows, weights, sizes):
@@ -156,18 +151,103 @@ def _on_rows(network, x, gates, weights, local, grouped):
     return collect_rows(out, order, place) * gates.astype(out.dtype)[:, None]
 
 
-def _on_blocks(network, blocks, x, gates, weights, local, grouped):
-    """The same on ``blocks = (count, width)`` blocks of slots,
-    (count, width, D): an expert takes as many blocks as its routes need,
-    a block computes against its owner's weights, and the experts are one
-    dense batched product a weight whose time does not follow the routing.
-    Right where the experts' needs add up to no more than ``count``
-    blocks.  ``local`` (k N,) is each route's expert counted from the first
-    held.  A slot that no route fills holds some other route's row, is
-    computed and is never read: its cotangent is zero."""
+def _sum_routes(y, n):
+    """A token's k gated parts, (k N, D) with route r of token r % N,
+    summed in float32: (N, D)."""
+    return jnp.sum(y.reshape(-1, n, y.shape[-1]), axis=0,
+                   dtype=jnp.float32).astype(y.dtype)
+
+
+def _rows_at_slots(x, slot_route):
+    return jnp.take(x, jnp.maximum(slot_route, 0) % x.shape[0], axis=0,
+                    mode="clip")
+
+
+def _sum_at_tokens(out, weight, route_slot):
+    """k gathers of (N, D) and one sum that reads them (on the chip one
+    gather of (k, N, D) is converted to float32 on its own, 528 MB a
+    Nemotron layer, before the sum reads it)."""
+    return sum(w[:, None] * jnp.take(out, slot, axis=0,
+                                     mode="clip").astype(jnp.float32)
+               for w, slot in zip(weight.astype(jnp.float32),
+                                  jnp.maximum(route_slot, 0))
+               ).astype(out.dtype)
+
+
+@jax.custom_vjp
+def dispatch(x, slot_route, route_slot):
+    """Token order to slot order: row s of the result is the row of ``x``
+    (N, D) of the token whose route fills slot s — ``slot_route`` (S,) is
+    that route's number, route r being a route of token r % N, or -1.  A
+    slot that no route fills holds some token's row, is computed and is
+    never read.  ``route_slot`` (k, N), the slot of each route or -1, is
+    the way home: the cotangent of ``x`` is ``combine`` of the slots'
+    cotangent with the weight one on every route that has a slot."""
+    return _rows_at_slots(x, slot_route)
+
+
+def _dispatch_fwd(x, slot_route, route_slot):
+    return _rows_at_slots(x, slot_route), (slot_route, route_slot)
+
+
+def _dispatch_bwd(res, g):
+    slot_route, route_slot = res
+    return (_sum_at_tokens(g, route_slot >= 0, route_slot),
+            _no_grad(slot_route), _no_grad(route_slot))
+
+
+dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def combine(out, weight, slot_route, route_slot):
+    """Slot order back to token order, weighted and summed:
+    ``y[t] = sum_j weight[j, t] * out[route_slot[j, t]]`` over the k
+    routes of token t, in float32: (N, D).  ``weight`` (k, N) is zero
+    where the route has no slot (``route_slot < 0``): the mask is k N
+    scalars, and all that is route-sized are k gathers of (N, D) rows that
+    one sum reads.
+    ``dispatch``'s transpose: the cotangent of ``out`` is ``dispatch`` of
+    the result's, each slot's row times the weight of the route that fills
+    it, and the weights' cotangent the slots' row-wise dot of ``out`` with
+    it, k N scalars read back by ``route_slot`` — slot-sized, both."""
+    return _sum_at_tokens(out, weight, route_slot)
+
+
+def _combine_fwd(out, weight, slot_route, route_slot):
+    return _sum_at_tokens(out, weight, route_slot), (out, weight, slot_route,
+                                                     route_slot)
+
+
+def _combine_bwd(res, g):
+    out, weight, slot_route, route_slot = res
+    g_slots = _rows_at_slots(g, slot_route)
+    weight_slot = jnp.where(slot_route >= 0, jnp.take(
+        weight.reshape(-1), jnp.maximum(slot_route, 0), mode="clip"), 0)
+    dots = jnp.sum(out.astype(jnp.float32) * g_slots.astype(jnp.float32),
+                   axis=-1)
+    d_weight = jnp.where(route_slot >= 0, jnp.take(
+        dots, jnp.maximum(route_slot, 0), mode="clip"), 0)
+    return (g_slots * weight_slot.astype(g.dtype)[:, None],
+            d_weight.astype(weight.dtype), _no_grad(slot_route),
+            _no_grad(route_slot))
+
+
+combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+def _slots(blocks, local, grouped):
+    """Who takes which slot of ``blocks = (count, width)``: an expert takes
+    as many blocks as its routes need, in the order of the held experts.
+    ``local`` (k N,) is each route's expert counted from the first held.
+    Returns ``owner`` (count,) each block's expert, ``slot_route``
+    (count x width,) the route that fills each slot or -1, and
+    ``route_slot`` (k N,) its inverse: the slot of each route, -1 for a
+    route to an expert not held.  Right where the experts' needs add up to
+    no more than ``count`` blocks."""
     order, place, sizes = grouped
     count, width = blocks
-    held, n, total = sizes.shape[0], x.shape[0], local.shape[0]
+    held, total = sizes.shape[0], local.shape[0]
     start = jnp.cumsum(sizes) - sizes             # an expert's first route
     need = (sizes + width - 1) // width           # blocks an expert
     first = jnp.cumsum(need) - need               # an expert's first block
@@ -176,37 +256,71 @@ def _on_blocks(network, blocks, x, gates, weights, local, grouped):
         jnp.cumsum(need), block, side="right").astype(jnp.int32), held - 1)
     offset = (block - jnp.take(first, owner))[:, None] * width \
         + jnp.arange(width, dtype=jnp.int32)[None, :]
-    filled = offset < jnp.take(sizes, owner)[:, None]
-    slot_route = jnp.take(order, jnp.clip(
-        jnp.take(start, owner)[:, None] + offset, 0, total - 1))
+    slot_route = jnp.where(
+        offset < jnp.take(sizes, owner)[:, None], jnp.take(order, jnp.clip(
+            jnp.take(start, owner)[:, None] + offset, 0, total - 1)), -1)
     mine = (local >= 0) & (local < held)
     local = jnp.clip(local, 0, held - 1)
     within = place - jnp.take(start, local)
     route_slot = jnp.where(
         mine, (jnp.take(first, local) + within // width) * width
         + within % width, -1)
-    rows = _gather_rows(x, (slot_route % n).reshape(-1), route_slot,
-                        total // n, True)
-    out = network(rows.reshape(count, width, -1), sizes,
+    return owner, slot_route.reshape(-1), route_slot
+
+
+def _on_blocks(network, blocks, x, gates, weights, local, grouped):
+    """The same on ``blocks = (count, width)`` blocks of slots,
+    (count, width, D): a block computes against its owner's weights, and
+    the experts are one dense batched product a weight whose time does not
+    follow the routing.  The rows move through ``dispatch`` and
+    ``combine``, the gates and the sum over a token's routes inside the
+    latter: (N, D), and nothing here holds a (k N, D) array."""
+    n = x.shape[0]
+    owner, slot_route, route_slot = _slots(blocks, local, grouped)
+    route_slot = route_slot.reshape(-1, n)
+    rows = dispatch(x, slot_route, route_slot)
+    out = network(rows.reshape(blocks + rows.shape[1:]), grouped[2],
                   *(jnp.take(w, owner, axis=0) for w in weights))
-    out = _gather_rows(out.reshape(count * width, -1), route_slot,
-                       jnp.where(filled, slot_route, -1).reshape(-1), 1, True)
-    return out * gates.astype(out.dtype)[:, None]
+    return combine(out.reshape(rows.shape[0], -1),
+                   jnp.where(route_slot >= 0, gates.reshape(-1, n), 0),
+                   slot_route, route_slot)
 
 
-def _blocks_fit(blocks, sizes):
+def flat_routes(k, normalize):
+    """Whether ``sparse_experts`` hands ``sparse_ffn`` one flat route a
+    token, (N,) — sorted rows, never blocks — and not (N, k) routes."""
+    return k == 1 and not normalize
+
+
+def plan_blocks(total, held, num_experts):
+    """The blocks of slots ``total`` routes over ``held`` of
+    ``num_experts`` experts run on: ``(count, width)``, two blocks a held
+    expert, each half of ``_ROWS_OVER_EVEN`` times an even router's routes
+    to it (in 128s); None where that is no fewer rows than all the
+    routes."""
+    width = -(-_ROWS_OVER_EVEN * total // num_experts // 256) * 128
+    return (2 * held, width) if 2 * held * width < total else None
+
+
+def blocks_fit(blocks, sizes):
+    """Whether experts with ``sizes`` (held,) routes each fit the
+    ``blocks``, a block holding one expert's routes: on the device for the
+    step (``sparse_ffn``), on the host for who counts which side ran
+    (``publish_routing_counts``)."""
     count, width = blocks
-    return jnp.sum((sizes + width - 1) // width) <= count
+    return ((sizes + width - 1) // width).sum() <= count
 
 
 def _either(network, blocks, sizes, run):
     """``run`` on ``_on_blocks`` where the held experts' routes fit the
-    ``blocks`` — tested on the device — and on ``_on_rows`` otherwise
-    (always, with no blocks)."""
-    full = functools.partial(_on_rows, network)
+    ``blocks`` — tested on the device — and on ``_on_rows`` with the k
+    parts summed otherwise (always, with no blocks): (N, D) either way."""
+    def full(x, *rest):
+        return _sum_routes(_on_rows(network, x, *rest), x.shape[0])
+
     if blocks is None:
         return run(full)
-    return lax.cond(_blocks_fit(blocks, sizes),
+    return lax.cond(blocks_fit(blocks, sizes),
                     lambda: run(functools.partial(_on_blocks, network,
                                                   blocks)),
                     lambda: run(full))
@@ -228,17 +342,14 @@ def _budgeted_fwd(network, blocks, *args):
 
 
 def _budgeted_bwd(network, blocks, args, g):
-    import numpy as onp
     x, gates, weights, local, grouped = args
 
     def back(side):
         return jax.vjp(lambda x, gates, weights: side(
             x, gates, weights, local, grouped), x, gates, weights)[1](g)
 
-    return _either(network, blocks, grouped[2], back) + tuple(
-        onp.zeros(t.shape, jax.dtypes.float0) if not isinstance(t, tuple)
-        else tuple(onp.zeros(u.shape, jax.dtypes.float0) for u in t)
-        for t in (local, grouped))
+    return _either(network, blocks, grouped[2], back) + (
+        _no_grad(local), tuple(_no_grad(index) for index in grouped))
 
 
 _budgeted.defvjp(_budgeted_fwd, _budgeted_bwd)
@@ -283,16 +394,10 @@ def sparse_ffn(x, expert, gate, ffn, first, held, num_experts=None):
                         grouped), grouped[2]
     routes = expert.T.reshape(-1)
     grouped = group_by_expert(routes, first, held)
-    blocks, total = None, routes.shape[0]
-    if num_experts is not None:
-        # two blocks a held expert, each half the rows in all over held
-        width = -(-_ROWS_OVER_EVEN * total // num_experts // 256) * 128
-        if 2 * held * width < total:    # fewer rows than all the routes
-            blocks = (2 * held, width)
-    y = _budgeted(ffn.network, blocks, x, gate.T.reshape(-1), ffn.weights,
-                  routes - first, grouped)
-    return jnp.sum(y.reshape(expert.shape[1], x.shape[0], -1), axis=0,
-                   dtype=jnp.float32).astype(y.dtype), grouped[2]
+    blocks = None if num_experts is None else plan_blocks(
+        routes.shape[0], held, num_experts)
+    return _budgeted(ffn.network, blocks, x, gate.T.reshape(-1), ffn.weights,
+                     routes - first, grouped), grouped[2]
 
 
 class _Experts(NamedTuple):
@@ -342,7 +447,7 @@ def sparse_experts(data, probs, w_gate, w_up, w_down, bias, first: int = 0,
     probs = probs.reshape(-1, n_experts)
     pick = lax.stop_gradient(probs.astype(jnp.float32)) \
         + bias.astype(jnp.float32)
-    if k == 1 and not normalize:
+    if flat_routes(k, normalize):
         expert, gate = top1_route(probs, pick)
     else:
         expert, gate = topk_route(probs, k, pick, normalize)

@@ -14,6 +14,7 @@ a test of this file has started — never at import, in a ``skipif`` or a
 from another worker.
 """
 import collections
+import math
 import os
 import re
 
@@ -613,6 +614,44 @@ def _reachable(comps, name):
     return "\n".join(seen)
 
 
+def _route_sized(text, elements):
+    """What makes an array of ``elements`` elements — the k N routes of an
+    expert layer by its width — in a compiled step: the kind of every
+    instruction with such a result (a fusion round a gather reads
+    ``gather``, another fusion by its name), fusions' bodies, views
+    (``bitcast``, ``parameter``, ``get-tuple-element``) and the ragged side
+    of the experts' ``conditional``s (their first branch: it sorts all
+    k N routes by design) left out."""
+    comps = _computations(text)
+    todo, seen, kinds = ["ENTRY"], set(), []
+    while todo:
+        name = todo.pop()
+        if name in seen or name not in comps:
+            continue
+        seen.add(name)
+        for line in comps[name].splitlines()[1:]:
+            m = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = (.*?) ([\w\-]+)\(",
+                         line)
+            if not m:
+                continue
+            head, result, opcode = m.groups()
+            if opcode == "conditional":
+                todo.append(re.findall(r"%([\w.\-]+)", line.split(
+                    "branch_computations=")[1].split("}")[0])[1])
+            todo += re.findall(r"(?:body|condition)=%([\w.\-]+)", line)
+            sizes = [math.prod(int(d) for d in dims.split(",") if d)
+                     for dims in re.findall(r"\w+\[([\d,]*)\]", result)]
+            if elements not in sizes or opcode in (
+                    "bitcast", "parameter", "get-tuple-element", "tuple"):
+                continue
+            if opcode == "fusion":
+                body = comps[re.search(r"calls=%([\w.\-]+)", line).group(1)]
+                opcode = "gather" if " gather(" in body \
+                    else re.sub(r"(\.\d+)+$", "", head)
+            kinds.append(opcode)
+    return kinds
+
+
 # compiled temporaries of the head alone (sandbox compile, described v5e,
 # PR 29) plus 5%: under the row mean the compiler proves the cotangent
 # uniform and drops the recomputing branch; under a loss scale it learns
@@ -683,8 +722,9 @@ _NEMOTRON_LAYERS = {
     # are dense batched products (no kernel); the side that runs when they
     # do not holds the compiler's own ragged-dot kernel: two grouped
     # products forward, again in the recomputed backward, four gradients.
-    # The routes' buffers are recomputed, not kept: 2.073 GB and 5%
-    "E": ({"ragged-dot-none": 8, "ragged-dot-metadata": 3}, 2.073 * 1.05),
+    # The routes' buffers are recomputed, not kept, and the blocks side
+    # holds no (k N, D) array since PR 34: 1.736 GB and 5% (2.073 before)
+    "E": ({"ragged-dot-none": 8, "ragged-dot-metadata": 3}, 1.736 * 1.05),
 }
 
 
@@ -700,7 +740,13 @@ def test_nemotron_layer_train_step_compiles(one_chip, monkeypatch, kind):
     three ``ssd_*`` kernels and nothing of the chunked form: no
     state-passing ``while``, no ``ssd.*`` scope, no float32 (Q, Q) decay
     or score block and no 5-D (…, G, R, P) array among the program's
-    buffers."""
+    buffers.  The expert layer's blocks side moves its rows through
+    ``ops.moe.dispatch`` and ``combine``: at most two instructions of the
+    step make an array of k N x D = 49,152 x 2,688 elements, both gathers,
+    none a ``select`` or a ``broadcast`` (the parent of PR 34 made seven:
+    three gathers, a ``select_select_fusion``, a
+    ``broadcast_multiply_fusion``, a ``broadcast`` and the ``conditional``
+    that handed the routes' rows out)."""
     import mxnet_tpu as mx
     from mxnet_tpu import context, gluon, parallel
     from mxnet_tpu import random as mx_random
@@ -743,6 +789,9 @@ def test_nemotron_layer_train_step_compiles(one_chip, monkeypatch, kind):
             r"f32\[[\d,]*128,128\]|\w+\[1,8192,8,8,64\]|"
             r"\w+\[1,64,128,8,8,64\]", text)
         assert not scan_arrays, sorted(set(scan_arrays))
+    else:
+        routes = _route_sized(text, 6 * 8192 * 2688)
+        assert len(routes) <= 2 and set(routes) <= {"gather"}, routes
     temp = compiled.memory_analysis().temp_size_in_bytes / 1e9
     print("temporaries of the %s layer's step: %.3f GB" % (kind, temp))
     assert temp <= temp_gb, temp
@@ -760,7 +809,11 @@ def test_sdar_layer_train_step_compiles_to_the_masked_kernels(one_chip,
     kernel, and no (8192, 8192) score or mask array anywhere outside
     them —; the experts' other kernels are the compiler's ragged
     products on the side of the ``conditional`` that a lumpy router
-    takes; the blocks' names are in the instructions' ``op_name``s."""
+    takes; the blocks' names are in the instructions' ``op_name``s.  On
+    the blocks side at most two instructions make an array of k N x D =
+    65,536 x 2,048 elements, both gathers, none a ``select`` or a
+    ``broadcast`` (the parent of PR 34 made six: three gathers, a
+    ``select_select_fusion``, a ``broadcast`` and one more fusion)."""
     import mxnet_tpu as mx
     from mxnet_tpu import context, gluon, parallel
     from mxnet_tpu import random as mx_random
@@ -804,6 +857,8 @@ def test_sdar_layer_train_step_compiles_to_the_masked_kernels(one_chip,
                   "layer0_attn/flash_masked_dkv", "layer0_router",
                   "layer0_experts", "mask"):
         assert re.search(r"[/_]%s/" % re.escape(block), text), block
+    routes = _route_sized(text, 8 * 8192 * 2048)
+    assert len(routes) <= 2 and set(routes) <= {"gather"}, routes
     temp = compiled.memory_analysis().temp_size_in_bytes / 1e9
     print("temporaries of the SDAR layer's step: %.3f GB" % temp)
     assert temp <= 2.089 * 1.05, temp

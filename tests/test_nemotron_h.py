@@ -584,6 +584,200 @@ def test_expert_blocks_compute_every_held_route(case):
                                 rtol=1e-4, atol=1e-5)
 
 
+def _hand_made_slots(k, tokens=9, slots=16, seed=0):
+    """A slot plan made by hand, no router: token 0 has all its k routes
+    in slots, token 1 none, the others each route with probability a half;
+    the routes with a slot sit in distinct random slots of ``slots``, the
+    other slots are empty.  Route r is route r // tokens of token
+    r % tokens.  Returns (slot_route (slots,), route_slot (k, tokens))."""
+    rs = onp.random.RandomState(seed)
+    has = rs.rand(k, tokens) < 0.5
+    has[:, 0], has[:, 1] = True, False
+    routes = onp.flatnonzero(has.reshape(-1))
+    assert len(routes) <= slots
+    where = rs.permutation(slots)[:len(routes)]
+    slot_route = onp.full(slots, -1, "int32")
+    slot_route[where] = routes
+    route_slot = onp.full(k * tokens, -1, "int32")
+    route_slot[routes] = where
+    return slot_route, route_slot.reshape(k, tokens)
+
+
+@pytest.mark.parametrize("k", [1, 6, 8], ids=lambda k: "k%d" % k)
+def test_dispatch_and_combine_against_plain_loops(k):
+    """``ops.moe.dispatch`` (token order to slot order) and ``combine``
+    (back, weighted, a token's k routes summed) on a plan made by hand,
+    against plain ``numpy`` loops: both results and every cotangent — each
+    one's backward is the other.  A token with all k routes in slots, one
+    with none, empty slots."""
+    tokens, slots, width = 9, 8 * 9, 5
+    slot_route, route_slot = _hand_made_slots(k, tokens, slots, seed=k)
+    rs = onp.random.RandomState(100 + k)
+    x = rs.randn(tokens, width).astype("float32")
+    out = rs.randn(slots, width).astype("float32")
+    weight = onp.where(route_slot >= 0, rs.rand(k, tokens), 0) \
+        .astype("float32")
+    g_rows = rs.randn(slots, width).astype("float32")
+    g_y = rs.randn(tokens, width).astype("float32")
+    maps = (jnp.asarray(slot_route), jnp.asarray(route_slot))
+
+    rows, back = jax.vjp(lambda x: moe_ops.dispatch(x, *maps),
+                         jnp.asarray(x))
+    y, home = jax.vjp(lambda out, weight: moe_ops.combine(out, weight, *maps),
+                      jnp.asarray(out), jnp.asarray(weight))
+    dx, = back(jnp.asarray(g_rows))
+    d_out, d_weight = home(jnp.asarray(g_y))
+
+    want_y, want_dx = onp.zeros_like(x), onp.zeros_like(x)
+    want_d_out, want_d_weight = onp.zeros_like(out), onp.zeros_like(weight)
+    for s, route in enumerate(slot_route):
+        if route >= 0:
+            onp.testing.assert_array_equal(onp.asarray(rows)[s],
+                                           x[route % tokens])
+    for j in range(k):
+        for t in range(tokens):
+            s = route_slot[j, t]
+            if s < 0:
+                continue
+            assert slot_route[s] == j * tokens + t
+            want_y[t] += weight[j, t] * out[s]
+            want_dx[t] += g_rows[s]
+            want_d_out[s] = weight[j, t] * g_y[t]
+            want_d_weight[j, t] = out[s] @ g_y[t]
+    assert (route_slot[:, 0] >= 0).all() and (route_slot[:, 1] < 0).all()
+    assert not want_y[1].any() and (slot_route < 0).any()
+    for got, want in ((y, want_y), (dx, want_dx), (d_out, want_d_out),
+                      (d_weight, want_d_weight)):
+        onp.testing.assert_allclose(onp.asarray(got), want, rtol=1e-5,
+                                    atol=1e-6)
+
+
+def _route_order_form(network, blocks, x, gates, weights, local, grouped):
+    """The blocks side as the parent of PR 34 had it, in plain indexing
+    for autodiff: the slots' outputs gathered to ROUTE order, masked,
+    gated there, the k parts summed in float32."""
+    owner, slot_route, route_slot = moe_ops._slots(blocks, local, grouped)
+    n = x.shape[0]
+    rows = x[jnp.maximum(slot_route, 0) % n]
+    out = network(rows.reshape(blocks + (-1,)), grouped[2],
+                  *(w[owner] for w in weights)).reshape(rows.shape)
+    routes = jnp.where((route_slot >= 0)[:, None],
+                       out[jnp.maximum(route_slot, 0)], 0) \
+        * gates.astype(out.dtype)[:, None]
+    return jnp.sum(routes.reshape(-1, n, routes.shape[-1]), axis=0,
+                   dtype=jnp.float32).astype(out.dtype)
+
+
+# (k, tokens): 12 of 64 experts held, 24 blocks of 128 slots
+_BLOCK_CASES = {"k1": (1, 4096), "k6": (6, 640), "k8": (8, 512)}
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", list(_BLOCK_CASES))
+def test_blocks_side_matches_the_route_order_form(case, dtype):
+    """The experts on blocks of slots through ``dispatch`` and
+    ``combine`` against autodiff of the route-order form the parent had,
+    on the same slots: the layer's output and the cotangents of ``x``, the
+    gates and each weight, at 1, 6 and 8 routes a token; one held expert
+    with no route at all, blocks left empty, token 0 with all its routes
+    held and token 1 with none.  In float32 to rounding; with bfloat16
+    inputs no further (rms) from the float32 result than the parent's
+    form is."""
+    k, n = _BLOCK_CASES[case]
+    experts, first, held, units, hidden = 64, 8, 12, 16, 24
+    rs = onp.random.RandomState(40 + k)
+    # no token chooses expert 10 (held); token 0 only held ones, token 1 none
+    allowed = onp.array([e for e in range(experts) if e != 10])
+    expert = onp.stack([rs.choice(allowed, k, replace=False)
+                        for _ in range(n)]).astype("int32")
+    expert[0] = onp.array([e for e in range(first, first + held)
+                           if e != 10])[:k]
+    expert[1] = onp.arange(first + held, first + held + k)
+    gate = rs.rand(n, k).astype("float32") + 0.1
+    weights = (rs.randn(held, units, hidden).astype("float32") * 0.3,
+               rs.randn(held, hidden, units).astype("float32") * 0.3)
+    x = rs.randn(n, units).astype("float32")
+    g = rs.randn(n, units).astype("float32")
+
+    routes = jnp.asarray(expert.T.reshape(-1))
+    grouped = moe_ops.group_by_expert(routes, first, held)
+    blocks = moe_ops.plan_blocks(k * n, held, experts)
+    assert blocks == (24, 128)
+    sizes = onp.asarray(grouped[2])
+    assert sizes[2] == 0 and sizes.sum() > 0
+    assert bool(moe_ops.blocks_fit(blocks, grouped[2]))
+    assert -(-sizes // 128).sum() < 24                  # blocks left empty
+    network = moe_ops.mlp_experts(None, None, jax.nn.gelu).network
+
+    def both(cast):
+        args = [jnp.asarray(a, cast) for a in (x, *weights)]
+        gates = jnp.asarray(gate.T.reshape(-1))
+
+        def run(form):
+            y, back = jax.vjp(
+                lambda x, gates, up, down: form(
+                    network, blocks, x, gates, (up, down), routes - first,
+                    grouped), args[0], gates, *args[1:])
+            return [onp.asarray(v, "float32")
+                    for v in (y,) + back(jnp.asarray(g, cast))]
+        return run(moe_ops._budgeted), run(_route_order_form)
+
+    (got, want), names = both("float32"), ("y", "dx", "dgates", "dup",
+                                           "ddown")
+    assert not want[0][1].any() and want[0][0].any()
+    if dtype == "float32":
+        for name, a, b in zip(names, got, want):
+            onp.testing.assert_allclose(a, b, rtol=2e-5, err_msg=name,
+                                        atol=2e-6 * onp.abs(b).max())
+        return
+    new, parents = both("bfloat16")
+    for name, a, b, exact in zip(names, new, parents, want):
+        mine, parent = (onp.sqrt(onp.mean((v - exact) ** 2)) for v in (a, b))
+        print(name, "rms error", mine, "the parent's form", parent)
+        assert mine <= 1.1 * parent, name      # two roundings of one size
+
+
+@pytest.mark.parametrize("case", ["fits_the_budget", "falls_back", "flat"])
+def test_publish_routing_counts_says_which_side_ran(case):
+    """The layer of ``test_expert_blocks_compute_every_held_route``: with
+    its routes inside the 2 blocks of 256 slots the block's record says
+    ``on_blocks`` True, with every token sent to the held expert False —
+    the step's own rule, ``ops.moe.blocks_fit``, on the rows the step
+    counted — and with one flat route a token None (no blocks to be on).
+    The gauges count the layers with blocks and those on them, and the
+    benchmark's reader gives their share."""
+    rs = onp.random.RandomState(11)
+    scores = (rs.rand(1, 600, 16) * 0.8 + 0.1).astype("float32")
+    bias = onp.zeros(16, "float32")
+    if case == "falls_back":
+        bias[3] = 10.0
+    flat = case == "flat"
+    block = cnn.SparseExperts(6, 10, 16, experts_held=(3, 4),
+                              experts_per_token=1 if flat else 2,
+                              gated=False, activation="relu2",
+                              normalize_gates=not flat)
+    block.initialize(mx.init.Normal(0.3))
+    block.balance_bias.set_data(mx.nd.array(bias))
+    with autograd.train_mode():
+        block(mx.nd.array(rs.randn(1, 600, 6).astype("float32")),
+              mx.nd.array(scores))
+    records = cnn.publish_routing_counts()
+    want = {"fits_the_budget": True, "falls_back": False, "flat": None}
+    assert records[block.name]["on_blocks"] is want[case]
+    sides = [r["on_blocks"] for r in records.values()
+             if r["on_blocks"] is not None]
+    gauges = telemetry.snapshot()["gauges"]
+    assert gauges["moe.layers_with_blocks"] == len(sides)
+    assert gauges["moe.layers_on_blocks"] == sum(sides)
+    spec = importlib.util.spec_from_file_location("reader", os.path.join(
+        HERE, "..", "benchmark", "layer_metrics",
+        "moe_blocks_side_share.train.py"))
+    reader = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(reader)
+    assert reader.read({}) == (100.0 * sum(sides) / len(sides)
+                               if sides else None)
+
+
 def test_a_lumpy_expert_takes_the_blocks_it_needs():
     """2 of 32 experts held, 2 routes a token, 1,024 tokens: 4 blocks of
     128 slots, an even share 64 routes an expert.  One held expert gets
