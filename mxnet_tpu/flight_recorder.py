@@ -145,6 +145,7 @@ def dump_incident(reason, detail=None, extra=None):
                      "spans": snap["spans"],
                      "histograms": snap["histograms"],
                      "compiles": snap["compiles"],
+                     "compile_totals": snap["compile_totals"],
                      "last_cache_keys": last_keys})
         _write_json(os.path.join(tmp, "lockgraph.json"),
                     [r for r in journal if r.get("kind") == "lockorder"])

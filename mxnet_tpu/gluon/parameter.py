@@ -14,12 +14,13 @@ reference's ``kWriteTo``/``kAddTo`` req semantics.
 """
 from __future__ import annotations
 
+import time
 from collections import OrderedDict
 from typing import Optional
 
 import numpy as onp
 
-from .. import autograd, initializer
+from .. import autograd, initializer, telemetry
 from ..base import MXNetError
 from ..context import Context, cpu, current_context
 from ..ndarray import NDArray, zeros
@@ -153,6 +154,7 @@ class Parameter:
         # accelerator backend every per-shape init op would compile over
         # the device link), then place with ONE transfer; jax RNG is
         # backend-independent so values are identical either way
+        t0 = time.perf_counter()
         host = _cpu_ctx()
         from ..ndarray.ndarray import _wrap
         import jax.numpy as jnp
@@ -180,6 +182,9 @@ class Parameter:
         self._data = data
         if self._grad_req != "null":
             self._init_grad()
+        # the host draw and the CALL that places it (a transfer's end is
+        # not waited for); aggregate only: a model has hundreds of these
+        telemetry.observe("gluon.param.init", time.perf_counter() - t0)
 
     def _init_grad(self):
         """The eager gradient buffer is made when first READ (an eager
@@ -267,19 +272,23 @@ class Parameter:
 
     def reset_ctx(self, ctx):
         if self._data is not None:
+            t0 = time.perf_counter()
             self._data = self._data.as_in_context(
                 ctx[0] if isinstance(ctx, (list, tuple)) else ctx)
             if self._grad_req != "null":
                 self._init_grad()
+            telemetry.observe("gluon.param.place", time.perf_counter() - t0)
 
     def cast(self, dtype):
         self.dtype = dtype
         if self._data is not None:
+            t0 = time.perf_counter()
             with autograd.pause():
                 self._data._data = self._data._data.astype(onp.dtype(dtype))
                 if self._grad is not None:
                     self._grad._data = self._grad._data.astype(onp.dtype(dtype))
                     autograd.mark_variables([self._data], [self._grad], self._grad_req)
+            telemetry.observe("gluon.param.place", time.perf_counter() - t0)
 
     def _load_init(self, data, ctx=None):
         """Initialize directly from a loaded array (reference _load_init)."""

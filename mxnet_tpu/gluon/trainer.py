@@ -285,14 +285,8 @@ class _FusedUpdate:
                self._shard_n if sharded else 0,
                self._compress if sharded else "")
         jfn = self._cache.get(key)
-        if jfn is None:
-            telemetry.record_compile(
-                "FusedUpdate[%x]" % id(self),
-                {"indices": list(indices),
-                 "hyperparams": dict(fingerprint),
-                 "wds": list(key[2]),
-                 "weights": [{"shape": list(w.shape),
-                              "dtype": str(w.dtype)} for w in weights]})
+        missed = jfn is None
+        if missed:
             try:
                 steps = [optimizer.make_step(i) for i in indices]
             except NotImplementedError:
@@ -378,9 +372,23 @@ class _FusedUpdate:
                     for sv in svals for l in sv))
         else:
             svals = [[l._data for l in lv] for lv in leaves_per]
-        new_w, new_s = jfn(wvals, gvals, svals,
-                           jnp.asarray(optimizer.num_update, jnp.int32),
-                           jnp.asarray(lrs, jnp.float32))
+        t = jnp.asarray(optimizer.num_update, jnp.int32)
+        lr_vec = jnp.asarray(lrs, jnp.float32)
+        compiles = telemetry.thread_compiles()
+        seq = compiles.seq
+        new_w, new_s = jfn(wvals, gvals, svals, t, lr_vec)
+        if missed or compiles.seq != seq:
+            # this cache's miss, or jax.jit compiling the cached update
+            # again for arguments it keys apart: the detector's diff
+            # names the hyperparameter or the leaf (its shape, dtype,
+            # committedness or sharding) that moved
+            # graftlint: disable-next=donate-use-after-donate -- avals and
+            # shardings only: donation takes the buffer, not these
+            args = {"weights": wvals, "grads": gvals, "states": svals}
+            telemetry.record_compile(
+                "FusedUpdate[%x]" % id(self),
+                dict(telemetry.arg_signature(args), indices=list(indices),
+                     hyperparams=dict(fingerprint), wds=list(key[2])))
         if self._donate_grads:
             telemetry.inc("donation.grad_buffers", len(gvals))
         with autograd.pause():

@@ -202,6 +202,30 @@ class DataParallelStep:
         self._base_leaves = []   # per-slot: leaf count sans residual
         self._mp_written = {}   # slot -> last weight array THIS step wrote
         mp = bool(getattr(optimizer, "multi_precision", False))
+        # a scoped span: it owns the small programs compiled here (casts,
+        # zeros, placements), so they read as state, not as `eager`
+        with telemetry.span("parallel.state_init"):
+            self._init_states(optimizer, params, mp)
+        self._report_shard_layout()
+        self._t = optimizer.begin_num_update
+        self._cache = {}
+        # device-resident per-call operands: every tiny host->device
+        # transfer is a dispatch of its own on the step's critical path, so
+        # the lr vector is cached (re-uploaded only when the schedule moves),
+        # and the step counter and RNG key live on-device, threaded
+        # through the jitted step as donated carry values
+        self._lrs_key = None
+        self._lrs_dev = None
+        self._t_dev = None
+        self._rng_dev = None
+        self._rng_epoch = None
+        # one jitted copy-program for checkpoint snapshots (see
+        # checkpoint_state)
+        self._ckpt_copier = None
+
+    def _init_states(self, optimizer, params, mp):
+        """Master copies, ``create_state`` / ``_create_sharded_state`` for
+        every trainable slot."""
         for slot, i in enumerate(self._trainable):
             wdata = params[i].data()
             use_mp = mp and onp.dtype(wdata.dtype).itemsize < 4
@@ -234,22 +258,6 @@ class DataParallelStep:
             self._opt_states.append(
                 [jax.device_put(l._data, wdev) if wdev is not None
                  else l._data for l in leaves])
-        self._report_shard_layout()
-        self._t = optimizer.begin_num_update
-        self._cache = {}
-        # device-resident per-call operands: every tiny host->device
-        # transfer is a dispatch of its own on the step's critical path, so
-        # the lr vector is cached (re-uploaded only when the schedule moves),
-        # and the step counter and RNG key live on-device, threaded
-        # through the jitted step as donated carry values
-        self._lrs_key = None
-        self._lrs_dev = None
-        self._t_dev = None
-        self._rng_dev = None
-        self._rng_epoch = None
-        # one jitted copy-program for checkpoint snapshots (see
-        # checkpoint_state)
-        self._ckpt_copier = None
 
     # ------------------------------------------------------------------
     # ZeRO-style sharded weight update (arxiv 2004.13336)
@@ -720,9 +728,12 @@ class DataParallelStep:
             with telemetry.span("parallel.step", hist=True,
                                 memory=(idx % 32 == 0),
                                 step_num=idx) as _sp:
-                out = self._dispatch_inner(data, label, scan)
+                out, compiled = self._dispatch_inner(data, label, scan)
+            # a step in which jax compiled says so: a stall in the step
+            # times can be put down to its step from the journal alone
             telemetry.emit_step("parallel", idx, step_ms=_sp.duration_ms,
-                                owner=self)
+                                owner=self,
+                                **({"compiled": True} if compiled else {}))
         return out
 
     def _batch_sharding(self, ndim, scan):
@@ -760,8 +771,7 @@ class DataParallelStep:
         def spec(v):
             return jax.ShapeDtypeStruct(
                 v.shape, v.dtype,
-                sharding=v.sharding if getattr(v, "committed", False)
-                else None)
+                sharding=telemetry.leaf_signature(v)["sharding"])
 
         def batch_spec(x):
             if x is None:
@@ -830,23 +840,8 @@ class DataParallelStep:
             lead = 1
         key = self._cache_key(dval, lval, scan)
         jfn = self._cache.get(key)
-        if jfn is None:
-            # cache miss = an XLA retrace; report the structured key so
-            # the recompile detector can name the shape/dtype/mode that
-            # moved (a silent retrace storm is the dominant hidden cost
-            # on this backend)
-            sig_d = lambda v: (None if v is None
-                               else {"shape": list(v.shape),
-                                     "dtype": str(v.dtype)})
-            # per-INSTANCE detector key: first compiles of unrelated
-            # steps (a bench builds ~10) must not read as retraces of
-            # one function and trip the warning on each other
-            telemetry.record_compile(
-                "DataParallelStep[%x]" % id(self),
-                {"mode": "scan" if scan else "call",
-                 "data": ([sig_d(d) for d in dval]
-                          if isinstance(dval, tuple) else sig_d(dval)),
-                 "label": sig_d(lval)})
+        missed = jfn is None
+        if missed:
             self._journal_hbm_estimate(dval, lval, scan)
             jfn = self._build(scan=scan)
             self._cache[key] = jfn
@@ -918,9 +913,18 @@ class DataParallelStep:
             argv.append(self._corrupt_fire_dev if chaos.should_fire(
                 "grad_compress_corrupt", step=self._t)
                 else self._corrupt_ok_dev)
+        # steady state pays this integer read and one compare: jax's
+        # listener counts the programs it hands the backend on this thread
+        compiles = telemetry.thread_compiles()
+        seq = compiles.seq
         with telemetry.span("parallel.step.call"):
             new_pvals, new_states, self._t_dev, self._rng_dev, loss = \
                 jfn(*argv)
+            compiled = missed or compiles.seq != seq
+            if compiled:
+                self._report_compile(
+                    scan, argv, self._t - lead,
+                    compiles.last if compiles.seq != seq else None)
         if self._donate_batch:
             # remember this call's donated buffers so re-feeding one
             # raises in prep — accumulated (not replaced) so a buffer
@@ -941,7 +945,38 @@ class DataParallelStep:
             if self._mp_slots[slot]:
                 self._mp_written[slot] = new_pvals[i]
         self._opt_states = new_states
-        return _wrap(loss)
+        return _wrap(loss), compiled
+
+    _ARG_NAMES = ("params", "opt_states", "t", "lrs", "rng", "data",
+                  "label", "corrupt")
+
+    def _report_compile(self, scan, argv, step, program):
+        """A call in which the step program was compiled: the framework's
+        own cache missed, or ``jax.jit`` compiled the cached step again
+        for arguments it keys apart (committedness, sharding — same
+        shapes).  Either way the recompile detector gets the signature
+        of what the call was handed, read from the retained ``argv``
+        (donation leaves avals and shardings), so its diff names the
+        leaves; and the journal gets a ``parallel.step.compile`` record
+        under this call's span, with the ``step`` index the profiler's
+        step annotation carries.  ``program`` is jax's record of the
+        compile (None while telemetry was off)."""
+        key = dict(zip(self._ARG_NAMES, telemetry.arg_signature(argv)),
+                   mode="scan" if scan else "call")
+        # per-INSTANCE detector key: first compiles of unrelated steps
+        # (a bench builds ~10) must not read as retraces of one function
+        # and trip the warning on each other
+        name = "DataParallelStep[%x]" % id(self)
+        changed = telemetry.record_compile(name, key)
+        if program is None:
+            return
+        telemetry.span_event(
+            "parallel.step.compile",
+            program["trace_s"] + program["lower_s"] + program["backend_s"],
+            parent=telemetry.current_span(), step=step,
+            n=telemetry.compile_counts()[name], changed=changed,
+            **{k: program[k] for k in ("trace_s", "lower_s", "backend_s",
+                                       "cache")})
 
     # ------------------------------------------------------------------
     def _build(self, scan=False):

@@ -29,10 +29,21 @@ Primitives
   journal (a ``deque(maxlen=...)``: old events fall off, memory stays
   bounded no matter how long the run).
 * ``record_compile(fn, key)`` — the recompile detector: every jit-cache
-  miss reports its cache key here; the detector diffs it against the
-  function's previous key and journals WHICH leaf moved
-  (``data.shape[0]: 8 -> 16``), warning on the Nth retrace (the
-  dominant silent cost on XLA backends is exactly this).
+  miss — the framework's own, and ``jax.jit``'s retraces of a step the
+  framework had cached — reports its key here; the detector diffs it
+  against the function's previous key and journals WHICH leaf moved
+  (``data.shape[0]: 8 -> 16``, ``opt_states[3][0].committed: False ->
+  True``), warning on the Nth retrace (the dominant silent cost on XLA
+  backends is exactly this; a step's one recompile for the placement
+  of its carried state is not counted).
+* ``compile_totals()`` — jax's own compile events (``jax.monitoring``:
+  trace, lowering, backend compile, persistent-cache hit or miss),
+  booked to an OWNER: the innermost scoped ``span`` open on the thread
+  the event fires on, ``eager`` outside any.  A program compiled under
+  a span is also one ``xla_compile`` journal event.
+  ``thread_compiles().seq`` counts them a thread, so a step builder
+  sees jax compile inside its call; ``arg_signature`` is the key it
+  then hands ``record_compile``.
 * ``sample_memory()`` — gauges for device ``memory_stats()`` bytes and
   host RSS; sampled automatically at ``span(..., memory=True)``
   boundaries (the trainer step does this).
@@ -49,7 +60,7 @@ Primitives
 Exporters
 ---------
 * ``snapshot()`` — in-process dict (counters, gauges, span aggregates,
-  compile counts, recent events).
+  compile counts, compile totals by owner, recent events).
 * the profiler's own trace — scoped spans are written into whatever
   ``jax.profiler`` capture is running (see ``span``); there is no second
   timeline file.
@@ -77,6 +88,7 @@ __all__ = [
     "set_rank", "get_rank", "sync_clock",
     "Histogram", "hist_observe", "histogram", "hist_snapshot",
     "record_compile", "compile_counts", "compile_deltas",
+    "leaf_signature", "arg_signature", "thread_compiles", "compile_totals",
     "sample_memory",
     "add_step_hook", "remove_step_hook", "emit_step",
     "export_jsonl", "set_jsonl_sink",
@@ -98,13 +110,16 @@ _counters = {}
 _gauges = {}
 _spans = {}          # name -> [count, total_s, min_s, max_s, last_s]
 _journal = deque(maxlen=JOURNAL_MAXLEN)
-_compiles = {}       # fn -> {"count": int, "key": last_key}
+_compiles = {}       # fn -> {"count": int, "key": last_key, "placed": 0|1}
+_compile_totals = {}  # owner -> {"programs", "trace_s", ..., "cache_misses"}
 _retrace_warned = set()   # (fn, changed-leaf family) already warned
 _hists = {}          # name -> Histogram
 _step_hooks = []
 _jsonl = {"path": None, "fh": None}
 _rank = None         # distributed rank stamped on every journal record
-_tls = threading.local()     # .trace = active trace id, .span = span id
+# .trace = active trace id, .span = span id, .name = innermost scoped
+# span's name, .jax = this thread's _ThreadCompiles
+_tls = threading.local()
 _ids = [0]           # process-local trace/span id counter (under _lock)
 
 
@@ -123,6 +138,7 @@ def enabled():
 def enable():
     global _enabled
     _enabled = True
+    _listen()
 
 
 def disable():
@@ -337,7 +353,7 @@ class _Span:
     annotation when it was given a ``step_num``)."""
 
     __slots__ = ("name", "memory", "hist", "_t0", "duration_ms",
-                 "_trace", "_sid", "_parent", "_ann")
+                 "_trace", "_sid", "_parent", "_outer", "_ann")
 
     def __init__(self, name, memory=False, hist=False, step_num=None):
         self.name = name
@@ -357,6 +373,8 @@ class _Span:
             self._parent = getattr(_tls, "span", None)
             self._sid = _next_id()
             _tls.span = self._sid
+        self._outer = getattr(_tls, "name", None)
+        _tls.name = self.name
         self._ann.__enter__()
         self._t0 = _now()
         return self
@@ -367,6 +385,7 @@ class _Span:
         self.duration_ms = dur * 1e3
         if self._trace is not None:
             _tls.span = self._parent
+        _tls.name = self._outer
         _record_span(self.name, self._t0, dur, trace=self._trace,
                      sid=self._sid, parent=self._parent)
         if self.hist:
@@ -718,13 +737,18 @@ def record_compile(fn, key):
     ``MXNET_TELEMETRY_RETRACE_WARN``-th (default 3rd) compile of the
     same function a ``logging`` warning fires — a retrace storm on a
     hot step usually means an unstable shape/dtype/static-arg upstream.
+    A function's FIRST recompile in which nothing but the committedness
+    or sharding of leaves moved is not counted toward that threshold:
+    every jitted step has one, at its second call, when the state it
+    carries comes back committed (``arg_signature`` keys), and the
+    warning keeps meaning two retraces beyond the expected.
     """
     if not _enabled:
         return None
     with _lock:
         ent = _compiles.get(fn)
         if ent is None:
-            ent = _compiles[fn] = {"count": 0, "key": None}
+            ent = _compiles[fn] = {"count": 0, "key": None, "placed": 0}
         ent["count"] += 1
         n = ent["count"]
         prev = ent["key"]
@@ -734,7 +758,11 @@ def record_compile(fn, key):
         return []
     changed = _diff_keys(prev, key) or ["<cache key unchanged>"]
     event("recompile", fn, n=n, changed=changed)
-    if n >= _RETRACE_WARN:
+    if not ent["placed"] and all(
+            c.split(":", 1)[0].endswith((".committed", ".sharding"))
+            for c in changed):
+        ent["placed"] = 1
+    if n - ent["placed"] >= _RETRACE_WARN:
         # warn once per (instance, cache-key family): ``fn`` keys are
         # already instance-qualified (``serve.<name>.b<N>``,
         # ``DataParallelStep[<id>]``), and the family is the SET of key
@@ -754,6 +782,35 @@ def record_compile(fn, key):
     return changed
 
 
+def leaf_signature(v):
+    """What ``jax.jit`` keys an argument leaf by beyond the tree it sits
+    in: shape, dtype, weak type, and the sharding of a COMMITTED array
+    (an uncommitted one goes wherever the others are).  Read from a
+    donated array as well as from a live one: donation takes the buffer,
+    not the aval or the sharding."""
+    committed = bool(getattr(v, "committed", False))
+    return {"shape": list(v.shape), "dtype": str(v.dtype),
+            "weak_type": bool(getattr(v, "weak_type", False)),
+            "committed": committed,
+            "sharding": v.sharding if committed else None}
+
+
+def arg_signature(args):
+    """``leaf_signature`` of every array leaf of ``args`` (nested lists,
+    tuples and dicts; ``None`` stays), shardings as text: a key for
+    ``record_compile``, whose diff then names the leaf that made
+    ``jax.jit`` compile a cached step again —
+    ``opt_states[3][0].committed: False -> True``."""
+    import jax
+
+    def text(v):
+        sig = leaf_signature(v)
+        sig["sharding"] = None if sig["sharding"] is None \
+            else str(sig["sharding"])
+        return sig
+    return jax.tree_util.tree_map(text, args)
+
+
 def compile_counts():
     with _lock:
         return {k: v["count"] for k, v in _compiles.items()}
@@ -768,6 +825,132 @@ def compile_deltas(baseline):
     cur = compile_counts()
     return {k: v - baseline.get(k, 0) for k, v in cur.items()
             if v > baseline.get(k, 0)}
+
+
+# ---------------------------------------------------------------------------
+# jax's own compile events
+# ---------------------------------------------------------------------------
+
+_TRACE = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_BACKEND = "/jax/core/compile/backend_compile_duration"
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+_CACHE_MISS = "/jax/compilation_cache/cache_misses"
+_listening = False
+
+
+class _ThreadCompiles:
+    """What one thread has seen of jax's compile events: ``seq`` programs
+    handed to the backend here, the record of the ``last`` one, and the
+    trace (seconds by function name), lowering and cache events still
+    waiting for their backend event (they fire before it, on the same
+    thread)."""
+
+    __slots__ = ("seq", "last", "trace", "lower", "cache")
+
+    def __init__(self):
+        self.seq = 0
+        self.last = None
+        self.trace = {}
+        self.lower = None
+        self.cache = "off"
+
+
+def thread_compiles():
+    """This thread's ``_ThreadCompiles``.  A jitted step's builder reads
+    ``.seq`` before and after its call: where it moved, jax compiled
+    inside the call, whatever the builder's own cache said, and ``.last``
+    is the program (``DataParallelStep``, ``_FusedUpdate``)."""
+    state = getattr(_tls, "jax", None)
+    if state is None:
+        state = _tls.jax = _ThreadCompiles()
+    return state
+
+
+def _on_jax_duration(event, seconds, fun_name=None, **_):
+    if not _enabled:
+        return
+    if event == _TRACE:
+        # inner jitted functions are traced too, before the program's
+        # own trace ends and while it is lowered: kept by name
+        thread_compiles().trace[fun_name] = seconds
+    elif event == _LOWER:
+        thread_compiles().lower = (fun_name, seconds)
+    elif event == _BACKEND:
+        _book_compile(fun_name, seconds)
+
+
+def _on_jax_event(event, **_):
+    if not _enabled:
+        return
+    if event == _CACHE_HIT:
+        thread_compiles().cache = "hit"
+    elif event == _CACHE_MISS:
+        thread_compiles().cache = "miss"
+
+
+def _book_compile(fun_name, backend_s):
+    """One program handed to the backend (compiled, or reloaded from the
+    persistent cache): pair it with the trace and lowering that led to
+    it, book it to its owner, journal it."""
+    state = thread_compiles()
+    # ``jit(step_fn)`` was traced as ``step_fn``; a program jax had traced
+    # before (same avals, another sharding or committedness) is lowered
+    # and compiled again without a trace
+    traced = fun_name[fun_name.find("(") + 1:-1] \
+        if fun_name.endswith(")") else fun_name
+    trace_s = state.trace.get(traced, 0.0)
+    lower, cache = state.lower, state.cache
+    state.trace.clear()
+    state.lower = None
+    state.cache = "off"
+    lower_s = lower[1] if lower and lower[0] == fun_name else 0.0
+    owner = getattr(_tls, "name", None) or "eager"
+    rec = {"owner": owner, "trace_s": trace_s, "lower_s": lower_s,
+           "backend_s": backend_s, "cache": cache}
+    state.seq += 1
+    state.last = dict(rec, name=fun_name)
+    with _lock:
+        total = _compile_totals.setdefault(owner, {
+            "programs": 0, "trace_s": 0.0, "lower_s": 0.0,
+            "backend_s": 0.0, "cache_hits": 0, "cache_misses": 0})
+        total["programs"] += 1
+        total["trace_s"] += trace_s
+        total["lower_s"] += lower_s
+        total["backend_s"] += backend_s
+        if cache != "off":
+            total["cache_hits" if cache == "hit" else "cache_misses"] += 1
+    # op-by-op programs outside every span come by the hundred in a
+    # set-up: counted above, not journaled (they would push everything
+    # else off a snapshot's tail of recent events)
+    if owner != "eager":
+        event("xla_compile", fun_name, **rec)
+
+
+def _listen():
+    """Register the one pair of ``jax.monitoring`` listeners, once a
+    process however often it is asked; nothing while telemetry is
+    disabled (``enable()`` asks again)."""
+    global _listening
+    if _listening or not _enabled:
+        return
+    import jax.monitoring
+    with _lock:
+        if _listening:
+            return
+        _listening = True
+    jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
+    jax.monitoring.register_event_listener(_on_jax_event)
+
+
+def compile_totals():
+    """``{owner: {"programs", "trace_s", "lower_s", "backend_s",
+    "cache_hits", "cache_misses"}}``: every program jax handed its
+    backend since the last ``reset()``, by the scoped span it compiled
+    under (``eager`` outside any)."""
+    with _lock:
+        return {owner: dict(total)
+                for owner, total in _compile_totals.items()}
 
 
 # ---------------------------------------------------------------------------
@@ -866,6 +1049,7 @@ def snapshot(events=64):
     """In-process view of everything: counters, gauges, span aggregates
     (ms), compile counts, and the ``events`` most recent journal
     entries.  Cheap enough to embed per-run in BENCH artifacts."""
+    totals = compile_totals()
     with _lock:
         spans = {
             name: {"count": a[0],
@@ -883,6 +1067,7 @@ def snapshot(events=64):
             "histograms": {name: h.summary()
                            for name, h in _hists.items()},
             "compiles": {k: v["count"] for k, v in _compiles.items()},
+            "compile_totals": totals,
             "events": list(_journal)[-events:] if events else [],
         }
 
@@ -895,6 +1080,7 @@ def reset():
         _spans.clear()
         _journal.clear()
         _compiles.clear()
+        _compile_totals.clear()
         _retrace_warned.clear()
         _hists.clear()
 
@@ -927,7 +1113,8 @@ def export_jsonl(path):
     rec = {"ts": round(_WALL0 + _now(), 6), "kind": "snapshot",
            "counters": snap["counters"], "gauges": snap["gauges"],
            "spans": snap["spans"], "histograms": hists,
-           "compiles": snap["compiles"]}
+           "compiles": snap["compiles"],
+           "compile_totals": snap["compile_totals"]}
     if _rank is not None:
         rec["rank"] = _rank
     # atomic (tmp + os.replace via fsutil): a collector must never read
@@ -939,3 +1126,7 @@ def export_jsonl(path):
                 f.write(json.dumps(r, default=str) + "\n")
             f.write(json.dumps(rec, default=str) + "\n")
     return path
+
+
+# the model's first eager programs compile before any span opens
+_listen()

@@ -72,6 +72,22 @@ def _float_feed(n_batches=4, bs=4, dim=6):
     return DevicePrefetchIter(F32Iter(), dtype="float32", depth=2)
 
 
+def _count_backend_compiles(wanted):
+    """The test's own ``jax.monitoring`` listener, beside the program's:
+    a list that grows by one for every ``wanted`` program jax hands its
+    backend from now on.  (jax keeps listeners for the life of the
+    process: one that outlives its test appends to a list nobody reads.)"""
+    import jax.monitoring
+    seen = []
+
+    def listener(event, seconds, fun_name=None, **_):
+        if event == telemetry._BACKEND and fun_name == wanted:
+            seen.append(seconds)
+
+    jax.monitoring.register_event_duration_secs_listener(listener)
+    return seen
+
+
 # ---------------------------------------------------------------------------
 # primitives
 # ---------------------------------------------------------------------------
@@ -167,6 +183,51 @@ def test_retrace_warning_fires(caplog):
                for r in caplog.records)
 
 
+def test_the_steps_own_placement_recompile_does_not_count_to_the_warning(
+        caplog):
+    """Every step compiles twice by design (its second call brings the
+    carried ``t`` and ``rng`` back committed), so the third compile — an
+    epoch's last partial batch, an evaluation batch size — is the FIRST
+    real retrace and stays quiet; the next one warns, as it did when the
+    detector saw the framework's own misses only."""
+    net = _make_net()
+    step = mx.parallel.DataParallelStep(
+        net, gloss.SoftmaxCrossEntropyLoss(),
+        mx.optimizer.SGD(learning_rate=0.1), mesh=None)
+    rs = onp.random.RandomState(0)
+
+    def batch(n):
+        return (mx.nd.array(rs.randn(n, 6).astype("float32")),
+                mx.nd.array(rs.randint(0, 4, n).astype("float32")))
+
+    with caplog.at_level(logging.WARNING):
+        step(*batch(4))
+        step(*batch(4))
+        step(*batch(8))
+    name, = [k for k in telemetry.compile_counts()
+             if k.startswith("DataParallelStep[")]
+    assert telemetry.compile_counts()[name] == 3
+    assert not [r for r in caplog.records if "retrace" in r.message]
+    with caplog.at_level(logging.WARNING):
+        step(*batch(2))
+    assert any("compiled 4 times" in r.message and "data.shape[0]" in
+               r.message for r in caplog.records)
+
+
+def test_only_the_first_placement_recompile_is_free(caplog):
+    key = lambda committed: {"t": {"shape": [], "committed": committed,
+                                   "sharding": None}}
+    with caplog.at_level(logging.WARNING):
+        telemetry.record_compile("fn", key(False))
+        telemetry.record_compile("fn", key(True))
+        telemetry.record_compile("fn", key(False))
+    assert not caplog.records
+    with caplog.at_level(logging.WARNING):
+        telemetry.record_compile("fn", key(True))
+    assert any("compiled 4 times" in r.message and "t.committed" in r.message
+               for r in caplog.records)
+
+
 def test_diff_keys_dtype_and_static_args():
     old = {"data": {"shape": [4, 6], "dtype": "float32"}, "mode": "call"}
     new = {"data": {"shape": [4, 6], "dtype": "bfloat16"}, "mode": "scan"}
@@ -186,6 +247,7 @@ def test_trainer_run_snapshot_has_spans_ring_and_memory():
     L = gloss.SoftmaxCrossEntropyLoss()
     feed = _float_feed(n_batches=3)
     steps = 0
+    fused = _count_backend_compiles("jit(fused)")
     for batch in feed:
         with autograd.record():
             loss = L(net(batch.data[0]), batch.label[0])
@@ -206,9 +268,18 @@ def test_trainer_run_snapshot_has_spans_ring_and_memory():
     assert snap["spans"]["prefetch.ship"]["count"] == 3
     # memory gauge sampled at the trainer.step span boundary
     assert snap["gauges"]["mem.host_rss_bytes"] > 0
-    # the fused update compiled exactly once (no retrace storm)
+    # the detector counts what jax compiled, its own retraces of the
+    # cached update included (the second call arrives with committed
+    # outputs), and names the leaves that moved: no blind spot, no storm
     assert [v for k, v in snap["compiles"].items()
-            if k.startswith("FusedUpdate[")] == [1]
+            if k.startswith("FusedUpdate[")] == [len(fused)]
+    assert 1 <= len(fused) <= 2
+    retraces = [e for e in snap["events"] if e["kind"] == "recompile"
+                and e["name"].startswith("FusedUpdate[")]
+    assert len(retraces) == len(fused) - 1
+    for rec in retraces:
+        assert any(".committed: " in c or ".sharding: " in c
+                   for c in rec["changed"]), rec
 
 
 # ---------------------------------------------------------------------------
@@ -561,3 +632,131 @@ def test_attention_dispatch_counted():
     snap = telemetry.snapshot()
     evs = [e for e in snap["events"] if e["kind"] == "attention_dispatch"]
     assert evs and evs[-1]["seq_q"] == 2048
+
+
+# ---------------------------------------------------------------------------
+# jax's own compile events, booked to the span they fire under (ISSUE 35)
+# ---------------------------------------------------------------------------
+
+def _compile_fresh(name="probe_fn"):
+    """Hand jax a program it has not compiled in this process: a new
+    function object misses ``jax.jit``'s in-memory cache whatever the
+    persistent one holds, so exactly one backend event fires."""
+    import jax
+    import jax.numpy as jnp
+
+    def fn(x):
+        return x * 2.0 + 1.0
+    fn.__name__ = fn.__qualname__ = name
+    x = jnp.ones((3,), jnp.float32)      # its own small programs first
+    before = telemetry.compile_totals()
+    jax.jit(fn)(x).block_until_ready()
+    return before
+
+
+def _programs(totals, owner):
+    return totals.get(owner, {}).get("programs", 0)
+
+
+@pytest.mark.parametrize("span_name,fun_name,owner", [
+    ("unit.build", "probe_fn", "unit.build"),
+    (None, "probe_fn", "eager"),
+    ("parallel.step.call", "step_fn", "parallel.step.call"),
+])
+def test_a_compile_is_booked_to_the_innermost_open_span(span_name, fun_name,
+                                                        owner):
+    if span_name is None:
+        before = _compile_fresh(fun_name)
+    else:
+        with telemetry.span("unit.outer"):
+            with telemetry.span(span_name):
+                before = _compile_fresh(fun_name)
+    totals = telemetry.compile_totals()
+    assert _programs(totals, owner) == _programs(before, owner) + 1
+    assert sum(t["programs"] for t in totals.values()) \
+        == sum(t["programs"] for t in before.values()) + 1
+    mine = totals[owner]
+    assert set(mine) == {"programs", "trace_s", "lower_s", "backend_s",
+                         "cache_hits", "cache_misses"}
+    assert mine["trace_s"] > 0 and mine["lower_s"] > 0 \
+        and mine["backend_s"] > 0
+    snap = telemetry.snapshot()
+    assert snap["compile_totals"] == totals
+    # a program compiled under a span is one journal record; the
+    # op-by-op programs outside every span are counted only
+    recs = [e for e in snap["events"] if e["kind"] == "xla_compile"]
+    if owner == "eager":
+        assert not recs
+    else:
+        rec = recs[-1]
+        assert rec["name"] == "jit(%s)" % fun_name \
+            and rec["owner"] == owner
+        assert rec["cache"] in ("hit", "miss", "off")
+        assert rec["trace_s"] > 0 and rec["lower_s"] > 0 \
+            and rec["backend_s"] > 0
+    # the span closed: the next compile on this thread is eager again
+    assert getattr(telemetry._tls, "name", None) is None
+
+
+def test_disabled_books_no_compile():
+    with telemetry.disabled():
+        _compile_fresh()
+        assert telemetry.compile_totals() == {}
+        seq = telemetry.thread_compiles().seq
+        _compile_fresh()
+        assert telemetry.thread_compiles().seq == seq
+    snap = telemetry.snapshot()
+    assert snap["compile_totals"] == {}
+    assert not [e for e in snap["events"] if e["kind"] == "xla_compile"]
+
+
+def test_the_listeners_register_once():
+    from jax._src import monitoring
+    for _ in range(3):
+        telemetry.enable()
+        telemetry._listen()
+        with telemetry.disabled():
+            telemetry._listen()
+    assert monitoring.get_event_duration_listeners().count(
+        telemetry._on_jax_duration) == 1
+    assert monitoring.get_event_listeners().count(
+        telemetry._on_jax_event) == 1
+    before = _compile_fresh()
+    after = telemetry.compile_totals()
+    assert _programs(after, "eager") == _programs(before, "eager") + 1
+
+
+def test_reset_clears_the_compile_totals(tmp_path):
+    _compile_fresh()
+    assert telemetry.compile_totals()["eager"]["programs"] >= 1
+    # the exporter and the flight recorder carry the totals as they are
+    path = telemetry.export_jsonl(str(tmp_path / "t.jsonl"))
+    with open(path) as f:
+        last = json.loads(f.read().strip().splitlines()[-1])
+    assert last["kind"] == "snapshot"
+    assert last["compile_totals"] == telemetry.compile_totals()
+    telemetry.reset()
+    assert telemetry.compile_totals() == {}
+    assert telemetry.snapshot()["compile_totals"] == {}
+
+
+def test_a_backend_event_uses_up_what_waited_for_it():
+    """Trace and lowering seconds wait on the thread for their backend
+    event and go with it: a later program that jax compiles without
+    tracing (another committedness of the same avals) pairs none of
+    them."""
+    import jax
+    import jax.numpy as jnp
+
+    fn = jax.jit(lambda x: x + 1.0)
+    x = jnp.ones((5,), jnp.float32)
+    fn(x)
+    state = telemetry.thread_compiles()
+    first, seq = state.last, state.seq
+    assert state.trace == {} and state.lower is None \
+        and state.cache == "off"
+    fn(jax.device_put(x, jax.devices()[0]))     # committed now
+    assert state.seq == seq + 1 and state.last is not first
+    assert state.last["name"] == first["name"] == "jit(<lambda>)"
+    assert state.last["lower_s"] > 0 and state.last["backend_s"] > 0
+    assert state.trace == {} and state.lower is None

@@ -26,11 +26,11 @@ def _clean_telemetry():
     telemetry.reset()
 
 
-def _toy_step():
+def _toy_step(hidden=16):
     net = nn.HybridSequential(prefix="toy_")
     with net.name_scope():
-        net.add(nn.Dense(16, activation="relu", in_units=10),
-                nn.Dense(4, in_units=16))
+        net.add(nn.Dense(hidden, activation="relu", in_units=10),
+                nn.Dense(4, in_units=hidden))
     net.initialize()
     step = parallel.DataParallelStep(
         net, gluon.loss.SoftmaxCrossEntropyLoss(),
@@ -202,6 +202,179 @@ def test_recent_spans_does_not_count_a_span_whose_children_fell_off(
     spans, short = telemetry.recent_spans("tl.parent", 3)
     assert (len(spans), short) == (2, 1)
     assert all(s["children"]["tl.child"] <= s["dur_ms"] for s in spans)
+
+
+# ---------------------------------------------------------------------------
+# (2b) the step's compiles: jax's own count, the leaves that caused the
+# second, the six set-up readers (ISSUE 35)
+# ---------------------------------------------------------------------------
+
+SETUP_READERS = ("step_compiles.setup", "step_compile_s.setup",
+                 "eager_programs.setup", "eager_compile_s.setup",
+                 "compile_cache_hit_share.setup", "param_build_s.setup")
+
+
+def _reader(name):
+    import importlib.util
+    path = os.path.join(os.path.dirname(PACKAGE), "benchmark",
+                        "layer_metrics", name + ".py")
+    spec = importlib.util.spec_from_file_location("setup_reader", path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+@pytest.fixture(scope="module")
+def four_steps():
+    """Four steps of a small ``DataParallelStep``, once a module: what the
+    journal, the detector and the readers held afterwards (the autouse
+    fixture wipes the program's telemetry before every test)."""
+    import time
+
+    import jax.monitoring
+    jax_saw, walks = [], []
+
+    def listener(event, seconds, fun_name=None, **_):
+        if event == telemetry._BACKEND and fun_name == "jit(step_fn)":
+            jax_saw.append(len(walks))
+
+    real = telemetry.arg_signature
+
+    def counted(args):
+        walks.append(step._t - 1)
+        return real(args)
+
+    telemetry.reset()
+    telemetry.enable()
+    jax.monitoring.register_event_duration_secs_listener(listener)
+    t0 = time.perf_counter()
+    # a width no other test builds: its initializers and optimizer state
+    # are programs this process has not compiled yet
+    step, data, label = _toy_step(hidden=23)
+    telemetry.arg_signature = counted
+    try:
+        for _ in range(4):
+            step(data, label).wait_to_read()
+    finally:
+        telemetry.arg_signature = real
+    wall_s = time.perf_counter() - t0
+    seen = {
+        "name": "DataParallelStep[%x]" % id(step),
+        "jax_compiles": len(jax_saw), "walks": walks, "wall_s": wall_s,
+        "counts": telemetry.compile_counts(),
+        "events": telemetry.snapshot(events=4096)["events"],
+        "recent": telemetry.recent_spans("parallel.step", 4),
+        "read": {name: _reader(name).read({"steps": 4})
+                 for name in SETUP_READERS},
+        "read_nothing": {name: _reader(name).read({"spans": {}})
+                         for name in SETUP_READERS},
+    }
+    jax_saw.clear()      # jax keeps the listener: leave it nothing to hold
+    return seen
+
+
+def test_the_detector_counts_what_jax_compiled(four_steps):
+    assert four_steps["jax_compiles"] >= 1
+    assert four_steps["counts"][four_steps["name"]] \
+        == four_steps["jax_compiles"]
+    compiles = [e for e in four_steps["events"]
+                if e["kind"] in ("compile", "recompile")
+                and e["name"] == four_steps["name"]]
+    assert [e["n"] for e in compiles] \
+        == list(range(1, four_steps["jax_compiles"] + 1))
+    assert compiles[0]["kind"] == "compile"
+
+
+def test_every_later_compile_names_the_leaves_that_moved(four_steps):
+    events = four_steps["events"]
+    records = [e for e in events if e["kind"] == "span"
+               and e["name"] == "parallel.step.compile"]
+    retraces = [e for e in events if e["kind"] == "recompile"
+                and e["name"] == four_steps["name"]]
+    assert len(records) == four_steps["jax_compiles"]
+    assert len(retraces) == four_steps["jax_compiles"] - 1
+    assert records[0]["step"] == 0 and records[0]["changed"] == []
+    for retrace, record in zip(retraces, records[1:]):
+        changed = retrace["changed"]
+        assert changed and changed == record["changed"]
+        # leaf paths over the step's arguments, by what jax keys apart
+        assert all(re.match(
+            r"(params|opt_states|t|lrs|rng|data|label)[\[.\w\]]*"
+            r"\.(committed|sharding|shape\[\d+\]|dtype|weak_type): ", c)
+            for c in changed), changed
+        assert record["n"] == retrace["n"]
+    for record in records:
+        assert record["cache"] in ("hit", "miss", "off")
+        assert record["lower_s"] > 0 and record["backend_s"] > 0
+        assert record["dur_ms"] == pytest.approx(
+            (record["trace_s"] + record["lower_s"] + record["backend_s"])
+            * 1e3, abs=1e-3)
+
+
+def test_a_compile_record_hangs_under_its_steps_call_span(four_steps):
+    events = four_steps["events"]
+    spans = [e for e in events if e["kind"] == "span"]
+    steps = [e for e in spans if e["name"] == "parallel.step"]
+    hooks = [e for e in events if e["kind"] == "step"]
+    assert len(steps) == len(hooks) == 4
+    records = [e for e in spans if e["name"] == "parallel.step.compile"]
+    compiled_steps = []
+    for record in records:
+        call, = [e for e in spans if e.get("sid") == record["parent"]]
+        assert call["name"] == "parallel.step.call"
+        assert call["trace"] == record["trace"]
+        parent, = [e for e in steps if e["sid"] == call["parent"]]
+        assert steps.index(parent) == record["step"]
+        compiled_steps.append(record["step"])
+    # the step hook of exactly those steps says so
+    assert [h["index"] for h in hooks if h.get("compiled")] \
+        == compiled_steps
+    assert all(h.get("compiled", True) is True for h in hooks)
+
+
+def test_steady_steps_record_nothing_and_walk_no_signature(four_steps):
+    assert four_steps["jax_compiles"] <= 2
+    compiled = sorted({e["step"] for e in four_steps["events"]
+                       if e.get("name") == "parallel.step.compile"})
+    assert compiled == list(range(four_steps["jax_compiles"]))
+    # one walk of the arguments a compile, none at steps 2 and 3
+    assert four_steps["walks"] == compiled
+    assert not [e for e in four_steps["events"] if e["kind"] == "step"
+                and e["index"] >= 2 and e.get("compiled")]
+
+
+def test_the_compile_record_leaves_the_steps_self_time_alone(four_steps):
+    spans, short = four_steps["recent"]
+    assert short == 0 and len(spans) == 4
+    steps = [e for e in four_steps["events"] if e["kind"] == "span"
+             and e["name"] == "parallel.step"]
+    for span, rec in zip(spans, steps):
+        assert sorted(span["children"]) == ["parallel.step.call",
+                                            "parallel.step.place"]
+        assert span["dur_ms"] == rec["dur_ms"]
+        self_ms = span["dur_ms"] - span["children"]["parallel.step.place"] \
+            - span["children"]["parallel.step.call"]
+        assert 0 <= self_ms < span["dur_ms"]
+
+
+@pytest.mark.parametrize("name", SETUP_READERS)
+def test_setup_readers_read_the_program_in_process(four_steps, name):
+    value = four_steps["read"][name]
+    assert four_steps["read_nothing"][name] is None
+    read = four_steps["read"]
+    if name == "step_compiles.setup":
+        assert value == four_steps["jax_compiles"] \
+            and isinstance(value, int)
+    elif name == "eager_programs.setup":
+        assert isinstance(value, int) and value > 0
+    elif name == "compile_cache_hit_share.setup":
+        # None where the persistent cache is off
+        assert value is None or 0.0 <= value <= 100.0
+    else:
+        assert isinstance(value, float) and value >= 0.0
+        assert read["step_compile_s.setup"] \
+            + read["eager_compile_s.setup"] <= four_steps["wall_s"]
+        assert read["param_build_s.setup"] <= four_steps["wall_s"]
 
 
 # ---------------------------------------------------------------------------
