@@ -9,10 +9,17 @@ all-to-all).  Nothing has a capacity: a route to a held expert is always
 computed, however uneven the routing.  Top-1 is the case of one route a
 token, through the same grouping, gathers and products.
 
-The grouped product is ``lax.ragged_dot``: the v5e compiler lowers it to
-its own Mosaic kernel (``ragged-dot-none`` in the step program: tiles of
-rows, each against its group's weight, work in proportion to the rows that
-have a group), forward and both gradients.
+The grouped product on sorted rows is ``lax.ragged_dot``: the v5e compiler
+lowers it to its own Mosaic kernel (``ragged-dot-none`` in the step
+program: tiles of rows, each against its group's weight, work in
+proportion to the rows that have a group), forward and both gradients.
+With k > 1 routes a token and a known expert count the held experts run on
+BLOCKS of slots instead (``_on_blocks``: constant work, whatever the
+routing), each block against its owner's weight: on a TPU a Pallas product
+that reads ``weights[owner[b]]`` where it lies and sums an owner's blocks
+into its row of the weights' gradient (``ops/pallas_moe.py``: no gathered
+copy of the weights, no ``scatter-add``), elsewhere the owners' weights
+gathered and one dense batched product.
 """
 from __future__ import annotations
 
@@ -23,6 +30,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from .. import context as _context
 from .nn import activation as _activation
 from .registry import register
 
@@ -120,24 +128,52 @@ def collect_rows(out, order, place):
     return _gather_rows(out, place, order, 1)
 
 
-def grouped_matmul(rows, weights, sizes):
-    """``rows[i] @ weights[g(i)]``: rows (N, K) sorted by group, weights
-    (G, K, M), ``sizes`` (G,) rows per group.  Rows past ``sizes.sum()``
-    have no group and come out zero.  The chip's kernel leaves them
-    UNWRITTEN — whatever the buffer held, NaN included — in the product
-    and in the gradient it hands back for ``rows`` alike, so they are
-    masked on the way in (which masks that gradient) and on the way
-    out.  Rows already laid out in blocks, (B, S, K) against (B, K, M)
-    — each block's owner's weight — are one dense batched product: every
-    slot is computed, the caller reads the ones it filled."""
+def grouped_matmul(rows, weights, groups):
+    """``rows[i] @ weights[g(i)]`` for weights (G, K, M), one of two ways.
+
+    Rows (N, K) sorted by group, ``groups`` the ``sizes`` (G,) of rows per
+    group: one ``lax.ragged_dot``.  Rows past ``sizes.sum()`` have no group
+    and come out zero.  The chip's kernel leaves them UNWRITTEN — whatever
+    the buffer held, NaN included — in the product and in the gradient it
+    hands back for ``rows`` alike, so they are masked on the way in (which
+    masks that gradient) and on the way out.
+
+    Rows laid out in blocks of slots, (B, S, K), ``groups`` the ``owner``
+    (B,) of each block, non-decreasing: every slot of block b against
+    ``weights[owner[b]]``, the caller reads the ones it filled.  On a TPU,
+    at the shapes ``pallas_moe.moe_product_dispatch`` takes, a Pallas
+    product that reads the owner's weight where it lies and sums an owner's
+    blocks into its row of the weights' gradient (``ops/pallas_moe.py``);
+    elsewhere the owners' weights gathered and one dense batched product
+    (``einsum_block_products``).  The same numbers, the same work, whatever
+    the routing."""
     if rows.ndim == 3:      # blocks of slots: static in a compiled step
-        return jnp.einsum("gsk,gkm->gsm", rows, weights,
-                          preferred_element_type=rows.dtype)
+        return _block_products(rows, weights, groups)
     grouped = lax.broadcasted_iota(jnp.int32, (rows.shape[0], 1), 0) \
-        < jnp.sum(sizes)
+        < jnp.sum(groups)
     out = lax.ragged_dot(jnp.where(grouped, rows, jnp.zeros((), rows.dtype)),
-                         weights, sizes, preferred_element_type=rows.dtype)
+                         weights, groups, preferred_element_type=rows.dtype)
     return jnp.where(grouped, out, jnp.zeros((), out.dtype))
+
+
+def _block_products(blocks, weights, owner):
+    """``grouped_matmul`` on blocks, by the path
+    ``pallas_moe.moe_product_dispatch`` gives the shape."""
+    from .. import telemetry
+    from ..parallel.mesh import batch_shards
+    from . import pallas_moe
+
+    (count, width, k), (held, _, m) = blocks.shape, weights.shape
+    path = pallas_moe.moe_product_dispatch(
+        width, k, m, blocks.dtype,
+        on_tpu=_context.on_tpu(blocks, weights), shards=batch_shards())
+    # trace time: once a traced shape, as ssm.scan.* and attention.kernel.*
+    telemetry.inc("moe.product.%s" % path)
+    telemetry.event("moe.product", path, path=path, blocks=int(count),
+                    width=int(width), k=int(k), m=int(m), held=int(held))
+    if path == "kernel":
+        return pallas_moe.block_products(blocks, weights, owner)
+    return pallas_moe.einsum_block_products(blocks, weights, owner)
 
 
 _ROWS_OVER_EVEN = 4        # slots in all, over an even router's held routes
@@ -270,17 +306,18 @@ def _slots(blocks, local, grouped):
 
 def _on_blocks(network, blocks, x, gates, weights, local, grouped):
     """The same on ``blocks = (count, width)`` blocks of slots,
-    (count, width, D): a block computes against its owner's weights, and
-    the experts are one dense batched product a weight whose time does not
-    follow the routing.  The rows move through ``dispatch`` and
-    ``combine``, the gates and the sum over a token's routes inside the
-    latter: (N, D), and nothing here holds a (k N, D) array."""
+    (count, width, D): a block computes against its owner's weights —
+    ``network`` gets the blocks, their ``owner`` and the weights as they
+    are held — and the experts are one product a weight over every slot,
+    whose time does not follow the routing.  The rows move through
+    ``dispatch`` and ``combine``, the gates and the sum over a token's
+    routes inside the latter: (N, D), and nothing here holds a (k N, D)
+    array."""
     n = x.shape[0]
     owner, slot_route, route_slot = _slots(blocks, local, grouped)
     route_slot = route_slot.reshape(-1, n)
     rows = dispatch(x, slot_route, route_slot)
-    out = network(rows.reshape(blocks + rows.shape[1:]), grouped[2],
-                  *(jnp.take(w, owner, axis=0) for w in weights))
+    out = network(rows.reshape(blocks + rows.shape[1:]), owner, *weights)
     return combine(out.reshape(rows.shape[0], -1),
                    jnp.where(route_slot >= 0, gates.reshape(-1, n), 0),
                    slot_route, route_slot)
@@ -348,8 +385,13 @@ def _budgeted_bwd(network, blocks, args, g):
         return jax.vjp(lambda x, gates, weights: side(
             x, gates, weights, local, grouped), x, gates, weights)[1](g)
 
-    return _either(network, blocks, grouped[2], back) + (
-        _no_grad(local), tuple(_no_grad(index) for index in grouped))
+    d_x, d_gates, d_weights = _either(network, blocks, grouped[2], back)
+    # the ``cond`` hands the weights' gradients out in the weights' dtype:
+    # left to itself the compiler moves the optimizer's float32 cast into
+    # both branches, (held, K, M) float32 a weight held to the step's end
+    d_weights = lax.optimization_barrier(d_weights)
+    return d_x, d_gates, d_weights, _no_grad(local), tuple(
+        _no_grad(index) for index in grouped)
 
 
 _budgeted.defvjp(_budgeted_fwd, _budgeted_bwd)
@@ -361,7 +403,8 @@ def sparse_ffn(x, expert, gate, ffn, first, held, num_experts=None):
     ``x`` (N, D) tokens, ``expert`` / ``gate`` (N,) from ``top1_route`` or
     (N, k) from ``topk_route``, ``ffn`` the experts' network with its
     weights (``gated_experts`` / ``mlp_experts``: ``ffn.network(rows,
-    sizes, *ffn.weights)`` on rows sorted by expert, built from
+    groups, *ffn.weights)`` on rows sorted by expert with their ``sizes``
+    or on blocks of slots with their ``owner``, built from
     ``grouped_matmul``).  The k N routes go through ONE grouping: a
     token's row is gathered once a route, and its k results are summed
     with their gates.  Returns ``(y, sizes)``: ``y`` (N, D) is the sum of
@@ -382,10 +425,11 @@ def sparse_ffn(x, expert, gate, ffn, first, held, num_experts=None):
     many experts there are, the experts run on FOUR times that many slots,
     in 2 held blocks that an expert takes by need (a lumpy router gives
     one expert several times its share, the held experts together much
-    less), as one dense batched product a weight — constant work, whatever
-    the routing — while the experts' needs fit the blocks; a step that
-    needs more runs the ragged products on all k N sorted rows (one
-    ``lax.cond`` on the device, exact on both sides)."""
+    less), as one product a weight over all the slots, a block against its
+    owner's weight — constant work, whatever the routing — while the
+    experts' needs fit the blocks; a step that needs more runs the ragged
+    products on all k N sorted rows (one ``lax.cond`` on the device, exact
+    on both sides)."""
     # one route a token (N,) or k of them (N, k): static in a compiled step
     # graftlint: disable-next=retrace-shape-branch -- rank dispatch
     if expert.ndim == 1:
@@ -401,26 +445,26 @@ def sparse_ffn(x, expert, gate, ffn, first, held, num_experts=None):
 
 
 class _Experts(NamedTuple):
-    network: Callable          # (rows, sizes, *weights) -> rows
+    network: Callable          # (rows, groups, *weights) -> rows
     weights: tuple
 
 
 def gated_experts(w_gate, w_up, w_down, activation=jax.nn.silu):
     """Gated experts: ``(activation(x Wg) * x Wu) Wd`` with Wg, Wu
     (held, D, F) and Wd (held, F, D)."""
-    def network(rows, sizes, w_gate, w_up, w_down):
-        gate = grouped_matmul(rows, w_gate, sizes)
-        up = grouped_matmul(rows, w_up, sizes)
-        return grouped_matmul(activation(gate) * up, w_down, sizes)
+    def network(rows, groups, w_gate, w_up, w_down):
+        gate = grouped_matmul(rows, w_gate, groups)
+        up = grouped_matmul(rows, w_up, groups)
+        return grouped_matmul(activation(gate) * up, w_down, groups)
     return _Experts(network, (w_gate, w_up, w_down))
 
 
 def mlp_experts(w_up, w_down, activation):
     """Experts without a gate matrix: ``activation(x Wu) Wd`` with Wu
     (held, D, F) and Wd (held, F, D)."""
-    def network(rows, sizes, w_up, w_down):
-        return grouped_matmul(activation(grouped_matmul(rows, w_up, sizes)),
-                              w_down, sizes)
+    def network(rows, groups, w_up, w_down):
+        return grouped_matmul(activation(grouped_matmul(rows, w_up, groups)),
+                              w_down, groups)
     return _Experts(network, (w_up, w_down))
 
 

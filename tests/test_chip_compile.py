@@ -26,6 +26,7 @@ from jax.sharding import SingleDeviceSharding
 
 from mxnet_tpu.ops import pallas_attention as PA
 from mxnet_tpu.ops import pallas_layernorm as LN
+from mxnet_tpu.ops import pallas_moe as MOE
 from mxnet_tpu.ops import pallas_ssd as SSD
 
 
@@ -200,6 +201,41 @@ def _scan_programs(case):
     return programs
 
 
+# (id, blocks, slots, held, K, M): the experts' block products of the SDAR
+# cell (gate / up, down) and of the Nemotron cell (up — which the chip keeps
+# with 2688 minor, so the kernels read its (8, 1856, 2688) view —, down)
+_BLOCK_PRODUCTS = [
+    ("moe_sdar_up", 32, 1024, 16, 2048, 768),
+    ("moe_sdar_down", 32, 1024, 16, 768, 2048),
+    ("moe_nemotron_up", 16, 768, 8, 2688, 1856),
+    ("moe_nemotron_down", 16, 768, 8, 1856, 2688),
+]
+
+
+def _block_product_programs(case):
+    """The product through its ``custom_vjp``: the forward kernel, and what
+    the backward rule runs — ``d_rows`` and ``d_weights`` — at a shape
+    ``moe_product_dispatch`` gives the kernels."""
+    _, B, S, G, K, M = case
+
+    def programs(one_chip):
+        assert MOE.moe_product_dispatch(S, K, M, "bfloat16",
+                                        on_tpu=True) == "kernel"
+
+        def sds(shape, dt=jnp.bfloat16):
+            return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+        x, w, dy = sds((B, S, K)), sds((G, K, M)), sds((B, S, M))
+        owner = sds((B,), jnp.int32)
+
+        def bwd(x, w, owner, dy):
+            return jax.vjp(lambda x, w: MOE.block_products(x, w, owner),
+                           x, w)[1](dy)
+
+        return [(MOE.block_products, (x, w, owner)), (bwd, (x, w, owner, dy))]
+    return programs
+
+
 # (id, B, H, key-value heads, S, D, dtype): a mask given as data (q_mask,
 # kv_mask)
 _MASKED = [
@@ -249,6 +285,7 @@ _PROGRAMS = {c[0]: _attention_programs(c) for c in _ATTENTION}
 _PROGRAMS.update({c[0]: _masked_programs(c) for c in _MASKED})
 _PROGRAMS["layernorm_bert"] = _layernorm_programs
 _PROGRAMS.update({c[0]: _scan_programs(c) for c in _SCANS})
+_PROGRAMS.update({c[0]: _block_product_programs(c) for c in _BLOCK_PRODUCTS})
 
 # case id -> the kernels of its forward, of its backward: the dispatcher's
 # variants and the two layouts each under its own stable name
@@ -273,6 +310,11 @@ _KERNELS = {
 }
 _KERNELS.update({c[0]: (["ssd_fwd"], ["ssd_bwd", "ssd_states"])
                  for c in _SCANS})
+# the backward rule's jax.vjp traces the forward again: the compiler drops
+# the product nothing reads
+_KERNELS.update({c[0]: (["moe_blocks_fwd"],
+                        ["moe_blocks_dw", "moe_blocks_dx"])
+                 for c in _BLOCK_PRODUCTS})
 # a streamed K axis under a mask as data has kernels of its own names (the
 # tile summary is scalar-prefetched); the one-block kernels take the mask
 # as two more operands under the names they have
@@ -304,6 +346,11 @@ def test_layernorm_fwd_bwd_compiles_at_bert_shape(compiled):
 
 @pytest.mark.parametrize("case_id", [c[0] for c in _SCANS])
 def test_ssd_scan_kernels_compile_at_dispatched_shapes(compiled, case_id):
+    compiled(case_id)
+
+
+@pytest.mark.parametrize("case_id", [c[0] for c in _BLOCK_PRODUCTS])
+def test_moe_block_products_compile_at_both_cells_shapes(compiled, case_id):
     compiled(case_id)
 
 
@@ -719,13 +766,50 @@ _NEMOTRON_LAYERS = {
     # the parent, its (Q, Q) decay blocks recomputed; 1.347 now)
     "M": ({"ssd_fwd": 1, "ssd_states": 1, "ssd_bwd": 1}, 1.360),
     # while the held experts' routes fit 16 blocks of 768 slots the experts
-    # are dense batched products (no kernel); the side that runs when they
-    # do not holds the compiler's own ragged-dot kernel: two grouped
-    # products forward, again in the recomputed backward, four gradients.
-    # The routes' buffers are recomputed, not kept, and the blocks side
-    # holds no (k N, D) array since PR 34: 1.736 GB and 5% (2.073 before)
-    "E": ({"ragged-dot-none": 8, "ragged-dot-metadata": 3}, 1.736 * 1.05),
+    # are the block products' kernels (PR 36): two forward, the same two
+    # recomputed in the backward, two ``d_rows``, two ``d_weights``; the
+    # side that runs when they do not fit holds the compiler's own
+    # ragged-dot kernel: two grouped products forward, again in the
+    # recomputed backward, four gradients.  The routes' buffers are
+    # recomputed, not kept, the blocks side holds no (k N, D) array since
+    # PR 34 and no gathered copy of a weight since PR 36: 1.727 GB and 5%
+    # (1.736 with the copies, 2.073 before PR 34; 1.644 where the compiler
+    # is let move the optimizer's float32 cast of the weights' gradients
+    # into the ``conditional`` — which then hands out 0.32 GB a layer in
+    # float32 to the step's end: four such layers need 2.548 GB with the
+    # gradients handed out in bfloat16, 3.336 with the cast moved in)
+    "E": ({"ragged-dot-none": 8, "ragged-dot-metadata": 3,
+           "moe_blocks_fwd": 4, "moe_blocks_dx": 2, "moe_blocks_dw": 2},
+          1.727 * 1.05),
 }
+
+
+def _float32_handed_out(text, held, k, m):
+    """The float32 arrays of a weight's size, (held, k, m) or (held, m, k),
+    among the results of a compiled step's ``conditional``s: the weights'
+    gradients cast for the optimizer INSIDE the experts' ``cond`` and held
+    in float32 from there to the step's end."""
+    results = [line.split(" conditional(")[0]
+               for line in text.splitlines() if " conditional(" in line]
+    return [r for result in results for r in re.findall(
+        r"f32\[%d,(?:%d,%d|%d,%d)\]" % (held, k, m, m, k), result)]
+
+
+def _owners_copies(text, count, held, k, m):
+    """What a compiled step holds of the gathered form of the experts'
+    block products: every array of (count, k, m) or (count, m, k) — a copy
+    of the (held, k, m) weight a block — and every ``scatter`` or
+    ``dynamic-update-slice`` (the loops the compiler made of the
+    ``scatter-add``) into an array of the weight's size: the copy's
+    gradient un-made."""
+    copies = re.findall(r"\w+\[%d,(?:%d,%d|%d,%d)\]" % (count, k, m, m, k),
+                        text)
+    scatters = [line.split(" = ", 1)[0].split()[-1]
+                for line in text.splitlines()
+                if re.search(r" (scatter|dynamic-update-slice)\(", line)
+                and re.search(r"= \w+\[%d,(?:%d,%d|%d,%d)\]"
+                              % (held, k, m, m, k), line)]
+    return sorted(set(copies)), scatters
 
 
 @pytest.mark.parametrize("kind", list(_NEMOTRON_LAYERS))
@@ -746,7 +830,10 @@ def test_nemotron_layer_train_step_compiles(one_chip, monkeypatch, kind):
     none a ``select`` or a ``broadcast`` (the parent of PR 34 made seven:
     three gathers, a ``select_select_fusion``, a
     ``broadcast_multiply_fusion``, a ``broadcast`` and the ``conditional``
-    that handed the routes' rows out)."""
+    that handed the routes' rows out).  Its block products read their
+    owner's weight where it lies (PR 36): no array of 16 x 2,688 x 1,856
+    elements — the parent held a gathered copy a product, 250 mentions in
+    the text — and no ``scatter`` into a weight-sized array."""
     import mxnet_tpu as mx
     from mxnet_tpu import context, gluon, parallel
     from mxnet_tpu import random as mx_random
@@ -792,6 +879,9 @@ def test_nemotron_layer_train_step_compiles(one_chip, monkeypatch, kind):
     else:
         routes = _route_sized(text, 6 * 8192 * 2688)
         assert len(routes) <= 2 and set(routes) <= {"gather"}, routes
+        assert _owners_copies(text, 16, 8, 2688, 1856) == ([], [])
+        assert _float32_handed_out(text, 8, 2688, 1856) == []
+        assert ".remat" not in text
     temp = compiled.memory_analysis().temp_size_in_bytes / 1e9
     print("temporaries of the %s layer's step: %.3f GB" % (kind, temp))
     assert temp <= temp_gb, temp
@@ -807,9 +897,12 @@ def test_sdar_layer_train_step_compiles_to_the_masked_kernels(one_chip,
     ``DataParallelStep`` program compiles for the described chip; its
     attention is EXACTLY the three masked kernels — no unmasked flash
     kernel, and no (8192, 8192) score or mask array anywhere outside
-    them —; the experts' other kernels are the compiler's ragged
-    products on the side of the ``conditional`` that a lumpy router
-    takes; the blocks' names are in the instructions' ``op_name``s.  On
+    them —; the experts are the block products' kernels (PR 36: three
+    forward, three recomputed, three ``d_rows``, three ``d_weights``, no
+    array of 32 x 2,048 x 768 elements and no ``scatter`` into a
+    weight-sized one) and, on the side of the ``conditional`` that a lumpy
+    router takes, the compiler's ragged products; the blocks' names are in
+    the instructions' ``op_name``s.  On
     the blocks side at most two instructions make an array of k N x D =
     65,536 x 2,048 elements, both gathers, none a ``select`` or a
     ``broadcast`` (the parent of PR 34 made six: three gathers, a
@@ -848,9 +941,12 @@ def test_sdar_layer_train_step_compiles_to_the_masked_kernels(one_chip,
     names = collections.Counter(_kernel_names(text))
     assert {n: c for n, c in names.items() if n.startswith("flash")} == {
         "flash_masked_fwd": 1, "flash_masked_dq": 1, "flash_masked_dkv": 1}
-    assert set(names) - {"flash_masked_fwd", "flash_masked_dq",
-                         "flash_masked_dkv"} \
+    assert {n: c for n, c in names.items() if n.startswith("moe")} == {
+        "moe_blocks_fwd": 6, "moe_blocks_dx": 3, "moe_blocks_dw": 3}
+    assert {n for n in names if not n.startswith(("flash", "moe"))} \
         == {"ragged-dot-none", "ragged-dot-metadata"}
+    assert _owners_copies(text, 32, 16, 2048, 768) == ([], [])
+    assert _float32_handed_out(text, 16, 2048, 768) == []
     assert not re.findall(r"\w+\[(?:\d+,)*8192,8192\]", text)
     for block in ("layer0_attn_qkv", "layer0_attn/flash_masked_fwd",
                   "layer0_attn/flash_masked_dq",
@@ -861,4 +957,5 @@ def test_sdar_layer_train_step_compiles_to_the_masked_kernels(one_chip,
     assert len(routes) <= 2 and set(routes) <= {"gather"}, routes
     temp = compiled.memory_analysis().temp_size_in_bytes / 1e9
     print("temporaries of the SDAR layer's step: %.3f GB" % temp)
-    assert temp <= 2.089 * 1.05, temp
+    assert ".remat" not in text
+    assert temp <= 2.081 * 1.05, temp

@@ -659,8 +659,8 @@ def _route_order_form(network, blocks, x, gates, weights, local, grouped):
     owner, slot_route, route_slot = moe_ops._slots(blocks, local, grouped)
     n = x.shape[0]
     rows = x[jnp.maximum(slot_route, 0) % n]
-    out = network(rows.reshape(blocks + (-1,)), grouped[2],
-                  *(w[owner] for w in weights)).reshape(rows.shape)
+    out = network(rows.reshape(blocks + (-1,)), owner,
+                  *weights).reshape(rows.shape)
     routes = jnp.where((route_slot >= 0)[:, None],
                        out[jnp.maximum(route_slot, 0)], 0) \
         * gates.astype(out.dtype)[:, None]
