@@ -27,7 +27,7 @@ from ...block import HybridBlock
 from ...nn import Dense, Dropout, LayerNorm
 
 __all__ = ["MultiHeadAttention", "GroupedQueryAttention", "PositionwiseFFN",
-           "TransformerEncoderCell", "TransformerEncoder",
+           "GatedFFN", "TransformerEncoderCell", "TransformerEncoder",
            "CompressedConvAttention", "MaskTileCount", "publish_mask_tiles"]
 
 _TILE_COUNTS = weakref.WeakSet()   # the MaskTileCount blocks of this process
@@ -118,27 +118,47 @@ class GroupedQueryAttention(HybridBlock):
     else, each with one learned gain of ``head_dim`` that its heads share.
     ``rope_theta``: q and k are rotated (rotate-half, all of ``head_dim``)
     at the token's place in the row, or at ``position_ids`` (B, S) where
-    the call gives them; None: no position embedding.
+    the call gives them; None: no position embedding.  ``rope``: the
+    rotation by ``rotary_embedding``'s keyword arguments instead
+    (``rotary_dim``, ``theta``, ``rope_type="yarn"`` with ``factor``,
+    ``original_length``, ``beta_fast``, ``beta_slow``,
+    ``attention_factor``): a partial or a scaled one.
+    ``head_gate``: every head's output is multiplied by ``sigmoid(x W_g)``
+    before the output projection, ``W_g`` (units, num_heads) — the
+    head-wise output gate of Qiu et al., arXiv:2505.06708.
+    ``window``: a query sees the ``window`` keys that end with its own
+    (``flash_attention``'s ``window``: the mask's three integers made from
+    the shapes, the tiles under the band never visited).
 
-    ``forward(x)`` is causal.  ``forward(x, position_ids, q_mask,
-    kv_mask)`` takes the mask as data, (B, S, 2) integers each — a
-    query's [reach, own], a key's [rank, own], ``flash_attention`` has
-    the rule — and is causal only if they say so."""
+    ``forward(x)`` is causal, inside the window where there is one.
+    ``forward(x, position_ids, q_mask, kv_mask)`` takes the mask as data,
+    (B, S, 2) integers each (``q_mask`` (B, S, 3) with a floor) — a
+    query's [reach, own(, floor)], a key's [rank, own],
+    ``flash_attention`` has the rule — and is causal only if they say
+    so."""
 
     def __init__(self, units, num_heads, num_kv_heads, head_dim,
-                 qk_norm=False, rope_theta=None, epsilon=1e-6, **kwargs):
+                 qk_norm=False, rope_theta=None, epsilon=1e-6, rope=None,
+                 head_gate=False, window=None, **kwargs):
         super().__init__(**kwargs)
         if num_heads % num_kv_heads:
             raise ValueError("%d query heads do not divide over %d "
                              "key-value heads" % (num_heads, num_kv_heads))
+        if rope is not None and rope_theta is not None:
+            raise ValueError("rope_theta is rope={'theta': ...}: give one")
         self._heads = (num_heads, num_kv_heads, head_dim)
-        self._rope_theta, self._epsilon = rope_theta, epsilon
+        self._rope = dict(rope) if rope is not None else \
+            None if rope_theta is None else {"theta": rope_theta}
+        self._epsilon, self._window = epsilon, window
         with self.name_scope():
             self.qkv = Dense((num_heads + 2 * num_kv_heads) * head_dim,
                              flatten=False, use_bias=False, in_units=units,
                              prefix="qkv_")
             self.proj = Dense(units, flatten=False, use_bias=False,
                               in_units=num_heads * head_dim, prefix="out_")
+            self.gate = Dense(num_heads, flatten=False, use_bias=False,
+                              in_units=units, prefix="gate_") \
+                if head_gate else None
             if qk_norm:
                 self.q_norm_gamma = self.params.get(
                     "q_norm_gamma", shape=(head_dim,), init="ones")
@@ -158,17 +178,21 @@ class GroupedQueryAttention(HybridBlock):
             if gamma is not None:
                 out = F.RMSNorm(out, gamma, eps=self._epsilon)
             out = out.transpose(axes=(0, 2, 1, 3))
-            if rotate and self._rope_theta is not None:
-                out = F.rotary_embedding(out, position_ids,
-                                         theta=self._rope_theta)
+            if rotate and self._rope is not None:
+                out = F.rotary_embedding(out, position_ids, **self._rope)
             return out
 
         out = F.flash_attention(
             part(0, heads, q_norm_gamma, True),
             part(heads, heads + kv_heads, k_norm_gamma, True),
             part(heads + kv_heads, heads + 2 * kv_heads),
-            causal=q_mask is None, q_mask=q_mask, kv_mask=kv_mask)
-        return self.proj(out.transpose(axes=(0, 2, 1, 3)).reshape(b, s, -1))
+            causal=q_mask is None, q_mask=q_mask, kv_mask=kv_mask,
+            window=self._window if q_mask is None else None)
+        out = out.transpose(axes=(0, 2, 1, 3))               # (B, S, h, d)
+        if self.gate is not None:
+            gate = F.sigmoid(self.gate(x).astype("float32")).astype(x.dtype)
+            out = F.broadcast_mul(out, gate.reshape(b, s, heads, 1))
+        return self.proj(out.reshape(b, s, -1))
 
 
 class MaskTileCount(HybridBlock):
@@ -181,11 +205,14 @@ class MaskTileCount(HybridBlock):
     the one mask), and a TRAINING step keeps the pair as non-trainable
     state, as ``SparseExperts`` keeps its loads; ``publish_mask_tiles``
     reads it.  Zeros where the kernels stream no K blocks (short rows,
-    no TPU)."""
+    no TPU).  ``window``: the masks handed in are
+    ``ops.pallas_attention.window_mask``'s of that window, whose calls
+    plan their blocks by the band."""
 
-    def __init__(self, head_dim, calls=1, dtype="bfloat16", **kwargs):
+    def __init__(self, head_dim, calls=1, dtype="bfloat16", window=None,
+                 **kwargs):
         super().__init__(**kwargs)
-        self._plan = dict(head_dim=int(head_dim), dtype=dtype)
+        self._plan = dict(head_dim=int(head_dim), dtype=dtype, window=window)
         self._calls = float(calls)
         with self.name_scope():
             self.tiles = self.params.get(
@@ -249,6 +276,30 @@ class PositionwiseFFN(HybridBlock):
         if self.drop is not None:
             out = self.drop(out)
         return out
+
+
+class GatedFFN(HybridBlock):
+    """The gated dense feed-forward block: ``W_down(act(W_gate x) *
+    W_up x)``, no bias — the network ``SparseExperts`` runs an expert
+    (``gated``), dense: a leading dense layer's, a shared expert's.
+    ``activation`` is any of ``Activation``'s; ``in_units`` as
+    ``PositionwiseFFN``'s."""
+
+    def __init__(self, units, hidden_size, activation="silu", in_units=0,
+                 **kwargs):
+        super().__init__(**kwargs)
+        with self.name_scope():
+            def dense(width, in_units, prefix, activation=None):
+                return Dense(width, flatten=False, use_bias=False,
+                             in_units=in_units, activation=activation,
+                             prefix=prefix)
+            self.gate = dense(hidden_size, in_units, "gate_", activation)
+            self.up = dense(hidden_size, in_units, "up_")
+            self.down = dense(units, hidden_size if in_units else 0,
+                              "down_")
+
+    def hybrid_forward(self, F, x):
+        return self.down(self.gate(x) * self.up(x))
 
 
 class TransformerEncoderCell(HybridBlock):

@@ -931,25 +931,72 @@ def l2_normalize(data, scale=None, axis: int = -1, eps: float = 1e-12):
     return out.astype(data.dtype)
 
 
+def yarn_ramp(rotary_dim, theta, original_length, beta_fast=32.0,
+              beta_slow=1.0):
+    """YaRN's blend a frequency (Peng et al., arXiv:2309.00071, as the
+    ``transformers`` rule has it), (rotary_dim / 2,) float32 in [0, 1]: 0
+    where dimension i turns more than ``beta_fast`` times over
+    ``original_length`` positions (kept as trained), 1 where fewer than
+    ``beta_slow`` (interpolated), linear between.  With r =
+    ``rotary_dim``: ``corr(n) = r ln(original_length / (2 pi n)) /
+    (2 ln theta)``, ``low = max(floor(corr(beta_fast)), 0)``, ``high =
+    min(ceil(corr(beta_slow)), r - 1)``, ``ramp_i = clip((i - low) /
+    (high - low), 0, 1)``."""
+    import numpy as onp
+
+    def corr(turns):
+        return rotary_dim * math.log(original_length / (turns * 2 * math.pi)
+                                     ) / (2 * math.log(theta))
+    low = max(math.floor(corr(beta_fast)), 0)
+    high = min(math.ceil(corr(beta_slow)), rotary_dim - 1)
+    if low == high:
+        high += 0.001           # the rule's own guard against 0 / 0
+    return onp.clip((onp.arange(rotary_dim // 2, dtype="float32") - low)
+                    / (high - low), 0, 1)
+
+
 @register("rotary_embedding")
 def rotary_embedding(data, positions=None, rotary_dim: int = 0,
-                     theta: float = 10000.0):
+                     theta: float = 10000.0, rope_type: str = "default",
+                     factor: float = 1.0, original_length: int = 0,
+                     beta_fast: float = 32.0, beta_slow: float = 1.0,
+                     attention_factor: float = 1.0):
     """Rotary position embedding on the first ``rotary_dim`` of the D
     dimensions of a (B, H, S, D) tensor (0: all of them), the rest passed
     through.  Rotate-half pairing: dimension i turns with i + rotary_dim/2
-    by the angle ``t * theta ** (-2 i / rotary_dim)``; t is the token's
+    by the angle ``t * freq_i``, ``freq_i = theta ** (-2 i / rotary_dim)``;
+    t is the token's
     place in the row, from 0, or where ``positions`` (B, S) is given the
     token's own position id (ids may repeat: a row that holds a sequence
-    twice)."""
+    twice).
+
+    ``rope_type="yarn"``: a model trained to ``original_length`` positions
+    and stretched ``factor`` times — ``freq_i (1 - ramp_i) + freq_i /
+    factor * ramp_i`` with ``yarn_ramp``'s blend, and cos and sin times
+    ``attention_factor`` (the rule's scale on the scores, carried by q
+    and k)."""
+    from .. import telemetry
+    if rope_type not in ("default", "yarn"):
+        raise ValueError("rope_type=%r is neither default nor yarn"
+                         % (rope_type,))
     r = rotary_dim or data.shape[-1]
     half = r // 2
+    # trace-time census, once a traced shape, like attention_dispatch's
+    telemetry.inc("rotary.rule.%s" % rope_type)
+    telemetry.event("rotary", rope_type, rule=rope_type, rotary_dim=int(r),
+                    factor=float(factor), theta=float(theta))
     if positions is None:
         pos = jnp.arange(data.shape[-2], dtype=jnp.float32)
     else:
         pos = positions.astype(jnp.float32)[:, None, :]      # (B, 1, S)
     freq = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / r)
+    if rope_type == "yarn":
+        ramp = yarn_ramp(r, theta, original_length, beta_fast, beta_slow)
+        freq = freq * (1.0 - ramp) + freq / factor * ramp
     ang = pos[..., None] * freq[None, :]                     # (.., S, r/2)
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if attention_factor != 1.0:
+        cos, sin = cos * attention_factor, sin * attention_factor
     x32 = data.astype(jnp.float32)
     x1, x2, rest = x32[..., :half], x32[..., half:r], x32[..., r:]
     return jnp.concatenate(

@@ -37,7 +37,7 @@ __all__ = ["flash_attention", "flash_attention_bshd",
            "pallas_flash_attention", "pallas_flash_attention_bshd",
            "pallas_flash_attention_bwd", "pallas_flash_attention_bwd_bshd",
            "attention_dispatch", "tune_attention_blocks",
-           "bshd_layout_fits", "mask_tiles"]
+           "bshd_layout_fits", "mask_tiles", "window_mask"]
 
 _NEG_INF = -1e30
 _LANES = 128
@@ -71,9 +71,18 @@ _BWD_MIN_BLOCK_Q = 128
 #    the kernels alone): 14.2 ms at 1024 x 1024, 16.1 at 512 x 1024, 17.5
 #    at 1024 x 512, 18.6 at 512 x 512, 19.5 at 512 x 2048, 25.3 at 256 x
 #    512 (my chip runs, PR 33).
+#  * _WINDOW_MIN_BLOCK: under a causal window the blocks are no wider than
+#    the band (the window rounded up to a power of two), this at least: a
+#    band of 512 keys in a row of 4096 leaves 15 of 64 tiles of 512 x 512
+#    live against 7 of 16 of 1024 x 1024.  One layer's call (72 heads on
+#    8, D=128), forward + backward, the kernels alone: 8.33 ms at 512 x
+#    512, 8.66 at 512 x 1024, 9.05 at 1024 x 512, 9.48 at 1024 x 1024,
+#    10.97 at 256 x 512, 12.14 at 512 x 256, 16.04 at 256 x 256; the same
+#    call as plain causal attention 14.82 (my chip runs, PR 37).
 _RANK_NEVER = 2 ** 31 - 1
 _REACH_NONE = -2 ** 31
 _MASKED_BLOCKS = (1024, 1024)
+_WINDOW_MIN_BLOCK = 512
 
 
 def _fwd_vmem_bytes(block_q, block_k, Dp, itemsize):
@@ -133,15 +142,17 @@ def _blocks_fit(block_q, block_k, Dp, itemsize):
 
 
 def tune_attention_blocks(seq_q, seq_k, head_dim, dtype="bfloat16",
-                          masked=False):
+                          masked=False, window=None):
     """Default (block_q, block_k) for a (S, D, dtype) attention shape.
 
     Short K axes (<= _SHORT_SEQ_MAX_TK) take the whole axis as one
     lane-aligned block so the single-pass kernel applies; long axes take
     (512, 2048) — the largest blocks today's v5e compiler accepts with
     every mask variant, forward and backward — or, under a mask given as
-    data (``masked``), ``_MASKED_BLOCKS``; halved until the working sets
-    honour their VMEM budgets (large D / fp32 shapes)."""
+    data (``masked``), ``_MASKED_BLOCKS``, and where that mask is a causal
+    ``window`` blocks no wider than its band (``_WINDOW_MIN_BLOCK``);
+    halved until the working sets honour their VMEM budgets (large D /
+    fp32 shapes)."""
     itemsize = jnp.dtype(dtype).itemsize
     Dp = head_dim + (-head_dim) % 64
     if seq_k <= _SHORT_SEQ_MAX_TK:
@@ -154,6 +165,9 @@ def tune_attention_blocks(seq_q, seq_k, head_dim, dtype="bfloat16",
     block_q, block_k = 512, 2048
     if masked:
         block_q, block_k = _MASKED_BLOCKS
+        if window is not None:
+            band = max(_WINDOW_MIN_BLOCK, 1 << (int(window) - 1).bit_length())
+            block_q, block_k = min(block_q, band), min(block_k, band)
     while block_k > 512 and \
             not _blocks_fit(block_q, block_k, Dp, itemsize):
         block_k //= 2
@@ -188,7 +202,7 @@ def bshd_layout_fits(num_heads, head_dim):
 
 def attention_dispatch(seq_q, seq_k, head_dim, dtype="bfloat16",
                        on_tpu=None, census=True, bshd_heads=None,
-                       masked=False, tiles_visited=None):
+                       masked=False, tiles_visited=None, window=None):
     """Per-shape kernel choice for the public flash-attention ops.
 
     Returns ``{"kernel": "short_seq" | "streaming" | "dense_fallback",
@@ -217,8 +231,12 @@ def attention_dispatch(seq_q, seq_k, head_dim, dtype="bfloat16",
     unit the mask skips by; the plan says so (``"masked"``) and counts the
     forward's ``"tiles"`` a head row.  ``tiles_visited`` is the caller's
     count of those the mask leaves live, where the mask is there to be
-    read at trace time (an eager call; None under ``jit``): it goes into
-    the event, no further.
+    read at trace time (an eager call, or a mask made from shapes: a
+    window; None for a traced one): it goes into the event, no further.
+    ``window``: the masked call is ``flash_attention``'s causal window of
+    that many keys: the blocks follow its band
+    (``tune_attention_blocks``), the census counts it and the event names
+    it.
 
     ``census=False`` is the secondary-lookup spelling (the custom-vjp
     backward re-reading the forward's decision): same answer, but no
@@ -233,7 +251,7 @@ def attention_dispatch(seq_q, seq_k, head_dim, dtype="bfloat16",
         return {"kernel": "dense_fallback", "block_q": None,
                 "block_k": None, "layout": None, "heads_per_block": None}
     block_q, block_k = tune_attention_blocks(seq_q, seq_k, head_dim, dtype,
-                                             masked)
+                                             masked, window)
     kernel = "short_seq" if seq_k <= block_k else "streaming"
     per_block = 0 if bshd_heads is None else _bshd_heads_per_block(
         bshd_heads, head_dim, kernel == "short_seq")
@@ -250,14 +268,16 @@ def attention_dispatch(seq_q, seq_k, head_dim, dtype="bfloat16",
         telemetry.inc("attention.kernel.%s" % kernel)
         if masked:
             telemetry.inc("attention.kernel.masked")
+        if window is not None:
+            telemetry.inc("attention.kernel.window")
         telemetry.inc("attention.layout.%s" % layout)
         telemetry.event("attention_dispatch", kernel, seq_q=int(seq_q),
                         seq_k=int(seq_k), head_dim=int(head_dim),
                         dtype=str(dtype), block_q=block_q,
                         block_k=block_k, layout=layout,
                         heads_per_block=per_block,
-                        **(dict(plan_mask, tiles_visited=tiles_visited)
-                           if masked else {}))
+                        **(dict(plan_mask, tiles_visited=tiles_visited,
+                                window=window) if masked else {}))
     return dict({"kernel": kernel, "block_q": block_q, "block_k": block_k,
                  "layout": layout, "heads_per_block": per_block},
                 **plan_mask)
@@ -320,18 +340,25 @@ def _run_mask_specialized(pl, compute, run, qi, ki, block_q, block_k,
 
 
 def _visible(qm, km):
-    """The mask given as data, on one tile: ``qm`` (block_q, 2) holds a
-    query's [reach, own] and ``km`` (2, block_k) a key's [rank, own]; a
-    query sees a key whose rank is at most its reach, or whose ``own`` is
-    its own (the operands arrive with -1 and -2 for "none", which never
-    meet).  Integer compares, (block_q, block_k) bool."""
-    return (km[0:1, :] <= qm[:, 0:1]) | (km[1:2, :] == qm[:, 1:2])
+    """The mask given as data, on one tile: ``qm`` (block_q, 2 or 3) holds
+    a query's [reach, own(, floor)] and ``km`` (2, block_k) a key's [rank,
+    own]; a query sees a key whose rank is at most its reach — and, where
+    the queries carry a floor, at least that — or whose ``own`` is its own
+    (the operands arrive with -1 and -2 for "none", which never meet).
+    Integer compares, (block_q, block_k) bool."""
+    ranked = km[0:1, :] <= qm[:, 0:1]
+    if qm.shape[1] == 3:
+        ranked = ranked & (km[0:1, :] >= qm[:, 2:3])
+    return ranked | (km[1:2, :] == qm[:, 1:2])
 
 
 def _visible_T(qm, km):
     """``_visible`` for the backward's transposed score block:
-    ``qm`` (2, block_q), ``km`` (block_k, 2) -> (block_k, block_q)."""
-    return (km[:, 0:1] <= qm[0:1, :]) | (km[:, 1:2] == qm[1:2, :])
+    ``qm`` (2 or 3, block_q), ``km`` (block_k, 2) -> (block_k, block_q)."""
+    ranked = km[:, 0:1] <= qm[0:1, :]
+    if qm.shape[0] == 3:
+        ranked = ranked & (km[:, 0:1] >= qm[2:3, :])
+    return ranked | (km[:, 1:2] == qm[1:2, :])
 
 
 def _run_streamed_block(pl, compute, tile, qi, ki, block_q, block_k, causal,
@@ -690,13 +717,32 @@ def _check_mask_alone(q_mask, kv_mask, causal, kv_lens, q_segments):
             "and segments in the ranks, reaches and owns themselves")
 
 
+def window_mask(batch, seq_q, seq_k, window):
+    """A causal window as the mask's integers, from shapes alone (numpy,
+    so a traced program holds them as constants): query i sees the
+    ``window`` keys ``i - window < j <= i``, itself included.  Returns
+    ``q_mask`` (batch, seq_q, 3) [reach = i, own = none, floor = i -
+    window + 1] and ``kv_mask`` (batch, seq_k, 2) [rank = j, none]."""
+    import numpy as onp
+    pos_q, pos_k = onp.arange(seq_q), onp.arange(seq_k)
+    # graftlint: disable-next=trace-host-sync -- numpy on purpose: shapes
+    # and a static window in, constants of the traced program out
+    q_mask = onp.stack([pos_q, onp.full(seq_q, -1), pos_q - window + 1], -1)
+    kv_mask = onp.stack([pos_k, onp.full(seq_k, -1)], -1)
+    return tuple(onp.broadcast_to(m.astype("int32"), (batch,) + m.shape)
+                 for m in (q_mask, kv_mask))
+
+
 def _mask_operands(q_mask, kv_mask, Tqp, Tkp):
     """The mask given as data, as the kernels read it: ``q_mask``
-    (B, Tq, 2) [reach, own] and ``kv_mask`` (B, Tk, 2) [rank, own] ->
-    int32 (B, Tqp, 2) and (B, Tkp, 2).  A negative ``own`` (none) becomes
-    -1 on a query and -2 on a key, so that equality alone decides; a
-    padded query gets the reach no rank is under and a padded key the
-    rank no reach is over, so that padding needs no mask of its own."""
+    (B, Tq, 2) [reach, own] or (B, Tq, 3) [reach, own, floor] and
+    ``kv_mask`` (B, Tk, 2) [rank, own] -> int32 (B, Tqp, 2 or 3) and
+    (B, Tkp, 2).  A negative ``own`` (none) becomes -1 on a query and -2
+    on a key, so that equality alone decides; a padded query gets the
+    reach no rank is under (and the floor no rank is over) and a padded
+    key the rank no reach is over, so that padding needs no mask of its
+    own.  Two integers a query stay two: the kernels' code for them is
+    what it was."""
     qm, km = q_mask.astype(jnp.int32), kv_mask.astype(jnp.int32)
     reach = jnp.minimum(qm[..., 0], _RANK_NEVER - 1)
     q_own = jnp.where(qm[..., 1] < 0, -1, qm[..., 1])
@@ -705,8 +751,12 @@ def _mask_operands(q_mask, kv_mask, Tqp, Tkp):
 
     def padded(x, pad, value):
         return jnp.pad(x, ((0, 0), (0, pad)), constant_values=value)
-    return (jnp.stack([padded(reach, pad_q, _REACH_NONE),
-                       padded(q_own, pad_q, -1)], axis=-1),
+    q_cols = [padded(reach, pad_q, _REACH_NONE), padded(q_own, pad_q, -1)]
+    # graftlint: disable-next=retrace-shape-branch -- the mask's own width,
+    # two integers a query or three: a property of the call, by design
+    if qm.shape[-1] == 3:
+        q_cols.append(padded(qm[..., 2], pad_q, _RANK_NEVER))
+    return (jnp.stack(q_cols, axis=-1),
             jnp.stack([padded(km[..., 0], pad_k, _RANK_NEVER),
                        padded(k_own, pad_k, -2)], axis=-1))
 
@@ -715,8 +765,11 @@ def _tile_states(qm, km, block_q, block_k):
     """The per-tile summary of ``_mask_operands``' arrays, (B, n_q, n_k)
     int32, from each block's extremes and never from the pairs: 2 where
     every pair of the tile is live (the largest rank is under the
-    smallest reach), 0 where none can be (the smallest rank is over the
-    largest reach and the blocks' ranges of ``own`` do not meet), else 1.
+    smallest reach, and the smallest over the largest floor), 0 where none
+    can be (the smallest rank is over the largest reach, or the largest
+    rank of a key that has one is under the smallest floor — a tile under
+    a window's band is dead as one over the diagonal is — and the blocks'
+    ranges of ``own`` do not meet), else 1.
     A 0 is always true; a 1 may hold no live pair where the ``own``s of a
     block are not consecutive integers — such a tile is visited and
     masked to nothing."""
@@ -730,9 +783,19 @@ def _tile_states(qm, km, block_q, block_k):
                 jnp.where(some, own, -1).max(-1))
     (q_lo, q_hi), (k_lo, k_hi) = own_range(q_own), own_range(k_own)
     every = rank.max(-1)[:, None, :] <= reach.min(-1)[:, :, None]
-    some = (rank.min(-1)[:, None, :] <= reach.max(-1)[:, :, None]) | (
-        (q_lo[:, :, None] <= k_hi[:, None, :])
-        & (k_lo[:, None, :] <= q_hi[:, :, None]))
+    ranked = rank.min(-1)[:, None, :] <= reach.max(-1)[:, :, None]
+    # graftlint: disable-next=retrace-shape-branch -- the mask's own width
+    # (``_mask_operands``)
+    if qm.shape[-1] == 3:
+        floor = qm[..., 2].reshape(B, -1, block_q)
+        # a key that never ranks (padding) is over every reach already
+        ranked_hi = jnp.where(rank == _RANK_NEVER, _REACH_NONE, rank).max(-1)
+        every = every & (rank.min(-1)[:, None, :]
+                         >= floor.max(-1)[:, :, None])
+        ranked = ranked & (ranked_hi[:, None, :]
+                           >= floor.min(-1)[:, :, None])
+    some = ranked | ((q_lo[:, :, None] <= k_hi[:, None, :])
+                     & (k_lo[:, None, :] <= q_hi[:, :, None]))
     return jnp.where(every, 2, some.astype(jnp.int32))
 
 
@@ -756,16 +819,17 @@ def _resident_block(live):
     return jnp.where(last >= 0, last, first)
 
 
-def mask_tiles(q_mask, kv_mask, head_dim, dtype="bfloat16"):
+def mask_tiles(q_mask, kv_mask, head_dim, dtype="bfloat16", window=None):
     """``(visited, total)`` tiles of one masked ``flash_attention`` call
     a head row, forward and backward together (the forward's, ``flash_dq``'s
     and ``flash_dkv``'s grids), at the blocks ``attention_dispatch`` plans
     and from the summary the kernels skip by; float32 scalars.  (0, 0)
     where no streamed kernel would run (one K block, or no TPU: there is
-    nothing to skip)."""
+    nothing to skip).  ``window``: the mask is ``window_mask``'s, whose
+    calls plan their blocks by the band."""
     Tq, Tk = q_mask.shape[1], kv_mask.shape[1]
     plan = attention_dispatch(Tq, Tk, head_dim, dtype, census=False,
-                              masked=True)
+                              masked=True, window=window)
     if plan["kernel"] != "streaming":
         return jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32)
     block_q, block_k = plan["block_q"], plan["block_k"]
@@ -841,7 +905,8 @@ def pallas_flash_attention(q, k, v, causal=False, scale=None,
     if masked and single:
         extra += [qm, km]
         extra_specs += [
-            pl.BlockSpec((1, block_q, 2), lambda b, qi: (b // H, qi, 0)),
+            pl.BlockSpec((1, block_q, qm.shape[-1]),
+                         lambda b, qi: (b // H, qi, 0)),
             pl.BlockSpec((1, 2, block_k), lambda b, qi: (b // H, 0, 0)),
         ]
     if lens is not None:
@@ -916,7 +981,7 @@ def pallas_flash_attention(q, k, v, causal=False, scale=None,
                                  (kvb(b), kblk(b, qi, ki, *t), 0)),
                     pl.BlockSpec((1, block_k, Dp), lambda b, qi, ki, *t:
                                  (kvb(b), kblk(b, qi, ki, *t), 0)),
-                    pl.BlockSpec((1, block_q, 2),
+                    pl.BlockSpec((1, block_q, qm.shape[-1]),
                                  lambda b, qi, ki, *_: (b // H, qi, 0)),
                     pl.BlockSpec((1, 2, block_k), lambda b, qi, ki, *t:
                                  (b // H, 0, kblk(b, qi, ki, *t))),
@@ -1178,7 +1243,7 @@ def _bwd_core(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref, qseg_ref,
     heads' lanes zero (``_head_lanes``), and q, k, v, do come back
     whole.  ``delta_of_out``: ``dlt_ref`` is the forward's output block
     and δ is summed here (``_delta_row``), not read.  ``mask_refs``: the
-    (2, block_q) and (block_k, 2) blocks of a mask given as data."""
+    (2 or 3, block_q) and (block_k, 2) blocks of a mask given as data."""
     q = q_ref[...].reshape(block_q, q_ref.shape[-1])
     k = k_ref[...].reshape(block_k, k_ref.shape[-1])
     v = v_ref[...].reshape(block_k, v_ref.shape[-1])
@@ -1439,7 +1504,7 @@ def _masked_split_bwd(pl, pltpu, operands, masks, common, dims, interpret):
                              lambda b, qi, ki, *_: (b, 0, qi)),
                 pl.BlockSpec((1, 1, block_q),
                              lambda b, qi, ki, *_: (b, 0, qi)),
-                pl.BlockSpec((1, 2, block_q),
+                pl.BlockSpec((1, qm_rows.shape[1], block_q),
                              lambda b, qi, ki, *_: (b // H, 0, qi)),
                 pl.BlockSpec((1, block_k, 2), lambda b, qi, ki, *t:
                              (b // H, kblk(b, qi, ki, *t), 0)),
@@ -1485,8 +1550,9 @@ def _masked_split_bwd(pl, pltpu, operands, masks, common, dims, interpret):
                 pl.BlockSpec((1, block_q, Dp), rows(lambda r, i: (r, i, 0))),
                 pl.BlockSpec((1, 1, block_q), rows(lambda r, i: (r, 0, i))),
                 pl.BlockSpec((1, 1, block_q), rows(lambda r, i: (r, 0, i))),
-                pl.BlockSpec((1, 2, block_q), lambda b, ki, j, *t: (
-                    b // Hkv, 0, step(b, ki, j, *t) % n_q)),
+                pl.BlockSpec((1, qm_rows.shape[1], block_q),
+                             lambda b, ki, j, *t: (
+                                 b // Hkv, 0, step(b, ki, j, *t) % n_q)),
                 pl.BlockSpec((1, block_k, 2),
                              lambda b, ki, j, *_: (b // Hkv, ki, 0)),
             ],
@@ -1606,7 +1672,8 @@ def pallas_flash_attention_bwd(q, k, v, out, lse, do, causal=False,
             if masked:
                 fused_extra += [qm_rows, km]
                 fused_especs += [
-                    pl.BlockSpec((1, 2, block_q), lambda b: (b // H, 0, 0)),
+                    pl.BlockSpec((1, qm_rows.shape[1], block_q),
+                                 lambda b: (b // H, 0, 0)),
                     pl.BlockSpec((1, block_k, 2), lambda b: (b // H, 0, 0)),
                 ]
             dq, dk, dv = pl.pallas_call(
@@ -1647,7 +1714,8 @@ def pallas_flash_attention_bwd(q, k, v, out, lse, do, causal=False,
         if masked:
             fused_extra += [qm_rows, km]
             fused_especs += [
-                pl.BlockSpec((1, 2, block_q), lambda b, qi: (b // H, 0, qi)),
+                pl.BlockSpec((1, qm_rows.shape[1], block_q),
+                             lambda b, qi: (b // H, 0, qi)),
                 pl.BlockSpec((1, block_k, 2), lambda b, qi: (b // H, 0, 0)),
             ]
         dq, dk, dv = pl.pallas_call(
@@ -1985,10 +2053,10 @@ def _int_zero_cotangent(x):
     return onp.zeros(x.shape, jax.dtypes.float0)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 10))
 def flash_attention(q, k, v, causal=False, scale=None, kv_lens=None,
                     q_segments=None, kv_segments=None, q_mask=None,
-                    kv_mask=None):
+                    kv_mask=None, window=None):
     """Fused attention: Pallas kernels on TPU, jnp blockwise elsewhere.
 
     softmax(q·kᵀ·scale [+ masks])·v over (B, H, T, D) inputs; ``k`` and
@@ -2003,15 +2071,25 @@ def flash_attention(q, k, v, causal=False, scale=None, kv_lens=None,
     Or a mask given as data, in place of the three: ``q_mask`` (B, Tq, 2)
     holds each query's [reach, own] and ``kv_mask`` (B, Tk, 2) each key's
     [rank, own], integers; query i sees key j iff ``rank[j] <= reach[i]``
-    or ``own[j] == own[i] >= 0`` (a negative ``own`` is none).  Causal
+    or ``own[j] == own[i] >= 0`` (a negative ``own`` is none).  A third
+    integer a query, ``q_mask`` (B, Tq, 3) [reach, own, floor], bounds the
+    ranks from below as well: ``floor[i] <= rank[j] <= reach[i]`` (absent:
+    no floor).  Causal
     order is rank = reach = position; packed documents are ``own``s;
     block diffusion's row of clean and noised halves is both (a clean
     key's rank its block, a noised key's ``2**31 - 1`` — never —, a
     noised query's reach the block before its own, and the noised
     tokens' ``own`` their block).  On the chip the kernels visit only the
-    tiles that hold a live pair (``pallas_flash_attention``)."""
+    tiles that hold a live pair (``pallas_flash_attention``).
+
+    ``window`` (static, with ``causal`` and nothing else): query i sees
+    the ``window`` keys ``i - window < j <= i``.  It is that mask, made
+    from the shapes (``window_mask``: floor = i - window + 1), so the
+    tiles under the band are skipped as those over the diagonal are; a
+    window that covers the row is plain causal attention and runs as
+    such."""
     return _flash_fwd(q, k, v, causal, scale, kv_lens, q_segments,
-                      kv_segments, q_mask, kv_mask)[0]
+                      kv_segments, q_mask, kv_mask, window)[0]
 
 
 def _reference_attention(q, k, v, causal, scale, kv_lens=None,
@@ -2039,12 +2117,19 @@ def _reference_attention(q, k, v, causal, scale, kv_lens=None,
     if q_segments is not None:
         mask = mask & (q_segments[:, None, :, None]
                        == kv_segments[:, None, None, :])
+    # graftlint: disable-next=trace-tracer-branch -- causal is the op's
+    # static (nondiff) argument
     if causal:
         mask = mask & (jnp.arange(Tq)[:, None] >= jnp.arange(Tk)[None, :])
     if q_mask is not None:
         reach, q_own = q_mask[:, None, :, None, 0], q_mask[:, None, :, None, 1]
         rank, k_own = kv_mask[:, None, None, :, 0], kv_mask[:, None, None, :, 1]
-        mask = mask & ((rank <= reach) | ((k_own == q_own) & (q_own >= 0)))
+        ranked = rank <= reach
+        # graftlint: disable-next=retrace-shape-branch -- the mask's own
+        # width (``_mask_operands``)
+        if q_mask.shape[-1] == 3:
+            ranked = ranked & (rank >= q_mask[:, None, :, None, 2])
+        mask = mask & (ranked | ((k_own == q_own) & (q_own >= 0)))
     s = jnp.where(mask, s, _NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     # fully-masked rows: uniform softmax garbage -> force exact zeros,
@@ -2054,31 +2139,62 @@ def _reference_attention(q, k, v, causal, scale, kv_lens=None,
     return jnp.einsum("bhqk,bhkd->bhqd", p, v)
 
 
-def _tiles_visited(q, k, q_mask, kv_mask, on_tpu):
+def _tiles_visited(q, k, q_mask, kv_mask, on_tpu, window=None):
     """The forward's tiles a head row that a mask given as data leaves
     live, at the planned blocks, where the mask is there to be read — an
-    eager call on a TPU with the K axis streamed; None otherwise."""
+    eager call, or a window's integers (numpy, from the shapes), on a TPU
+    with the K axis streamed; None otherwise."""
     if isinstance(q_mask, jax.core.Tracer) \
             or isinstance(kv_mask, jax.core.Tracer):
         return None
     plan = attention_dispatch(q.shape[2], k.shape[2], q.shape[3], q.dtype,
-                              on_tpu=on_tpu, census=False, masked=True)
+                              on_tpu=on_tpu, census=False, masked=True,
+                              window=window)
     if plan["kernel"] != "streaming":
         return None
-    states = _states_at(q_mask, kv_mask, plan["block_q"], plan["block_k"])
-    # graftlint: disable-next=trace-host-sync -- eager calls only: a traced
-    # mask returned above
-    return int(jnp.sum(states > 0))
+    # concrete operands — an eager call's, or a window's, made from the
+    # shapes — are summed now, inside a traced program too
+    with jax.ensure_compile_time_eval():
+        states = _states_at(jnp.asarray(q_mask), jnp.asarray(kv_mask),
+                            plan["block_q"], plan["block_k"])
+        # graftlint: disable-next=trace-host-sync -- a traced mask
+        # returned above
+        return int(jnp.sum(states > 0))
+
+
+def _windowed(q, k, causal, window, q_mask, kv_mask, *others):
+    """``flash_attention``'s ``window`` as the masked call it is: returns
+    ``(causal, q_mask, kv_mask, window)`` with the mask made from the
+    shapes (numpy) where the window is narrower than the row, and plain
+    causal attention (window None) where it covers it."""
+    if window is None:
+        return causal, q_mask, kv_mask, None
+    # graftlint: disable-next=trace-tracer-branch -- causal and window are
+    # the op's static (nondiff) arguments: Python values at trace time
+    if not causal or window < 1 or q_mask is not None or any(
+            o is not None for o in others):
+        raise ValueError("window=%r goes with causal=True and no other "
+                         "mask, and holds at least the query's own key"
+                         % (window,))
+    # graftlint: disable-next=* -- a static window against the row's
+    # length: which kernels run is a property of the shape, by design
+    if window >= k.shape[2]:
+        return True, None, None, None
+    return (False,) + window_mask(q.shape[0], q.shape[2], k.shape[2],
+                                  window) + (window,)
 
 
 def _flash_fwd(q, k, v, causal, scale, kv_lens, q_segments, kv_segments,
-               q_mask=None, kv_mask=None):
+               q_mask=None, kv_mask=None, window=None):
+    given = (q_mask, kv_mask)
+    causal, q_mask, kv_mask, window = _windowed(
+        q, k, causal, window, q_mask, kv_mask, kv_lens, q_segments)
     on_tpu = _context.on_tpu(q)
     plan = attention_dispatch(
         q.shape[2], k.shape[2], q.shape[3], q.dtype, on_tpu=on_tpu,
-        masked=q_mask is not None,
+        masked=q_mask is not None, window=window,
         tiles_visited=None if q_mask is None else _tiles_visited(
-            q, k, q_mask, kv_mask, on_tpu))
+            q, k, q_mask, kv_mask, on_tpu, window))
     # graftlint: disable-next=trace-tracer-branch -- the plan is Python
     # values: the dispatcher reads shapes, a dtype and a host-side count
     if plan["kernel"] != "dense_fallback":
@@ -2089,24 +2205,28 @@ def _flash_fwd(q, k, v, causal, scale, kv_lens, q_segments, kv_segments,
                 kv_lens=kl, q_segments=qs, kv_segments=ks, q_mask=qm,
                 kv_mask=km),
             (q, k, v, kv_lens, q_segments, kv_segments, q_mask, kv_mask))
-        return out, (q, k, v, out, lse, kv_lens, q_segments, kv_segments,
-                     q_mask, kv_mask)
+        return out, (q, k, v, out, lse, kv_lens, q_segments, kv_segments)\
+            + given
     _check_mask_alone(q_mask, kv_mask, causal, kv_lens, q_segments)
     out = _reference_attention(q, k, v, causal, scale, kv_lens, q_segments,
                                kv_segments, q_mask, kv_mask)
-    return out, (q, k, v, None, None, kv_lens, q_segments, kv_segments,
-                 q_mask, kv_mask)
+    return out, (q, k, v, None, None, kv_lens, q_segments, kv_segments) \
+        + given
 
 
-def _flash_bwd(causal, scale, res, g):
-    q, k, v, out, lse, kv_lens, q_segments, kv_segments, q_mask, kv_mask = res
+def _flash_bwd(causal, scale, window, res, g):
+    q, k, v, out, lse, kv_lens, q_segments, kv_segments = res[:8]
+    # the residuals hold the CALLER's mask (None under a window, which is
+    # made from the shapes again): the cotangents below are theirs
+    given = res[8:]
+    causal, q_mask, kv_mask, window = _windowed(q, k, causal, window, *given)
     if lse is not None:
         # re-consult the dispatcher (trace-time, deterministic) for the
         # forward's blocks: custom_vjp residuals cannot carry static
         # ints.  census=False: the shape was counted at the forward trace
         plan = attention_dispatch(q.shape[2], k.shape[2], q.shape[3],
                                   q.dtype, census=False,
-                                  masked=q_mask is not None)
+                                  masked=q_mask is not None, window=window)
         dq, dk, dv = per_batch_shard(
             lambda q, k, v, out, lse, g, kl, qs, ks, qm, km:
             pallas_flash_attention_bwd(
@@ -2125,7 +2245,7 @@ def _flash_bwd(causal, scale, res, g):
             q, k, v)
         dq, dk, dv = vjp(g)
     return (dq, dk, dv) + tuple(_int_zero_cotangent(m) for m in (
-        kv_lens, q_segments, kv_segments, q_mask, kv_mask))
+        kv_lens, q_segments, kv_segments) + given)
 
 
 flash_attention.defvjp(_flash_fwd, _flash_bwd)
@@ -2135,22 +2255,23 @@ flash_attention.defvjp(_flash_fwd, _flash_bwd)
 def _flash_attention_op(queries, keys, values, causal: bool = False,
                         scale: Optional[float] = None, kv_lens=None,
                         q_segments=None, kv_segments=None, q_mask=None,
-                        kv_mask=None):
+                        kv_mask=None, window: Optional[int] = None):
     """Fused multi-head attention op (TPU-native counterpart of the
     reference's ``_contrib_interleaved_matmul_selfatt_*`` pipeline,
     src/operator/contrib/transformer.cc).  The mask operands follow
     causal/scale so pre-mask positional callers keep working."""
     return flash_attention(queries, keys, values, causal, scale, kv_lens,
-                           q_segments, kv_segments, q_mask, kv_mask)
+                           q_segments, kv_segments, q_mask, kv_mask, window)
 
 
 @register("_contrib_attention_mask_tiles", num_outputs=2,
           differentiable=False, aliases=("attention_mask_tiles",))
 def _attention_mask_tiles_op(q_mask, kv_mask, head_dim: int = 128,
-                             dtype: str = "bfloat16"):
+                             dtype: str = "bfloat16",
+                             window: Optional[int] = None):
     """``mask_tiles``: the tiles a masked ``flash_attention`` call visits
     and has, a head row, forward and backward."""
-    return mask_tiles(q_mask, kv_mask, head_dim, dtype)
+    return mask_tiles(q_mask, kv_mask, head_dim, dtype, window)
 
 
 # --- BSHD (batch, seq, heads, head_dim) entry: no layout transposes ----

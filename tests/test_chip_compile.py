@@ -246,13 +246,19 @@ _MASKED = [
     # one K block, q streamed, and one block in all: the mask on every tile
     ("masked_s1024_d128_gqa8", 1, 32, 4, 1024, 128, "bfloat16"),
     ("masked_s256_d64", 2, 4, 4, 256, 64, "bfloat16"),
+    # three integers a query (a floor under the ranks): a sliding layer's
+    # call of the Laguna cell, 72 query on 8 key-value heads; and one K
+    # block with the floor, 48 on 8
+    ("masked_s4096_d128_gqa9_floor", 1, 72, 8, 4096, 128, "bfloat16", 3),
+    ("masked_s1024_d128_gqa6_floor", 1, 48, 8, 1024, 128, "bfloat16", 3),
 ]
 
 
 def _masked_programs(case):
     """Forward and backward under ``q_mask`` / ``kv_mask`` at the blocks
     ``attention_dispatch`` plans for a masked call."""
-    _, B, H, Hkv, S, D, dtype = case
+    _, B, H, Hkv, S, D, dtype = case[:7]
+    q_width = case[7] if len(case) > 7 else 2
     dtype = jnp.dtype(dtype)
 
     def programs(one_chip):
@@ -264,7 +270,8 @@ def _masked_programs(case):
             return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
 
         q, kv = sds((B, H, S, D)), sds((B, Hkv, S, D))
-        mask = sds((B, S, 2), jnp.int32)
+        mask, q_mask = sds((B, S, 2), jnp.int32), sds((B, S, q_width),
+                                                      jnp.int32)
 
         def fwd(q, k, v, qm, km):
             return PA.pallas_flash_attention(
@@ -274,8 +281,8 @@ def _masked_programs(case):
             return PA.pallas_flash_attention_bwd(
                 q, k, v, out, lse, do, q_mask=qm, kv_mask=km, **blocks)
 
-        return [(fwd, (q, kv, kv, mask, mask)),
-                (bwd, (q, kv, kv, q, sds((B, H, S), jnp.float32), q, mask,
+        return [(fwd, (q, kv, kv, q_mask, mask)),
+                (bwd, (q, kv, kv, q, sds((B, H, S), jnp.float32), q, q_mask,
                        mask))]
     return programs
 
@@ -325,6 +332,11 @@ _KERNELS.update({
                               ["flash_masked_dkv", "flash_masked_dq"]),
     "masked_s1024_d128_gqa8": (["flash_short_fwd"], ["flash_dqkv_fused"]),
     "masked_s256_d64": (["flash_short_fwd"], ["flash_dqkv_single"]),
+    # the floor rides in the same kernels: no fourth set
+    "masked_s4096_d128_gqa9_floor": (["flash_masked_fwd"],
+                                     ["flash_masked_dkv", "flash_masked_dq"]),
+    "masked_s1024_d128_gqa6_floor": (["flash_short_fwd"],
+                                     ["flash_dqkv_fused"]),
 })
 
 
@@ -959,3 +971,88 @@ def test_sdar_layer_train_step_compiles_to_the_masked_kernels(one_chip,
     print("temporaries of the SDAR layer's step: %.3f GB" % temp)
     assert ".remat" not in text
     assert temp <= 2.081 * 1.05, temp
+
+
+# layer kind -> (the three lists' entry, the step's kernels by name, the
+# ceiling on its temporaries in GB: 1.05 x the sandbox compile's)
+_LAGUNA_LAYERS = {
+    "sliding_sparse": (
+        ("sliding_attention", 72, "sparse"),
+        {"flash_masked_fwd": 1, "flash_masked_dq": 1, "flash_masked_dkv": 1,
+         "moe_blocks_fwd": 6, "moe_blocks_dx": 3, "moe_blocks_dw": 3},
+        1.847 * 1.05),
+    "full_dense": (
+        ("full_attention", 48, "dense"),
+        {"flash_stream_fwd": 1, "flash_dq": 1, "flash_dkv": 1},
+        0.779 * 1.05),
+}
+
+
+@pytest.mark.parametrize("kind", list(_LAGUNA_LAYERS))
+def test_laguna_layer_train_step_compiles_to_the_kernels_expected(
+        one_chip, monkeypatch, kind):
+    """One sliding sparse layer (72 query on 8 key-value heads of 128, a
+    gate a head, the default rotary, a window of 512; 8 of 256 gated-SiLU
+    experts of width 1024 held, 10 routes a token, the shared expert) and
+    one full dense layer (48 on 8, YaRN on 64 of 128 dimensions, the gated
+    block of 12,288) of the Laguna cell at the published widths under a
+    small untied head, one row of 4,096 tokens, bf16 with
+    ``Adam(multi_precision=True)``: the ``DataParallelStep`` program
+    compiles for the described chip; the window layer's attention is
+    EXACTLY the three masked kernels — no (4096, 4096) score or mask
+    array outside them —, the full layer's the three causal streamed
+    ones; the experts are the block products' kernels with the compiler's
+    ragged products on the ``conditional``'s other side; the blocks'
+    names are in the instructions' ``op_name``s."""
+    import mxnet_tpu as mx
+    from mxnet_tpu import context, gluon, parallel
+    from mxnet_tpu import random as mx_random
+
+    monkeypatch.setattr(context, "on_tpu", lambda *a: True)
+    (layer_type, heads, ffn), kernels, temp_gb = _LAGUNA_LAYERS[kind]
+    net = gluon.model_zoo.laguna(
+        num_layers=1, vocab_size=2048, experts_held=(0, 8),
+        layer_types=[layer_type], heads_per_layer=[heads],
+        mlp_layer_types=[ffn])
+    net.initialize(mx.init.Zero())
+    net.cast("bfloat16")
+    step = parallel.DataParallelStep(
+        net, gluon.loss.TiedSoftmaxCrossEntropyLoss(block_rows=2048),
+        mx.optimizer.Adam(learning_rate=1e-4, multi_precision=True))
+
+    def spec(v):
+        return jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=one_chip)
+
+    state = jax.tree_util.tree_map(
+        spec, [[p._data._data for p in step._params], step._opt_states])
+    carries = [jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
+               jax.ShapeDtypeStruct((len(step._trainable),), jnp.float32,
+                                    sharding=one_chip),
+               spec(mx_random.next_key())]
+    tokens = jax.ShapeDtypeStruct((1, 4096), jnp.int32, sharding=one_chip)
+    compiled = step._build().lower(*state, *carries, tokens,
+                                   tokens).compile()
+    text = compiled.as_text()
+    names = collections.Counter(_kernel_names(text))
+    ragged = {n: c for n, c in names.items() if n.startswith("ragged-dot")}
+    assert {n: c for n, c in names.items() if n not in ragged} == kernels
+    assert bool(ragged) == (ffn == "sparse")
+    assert not re.findall(r"\w+\[(?:\d+,)*4096,4096\]", text)
+    blocks = ["layer0_attn_qkv", "layer0_attn_gate", "layer0_attn_out"]
+    if ffn == "sparse":
+        blocks += ["layer0_attn/flash_masked_fwd",
+                   "layer0_attn/flash_masked_dq",
+                   "layer0_attn/flash_masked_dkv", "layer0_router",
+                   "layer0_experts", "layer0_shared_gate", "mask"]
+        assert _owners_copies(text, 16, 8, 3072, 1024) == ([], [])
+        assert _float32_handed_out(text, 8, 3072, 1024) == []
+    else:
+        blocks += ["layer0_attn/flash_stream_fwd", "layer0_attn/flash_dq",
+                   "layer0_attn/flash_dkv", "layer0_ffn_gate",
+                   "layer0_ffn_down"]
+    for block in blocks:
+        assert re.search(r"[/_]%s/" % re.escape(block), text), block
+    assert ".remat" not in text
+    temp = compiled.memory_analysis().temp_size_in_bytes / 1e9
+    print("temporaries of the Laguna %s layer's step: %.3f GB" % (kind, temp))
+    assert temp <= temp_gb, temp

@@ -685,3 +685,172 @@ def test_dispatch_plans_the_mask():
              if e.get("kind") == "attention_dispatch"][-1]
     assert (event["masked"], event["tiles"], event["tiles_visited"]) \
         == (True, 64, 80)
+
+
+# ---------------------------------------------------------------------------
+# a third integer a query: the floor under the ranks (a window's lower bound)
+# ---------------------------------------------------------------------------
+
+def _band_dense(q, k, v, window):
+    """Plain float32 attention under the dense band ``i - window < j <=
+    i``, GQA by repetition."""
+    group = q.shape[1] // k.shape[1]
+    k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
+    i = jnp.arange(q.shape[2])[:, None]
+    j = jnp.arange(k.shape[2])[None, :]
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) * q.shape[-1] ** -0.5
+    p = jax.nn.softmax(jnp.where((j <= i) & (j > i - window), s, -1e30), -1)
+    return jnp.einsum("bhqk,bhkd->bhqd", p, v)
+
+
+# (block_q, block_k) as the two-integer test's; 6 and 9 query heads a
+# key-value head (two key-value heads: rows of the merged layouts share
+# theirs through the index maps)
+@pytest.mark.parametrize("group", [6, 9])
+@pytest.mark.parametrize("blocks", [(64, 64), (64, 128), (128, 64),
+                                    (64, 512), (512, 512)])
+def test_window_kernels_match_dense_band_fwd_and_three_gradients(blocks,
+                                                                 group):
+    """``floor <= rank <= reach`` in every BHSD kernel: a window of 70 keys
+    — no multiple of any block — on a ragged row of 300; at 64 x 64 the
+    tiles under the band are dead as those over the diagonal are."""
+    s, window = 300, 70
+    q_mask, kv_mask = (jnp.asarray(m)
+                       for m in P.window_mask(1, s, s, window))
+    q, g = _rand((1, 2 * group, s, 64), 0), _rand((1, 2 * group, s, 64), 3)
+    k, v = _rand((1, 2, s, 64), 1), _rand((1, 2, s, 64), 2)
+    bq, bk = blocks
+    out, lse = P.pallas_flash_attention(
+        q, k, v, block_q=bq, block_k=bk, interpret=True, return_lse=True,
+        q_mask=q_mask, kv_mask=kv_mask)
+    want, vjp = jax.vjp(lambda q, k, v: _band_dense(q, k, v, window),
+                        q, k, v)
+    assert float(jnp.abs(out - want).max()) < 2e-5
+    ref = P._reference_attention(q, k, v, False, None, q_mask=q_mask,
+                                 kv_mask=kv_mask)
+    assert float(jnp.abs(ref - want).max()) < 2e-5
+    grads = P.pallas_flash_attention_bwd(
+        q, k, v, out, lse, g, block_q=bq, block_k=bk, interpret=True,
+        q_mask=q_mask, kv_mask=kv_mask)
+    for got, exp, name in zip(grads, vjp(g), "qkv"):
+        assert got.shape == exp.shape
+        assert float(jnp.abs(got - exp).max()) < 5e-5, name
+
+
+def test_tile_summary_marks_exactly_the_tiles_off_the_band_as_dead():
+    """``_tile_states`` against the pairs themselves, a ragged row padded
+    to whole blocks: 0 exactly where a tile holds no live pair — over the
+    diagonal AND under the band — 2 exactly where all are live."""
+    s, padded, window = 300, 320, 70
+    q_mask, kv_mask = (jnp.asarray(m)
+                       for m in P.window_mask(1, s, s, window))
+    qm, km = P._mask_operands(q_mask, kv_mask, padded, padded)
+    assert qm.shape == (1, padded, 3) and km.shape == (1, padded, 2)
+    i, j = onp.arange(padded)[:, None], onp.arange(padded)[None, :]
+    seen = (j <= i) & (j > i - window) & (i < s) & (j < s)
+    for bq, bk in ((64, 64), (32, 64), (64, 32), (160, 80), (16, 16)):
+        states = onp.asarray(P._tile_states(qm, km, bq, bk))[0]
+        tiles = seen.reshape(padded // bq, bq, padded // bk, bk)
+        onp.testing.assert_array_equal(states > 0, tiles.any((1, 3)))
+        onp.testing.assert_array_equal(states == 2, tiles.all((1, 3)))
+    # 16 x 16: a band of 70 keys leaves whole tiles inside it, dead ones
+    # under it, dead ones over the diagonal
+    states = onp.asarray(P._tile_states(qm, km, 16, 16))[0]
+    assert (states == 2).any() and (onp.tril(states == 0, -1)).any() \
+        and (onp.triu(states == 0, 1)).any()
+
+
+def test_two_integers_a_query_mean_and_cost_what_they_did():
+    """A mask of two integers a query stays two in the kernels' operands
+    (the block-diffusion cell's code is what it was), and is bit for bit
+    the three-integer mask whose floor is under every rank."""
+    q_mask, kv_mask = _block_diffusion_operands(96, 4, drop=3)
+    s = q_mask.shape[1]
+    qm, km = P._mask_operands(q_mask, kv_mask, 256, 256)
+    assert qm.shape == (1, 256, 2) and km.shape == (1, 256, 2)
+    floored = jnp.concatenate(
+        [q_mask, jnp.full(q_mask.shape[:2] + (1,), -2 ** 31, jnp.int32)], -1)
+    onp.testing.assert_array_equal(
+        onp.asarray(P._tile_states(qm, km, 64, 64)),
+        onp.asarray(P._tile_states(
+            *P._mask_operands(floored, kv_mask, 256, 256), 64, 64)))
+    q, g = _rand((1, 4, s, 64), 0), _rand((1, 4, s, 64), 3)
+    k, v = _rand((1, 1, s, 64), 1), _rand((1, 1, s, 64), 2)
+    results = []
+    for mask in (q_mask, floored):
+        out, lse = P.pallas_flash_attention(
+            q, k, v, block_q=64, block_k=64, interpret=True,
+            return_lse=True, q_mask=mask, kv_mask=kv_mask)
+        results.append((out, lse) + tuple(P.pallas_flash_attention_bwd(
+            q, k, v, out, lse, g, block_q=64, block_k=64, interpret=True,
+            q_mask=mask, kv_mask=kv_mask)))
+    for two, three in zip(*results):
+        onp.testing.assert_array_equal(onp.asarray(two), onp.asarray(three))
+
+
+def test_window_that_covers_the_row_is_causal_attention():
+    """``window >= S``: the integers give the causal kernels' result, and
+    the public op does not build them at all."""
+    s = 256
+    q, k, v = (_rand((1, 2, s, 64), 5 + i) for i in range(3))
+    causal = P.pallas_flash_attention(q, k, v, causal=True, block_q=64,
+                                      block_k=64, interpret=True)
+    for window in (s, s + 100):
+        q_mask, kv_mask = (jnp.asarray(m)
+                           for m in P.window_mask(1, s, s, window))
+        got = P.pallas_flash_attention(
+            q, k, v, block_q=64, block_k=64, interpret=True, q_mask=q_mask,
+            kv_mask=kv_mask)
+        assert float(jnp.abs(got - causal).max()) < 2e-6
+        onp.testing.assert_array_equal(
+            onp.asarray(P.flash_attention(q, k, v, True, None, None, None,
+                                          None, None, None, window)),
+            onp.asarray(P.flash_attention(q, k, v, True)))
+    with pytest.raises(ValueError, match="window=0 goes with"):
+        P.flash_attention(q, k, v, True, None, None, None, None, None, None,
+                          0)
+
+
+def test_flash_attention_op_takes_a_window_and_differentiates():
+    """The public op off the chip (the dense path) under ``window``, as
+    ``F.flash_attention`` and under ``jax.grad`` inside ``jit``."""
+    window = 9
+    q = _rand((1, 6, 48, 16), 0)
+    k, v = _rand((1, 1, 48, 16), 1), _rand((1, 1, 48, 16), 2)
+    want, vjp = jax.vjp(lambda q, k, v: _band_dense(q, k, v, window),
+                        q, k, v)
+    got = mx.nd.flash_attention(mx.nd.array(q), mx.nd.array(k),
+                                mx.nd.array(v), causal=True,
+                                window=window).asnumpy()
+    assert onp.abs(got - onp.asarray(want)).max() < 2e-5
+    g = _rand(want.shape, 3)
+    grads = jax.jit(jax.grad(lambda q, k, v: jnp.sum(P.flash_attention(
+        q, k, v, True, None, None, None, None, None, None, window) * g),
+        argnums=(0, 1, 2)))(q, k, v)
+    for got, exp in zip(grads, vjp(g)):
+        assert float(jnp.abs(got - exp).max()) < 5e-5
+
+
+def test_dispatch_counts_a_window():
+    from mxnet_tpu import telemetry
+    before = telemetry.snapshot()["counters"].get(
+        "attention.kernel.window", 0)
+    plan = P.attention_dispatch(4096, 4096, 128, on_tpu=True, masked=True,
+                                window=512, tiles_visited=7)
+    assert plan["masked"] and plan["kernel"] == "streaming"
+    # blocks no wider than the band, 512 at least, 1024 at most
+    assert (plan["block_q"], plan["block_k"], plan["tiles"]) == (512, 512, 64)
+    for window, side in ((100, 512), (513, 1024), (3000, 1024)):
+        wide = P.attention_dispatch(4096, 4096, 128, on_tpu=True,
+                                    census=False, masked=True, window=window)
+        assert (wide["block_q"], wide["block_k"]) == (side, side), window
+    assert telemetry.snapshot()["counters"]["attention.kernel.window"] \
+        == before + 1
+    event = [e for e in telemetry.snapshot()["events"]
+             if e.get("kind") == "attention_dispatch"][-1]
+    assert (event["window"], event["tiles_visited"]) == (512, 7)
+    # a masked call that is no window says so
+    P.attention_dispatch(8192, 8192, 128, on_tpu=True, masked=True)
+    event = [e for e in telemetry.snapshot()["events"]
+             if e.get("kind") == "attention_dispatch"][-1]
+    assert event["window"] is None and event["masked"] is True

@@ -452,6 +452,15 @@ def test_every_pallas_call_passes_a_stable_name():
     assert not bad, bad
 
 
+def test_a_window_adds_no_kernel_site():
+    """The window's lower bound (PR 37) rides in the masked family as a
+    third integer a query: 22 sites as before it, three of them masked."""
+    names = [name for _, name in _pallas_call_names()]
+    assert len(names) == 22
+    assert sorted(n for n in names if n.startswith("flash_masked")) == [
+        "flash_masked_dkv", "flash_masked_dq", "flash_masked_fwd"]
+
+
 def test_no_two_pallas_call_sites_share_a_name():
     counts = collections.Counter(name for _, name in _pallas_call_names())
     assert [n for n, c in counts.items() if c > 1] == []
