@@ -7,6 +7,17 @@ PR may edit ``chip_smoke.py``, none may move the yardstick.
 """
 import numpy as onp
 
+# The block kinds of the step's device time (``reduce_trace.kind_of``): a
+# pattern is searched in each ``Block.name`` scope of an operation's
+# ``op_name``, the outermost match decides.  A post-norm goes with the
+# block whose output it normalises.
+BLOCKS = {
+    "attention": [r"_layer\d+_attn(_ln)?$"],
+    "ffn": [r"_layer\d+_ffn(_ln)?$"],
+    "head": [r"_(word|pos|type)_embed$", r"_embed_ln$",
+             r"_decoder_(fc|ln|out)$", r"^mlmloss\d+$"],
+}
+
 
 def _net(sizes):
     from mxnet_tpu.gluon.model_zoo import bert_base, bert_small

@@ -51,6 +51,23 @@ CLEAR_DISAGREEMENT = 0.005   # of the clearly routed tokens
 
 FULL = "full_attention"
 
+# The block kinds of the step's device time (``reduce_trace.kind_of``): a
+# pattern is searched in each ``Block.name`` scope of an operation's
+# ``op_name``, the outermost match decides.  A pre-norm goes with the block
+# it feeds; a sparse layer's feed-forward norm with the router, which reads
+# it first.  Layer 0 is the one dense layer of the cut (``mlp_layer_types``).
+# The compiler's own ``ragged-dot-*`` kernels (``lax.ragged_dot`` in
+# ``ops.moe.grouped_matmul``, which only the experts call) carry no scope
+# and are matched by their name.
+BLOCKS = {
+    "attention": [r"_layer\d+_attn(_norm)?$"],
+    "experts": [r"_layer\d+_(experts|router)$", r"_layer[1-9]\d*_ffn_norm$",
+                r"^ragged-dot-"],
+    "ffn": [r"_layer0_ffn(_norm)?$", r"_layer\d+_shared$"],
+    "head": [r"_embed$", r"_final_norm$",
+             r"^tiedsoftmaxcrossentropyloss\d+$"],
+}
+
 
 def _rope(sizes, kind):
     """A layer kind's rotary embedding (``rope_parameters[kind]``) as
@@ -537,6 +554,31 @@ def attention_flops(sizes):
         return 0
     return sum(18 * sizes["head_dim"] * live_pairs(sizes, kind) * heads
                for kind, heads, _ in _layers(sizes) if kind != FULL)
+
+
+def causal_attention_flops(sizes):
+    """The floating-point operations the three plain causal flash kernels
+    (``flash_stream_fwd``, ``flash_dq``, ``flash_dkv``) execute ON LIVE
+    PAIRS in one step of one row: the full layers' calls, all their query
+    heads, ``S (S + 1) / 2`` pairs a head, ``18 D`` a pair as in
+    ``attention_flops``.  The tiles' pairs above the diagonal are not
+    counted, so a share of the peak made from this cannot pass 100%.  The
+    sliding layers run the masked kernels and are left out — unless the
+    window covers the row, when they run these kernels too."""
+    covers = sizes["sliding_window"] >= sizes["seq_len"]
+    return sum(18 * sizes["head_dim"] * live_pairs(sizes, FULL) * heads
+               for kind, heads, _ in _layers(sizes) if kind == FULL or covers)
+
+
+def moe_product_flops(sizes, routes):
+    """The floating-point operations of the routed experts' products for
+    ``routes`` routes to held experts (a step's, every layer's): ``2 D F``
+    for each of the three products of a gated expert (gate, up, down),
+    times 3 — forward, input gradient, weight gradient.  Left out: the
+    recomputing backward's second forward, the slots a block holds beyond
+    the routes, the shared expert, the router, the gathers and sums."""
+    return 3 * 3 * 2 * sizes["hidden_size"] \
+        * sizes["moe_intermediate_size"] * routes
 
 
 def model_flops(sizes):

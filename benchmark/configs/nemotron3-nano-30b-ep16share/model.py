@@ -46,6 +46,24 @@ ROUTING_AGREEMENT = 0.96     # of a layer's routes: 0.9838-0.9939 / 0.874-0.937
 CLEAR_GAP = 0.1              # of the standard deviation of a layer's s
 CLEAR_DISAGREEMENT = 0.005   # of the clearly routed tokens: 0 / 0.025-0.291
 
+# The block kinds of the step's device time (``reduce_trace.kind_of``): a
+# pattern is searched in each ``Block.name`` scope of an operation's
+# ``op_name``, the outermost match decides.  A layer's one pre-norm goes
+# with its mixer: by the kept pattern ``MEMEM*EME`` the Mamba-2 mixers are
+# layers 0, 2, 4, 7, the expert layers 1, 3, 6, 8, attention layer 5.
+# The compiler's own ``ragged-dot-*`` kernels (``lax.ragged_dot`` in
+# ``ops.moe.grouped_matmul``, which only the experts call) carry no scope
+# and are matched by their name.
+BLOCKS = {
+    "mixer": [r"_layer\d+_mamba$", r"_layer[0247]_norm$"],
+    "experts": [r"_layer\d+_(experts|router)$", r"_layer[1368]_norm$",
+                r"^ragged-dot-"],
+    "attention": [r"_layer\d+_attn$", r"_layer5_norm$"],
+    "ffn": [r"_layer\d+_shared$"],
+    "head": [r"_embed$", r"_final_norm$",
+             r"^tiedsoftmaxcrossentropyloss\d+$"],
+}
+
 
 def _net(sizes):
     from mxnet_tpu.gluon.model_zoo import nemotron_h
@@ -452,6 +470,49 @@ def reference_loss_and_grads(params, tokens, labels, sizes, follow=None):
             grads = g if grads is None else \
                 {k: grads[k] + g[k] for k in g}
     return loss, grads
+
+
+def causal_attention_flops(sizes):
+    """The floating-point operations the three causal flash kernels
+    (``flash_stream_fwd``, ``flash_dq``, ``flash_dkv``) execute ON LIVE
+    PAIRS in one step of one row: every attention layer (``*``), all its
+    query heads, ``S (S + 1) / 2`` pairs a head, ``18 D`` a pair — ``4 D``
+    forward (scores, values), ``6 D`` in ``flash_dq`` (scores again, dP,
+    dq), ``8 D`` in ``flash_dkv`` (scores again, dP, dk, dv).  The tiles'
+    pairs above the diagonal are not counted, so a share of the peak made
+    from this cannot pass 100%."""
+    s = sizes["seq_len"]
+    return 18 * sizes["head_dim"] * s * (s + 1) // 2 \
+        * sizes["num_attention_heads"] * _kinds(sizes).count("*")
+
+
+def moe_product_flops(sizes, routes):
+    """The floating-point operations of the routed experts' products for
+    ``routes`` routes to held experts (a step's, every layer's): ``2 D F``
+    for each of the two products of a relu² expert (up, down), times 3 —
+    forward, input gradient, weight gradient.  Left out: the recomputing
+    backward's second forward, the slots a block holds beyond the routes,
+    the shared expert, the router, the gathers and sums."""
+    return 3 * 2 * 2 * sizes["hidden_size"] \
+        * sizes["moe_intermediate_size"] * routes
+
+
+def ssd_flops(sizes):
+    """The floating-point operations of the chunked scan's products in one
+    step of one row, every Mamba-2 layer (``M``), at the published chunk
+    Q: a chunk's scores ``C B^T`` once a group (``2 Q^2 N``), and a head's
+    in-chunk product with x (``2 Q^2 P``), its chunk state ``B^T x`` and
+    the carried state's output ``C h`` (``2 Q N P`` each); ``S / Q`` chunks;
+    times 3 — forward, and the two products of each one's gradient.  Left
+    out: the decays, the cumulative sums and the states passed from chunk
+    to chunk (element-wise), the states the backward makes again
+    (``ssd_states``), the skip ``D x``, the gate and the norm."""
+    s, q = sizes["seq_len"], min(sizes["chunk_size"], sizes["seq_len"])
+    heads, p_dim = sizes["mamba_num_heads"], sizes["mamba_head_dim"]
+    n_dim, groups = sizes["ssm_state_size"], sizes["n_groups"]
+    chunk = 2 * groups * q * q * n_dim \
+        + heads * (2 * q * q * p_dim + 4 * q * n_dim * p_dim)
+    return 3 * chunk * (s // q) * _kinds(sizes).count("M")
 
 
 def model_flops(sizes):

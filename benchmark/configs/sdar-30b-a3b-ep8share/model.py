@@ -41,6 +41,20 @@ ROUTING_AGREEMENT = 0.96     # of a layer's routes
 CLEAR_GAP = 0.1              # of the standard deviation of a layer's p
 CLEAR_DISAGREEMENT = 0.005   # of the clearly routed tokens
 
+# The block kinds of the step's device time (``reduce_trace.kind_of``): a
+# pattern is searched in each ``Block.name`` scope of an operation's
+# ``op_name``, the outermost match decides.  A pre-norm goes with the block
+# it feeds.
+# The compiler's own ``ragged-dot-*`` kernels (``lax.ragged_dot`` in
+# ``ops.moe.grouped_matmul``, which only the experts call) carry no scope
+# and are matched by their name.
+BLOCKS = {
+    "attention": [r"_layer\d+_attn(_norm)?$"],
+    "experts": [r"_layer\d+_(experts|router|ffn_norm)$",
+                r"^ragged-dot-"],
+    "head": [r"_embed$", r"_final_norm$", r"^blockdiffusionloss\d+$"],
+}
+
 
 def _net(sizes):
     from mxnet_tpu.gluon.model_zoo import sdar
@@ -453,6 +467,29 @@ def attention_flops(sizes):
     computes, so a share of the peak made from this cannot pass 100%."""
     return 18 * sizes["head_dim"] * live_pairs(sizes) \
         * sizes["num_attention_heads"] * sizes["num_hidden_layers"]
+
+
+def moe_product_flops(sizes, routes):
+    """The floating-point operations of the routed experts' products for
+    ``routes`` routes to held experts (a step's, every layer's): ``2 D F``
+    for each of the three products of a gated expert (gate, up, down),
+    times 3 — forward, input gradient, weight gradient.  Left out: the
+    recomputing backward's second forward, the slots a block holds beyond
+    the routes, the router, the gathers and sums."""
+    return 3 * 3 * 2 * sizes["hidden_size"] \
+        * sizes["moe_intermediate_size"] * routes
+
+
+def moe_combine_bytes(sizes, rows, routes):
+    """The bytes one call of ``moe_combine`` has to move, for ``rows`` rows
+    of ``[clean ; noised]`` tokens (N = 2 ``seq_len`` tokens a row) and
+    ``routes`` routes to held experts in the layer: one bfloat16 row of
+    ``D`` read for each of those routes and the (N, D) bfloat16 result
+    written.  Left out: the pass that packs the slots' table, the reads of
+    row 0 for the routes that have no slot here, the routes' indices and
+    gates."""
+    tokens = rows * 2 * sizes["seq_len"]
+    return 2 * sizes["hidden_size"] * (routes + tokens)
 
 
 def model_flops(sizes):

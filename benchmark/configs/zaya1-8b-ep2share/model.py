@@ -37,6 +37,21 @@ ROUTING_AGREEMENT = 0.96     # of a layer's tokens: 0.9855-0.9993 / 0.923
 CLEAR_GAP = 0.1              # of the rms deviation of p from 1 / experts
 CLEAR_DISAGREEMENT = 0.005   # of the clearly routed tokens: 0 / 0.0163
 
+# The block kinds of the step's device time (``reduce_trace.kind_of``): a
+# pattern is searched in each ``Block.name`` scope of an operation's
+# ``op_name``, the outermost match decides.  A pre-norm goes with the block
+# it feeds.
+# The compiler's own ``ragged-dot-*`` kernels (``lax.ragged_dot`` in
+# ``ops.moe.grouped_matmul``, the experts' products) carry no scope
+# and are matched by their name.
+BLOCKS = {
+    "attention": [r"_layer\d+_(cca|attn_norm)$"],
+    "experts": [r"_layer\d+_(experts|router|moe_norm)$",
+                r"^ragged-dot-"],
+    "head": [r"_embed$", r"_final_norm$",
+             r"^tiedsoftmaxcrossentropyloss\d+$"],
+}
+
 
 def _net(sizes):
     from mxnet_tpu.gluon.model_zoo import zaya1
@@ -401,6 +416,32 @@ def reference_loss_and_grads(params, tokens, labels, sizes, follow=None):
             grads = g if grads is None else \
                 {k: grads[k] + g[k] for k in g}
     return loss, grads
+
+
+def causal_attention_flops(sizes):
+    """The floating-point operations the three causal flash kernels
+    (``flash_stream_fwd``, ``flash_dq``, ``flash_dkv``) execute ON LIVE
+    PAIRS in one step of one row: every layer, all its query heads, ``S (S
+    + 1) / 2`` pairs a head, ``18 D`` a pair — ``4 D`` forward (scores,
+    values), ``6 D`` in ``flash_dq`` (scores again, dP, dq), ``8 D`` in
+    ``flash_dkv`` (scores again, dP, dk, dv).  The tiles' pairs above the
+    diagonal are not counted, so a share of the peak made from this cannot
+    pass 100%; CCA's convolutions, projections and norms are not kernels'
+    work and are left out."""
+    s = sizes["seq_len"]
+    return 18 * sizes["head_dim"] * s * (s + 1) // 2 \
+        * sizes["num_attention_heads"] * sizes["num_hidden_layers"]
+
+
+def moe_product_flops(sizes, routes):
+    """The floating-point operations of the routed experts' products for
+    ``routes`` routes to held experts (a step's, every layer's): ``2 D F``
+    for each of the three products of a gated expert (gate, up, down),
+    times 3 — forward, input gradient, weight gradient.  Left out: the
+    rows past the last group that a ragged product may visit, the router,
+    the sort, gathers and sums."""
+    return 3 * 3 * 2 * sizes["hidden_size"] \
+        * sizes["moe_intermediate_size"] * routes
 
 
 def model_flops(sizes):

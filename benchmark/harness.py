@@ -140,7 +140,9 @@ class Run:
             if keep:
                 os.makedirs(os.path.dirname(keep) or ".", exist_ok=True)
                 shutil.copyfile(path, keep)
-            self.trace = reduce_trace.reduce(reduce_trace.load(path))
+            self.trace = reduce_trace.reduce(
+                reduce_trace.load(path),
+                blocks=getattr(self.model, "BLOCKS", None))
         finally:
             shutil.rmtree(self._trace_dir, ignore_errors=True)
 

@@ -1,9 +1,10 @@
 #!/usr/bin/env python3
-"""Cut a small recording out of a traced chip run's ``.xplane.pb`` for
-``test_reduce_trace.py``: the device planes' ``XLA Ops`` and the
-benchmark's host spans inside ``--ms`` milliseconds from the start of the
-traced window, nothing else.  A tool for whoever renews the recording, not
-part of any run:
+"""Cut a small recording out of a traced chip run's ``.xplane.pb`` for the
+tests of ``reduce_trace``: the device planes' ``XLA Ops`` and the
+benchmark's host spans inside ``--ms`` milliseconds from ``--from-ms`` after
+the start of the traced window, nothing else — an event's metadata keeps its
+name and its ``op_name`` stat (``tf_op``), which ``blocks`` reads.  A tool
+for whoever renews a recording, not part of any run:
 
     python3 benchmark/tests/cut_xplane.py full.xplane.pb out.xplane.pb --ms 6
 
@@ -11,23 +12,12 @@ Needs the ``xplane_pb2`` that ships inside the installed tensorflow wheel
 (loaded by path, tensorflow itself is not imported).
 """
 import argparse
-import glob
-import importlib.util
 import os
 import sys
-import sysconfig
 
-
-def _xplane_pb2():
-    hits = glob.glob(os.path.join(
-        sysconfig.get_paths()["purelib"], "tensorflow", "tsl", "profiler",
-        "protobuf", "xplane_pb2.py"))
-    if not hits:
-        raise SystemExit("no xplane_pb2.py in the installed tensorflow")
-    spec = importlib.util.spec_from_file_location("xplane_pb2", hits[0])
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__)))))
+from benchmark import reduce_trace  # noqa: E402
 
 
 def main():
@@ -35,8 +25,11 @@ def main():
     ap.add_argument("source")
     ap.add_argument("target")
     ap.add_argument("--ms", type=float, default=6.0)
+    ap.add_argument("--from-ms", type=float, default=0.0)
     args = ap.parse_args()
-    pb = _xplane_pb2()
+    pb = reduce_trace.xplane_pb2()
+    if pb is None:
+        raise SystemExit("no xplane_pb2.py in the installed tensorflow")
     space = pb.XSpace()
     with open(args.source, "rb") as f:
         space.ParseFromString(f.read())
@@ -56,6 +49,7 @@ def main():
                     start_ps = line.timestamp_ns * 1000 + e.offset_ps
     if start_ps is None:
         raise SystemExit("no bench.traced_window span in the trace")
+    start_ps += int(args.from_ms * 1e9)
     end_ps = start_ps + int(args.ms * 1e9)
 
     out = pb.XSpace()
@@ -93,9 +87,15 @@ def main():
                     e.duration_ps,
                     end_ps - line.timestamp_ns * 1000 - e.offset_ps)
                 used.add(e.metadata_id)
+        stat_names = {k: m.name for k, m in plane.stat_metadata.items()}
         for key in used:
             kept.event_metadata[key].id = key
             kept.event_metadata[key].name = names[key]
+            for stat in plane.event_metadata[key].stats:
+                if stat_names.get(stat.metadata_id) == reduce_trace.OP_NAME_STAT:
+                    kept.event_metadata[key].stats.add().CopyFrom(stat)
+                    kept.stat_metadata[stat.metadata_id].CopyFrom(
+                        plane.stat_metadata[stat.metadata_id])
     with open(args.target, "wb") as f:
         f.write(out.SerializeToString())
     print("%s: %d bytes" % (args.target, os.path.getsize(args.target)))

@@ -189,9 +189,10 @@ def test_rehearsal_ends_in_the_contracts_line(spec, cell, trace):
 
 
 def test_dp4_zero_cell_is_data_alone():
-    """The four-chip cell of PERF.md's Open questions is one entry and one
-    traffic file in ``tests/data``: the driver builds the mesh, shards the
-    batch and the optimizer from them."""
+    """The four-chip cell is one entry and one traffic file (those in
+    ``tests/data``, and the same among the benchmark's cells and mixes):
+    the driver builds the mesh, shards the batch and the optimizer from
+    them."""
     done = _rehearse(MORE, "bert-base-mlm-train-dp4-zero", 0)
     assert done.returncode == 0, done.stderr[-2000:]
     setup = next(json.loads(l.split("] ", 1)[1])
@@ -203,8 +204,14 @@ def test_dp4_zero_cell_is_data_alone():
     assert setup["optimizer_shards"] == 4
     assert window["global_batch"] == 4 * _sizes(
         "bert-base-mlm", rehearsal=True)["train"]["batch_per_chip"]
-    assert not os.path.exists(os.path.join(
-        ROOT, "benchmark", "traffic", "train-resident-dp4-zero.json"))
+    # the benchmark took the cell as it stands here: the same entry among
+    # its cells, the same traffic file among its mixes
+    entry = next(w for w in _load(MORE)["workloads"]
+                 if w["name"] == "bert-base-mlm-train-dp4-zero")
+    assert entry in _load(SPEC)["workloads"]
+    traffic = entry["traffic"] + ".json"
+    assert _load(os.path.join(ROOT, "benchmark", "traffic", traffic)) \
+        == _load(os.path.join(HERE, "data", "traffic", traffic))
 
 
 def test_without_a_tpu_there_is_no_result():

@@ -89,12 +89,15 @@ def test_the_cut_agrees_with_the_benchmarks_entry():
     # at most a quarter of the cells ask for four chips
     cells = spec["workloads"]
     assert sum(w["chips"] == 4 for w in cells) <= max(1, len(cells) // 4)
+    # the cell stands once in every list the other state-space-free expert
+    # cell with causal attention (ZAYA1) stands in — every list of train
+    # cells, the moe lists among them — and in the scan's own
+    zaya = "zaya1-8b-train-resident"
     for metric in spec["end_to_end"] + spec["per_layer"]:
-        named = metric["name"].startswith(("train_", "moe_")) \
-            or metric["name"].endswith(".train") \
-            and not metric["name"].startswith("collective_")
-        if named:
-            assert metric["workloads"][-1] == CELL, metric["name"]
+        listed = metric.get("workloads", [w["name"] for w in cells])
+        if zaya in listed or metric["name"] in ("mixer_ms.train",
+                                                "ssd_roofline.train"):
+            assert listed.count(CELL) == 1, metric["name"]
 
 
 def test_parameters_from_shapes_fill_two_thirds_of_the_chip():
