@@ -104,8 +104,11 @@ class SparseExperts(HybridBlock):
     the sum over ALL the chosen where ``normalize_gates``, times
     ``gate_scale``.  The expert network is ``Wdown(act(Wgate x) * Wup x)``
     where ``gated`` and ``Wdown act(Wup x)`` otherwise, ``activation`` one
-    of ``silu``, ``gelu``, ``relu2``.  The defaults are one gated-SiLU
-    expert a token.
+    of ``silu``, ``gelu``, ``relu2``.  ``expert_groups``: the experts are
+    that many groups in order and a token chooses only from its
+    ``topk_groups`` best groups, a group's score the sum of its two
+    largest ``probs + balance_bias`` (``ops.moe.topk_route``).  The
+    defaults are one gated-SiLU expert a token.
 
     ``balance_bias`` takes no gradient (``grad_req="null"``) and starts at
     zero.  It is kept by the auxiliary-loss-free balancing rule (Wang et
@@ -128,13 +131,16 @@ class SparseExperts(HybridBlock):
     def __init__(self, units, hidden_size, num_experts, experts_held=None,
                  bias_update_rate=0.0, experts_per_token=1, gated=True,
                  activation="silu", normalize_gates=False, gate_scale=1.0,
-                 **kwargs):
+                 expert_groups=None, topk_groups=None, **kwargs):
         super().__init__(**kwargs)
         self._bias_update_rate = float(bias_update_rate)
         self.experts_per_token = int(experts_per_token)
         self._network = dict(k=self.experts_per_token,
                              normalize=bool(normalize_gates),
                              scale=float(gate_scale), activation=activation)
+        if expert_groups is not None:
+            self._network.update(groups=int(expert_groups),
+                                 topk_groups=int(topk_groups))
         first, end = experts_held or (0, num_experts)
         if not 0 <= first < end <= num_experts:
             raise ValueError("experts_held=%r is no slice of %d experts"

@@ -1,5 +1,7 @@
 """State-space layers as Gluon blocks: the Mamba-2 mixer (Dao & Gu,
-arXiv:2405.21060) round ``ops.ssm.ssd_chunk_scan``."""
+arXiv:2405.21060) round ``ops.ssm.ssd_chunk_scan``, and Kimi Delta
+Attention (Kimi Linear, arXiv:2510.26692) round
+``ops.delta_rule.delta_rule_chunked``."""
 from __future__ import annotations
 
 import numpy as onp
@@ -8,7 +10,7 @@ from .... import initializer
 from ...block import HybridBlock
 from ...nn import Dense, RMSNorm
 
-__all__ = ["Mamba2Mixer"]
+__all__ = ["Mamba2Mixer", "KimiDeltaAttention"]
 
 
 class _TimeStepBias(initializer.Initializer):
@@ -104,3 +106,59 @@ class Mamba2Mixer(HybridBlock):
             part(xbc, to_c, None).reshape(b, s, groups, state),
             d_skip, dt_bias, chunk=self._chunk)
         return self.out_proj(self.norm(y.reshape(b, s, inner), z))
+
+
+class KimiDeltaAttention(HybridBlock):
+    """Kimi Delta Attention over a (B, S, units) sequence ``u`` (the
+    layer's normed input): the gated delta rule with a decay a channel,
+    ``num_heads`` heads of ``head_dim`` (``ops.delta_rule``'s
+    ``kimi_delta_attention`` has the equations)::
+
+        [q | k | v | f | b | h] = u W_proj     4 H d + 2 H wide, no bias
+        q, k, v = silu(conv(q | k | v))        causal depthwise, no bias
+        g       = lower_bound sigmoid(exp(a_log) (f + dt_bias))
+        o       = delta rule(q / |q|, k / |k|, v, g, sigmoid(b))
+        out     = (RMSNorm_head(o) * sigmoid(h)) W_out
+
+    in chunks of ``chunk_size`` (float32 state from zero), the heads in
+    groups one after another (``ops.delta_rule._HEAD_GROUPS``).  The taps
+    start N(0, 1 / conv_kernel) as ``Mamba2Mixer``'s, ``a_log`` at ``log``
+    of 1..16 spread over the heads, ``dt_bias`` so that ``softplus`` of it
+    is log-uniform in [1e-3, 1e-1], floored at 1e-4 (the
+    flash-linear-attention ``kda`` initialisation); the two stay float32
+    under ``cast``."""
+
+    def __init__(self, units, num_heads, head_dim, conv_kernel=4,
+                 lower_bound=-5.0, chunk_size=64, epsilon=1e-6, **kwargs):
+        super().__init__(**kwargs)
+        width = num_heads * head_dim
+        self._core = dict(heads=num_heads, head_dim=head_dim,
+                          lower_bound=float(lower_bound), chunk=chunk_size,
+                          eps=epsilon)
+        with self.name_scope():
+            self.proj = Dense(4 * width + 2 * num_heads, flatten=False,
+                              use_bias=False, in_units=units, prefix="proj_")
+            self.conv_weight = self.params.get(
+                "conv_weight", shape=(3 * width, 1, conv_kernel),
+                init=initializer.Normal(conv_kernel ** -0.5))
+            self.a_log = self.params.get(
+                "a_log", shape=(num_heads,), init=initializer.Constant(
+                    onp.log(onp.linspace(1, 16, num_heads)).tolist()))
+            self.dt_bias = self.params.get(
+                "dt_bias", shape=(width,),
+                init=_TimeStepBias(1e-3, 1e-1, 1e-4))
+            self.out_norm_gamma = self.params.get(
+                "out_norm_gamma", shape=(width,), init="ones")
+            self.out = Dense(units, flatten=False, use_bias=False,
+                             in_units=width, prefix="out_")
+
+    def cast(self, dtype):
+        super().cast(dtype)
+        for p in (self.a_log, self.dt_bias):
+            p.cast("float32")
+
+    def hybrid_forward(self, F, x, conv_weight, a_log, dt_bias,
+                       out_norm_gamma):
+        return self.out(F.kimi_delta_attention(
+            self.proj(x), conv_weight, a_log, dt_bias, out_norm_gamma,
+            **self._core))

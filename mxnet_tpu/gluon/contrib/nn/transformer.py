@@ -24,9 +24,10 @@ import numpy as onp
 from .... import autograd, initializer, telemetry
 from ....ops.pallas_attention import bshd_layout_fits
 from ...block import HybridBlock
-from ...nn import Dense, Dropout, LayerNorm
+from ...nn import Dense, Dropout, LayerNorm, RMSNorm
 
-__all__ = ["MultiHeadAttention", "GroupedQueryAttention", "PositionwiseFFN",
+__all__ = ["MultiHeadAttention", "GroupedQueryAttention", "LatentAttention",
+           "PositionwiseFFN",
            "GatedFFN", "TransformerEncoderCell", "TransformerEncoder",
            "CompressedConvAttention", "MaskTileCount", "publish_mask_tiles"]
 
@@ -122,7 +123,9 @@ class GroupedQueryAttention(HybridBlock):
     rotation by ``rotary_embedding``'s keyword arguments instead
     (``rotary_dim``, ``theta``, ``rope_type="yarn"`` with ``factor``,
     ``original_length``, ``beta_fast``, ``beta_slow``,
-    ``attention_factor``): a partial or a scaled one.
+    ``attention_factor``; ``interleave=True`` pairs dimensions 2i and
+    2i + 1 instead of i and i + rotary_dim / 2): a partial, a scaled or an
+    interleaved one.
     ``head_gate``: every head's output is multiplied by ``sigmoid(x W_g)``
     before the output projection, ``W_g`` (units, num_heads) — the
     head-wise output gate of Qiu et al., arXiv:2505.06708.
@@ -192,6 +195,84 @@ class GroupedQueryAttention(HybridBlock):
         if self.gate is not None:
             gate = F.sigmoid(self.gate(x).astype("float32")).astype(x.dtype)
             out = F.broadcast_mul(out, gate.reshape(b, s, heads, 1))
+        return self.proj(out.reshape(b, s, -1))
+
+
+class LatentAttention(HybridBlock):
+    """Causal multi-head latent attention (DeepSeek-V2, arXiv:2405.04434)
+    with no query compression, over ``num_heads`` heads::
+
+        q          = x W_q                  H x [nope | rope]
+        [c | k_r]  = x W_kv_a               kv_rank + rope
+        [k_n | v]  = RMSNorm(c) W_kv_b      H x [nope | v_dim]
+        k          = [k_n | k_r]            k_r shared by every head
+        q, k       = RMSNorm(q), RMSNorm(k)  over a whole head, then rotary
+        out        = (softmax(q k^T / sqrt(nope + rope), causal) v
+                      * sigmoid(x W_g)) W_out  a gate a head
+
+    The q and k norms have one gain each that the heads share; the head
+    gate is ``GroupedQueryAttention``'s ``head_gate``.  ``rope``:
+    ``rotary_embedding``'s keyword arguments for the rope dimensions
+    (``interleave=True`` pairs 2i with 2i + 1, DeepSeek's
+    ``rope_interleave``).
+
+    The flash kernels take one width for q, k and v: v is padded with
+    zeros to the width of q and k and the pad cut off the output — exact,
+    at (nope + rope) / v_dim times the value products."""
+
+    def __init__(self, units, num_heads, kv_rank, nope_dim, rope_dim, v_dim,
+                 rope=None, epsilon=1e-6, **kwargs):
+        super().__init__(**kwargs)
+        self._dims = (num_heads, kv_rank, nope_dim, rope_dim, v_dim)
+        self._rope = dict(rope or {})
+        self._epsilon = epsilon
+        qk = nope_dim + rope_dim
+        with self.name_scope():
+            def dense(width, in_units, prefix):
+                return Dense(width, flatten=False, use_bias=False,
+                             in_units=in_units, prefix=prefix)
+            self.q = dense(num_heads * qk, units, "q_")
+            self.kv_a = dense(kv_rank + rope_dim, units, "kv_a_")
+            self.kv_norm = RMSNorm(epsilon=epsilon, in_channels=kv_rank,
+                                   prefix="kv_norm_")
+            self.kv_b = dense(num_heads * (nope_dim + v_dim), kv_rank,
+                              "kv_b_")
+            self.gate = dense(num_heads, units, "gate_")
+            self.proj = dense(units, num_heads * v_dim, "out_")
+            self.q_norm_gamma = self.params.get(
+                "q_norm_gamma", shape=(qk,), init="ones")
+            self.k_norm_gamma = self.params.get(
+                "k_norm_gamma", shape=(qk,), init="ones")
+
+    def hybrid_forward(self, F, x, q_norm_gamma, k_norm_gamma):
+        heads, rank, nope, rope, v_dim = self._dims
+        b, s = x.shape[0], x.shape[1]
+        q = self.q(x).reshape(b, s, heads, nope + rope)
+        kv_a = self.kv_a(x)
+        kv = self.kv_b(self.kv_norm(F.slice_axis(kv_a, axis=-1, begin=0,
+                                                 end=rank)))
+        kv = kv.reshape(b, s, heads, nope + v_dim)
+        k_rope = F.slice_axis(kv_a, axis=-1, begin=rank, end=None).reshape(
+            b, s, 1, rope)
+        k = F.concat(F.slice_axis(kv, axis=-1, begin=0, end=nope),
+                     F.broadcast_to(k_rope, (b, s, heads, rope)), dim=-1)
+
+        def heads_first(t, gamma):          # -> (B, H, S, [rope | nope])
+            t = F.RMSNorm(t, gamma, eps=self._epsilon).transpose(axes=(0, 2, 1, 3))
+            return F.concat(F.rotary_embedding(
+                F.slice_axis(t, axis=-1, begin=nope, end=None), **self._rope),
+                F.slice_axis(t, axis=-1, begin=0, end=nope), dim=-1)
+
+        v = F.slice_axis(kv, axis=-1, begin=nope, end=None)
+        v = F.concat(v, F.zeros_like(F.slice_axis(
+            q, axis=-1, begin=0, end=nope + rope - v_dim)), dim=-1)
+        out = F.flash_attention(heads_first(q, q_norm_gamma),
+                                heads_first(k, k_norm_gamma),
+                                v.transpose(axes=(0, 2, 1, 3)), causal=True)
+        out = F.slice_axis(out.transpose(axes=(0, 2, 1, 3)), axis=-1,
+                           begin=0, end=v_dim)
+        gate = F.sigmoid(self.gate(x).astype("float32")).astype(x.dtype)
+        out = F.broadcast_mul(out, gate.reshape(b, s, heads, 1))
         return self.proj(out.reshape(b, s, -1))
 
 

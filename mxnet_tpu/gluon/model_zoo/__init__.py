@@ -17,8 +17,10 @@ from .sdar import (SDARModel, sdar, block_diffusion_mask,  # noqa: F401
                    block_diffusion_row)
 from . import laguna as _laguna  # noqa: F401
 from .laguna import LagunaModel, laguna  # noqa: F401
+from . import ling as _ling  # noqa: F401
+from .ling import LingModel, ling  # noqa: F401
 
 __all__ = ["vision", "bert", "BERTModel", "bert_base", "bert_small",
            "zaya", "ZAYA1Model", "zaya1", "NemotronHModel", "nemotron_h",
            "SDARModel", "sdar", "block_diffusion_mask",
-           "block_diffusion_row", "LagunaModel", "laguna"]
+           "block_diffusion_row", "LagunaModel", "laguna", "LingModel", "ling"]

@@ -19,5 +19,6 @@ from . import quantization  # noqa: F401
 from . import pallas_attention  # noqa: F401
 from . import moe           # noqa: F401
 from . import ssm           # noqa: F401
+from . import delta_rule    # noqa: F401
 
 __all__ = ["OpDef", "register", "get_op", "list_ops", "alias"]

@@ -53,18 +53,51 @@ def top1_route(probs, scores=None):
     return expert, gate
 
 
-def topk_route(probs, k, scores=None, normalize=False):
+def topk_route(probs, k, scores=None, normalize=False, groups=None,
+               topk_groups=None):
     """The ``k`` largest of ``scores`` ((N, E); ``probs`` itself where none
     are given; ties go to the lower index), gated by ``probs``: returns
     (expert (N, k) int32, gate (N, k)).  ``normalize`` divides a token's
     gates by their sum over ALL k chosen (+ 1e-20), held here or not.  The
-    scores take no gradient (a choice has none)."""
+    scores take no gradient (a choice has none).
+
+    ``groups``: the E experts are that many groups of E / groups in order,
+    a group's score the sum of its two largest ``scores``; only the
+    ``topk_groups`` best groups are chosen from (ties to the lower group):
+    the group-limited routing of DeepSeek-V3 (arXiv:2412.19437, ``n_group``
+    / ``topk_group``).  Counted at trace time: ``moe.route.grouped`` and a
+    ``moe.route`` event."""
     pick = probs if scores is None else scores
+    if groups is not None:
+        pick = _group_limited(pick, k, groups, topk_groups)
     expert = lax.top_k(pick, k)[1].astype(jnp.int32)
     gate = jnp.take_along_axis(probs, expert, axis=1)
     if normalize:
         gate = gate / (jnp.sum(gate, axis=-1, keepdims=True) + 1e-20)
     return expert, gate
+
+
+def _group_limited(scores, k, groups, topk_groups):
+    """``scores`` (N, E) with the experts outside each token's
+    ``topk_groups`` best groups set to -inf (``topk_route``)."""
+    from .. import telemetry
+
+    n, experts = scores.shape
+    if experts % groups or not 0 < topk_groups <= groups \
+            or k > topk_groups * (experts // groups):
+        raise ValueError("%d experts in %d groups, %s kept, give no %d "
+                         "routes" % (experts, groups, topk_groups, k))
+    # trace time: once a traced shape, as ssm.scan.*
+    telemetry.inc("moe.route.grouped")
+    telemetry.event("moe.route", "grouped", experts=int(experts),
+                    groups=int(groups), topk_groups=int(topk_groups),
+                    k=int(k))
+    by_group = scores.reshape(n, groups, experts // groups)
+    score = lax.top_k(by_group, 2)[0].sum(-1)                 # (N, groups)
+    kept = lax.top_k(score, topk_groups)[1]                   # (N, kept)
+    keep = (kept[:, :, None] == jnp.arange(groups)).any(1)    # (N, groups)
+    return jnp.where(keep[:, :, None], by_group, -jnp.inf).reshape(
+        n, experts)
 
 
 def group_by_expert(expert, first, held):
@@ -493,16 +526,17 @@ def mlp_experts(w_up, w_down, activation):
           aliases=("sparse_experts",))
 def sparse_experts(data, probs, w_gate, w_up, w_down, bias, first: int = 0,
                    k: int = 1, normalize: bool = False, scale: float = 1.0,
-                   activation: str = "silu"):
+                   activation: str = "silu", groups=None, topk_groups=None):
     """Dropless top-``k`` experts, the slice ``first..first+held-1`` of
     them held here (held = ``w_up.shape[0]``): ``data`` (..., S, D),
     ``probs`` (..., S, E) the router's scores of ALL E experts (a softmax,
     or sigmoids), ``bias`` (E,) its balancing bias.  A token goes to the
     ``k`` largest of ``probs + bias`` and is gated by ``probs`` of each —
     the bias moves the choice, never the gate — divided by their sum over
-    the k chosen where ``normalize``, times ``scale``.  With ``w_gate``
-    the experts are gated, ``Wd(act(Wg x) * Wu x)``; with ``w_gate=None``
-    they are ``Wd act(Wu x)``; ``activation`` is any ``act_type`` of the
+    the k chosen where ``normalize``, times ``scale``; with ``groups``
+    only from the ``topk_groups`` best groups of experts (``topk_route``,
+    so ``k > 1`` or ``normalize``).  With ``w_gate`` the experts are
+    gated, ``Wd(act(Wg x) * Wu x)``; with ``w_gate=None`` they are ``Wd act(Wu x)``; ``activation`` is any ``act_type`` of the
     ``Activation`` operator (``silu``, ``relu2``, ...).  Returns the held experts' part of the layer's output, the
     ROUTES to each of the E experts (fp32 counts: k a token), the rows
     each held expert computed, and each token's experts, (..., S) int32
@@ -513,9 +547,13 @@ def sparse_experts(data, probs, w_gate, w_up, w_down, bias, first: int = 0,
     pick = lax.stop_gradient(probs.astype(jnp.float32)) \
         + bias.astype(jnp.float32)
     if flat_routes(k, normalize):
+        if groups is not None:
+            raise ValueError("routing in groups of experts takes k > 1 "
+                             "or normalized gates (topk_route)")
         expert, gate = top1_route(probs, pick)
     else:
-        expert, gate = topk_route(probs, k, pick, normalize)
+        expert, gate = topk_route(probs, k, pick, normalize, groups,
+                                  topk_groups)
     if scale != 1.0:
         gate = gate * scale
     act = functools.partial(_activation, act_type=activation)

@@ -960,12 +960,14 @@ def rotary_embedding(data, positions=None, rotary_dim: int = 0,
                      theta: float = 10000.0, rope_type: str = "default",
                      factor: float = 1.0, original_length: int = 0,
                      beta_fast: float = 32.0, beta_slow: float = 1.0,
-                     attention_factor: float = 1.0):
+                     attention_factor: float = 1.0, interleave: bool = False):
     """Rotary position embedding on the first ``rotary_dim`` of the D
     dimensions of a (B, H, S, D) tensor (0: all of them), the rest passed
     through.  Rotate-half pairing: dimension i turns with i + rotary_dim/2
     by the angle ``t * freq_i``, ``freq_i = theta ** (-2 i / rotary_dim)``;
-    t is the token's
+    ``interleave``: dimension 2i turns with 2i + 1 by the same angle
+    (DeepSeek-V2's ``rope_interleave``), written back in place; t is the
+    token's
     place in the row, from 0, or where ``positions`` (B, S) is given the
     token's own position id (ids may repeat: a row that holds a sequence
     twice).
@@ -998,6 +1000,11 @@ def rotary_embedding(data, positions=None, rotary_dim: int = 0,
     if attention_factor != 1.0:
         cos, sin = cos * attention_factor, sin * attention_factor
     x32 = data.astype(jnp.float32)
+    if interleave:
+        x1, x2, rest = x32[..., 0:r:2], x32[..., 1:r:2], x32[..., r:]
+        pairs = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
+        return jnp.concatenate([pairs.reshape(x1.shape[:-1] + (r,)), rest],
+                               axis=-1).astype(data.dtype)
     x1, x2, rest = x32[..., :half], x32[..., half:r], x32[..., r:]
     return jnp.concatenate(
         [x1 * cos - x2 * sin, x2 * cos + x1 * sin, rest],

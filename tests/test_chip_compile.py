@@ -1095,3 +1095,74 @@ def test_laguna_layer_train_step_compiles_to_the_kernels_expected(
     temp = compiled.memory_analysis().temp_size_in_bytes / 1e9
     print("temporaries of the Laguna %s layer's step: %.3f GB" % (kind, temp))
     assert temp <= temp_gb, temp
+
+
+# published layer -> (the step's kernels by name, the ceiling on its
+# temporaries in GB: 1.05 x the sandbox compile's)
+_LING_LAYERS = {
+    "delta_sparse": (2, {"moe_blocks_fwd": 6, "moe_blocks_dx": 3,
+                         "moe_blocks_dw": 3}, 1.220 * 1.05),
+    "latent_sparse": (5, {"flash_stream_fwd": 1, "flash_dq": 1,
+                          "flash_dkv": 1, "moe_blocks_fwd": 6,
+                          "moe_blocks_dx": 3, "moe_blocks_dw": 3},
+                      1.521 * 1.05),
+}
+
+
+@pytest.mark.parametrize("kind", list(_LING_LAYERS))
+def test_ling_layer_train_step_compiles(one_chip, monkeypatch, kind):
+    """One sparse layer of the Ling cell at the published widths — Kimi
+    Delta Attention (32 heads of 128, the chunked rule at chunks of 64) or
+    latent attention (heads of 128 + 64 under an interleaved rotary, v
+    padded to 192 for the causal streamed kernels) beside 8 of 512
+    gated-SiLU experts of width 768 held, 8 routes a token from 4 of 8
+    groups, the shared expert — under a small untied head, one row of
+    4,096 tokens, bf16 with ``Adam(multi_precision=True)``: the
+    ``DataParallelStep`` program compiles for the described chip; the delta
+    rule is no kernel; the blocks' and the rule's scopes are in the
+    instructions' ``op_name``s; the temporaries stay where they were (the
+    delta layer's heads in four groups: 1.22 GB, against 2.84 in one)."""
+    import mxnet_tpu as mx
+    from mxnet_tpu import context, gluon, parallel
+    from mxnet_tpu import random as mx_random
+
+    monkeypatch.setattr(context, "on_tpu", lambda *a: True)
+    index, kernels, temp_gb = _LING_LAYERS[kind]
+    net = gluon.model_zoo.ling(layers=(index,), vocab_size=2048,
+                               experts_held=(0, 8))
+    net.initialize(mx.init.Zero())
+    net.cast("bfloat16")
+    step = parallel.DataParallelStep(
+        net, gluon.loss.TiedSoftmaxCrossEntropyLoss(block_rows=2048),
+        mx.optimizer.Adam(learning_rate=1e-4, multi_precision=True))
+
+    def spec(v):
+        return jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=one_chip)
+
+    state = jax.tree_util.tree_map(
+        spec, [[p._data._data for p in step._params], step._opt_states])
+    carries = [jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
+               jax.ShapeDtypeStruct((len(step._trainable),), jnp.float32,
+                                    sharding=one_chip),
+               spec(mx_random.next_key())]
+    tokens = jax.ShapeDtypeStruct((1, 4096), jnp.int32, sharding=one_chip)
+    compiled = step._build().lower(*state, *carries, tokens,
+                                   tokens).compile()
+    text = compiled.as_text()
+    names = collections.Counter(_kernel_names(text))
+    ragged = {n: c for n, c in names.items() if n.startswith("ragged-dot")}
+    assert {n: c for n, c in names.items() if n not in ragged} == kernels
+    blocks = ["layer%d_router" % index, "layer%d_experts" % index,
+              "layer%d_shared_gate" % index]
+    if kind == "delta_sparse":
+        blocks += ["layer2_kda_proj", "layer2_kda_out", "kda.heads",
+                   "kda.inputs",
+                   "delta.intra", "delta.states", "delta.output"]
+    else:
+        blocks += ["layer5_mla_q", "layer5_mla_kv_b", "layer5_mla_gate",
+                   "layer5_mla/flash_stream_fwd", "layer5_mla/flash_dq"]
+    for block in blocks:
+        assert re.search(r"[/_]%s/" % re.escape(block), text), block
+    temp = compiled.memory_analysis().temp_size_in_bytes / 1e9
+    print("temporaries of the Ling %s layer's step: %.3f GB" % (kind, temp))
+    assert temp <= temp_gb, temp

@@ -612,6 +612,11 @@ COVERED_ELSEWHERE = {
     # the tile count of a mask given as data (PR 33): tests/test_sdar.py,
     # against the kernels' own summary at the planned blocks
     "_contrib_attention_mask_tiles", "attention_mask_tiles",
+    # the chunked gated delta rule and the Kimi Delta Attention layer's
+    # core: tests/test_ling.py, value and every gradient against
+    # the token-by-token recurrence, every head grouping alike
+    "_contrib_delta_rule_chunked", "delta_rule_chunked",
+    "_contrib_kimi_delta_attention", "kimi_delta_attention",
     # exercised by dedicated test files: test_operator.py (NN core),
     # test_rnn.py (RNN), test_gluon.py (layers), test_symbol.py /
     # test_module.py (output ops), test_amp.py (amp_cast), test_loss.py,
