@@ -1,6 +1,9 @@
 """The gated delta rule with a decay a channel (Kimi Delta Attention, Kimi
 Linear arXiv:2510.26692; Gated DeltaNet arXiv:2412.06464), computed in
-chunks, plain ``jax.numpy`` / ``lax``.
+chunks.  On a TPU a shape ``pallas_delta.delta_dispatch`` accepts runs as
+the Pallas kernels of ``ops/pallas_delta.py``, forward and backward; every
+other call runs the chunked form here, plain ``jax.numpy`` / ``lax``,
+which is also the kernels' reference.
 
 The recurrence, one head (state ``S`` (d_k, d_v) float32, ``alpha_t =
 exp(g_t)`` a vector of d_k, ``beta_t`` a scalar in (0, 1))::
@@ -33,15 +36,18 @@ nothing overflows as long as every ``g >= -80 / 16``; queries before
 sub-chunk b are never formed.  An underflow to zero happens only where the
 exact product is below float32's range.
 
-Differentiation is JAX's own: the chunk states (chunks x H x d_k x d_v
-float32, 134 MB a layer at 4,096 tokens and 32 heads of 128) and the C x C
-matrices are its residuals.  ``kimi_delta_attention`` runs the rule a group
-of heads at a time under ``jax.checkpoint``, so they live only through a
-group's backward and nothing of them is kept across a step.  The three
+The chunked form is differentiated by JAX: the chunk states (chunks x H x
+d_k x d_v float32, 134 MB a layer at 4,096 tokens and 32 heads of 128)
+and the C x C matrices are its residuals.  ``kimi_delta_attention`` runs
+the rule a group of heads at a time under ``jax.checkpoint``, so they
+live only through a group's backward and nothing of them is kept across
+a step.  The three
 phases are ``jax.named_scope``s (``delta.intra``,
-``delta.states``, ``delta.output``) in every device operation's
-``op_name``.  A traced shape bumps ``linear_attention.scan.chunked`` and
-writes a ``linear_attention.scan`` event, once, as ``ssm.scan.*``.
+``delta.states``, ``delta.output``; the kernels' calls ``delta.fwd`` and
+``delta.bwd``) in every device operation's ``op_name``.  A traced call
+bumps ``linear_attention.scan.kernel`` or ``linear_attention.scan.chunked``
+and writes a ``linear_attention.scan`` event with its ``path``, once, as
+``ssm.scan.*``.
 """
 from __future__ import annotations
 
@@ -51,6 +57,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from .. import context as _context
 from .registry import register
 
 __all__ = ["delta_rule_chunked", "delta_rule_recurrent", "kimi_delta_attention"]
@@ -158,8 +165,12 @@ def delta_rule_chunked(q, k, v, g, beta, chunk: int = 64, scale=None,
     is — and cut again; one shorter than a chunk is a chunk of its length
     rounded up to whole sub-chunks.  ``chunk`` is a multiple of 16.
     ``lower_bound``: the bound the caller's gate keeps ``g`` above,
-    checked against the sub-chunk's room and written into the event."""
+    checked against the sub-chunk's room and written into the event.  On
+    a TPU a shape ``pallas_delta.delta_dispatch`` accepts (heads of 128
+    lanes) runs as Pallas kernels; the result, the dtype contract and the
+    gradients are the same."""
     from .. import telemetry
+    from . import pallas_delta
 
     s, h, dk = q.shape[1:]
     chunk = min(int(chunk), s + -s % _SUBCHUNK)
@@ -170,19 +181,25 @@ def delta_rule_chunked(q, k, v, g, beta, chunk: int = 64, scale=None,
         raise ValueError("a sub-chunk of %d steps at g >= %g spans more "
                          "than exp(%g)" % (_SUBCHUNK, lower_bound, _EXP_ROOM))
     scale = float(dk ** -0.5 if scale is None else scale)
+    pad = -s % chunk
+    path = pallas_delta.delta_dispatch(s + pad, chunk, h, dk, q.dtype,
+                                       on_tpu=_context.on_tpu(q),
+                                       v_dim=v.shape[-1])
     # trace time: once a traced shape, as ssm.scan.*
-    telemetry.inc("linear_attention.scan.chunked")
-    telemetry.event("linear_attention.scan", "chunked", seq_len=int(s),
-                    chunk=chunk, subchunk=_SUBCHUNK, heads=int(h),
-                    head_dim=int(dk),
+    telemetry.inc("linear_attention.scan.%s" % path)
+    telemetry.event("linear_attention.scan", path, path=path,
+                    seq_len=int(s), chunk=chunk, subchunk=_SUBCHUNK,
+                    heads=int(h), head_dim=int(dk),
                     lower_bound=None if lower_bound is None
                     else float(lower_bound))
-    pad = -s % chunk
     if pad:
         def padded(t):
             return jnp.pad(t, [(0, 0), (0, pad)] + [(0, 0)] * (t.ndim - 2))
         q, k, v, g, beta = (padded(t) for t in (q, k, v, g, beta))
-    out = _chunked(q, k, v, g, beta, chunk, scale)
+    if path == "kernel":
+        out = pallas_delta.delta_rule_kernels(q, k, v, g, beta, chunk, scale)
+    else:
+        out = _chunked(q, k, v, g, beta, chunk, scale)
     return out[:, :s].astype(v.dtype)
 
 

@@ -1101,12 +1101,38 @@ def test_laguna_layer_train_step_compiles_to_the_kernels_expected(
 # temporaries in GB: 1.05 x the sandbox compile's)
 _LING_LAYERS = {
     "delta_sparse": (2, {"moe_blocks_fwd": 6, "moe_blocks_dx": 3,
-                         "moe_blocks_dw": 3}, 1.220 * 1.05),
+                         "moe_blocks_dw": 3, "delta_fwd": 2,
+                         "delta_bwd": 1}, 1.215 * 1.05),
     "latent_sparse": (5, {"flash_stream_fwd": 1, "flash_dq": 1,
                           "flash_dkv": 1, "moe_blocks_fwd": 6,
                           "moe_blocks_dx": 3, "moe_blocks_dw": 3},
                       1.521 * 1.05),
 }
+
+
+@pytest.mark.parametrize("chunk", [16, 64, 128])
+def test_delta_kernels_compile_at_the_chunks_the_dispatch_takes(one_chip,
+                                                                chunk):
+    """The delta rule's two kernels (``ops/pallas_delta.py``), forward and
+    backward, two heads a grid step, at the chunks the dispatch gives them:
+    16 (a row shorter than a chunk), 64 (the cell's) and 128 (where two
+    heads' systems no longer share a lane tile and are inverted one by
+    one): the described chip's compiler takes both."""
+    from mxnet_tpu.ops import pallas_delta as PD
+
+    b, s, h, d = 1, 256, 2, 128
+    assert PD.delta_dispatch(s, chunk, h, d, on_tpu=True) == "kernel"
+
+    def spec(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    def grads(*a):
+        return jax.grad(lambda *x: jnp.sum(PD.delta_rule_kernels(
+            *x, chunk, d ** -0.5)), argnums=(0, 1, 2, 3, 4))(*a)
+
+    text = _compile(grads, *([spec(b, s, h, d)] * 4 + [spec(b, s, h)]))
+    assert collections.Counter(_kernel_names(text)) == {"delta_fwd": 1,
+                                                        "delta_bwd": 1}
 
 
 @pytest.mark.parametrize("kind", list(_LING_LAYERS))
@@ -1119,9 +1145,11 @@ def test_ling_layer_train_step_compiles(one_chip, monkeypatch, kind):
     groups, the shared expert — under a small untied head, one row of
     4,096 tokens, bf16 with ``Adam(multi_precision=True)``: the
     ``DataParallelStep`` program compiles for the described chip; the delta
-    rule is no kernel; the blocks' and the rule's scopes are in the
-    instructions' ``op_name``s; the temporaries stay where they were (the
-    delta layer's heads in four groups: 1.22 GB, against 2.84 in one)."""
+    rule is two kernels (``delta_fwd`` in the forward and again in the
+    group's recomputation, ``delta_bwd``); the blocks' and the rule's
+    scopes are in the instructions' ``op_name``s; the temporaries stay
+    where they were (the delta layer's heads in four groups: 1.215 GB with
+    the kernels, 1.220 with the chunked form, 2.84 in one group)."""
     import mxnet_tpu as mx
     from mxnet_tpu import context, gluon, parallel
     from mxnet_tpu import random as mx_random
@@ -1157,7 +1185,7 @@ def test_ling_layer_train_step_compiles(one_chip, monkeypatch, kind):
     if kind == "delta_sparse":
         blocks += ["layer2_kda_proj", "layer2_kda_out", "kda.heads",
                    "kda.inputs",
-                   "delta.intra", "delta.states", "delta.output"]
+                   "delta.fwd", "delta.bwd"]
     else:
         blocks += ["layer5_mla_q", "layer5_mla_kv_b", "layer5_mla_gate",
                    "layer5_mla/flash_stream_fwd", "layer5_mla/flash_dq"]

@@ -467,7 +467,8 @@ def test_trains_through_data_parallel_step(dtype):
 
 def test_blocks_put_the_scan_apart_from_its_layer():
     """``BLOCKS`` gives the rule's scopes to ``delta_scan`` though they lie
-    inside the delta layer's block, everything else of the layer to
+    inside the delta layer's block — the chunked form's and the kernels',
+    forward and backward —, everything else of the layer to
     ``linear_attention``, the latent layer to ``attention``."""
     import re
     from benchmark import reduce_trace
@@ -486,6 +487,17 @@ def test_blocks_put_the_scan_apart_from_its_layer():
         "jit(step_fn)/transpose(jvp(forward))/lingmodel0_layer2/"
         "lingmodel0_layer2_kda/while/body/closed_call/checkpoint/"
         "jvp(kda.inputs)/mul": "linear_attention",
+        # the kernels on a TPU, as the lowered step names them: the
+        # forward, the group's recomputation of it and the backward
+        "jit(step_fn)/jvp(forward)/lingmodel0_layer2/lingmodel0_layer2_kda/"
+        "while/body/closed_call/delta.fwd/delta_fwd/pallas_call":
+            "delta_scan",
+        "jit(step_fn)/transpose(jvp(forward))/lingmodel0_layer2/"
+        "lingmodel0_layer2_kda/while/body/closed_call/checkpoint/"
+        "rematted_computation/delta.fwd/delta_fwd/pallas_call": "delta_scan",
+        "jit(step_fn)/transpose(jvp(forward))/lingmodel0_layer2/"
+        "lingmodel0_layer2_kda/while/body/closed_call/checkpoint/"
+        "delta.bwd/delta_bwd/pallas_call": "delta_scan",
         "jit(step_fn)/jvp(forward)/lingmodel0_layer2/lingmodel0_layer2_kda/"
         "kda.heads/concatenate": "linear_attention",
         "jit(step_fn)/jvp(forward)/lingmodel0_layer2/lingmodel0_layer2_kda/"

@@ -443,12 +443,12 @@ def test_every_pallas_call_passes_a_stable_name():
     sites = _pallas_call_names()
     assert len(sites) >= 19
     # the scan's three kernels (PR 31), the three under a mask given as
-    # data (PR 33) and the experts' four (PR 36, PR 38), by the names a
-    # trace shows
+    # data (PR 33), the experts' four (PR 36, PR 38) and the delta rule's
+    # two, by the names a trace shows
     assert {"ssd_fwd", "ssd_states", "ssd_bwd", "flash_masked_fwd",
             "flash_masked_dq", "flash_masked_dkv", "moe_blocks_fwd",
-            "moe_blocks_dx", "moe_blocks_dw", "moe_combine"} \
-        <= {n for _, n in sites}
+            "moe_blocks_dx", "moe_blocks_dw", "moe_combine", "delta_fwd",
+            "delta_bwd"} <= {n for _, n in sites}
     bad = [(where, name) for where, name in sites
            if not (isinstance(name, str)
                    and re.fullmatch(r"[a-z][a-z0-9]*(_[a-z0-9]+)+", name))]
@@ -458,9 +458,10 @@ def test_every_pallas_call_passes_a_stable_name():
 def test_a_window_adds_no_kernel_site():
     """The window's lower bound (PR 37) rides in the masked family as a
     third integer a query: three masked sites, as before it; 23 in all
-    since the experts' combine (PR 38)."""
+    since the experts' combine (PR 38), 25 with the delta rule's forward
+    and backward."""
     names = [name for _, name in _pallas_call_names()]
-    assert len(names) == 23
+    assert len(names) == 25
     assert sorted(n for n in names if n.startswith("flash_masked")) == [
         "flash_masked_dkv", "flash_masked_dq", "flash_masked_fwd"]
 
